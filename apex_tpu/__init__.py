@@ -46,10 +46,7 @@ __version__ = "0.1.0"
 
 # Light-weight eager imports only; heavy subpackages are imported lazily so
 # `import apex_tpu` stays cheap (the reference's `apex/__init__.py` likewise
-# defers contrib imports behind availability probes).  _compat must come
-# first: it grafts jax.shard_map / jax.lax.axis_size / jax.lax.pcast onto
-# pinned jax releases that predate them, which everything else assumes.
-from apex_tpu import _compat  # noqa: F401
+# defers contrib imports behind availability probes).
 from apex_tpu import parallel_state  # noqa: F401
 
 _LAZY_SUBMODULES = (

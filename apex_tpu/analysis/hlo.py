@@ -449,7 +449,9 @@ def parse_computations(hlo_text: str):
     ``computations`` maps computation name → list of instruction dicts
     in program order; each record carries ``name``, ``shape`` (result
     shape string), ``opcode``, ``operands`` (list of operand shape
-    strings, as printed inline at the use site), ``operand_names``
+    strings: as printed inline at the use site, or — the installed XLA
+    prints bare ``%name`` operands — looked up from the operand's own
+    definition earlier in the computation), ``operand_names``
     (the ``%name`` tokens of the operand list — the def-use edges the
     memory live-range walk follows), ``op_name`` (the jax source path
     from metadata — named scopes land here), ``called`` (referenced
@@ -460,6 +462,7 @@ def parse_computations(hlo_text: str):
     comps: Dict[str, List[dict]] = {}
     entry = None
     current: Optional[List[dict]] = None
+    defs: Dict[str, str] = {}  # instruction name → shape, this computation
     for raw in hlo_text.splitlines():
         line = raw.strip()
         if not line:
@@ -468,6 +471,7 @@ def parse_computations(hlo_text: str):
         if hm and " = " not in line.split("{", 1)[0]:
             name = hm.group(2)
             current = comps.setdefault(name, [])
+            defs = {}
             if hm.group(1):
                 entry = name
             continue
@@ -483,15 +487,21 @@ def parse_computations(hlo_text: str):
         operand_text = line[open_paren + 1:close - 1]
         attrs = line[close:]
         onm = _OP_NAME_RE.search(attrs)
+        operand_names = re.findall(r"%([\w.-]+)", operand_text)
+        operand_shapes = _SHAPE_IN_TEXT_RE.findall(operand_text)
+        if not operand_shapes:
+            operand_shapes = [
+                sh
+                for op in operand_names
+                for sh in _SHAPE_IN_TEXT_RE.findall(defs.get(op, ""))
+            ]
+        defs[name] = shape
         current.append({
             "name": name,
             "shape": shape,
             "opcode": opcode,
-            "operands": [
-                f"{dt}[{dims}]"
-                for dt, dims in _SHAPE_IN_TEXT_RE.findall(operand_text)
-            ],
-            "operand_names": re.findall(r"%([\w.-]+)", operand_text),
+            "operands": [f"{dt}[{dims}]" for dt, dims in operand_shapes],
+            "operand_names": operand_names,
             "op_name": onm.group(1) if onm else "",
             "called": _CALLED_COMP_RE.findall(attrs),
             "attrs": attrs,

@@ -480,12 +480,9 @@ def roofline(
 
 
 def _local_device_kind() -> str:
-    try:
-        import jax
+    import jax
 
-        return jax.devices()[0].device_kind
-    except Exception:
-        return ""
+    return jax.devices()[0].device_kind
 
 
 def _local_vmem_budget(device_kind: Optional[str]) -> int:

@@ -153,6 +153,14 @@ def estimate_peak(hlo_text: str, top_k: int = 10) -> dict:
     board publish, the artifact sections, and the shard-report
     renderer all read the same compiled program — one parse serves
     them all.
+
+    Known to over-read on TPU HLO: against XLA's own
+    ``compiled.memory_analysis()`` for v5e (compile-only, ISSUE 21) it
+    says 16.10 GiB where XLA says 14.43 for the BERT-Large LAMB step at
+    batch 128, and 6.59 vs 5.23 GiB for the serving decode step.  The
+    scan frees a buffer at its last textual use and knows nothing of
+    XLA's in-place reuse inside fusions, so treat the figure as an upper
+    estimate; nothing arms ``hbm_budget`` by default.
     """
     est = _estimate_peak_cached(hlo_text, top_k)
     # shallow-copy the mutable tiers so one consumer's edits can't

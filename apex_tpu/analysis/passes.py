@@ -22,7 +22,7 @@ import dataclasses
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
-import jax.core as jax_core
+import jax.extend.core as jax_core
 import jax.numpy as jnp
 
 from apex_tpu.analysis import hlo as hlo_lib
@@ -50,7 +50,7 @@ class StepGraph:
     prove and stays quiet.
     """
 
-    jaxpr: Optional[Any] = None          # jax.core.ClosedJaxpr
+    jaxpr: Optional[Any] = None          # jax.extend.core.ClosedJaxpr
     hlo_text: Optional[str] = None
     policy: Optional[Any] = None         # amp.Policy / dtype-carrying obj
     donated: Optional[int] = None        # expected donated leaf count
@@ -124,7 +124,8 @@ def _eqn_path(eqn) -> str:
 #: primitives whose execution leaves the device for the host python
 #: runtime — one round-trip per step (or per scan iteration)
 _CALLBACK_PRIMITIVES = frozenset({
-    "debug_callback",   # jax.debug.print / jax.debug.callback
+    "debug_print",      # jax.debug.print
+    "debug_callback",   # jax.debug.callback
     "pure_callback",
     "io_callback",
     "callback",

@@ -16,7 +16,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 
 __all__ = ["BatchNorm2d_NHWC"]
@@ -86,7 +85,7 @@ class BatchNorm2d_NHWC(nn.Module):
                         f"bn_group={self.bn_group} needs axis "
                         f"{self.axis_name!r} bound (run inside shard_map)"
                     )
-                world = _compat.axis_size(self.axis_name)
+                world = jax.lax.axis_size(self.axis_name)
                 if world != self.bn_group:
                     raise ValueError(
                         f"bn_group ({self.bn_group}) must equal the "

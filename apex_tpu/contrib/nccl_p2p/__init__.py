@@ -13,7 +13,6 @@ import jax.numpy as jnp
 
 __all__ = ["left_right_halo_exchange", "halo_exchange_1d"]
 
-from apex_tpu import _compat
 from apex_tpu.contrib.peer_memory import halo_exchange_1d
 
 
@@ -26,7 +25,7 @@ def left_right_halo_exchange(
     (left_input_halo, right_input_halo) — what the left/right neighbors
     sent this rank (zeros at the global edges).
     """
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     to_left = [(i, (i - 1) % world) for i in range(world)]
     to_right = [(i, (i + 1) % world) for i in range(world)]

@@ -22,7 +22,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu import _compat
 from apex_tpu.ops.attention import flash_attention as mha  # noqa: F401
 from apex_tpu.ops.layer_norm import (  # noqa: F401
     fused_layer_norm_affine as layer_norm,
@@ -81,7 +80,7 @@ col_to_row = scatter_cols_gather_rows
 def scatter(x, axis_name: str, dim: int):
     """≙ dap.py :: scatter — enter the DAP region: keep this rank's slice
     of ``dim`` (use on a replicated tensor inside shard_map)."""
-    n = _compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     dim = dim % x.ndim
     if x.shape[dim] % n:

@@ -32,7 +32,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu import _compat
 from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.layer_norm import fused_layer_norm_affine
 
@@ -332,7 +331,7 @@ class OuterProductMean(nn.Module):
     @nn.compact
     def __call__(self, m, out_dim: int):
         s_total = m.shape[0] * (
-            _compat.axis_size(self.axis_name)
+            jax.lax.axis_size(self.axis_name)
             if self.axis_name is not None
             else 1
         )

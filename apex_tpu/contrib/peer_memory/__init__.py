@@ -18,7 +18,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 
 __all__ = ["halo_exchange_1d", "PeerHaloExchanger1d", "PeerMemoryPool"]
@@ -32,7 +31,7 @@ def halo_exchange_1d(x, halo: int, *, axis: int = 1, axis_name: str = "dp"):
     shape grows by ``2*halo`` along ``axis``.  Edge ranks receive zeros
     (zero padding, matching conv zero-pad semantics at the true borders).
     """
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
 
     top = jax.lax.slice_in_dim(x, 0, halo, axis=axis)

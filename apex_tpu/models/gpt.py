@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 from apex_tpu.models.bert import _LayerNorm
 from apex_tpu.ops.attention import flash_attention
@@ -44,7 +43,7 @@ _CP = ps.CONTEXT_PARALLEL_AXIS
 def _cp_world(cfg) -> int:
     """Bound cp-axis size when context parallelism is configured, else 1."""
     if cfg.context_parallel and ps.axis_is_bound(_CP):
-        return _compat.axis_size(_CP)
+        return jax.lax.axis_size(_CP)
     return 1
 
 
@@ -58,7 +57,7 @@ def _cp_shard_rows(table, cfg, s_local):
     if cfg.context_parallel == "ring_zigzag":
         from apex_tpu.transformer.context_parallel import zigzag_shard
 
-        cp = _compat.axis_size(_CP)
+        cp = jax.lax.axis_size(_CP)
         # chunk math runs on the GLOBAL SEQUENCE (cp·s_local rows), not
         # the full table — a learned-position table longer than the
         # sequence (max_seq_len > S) must be trimmed first
@@ -446,7 +445,7 @@ def gpt_lm_loss_cp(
     )
     # no SP under cp, so the copy_to boundary always applies at tp > 1
     logits = _tied_vocab_logits(params, model, h, sp_gathered=False)
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     valid = jnp.ones(
         (input_ids_local.shape[0], input_ids_local.shape[1]), jnp.float32

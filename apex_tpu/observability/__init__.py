@@ -158,6 +158,7 @@ from apex_tpu.observability.meter import (  # noqa: F401
     GoodputAccountant,
     StepMeter,
     categorize_op,
+    UnknownDeviceError,
     chip_peak_flops,
     peak_flops_for,
     peak_hbm_bandwidth_for,
@@ -279,6 +280,7 @@ __all__ = [
     "GoodputAccountant",
     "BUCKETS",
     "categorize_op",
+    "UnknownDeviceError",
     "chip_peak_flops",
     "peak_flops_for",
     "peak_hbm_bandwidth_for",

@@ -252,7 +252,9 @@ def attribute_cost_model(
     example's ``compute_grads`` + ``apply_update`` — and their costs
     merge into one step model).  Peaks default from the
     :mod:`~apex_tpu.observability.meter` table for ``device_kind``
-    (default: the first visible device)."""
+    (default: the first visible device); a kind the table does not
+    know raises :class:`~apex_tpu.observability.meter.UnknownDeviceError`
+    — off the chip, name the chip being modelled."""
     from apex_tpu.analysis import hlo as H
 
     if isinstance(hlo_texts, str):
@@ -260,7 +262,7 @@ def attribute_cost_model(
     if device_kind is None:
         import jax
 
-        device_kind = getattr(jax.devices()[0], "device_kind", "")
+        device_kind = jax.devices()[0].device_kind
     peak_flops = peak_flops or peak_flops_for(device_kind)
     hbm_bw = hbm_bw or peak_hbm_bandwidth_for(device_kind)
     ici_bw = ici_bw or peak_ici_bandwidth_for(device_kind)

@@ -48,10 +48,10 @@ def pallas_interpret() -> bool:
 
 
 # --------------------------------------------------------------------------
-# Trace-time path triage (ADVICE r4: the pallas and jnp paths draw
-# DIFFERENT dropout streams by documented contract, so when a shape or
-# backend change silently flips the dispatch, reproducibility debugging
-# needs to see which path a call actually took).
+# Trace-time path triage: the pallas and jnp paths draw DIFFERENT
+# dropout streams by documented contract, so when a shape or backend
+# change silently flips the dispatch, reproducibility debugging needs to
+# see which path a call actually took.
 # --------------------------------------------------------------------------
 
 _PATH_LOG: dict = {}

@@ -55,9 +55,9 @@ from jax.experimental.pallas import tpu as pltpu
 from apex_tpu.ops._dispatch import pallas_interpret
 from apex_tpu.ops.pallas import introspect
 from apex_tpu.ops.pallas.flash_attention import (
-    _CompilerParams,
     _LANES,
     MASK_VALUE,
+    _dot_precision,
 )
 # the ONE rotate_half (pure jnp split/concat — lowers fine inside the
 # kernel body), so serving can never drift from the training rotation
@@ -181,7 +181,7 @@ def kernel_specs(
 def _decode_kernel(
     pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, cos_ref, sin_ref,
     o_ref, acc_ref, m_ref, l_ref,
-    *, scale, page, np_, rope,
+    *, scale, page, np_, rope, prec,
 ):
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -213,7 +213,7 @@ def _decode_kernel(
         # head-batched mat-vec on the VPU: s[h, t] = q[h, :] . k[h, t, :]
         s = jax.lax.dot_general(
             q[:, None, :], k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=prec,
         )[:, 0, :] * scale  # (H, page)
         pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * page
         s = jnp.where(pos < length, s, MASK_VALUE)
@@ -227,7 +227,7 @@ def _decode_kernel(
         # o[h, d] += p[h, :] . v[h, :, d]
         pv = jax.lax.dot_general(
             p[:, None, :], v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=prec,
         )[:, 0, :]  # (H, D)
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -290,6 +290,12 @@ def paged_decode_fwd(
         raise ValueError("k_scale and v_scale must be given together")
     if has_rope != (rope_sin is not None):
         raise ValueError("rope_cos and rope_sin must be given together")
+    if has_rope and not (rope_cos.shape == rope_sin.shape == (b, d)):
+        # a half-width (B, D/2) table compiles silently at D=128
+        raise ValueError(
+            f"rope_cos/rope_sin must be (B, D) = {(b, d)}, got "
+            f"{rope_cos.shape} and {rope_sin.shape}"
+        )
 
     # q as (B, 1, H, D) so its block carries an (H, D) tile per program
     plan = _decode_plan(
@@ -305,6 +311,10 @@ def paged_decode_fwd(
     kernel = functools.partial(
         _decode_entry, scale=scale, page=page, np_=np_,
         rope=has_rope, has_scales=has_scales, has_rope=has_rope,
+        # f32 queries get true-f32 products like the flash kernel's: at
+        # DEFAULT the compiled kernel sat 3.5e-3 abs off the f32
+        # reference on v5e (PR 21); the bf16 serving path is unchanged
+        prec=_dot_precision(q.dtype),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -317,7 +327,7 @@ def paged_decode_fwd(
         kernel,
         grid_spec=grid_spec,
         out_shape=plan["out_shape"][0],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=plan["dimension_semantics"],
         ),
         interpret=pallas_interpret(),

@@ -70,12 +70,9 @@ def reset() -> None:
 
 
 def _backend_kind() -> str:
-    try:
-        import jax
+    import jax
 
-        return jax.devices()[0].device_kind
-    except Exception:
-        return ""
+    return jax.devices()[0].device_kind
 
 
 def load(path: Optional[str] = None) -> dict:

@@ -60,7 +60,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 
 __all__ = [
@@ -281,7 +280,7 @@ def reduce_scatter_flat(
     inside ``shard_map``.
     """
     check_wire(wire)
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     n = flat.shape[0]
     if n == 0 or world == 1:
         return flat.astype(jnp.float32)
@@ -337,7 +336,7 @@ def all_gather_flat(
     replicated).  Call inside ``shard_map``.
     """
     check_wire(wire)
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     s = shard.shape[0]
     if s == 0 or world == 1:
         return shard.astype(jnp.float32)
@@ -385,7 +384,7 @@ def all_gather_rows(
     (``comm/fleet/*``) like any other wire traffic.
     """
     check_wire(wire)
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     flat = row.reshape(-1).astype(jnp.float32)
     n = flat.shape[0]
     _publish_stats(
@@ -427,7 +426,7 @@ def sync_gradients(
     noise-sensitive — always ride the exact psum.
     """
     check_wire(wire)
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     post = 1.0
     if gradient_average:
         post = (

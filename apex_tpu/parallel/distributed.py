@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 from apex_tpu.parallel import comm
 
@@ -50,7 +49,7 @@ def all_reduce_gradients(
     .sync_gradients` layers wire formats and chunking on the same
     semantics.
     """
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
 
     def f(g):
         gf = g
@@ -201,8 +200,15 @@ class DistributedDataParallel:
                 l, g = self.value_and_grad(params, *mb)
             return jax.tree_util.tree_map(jnp.add, acc, g), l
 
+        # local (no_sync) grads are dp-varying; the scan carry must start
+        # with the same vma type
         zeros = jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, jnp.result_type(p)), params
+            lambda p: jax.lax.pcast(
+                jnp.zeros(p.shape, jnp.result_type(p)),
+                self.axis_name,
+                to="varying",
+            ),
+            params,
         )
         acc, losses = jax.lax.scan(micro, zeros, batch)
         if self.gradient_average:
@@ -234,7 +240,7 @@ class DistributedDataParallel:
         """
         if self._wants_manual_sync():
             params_v = jax.tree_util.tree_map(
-                lambda p: _compat.pcast(p, self.axis_name, to="varying"),
+                lambda p: jax.lax.pcast(p, self.axis_name, to="varying"),
                 params,
             )
             loss, grads = jax.value_and_grad(self.loss_fn)(params_v, *batch)
@@ -243,15 +249,8 @@ class DistributedDataParallel:
                 loss = jax.lax.pmean(loss, self.axis_name)
             return loss, grads
         loss, grads = jax.value_and_grad(self.loss_fn)(params, *batch)
-        if not _compat.HAS_VMA:
-            # pre-vma jax inserts no implicit psum in the transpose of
-            # replicated params — reduce by hand to keep the fast-path
-            # contract (grads arrive dp-summed) identical across releases
-            grads = jax.tree_util.tree_map(
-                lambda g: jax.lax.psum(g, self.axis_name), grads
-            )
         if self.gradient_average:
-            world = _compat.axis_size(self.axis_name)
+            world = jax.lax.axis_size(self.axis_name)
             grads = jax.tree_util.tree_map(lambda g: g / world, grads)
             loss = jax.lax.pmean(loss, self.axis_name)
         return loss, grads
@@ -287,11 +286,17 @@ class DistributedDataParallel:
             if accum_steps == 1
             else P(None, self.axis_name)  # (K, per-rank batch, ...)
         )
-        smapped = _compat.shard_map(
+        # The engine's bucketed sync (a quantized wire, or chunks) ends in
+        # an all_gather: replicated by construction but typed varying.
+        # Only there is the static replication check off; the exact-psum
+        # paths (fast, accumulation, predivide) stay checked.
+        bucketed = self.wire != "f32" or comm.chunks_requested(self.chunks)
+        smapped = jax.shard_map(
             _step,
             mesh=mesh,
             in_specs=(P(), P(), batch_spec),
             out_specs=(P(), P(), P()),
+            check_vma=not bucketed,
         )
         return jax.jit(smapped)
 
@@ -310,7 +315,7 @@ class Reducer:
         return params  # replicated by construction
 
     def reduce(self, tree, average: bool = True):
-        world = _compat.axis_size(self.axis_name)
+        world = jax.lax.axis_size(self.axis_name)
 
         def f(x):
             s = jax.lax.psum(x, self.axis_name)

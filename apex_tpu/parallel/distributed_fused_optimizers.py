@@ -60,7 +60,6 @@ import numpy as np
 from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 from apex_tpu._tree_util import to_f32
 from apex_tpu.parallel import comm
@@ -217,7 +216,7 @@ class _DistributedFusedBase:
 
         ``grads`` must be *local* per-shard gradients (not yet reduced):
         under ``check_vma=True`` shard_map, mark params varying first
-        (``_compat.pcast(p, axis, to='varying')``) or jax's autodiff will
+        (``jax.lax.pcast(p, axis, to='varying')``) or jax's autodiff will
         have already all-reduced them and the reduce-scatter here would
         double-count.
 
@@ -278,7 +277,7 @@ class _DistributedFusedBase:
             lambda x: P(self.axis_name) if getattr(x, "ndim", 0) == 1 else P(),
             self._init_state(self.spec),
         )
-        smapped = _compat.shard_map(
+        smapped = jax.shard_map(
             _step,
             mesh=mesh,
             in_specs=(P(), state_spec, P(self.axis_name)),

@@ -41,7 +41,6 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from apex_tpu import _compat
 
 __all__ = [
     "DATA_PARALLEL_AXIS",
@@ -313,7 +312,7 @@ def axis_is_bound(axis: str) -> bool:
     """Whether ``axis`` is a bound mesh axis here (inside shard_map) —
     regardless of its size (a bound size-1 axis is still bound)."""
     try:
-        _compat.axis_size(axis)
+        jax.lax.axis_size(axis)
         return True
     except (NameError, KeyError):
         return False
@@ -328,7 +327,7 @@ def bound_axis_size(axis: str) -> int:
     here".
     """
     try:
-        return _compat.axis_size(axis)
+        return jax.lax.axis_size(axis)
     except (NameError, KeyError):
         return 1
 

@@ -557,6 +557,7 @@ class TrainStep:
             flops_per_step=obs.transformer_train_flops(
                 self.n_params(), tokens
             ),
+            devices=self.mesh.devices.flat,
         )
         goodput = obs.GoodputAccountant()
         self.goodput = goodput

@@ -37,7 +37,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 
 __all__ = [
@@ -187,7 +186,7 @@ def ring_attention(
         )
     if layout != "contiguous":
         raise ValueError(f"unknown ring layout {layout!r}")
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     b, h, s_local, d = q.shape
     qf = q.astype(jnp.float32)
@@ -304,7 +303,7 @@ def _ring_attention_zigzag(q, k, v, bias, scale, dropout_p, dropout_rng,
     computes ~2 half-blocks per hop instead of the contiguous layout's
     worst-rank full block, halving causal ring wall on real hardware.
     """
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     b, h, s_local, d = q.shape
     if s_local % 2:
@@ -435,7 +434,7 @@ def ulysses_attention(
     """
     from apex_tpu.ops.attention import flash_attention
 
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     h = q.shape[1]
     if h % world:
         raise ValueError(

@@ -35,7 +35,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 
 __all__ = [
@@ -129,7 +128,7 @@ def moe_dispatch_combine(router_probs, top_k, capacity, stats_axis=None,
         mean_prob = jax.lax.pmean(mean_prob, stats_axis)
         aux = e * jnp.sum(frac_routed * mean_prob)
         scale = (
-            1.0 / _compat.axis_size(stats_axis)
+            1.0 / jax.lax.axis_size(stats_axis)
             if stats_grad_scale is None
             else stats_grad_scale
         )
@@ -196,7 +195,7 @@ def sync_moe_gradients(grads, axis: str = ps.EXPERT_PARALLEL_AXIS,
     from jax.tree_util import DictKey, tree_map_with_path
 
     reduce_ = jax.lax.pmean if average else jax.lax.psum
-    world = _compat.axis_size(axis)
+    world = jax.lax.axis_size(axis)
 
     def maybe_reduce(path, g):
         for k in path:

@@ -25,7 +25,6 @@ from typing import Any
 
 import jax
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 
 __all__ = [
@@ -43,7 +42,7 @@ _PP = ps.PIPELINE_PARALLEL_AXIS
 
 
 def _shift(tree: Any, delta: int, axis_name: str, cyclic: bool = False):
-    n = _compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if cyclic:
         perm = [(i, (i + delta) % n) for i in range(n)]
     else:

@@ -42,7 +42,6 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 from apex_tpu.transformer.pipeline_parallel import p2p_communication as p2p
 
@@ -191,7 +190,7 @@ def forward_backward_pipelining_without_interleaving(
     lfn = loss_fn if loss_takes_params else (lambda p, y, t: loss_fn(y, t))
 
     def pipeline_loss(params):
-        pp = _compat.axis_size(axis_name)
+        pp = jax.lax.axis_size(axis_name)
         stage = jax.lax.axis_index(axis_name)
         is_first = stage == 0
         is_last = stage == pp - 1
@@ -328,7 +327,7 @@ def forward_backward_pipelining_1f1b(
         )
         return losses, None
 
-    pp = _compat.axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     is_first = stage == 0
     is_last = stage == pp - 1
@@ -598,7 +597,7 @@ def forward_backward_pipelining_interleaved_1f1b(
         )
         return losses, None
 
-    pp = _compat.axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     if nm % pp != 0:
         raise ValueError(
             f"interleaved schedule requires num_microbatches ({nm}) to "
@@ -896,7 +895,7 @@ def forward_backward_pipelining_with_interleaving(
     lfn = loss_fn if loss_takes_params else (lambda p, y, t: loss_fn(y, t))
 
     def pipeline_loss(params):
-        pp = _compat.axis_size(axis_name)
+        pp = jax.lax.axis_size(axis_name)
         if nm % pp != 0:
             raise ValueError(
                 f"interleaved schedule requires num_microbatches ({nm}) to "

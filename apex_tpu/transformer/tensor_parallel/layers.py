@@ -33,7 +33,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 from apex_tpu.transformer.tensor_parallel.mappings import (
     copy_to_tensor_model_parallel_region,
@@ -57,7 +56,7 @@ _TP = ps.TENSOR_PARALLEL_AXIS
 
 def _tp_world(axis_name: str) -> int:
     try:
-        return _compat.axis_size(axis_name)
+        return jax.lax.axis_size(axis_name)
     except (NameError, KeyError):
         # Axis not bound.  Legitimate when running unsharded (no mesh, or
         # tp==1 outside shard_map); an error when the registry says the

@@ -30,7 +30,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from apex_tpu import _compat
 from apex_tpu import parallel_state as ps
 
 __all__ = [
@@ -63,7 +62,7 @@ def _reduce(x, axis_name=_TP):
 
 
 def _split_along_last_dim(x, axis_name=_TP):
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     chunk = ps.divide(x.shape[-1], world)
     return jax.lax.dynamic_slice_in_dim(x, rank * chunk, chunk, axis=x.ndim - 1)
@@ -74,7 +73,7 @@ def _gather_along_last_dim(x, axis_name=_TP):
 
 
 def _split_along_first_dim(x, axis_name=_TP):
-    world = _compat.axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     chunk = ps.divide(x.shape[0], world)
     return jax.lax.dynamic_slice_in_dim(x, rank * chunk, chunk, axis=0)

@@ -11,7 +11,7 @@ orbax-backed checkpoint/resume (:mod:`apex_tpu.checkpoint`).
     # resume from the newest checkpoint:
     python examples/bert/pretrain_bert.py --ckpt-dir /tmp/ckpt --resume
     # tiny smoke on CPU:
-    APEX_TPU_FORCE_CPU=1 python examples/bert/pretrain_bert.py --tiny
+    JAX_PLATFORMS=cpu python examples/bert/pretrain_bert.py --tiny
 """
 
 import os
@@ -24,11 +24,6 @@ sys.path.insert(
 import argparse
 import tempfile
 import time
-
-if os.environ.get("APEX_TPU_FORCE_CPU"):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 import jax
 import jax.numpy as jnp
@@ -48,9 +43,10 @@ from apex_tpu.data import (
 from apex_tpu.models import BertConfig, BertForPreTraining, bert_pretrain_loss
 from apex_tpu.optimizers import fused_lamb
 from apex_tpu.parallel import all_reduce_gradients
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=24)
     p.add_argument("--batch", type=int, default=32, help="global batch")
@@ -75,7 +71,7 @@ def parse_args():
         help="fixed-K masked-position MLM head (the reference recipe's "
         "masked_lm_* input; 0 = dense labels over all positions)",
     )
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
 def corpus_path(args, cfg) -> str:
@@ -120,8 +116,13 @@ def batch_stream(args, cfg, start_step=0):
         yield jax.tree_util.tree_map(lambda *xs: np.stack(xs), *chunk)
 
 
-def main():
-    args = parse_args()
+def main(argv=None):
+    """Run the recipe; returns the per-step losses, the params, the mesh
+    and the global parameter norm before and after (the initial params
+    are donated to the first step) for callers that check the run —
+    ``chip_smoke.py`` — rather than read its prints."""
+    args = parse_args(argv)
+    enable_compile_cache()
     cfg = (
         BertConfig(
             vocab_size=2048, hidden_size=64, num_layers=2, num_heads=4,
@@ -172,6 +173,12 @@ def main():
         f"BERT {n_params/1e6:.0f}M params | dp={dp} | "
         f"native input pipeline: {_native.available()}"
     )
+    param_norm = jax.jit(
+        lambda tree: jnp.sqrt(
+            sum(jnp.vdot(x, x) for x in jax.tree_util.tree_leaves(tree))
+        )
+    )
+    norm_start = float(param_norm(params))
 
     def one_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(
@@ -235,15 +242,17 @@ def main():
     )
     t0 = time.perf_counter()
     losses = jnp.zeros((1,))
+    all_losses = []
     with DevicePrefetcher(
         batch_stream(args, cfg, start_step), depth=2
     ) as prefetch:
         for c in range(n_chunks):
             batches = next(prefetch)
             params, opt_state, losses = step(params, opt_state, batches)
+            all_losses += [float(l) for l in losses]
             print(
                 f"chunk {c}: loss "
-                f"{' '.join(f'{float(l):.3f}' for l in losses)}"
+                f"{' '.join(f'{l:.3f}' for l in all_losses[-args.chunk:])}"
             )
             if mgr is not None:
                 done = start_step + (c + 1) * args.chunk
@@ -265,6 +274,15 @@ def main():
             f"{steps_done} steps in {dt:.1f}s = "
             f"{dt / steps_done * 1e3:.0f} ms/step"
         )
+    norm_end = float(param_norm(params))
+    print(f"param norm {norm_start:.4f} -> {norm_end:.4f}")
+    return {
+        "losses": all_losses,
+        "params": params,
+        "mesh": mesh,
+        "param_norm_start": norm_start,
+        "param_norm_end": norm_end,
+    }
 
 
 if __name__ == "__main__":

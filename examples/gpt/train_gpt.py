@@ -10,7 +10,7 @@ parallelism, over the packed-corpus input pipeline.
     python examples/gpt/train_gpt.py --tp 4 --sequence-parallel
     python examples/gpt/train_gpt.py --num-experts 8
     # tiny CPU smoke:
-    APEX_TPU_FORCE_CPU=1 python examples/gpt/train_gpt.py --tiny
+    JAX_PLATFORMS=cpu python examples/gpt/train_gpt.py --tiny
 """
 
 import os
@@ -23,11 +23,6 @@ sys.path.insert(
 import argparse
 import tempfile
 import time
-
-if os.environ.get("APEX_TPU_FORCE_CPU"):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 import jax
 import jax.numpy as jnp
@@ -51,9 +46,10 @@ from apex_tpu.transformer.moe import sync_moe_gradients
 from apex_tpu.transformer.tensor_parallel import (
     allreduce_sequence_parallel_gradients,
 )
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=16)
     p.add_argument("--batch", type=int, default=8, help="global batch")
@@ -71,7 +67,7 @@ def parse_args():
     p.add_argument("--num-experts", type=int, default=0)
     p.add_argument("--data", default=None, help="packed uint16 token file")
     p.add_argument("--tiny", action="store_true")
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
 def corpus(args, vocab) -> str:
@@ -87,8 +83,11 @@ def corpus(args, vocab) -> str:
     )
 
 
-def main():
-    args = parse_args()
+def main(argv=None):
+    """Run the recipe; returns the per-step losses, the params and the
+    mesh for callers (``chip_smoke.py``) that check the run."""
+    args = parse_args(argv)
+    enable_compile_cache()
     cp = args.cp if args.context_parallel else 1
     cfg = GptConfig(
         **(
@@ -229,19 +228,22 @@ def main():
     )
     t0 = time.perf_counter()
     losses = jnp.zeros((1,))
+    all_losses = []
     for c in range(args.steps // args.chunk):
         params, opt_state, losses = step_fn(
             params, opt_state, next_chunk()
         )
+        all_losses += [float(l) for l in losses]
         print(
             f"chunk {c}: loss "
-            f"{' '.join(f'{float(l):.3f}' for l in losses)}"
+            f"{' '.join(f'{l:.3f}' for l in all_losses[-args.chunk:])}"
         )
     jax.block_until_ready(losses)
     dt = time.perf_counter() - t0
     done = (args.steps // args.chunk) * args.chunk
     if done:
         print(f"{done} steps in {dt:.1f}s = {dt/done*1e3:.0f} ms/step")
+    return {"losses": all_losses, "params": params, "mesh": mesh}
 
 
 if __name__ == "__main__":

@@ -13,7 +13,7 @@ data (shape-identical), like the reference's ``--prof`` dry runs.
     python examples/imagenet/main_amp.py --opt-level O2 --sync-bn \
         --batch-size 64 --steps 30
 
-On CPU: APEX_TPU_FORCE_CPU=1 and an optional
+On CPU: ``JAX_PLATFORMS=cpu`` and an optional
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` for 8-way dp.
 """
 
@@ -29,11 +29,6 @@ import queue
 import threading
 import time
 
-if os.environ.get("APEX_TPU_FORCE_CPU"):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -43,6 +38,7 @@ from jax.sharding import PartitionSpec as P
 from apex_tpu import amp, parallel_state as ps
 from apex_tpu.models import resnet50
 from apex_tpu.parallel import all_reduce_gradients
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def parse_args():
@@ -96,6 +92,7 @@ def synthetic_loader(args, steps):
 
 def main():
     args = parse_args()
+    enable_compile_cache()
     mesh = ps.initialize_model_parallel()  # all devices on the dp axis
     dp = ps.get_data_parallel_world_size()
     if args.batch_size % dp:

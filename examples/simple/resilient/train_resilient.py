@@ -227,6 +227,7 @@ def main():
     meter = obs.StepMeter(
         tokens_per_step=rows,
         flops_per_step=obs.transformer_train_flops(n_params, rows),
+        devices=t["mesh"].devices.flat,
     )
     goodput = obs.GoodputAccountant()
     reporter = None

@@ -52,6 +52,14 @@ setup(
     packages=find_packages(include=["apex_tpu", "apex_tpu.*"]),
     package_data={"apex_tpu._native": ["host_ops.cpp"]},
     python_requires=">=3.10",
-    install_requires=["jax", "flax", "optax", "numpy", "einops"],
+    # The one installation the code is written for and tested on (jax /
+    # jaxlib 0.9.0, libtpu 0.0.34, flax 0.12.3, optax 0.2.6).  It leans
+    # on what 0.9 ships natively — jax.shard_map with vma typing,
+    # lax.axis_size / lax.pcast, jax.extend.core, pltpu.CompilerParams —
+    # and carries no shims for older releases.
+    install_requires=[
+        "jax>=0.9,<0.10", "flax>=0.12,<0.13", "optax>=0.2.6",
+        "numpy", "einops",
+    ],
     cmdclass={"build_native": build_native},
 )

@@ -119,9 +119,7 @@ def test_reduction_upcast_idiom_is_exempt():
 
 
 def test_planted_f64_is_caught():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: x * jnp.float64(3.0)
         )(jnp.zeros((4,), jnp.float64))
@@ -693,6 +691,9 @@ class TestReshardPlan:
             fn = jax.jit(jax.shard_map(
                 lambda p, b: ddp.value_and_grad(p, b), mesh=mesh,
                 in_specs=(P(), P("dp")), out_specs=(P(), P()),
+                # the quantized all-gather is replicated by construction
+                # but typed varying
+                check_vma=(wire == "f32"),
             ))
             plan = ddp.collective_plan(params, world)
             report = analysis.check(
@@ -730,6 +731,53 @@ class TestReshardPlan:
             rules=("reshard",), name="zero/int8",
         )
         assert report.findings == [], report.render()
+
+    # the dp collectives XLA:TPU compiled the demo trainer's ZeRO update
+    # to at dp=2 x tp=2 (v5e 2x2, PR 21): no reduce-scatter, no
+    # all-gather — each is an all-reduce of the full 65,920-element
+    # buffer, the first carrying the loss pmean along
+    _TPU_ZERO_HLO = """
+ENTRY %main {
+  %g = f32[65920]{0} parameter(0)
+  %l = f32[] parameter(1)
+  %all-reduce.2 = (f32[65920]{0:T(1024)S(1)}, f32[]{:T(128)}) all-reduce(%g, %l), channel_id=2, replica_groups={{0,2},{1,3}}, use_global_device_ids=true, to_apply=%add
+  %s = f32[65920]{0} get-tuple-element(%all-reduce.2), index=0
+  ROOT %all-reduce.1 = f32[65920]{0:T(1024)S(1)} all-reduce(%s), channel_id=3, replica_groups={{0,2},{1,3}}, use_global_device_ids=true, to_apply=%max
+}
+"""
+
+    def _zero_plan(self, n=65920):
+        from apex_tpu.parallel import comm
+
+        return {"mesh": _DPTP, "collectives": comm.zero_plan(n, 2, "dp")}
+
+    def test_zero_plan_accepts_the_all_reduce_form(self):
+        report = analysis.lint_hlo(
+            self._TPU_ZERO_HLO, expect_plan=self._zero_plan(),
+            rules=("reshard",),
+        )
+        assert report.findings == [], report.render()
+
+    def test_all_reduce_form_keeps_the_byte_bound(self):
+        """The credit is the planned ops' own allowance: a buffer twice
+        the planned size busts it, and a reduce-scatter that is simply
+        gone (no all-reduce beyond the plan's scalars) is still a
+        count finding."""
+        report = analysis.lint_hlo(
+            self._TPU_ZERO_HLO, expect_plan=self._zero_plan(n=65920 // 2),
+            rules=("reshard",),
+        )
+        assert report.rule_ids() == ["reshard-plan"]
+        assert "all-reduce@dp" in report.findings[0].path
+        gone = analysis.lint_hlo(
+            _AR_HLO.replace("f32[8,128]", "f32[8]").replace(
+                "{{0,1}}", "{{0,2},{1,3}}"
+            ),
+            expect_plan=self._zero_plan(), rules=("reshard",),
+        )
+        assert sorted(f.path for f in gone.findings) == [
+            "all-gather@dp", "reduce-scatter@dp",
+        ]
 
 
 class TestMemoryBudget:

@@ -772,6 +772,12 @@ class TestPagedDecodeAttention:
         q_rot = q * cos[:, None, :] + rotate_half(q) * sin[:, None, :]
         plain = paged_decode_attention(q_rot, kp, vp, table, lengths)
         np.testing.assert_allclose(fused, plain, atol=2e-6, rtol=2e-6)
+        # a half-width table must be refused, not compiled
+        with pytest.raises(ValueError, match=r"must be \(B, D\)"):
+            paged_decode_attention(
+                q, kp, vp, table, lengths,
+                rope_cos=cos[:, ::2], rope_sin=sin[:, ::2],
+            )
 
     def test_int8_kv_dequant_matches_reference(self, force_pallas):
         """In-kernel int8 dequant == the reference's gather+dequant,

@@ -399,9 +399,12 @@ def test_no_sync_returns_local_grads_then_engine_syncs(eight_devices):
         g_manual = jax.tree_util.tree_map(
             lambda x: jax.lax.psum(x, "dp"), g_local
         )
-        spread = sum(
-            jnp.max(jnp.abs(x - jax.lax.pmean(x, "dp")))
-            for x in jax.tree_util.tree_leaves(g_local)
+        spread = jax.lax.pmax(
+            sum(
+                jnp.max(jnp.abs(x - jax.lax.pmean(x, "dp")))
+                for x in jax.tree_util.tree_leaves(g_local)
+            ),
+            "dp",
         )
         return g_engine, g_manual, spread
 
@@ -472,6 +475,27 @@ def test_accum_with_quantized_boundary_sync_trains(eight_devices):
         p, o, l = step(p, o, micro)
         losses.append(float(l))
     assert losses[-1] < 0.6 * losses[0], losses
+
+
+def test_f32_accum_step_keeps_the_replication_check(eight_devices):
+    """Only the bucketed sync (quantized wire / chunks) turns shard_map's
+    static replication check off.  On the exact-psum paths it stays on:
+    an accumulation step whose grads leave unsynced is refused at trace
+    time instead of letting the replicas drift."""
+    from apex_tpu.optimizers import fused_adam
+
+    mesh = ps.initialize_model_parallel()
+    params, batch, loss = _ddp_toy()
+    micro = jax.tree_util.tree_map(
+        lambda x: x.reshape(4, 16, *x.shape[1:]), batch
+    )
+    tx = fused_adam(5e-2)
+    ddp = DistributedDataParallel(loss)
+    ddp.all_reduce_gradients = lambda grads: grads  # "forgot the sync"
+    with pytest.raises(ValueError, match="require replication"):
+        ddp.make_step(tx, mesh, accum_steps=4)(
+            params, tx.init(params), micro
+        )
 
 
 def test_make_step_rejects_bad_accum(eight_devices):

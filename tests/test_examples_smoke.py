@@ -17,23 +17,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run_example(relpath, argv, n_devices=2, timeout=420):
-    code = (
-        "import sys\n"
-        f"sys.argv = {['x'] + argv!r}\n"
-        "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
-        "import runpy\n"
-        f"runpy.run_path({os.path.join(REPO, relpath)!r}, "
-        "run_name='__main__')\n"
-    )
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={n_devices}"
     )
-    env.pop("JAX_PLATFORMS", None)
     proc = subprocess.run(
-        [sys.executable, "-c", code],
+        [sys.executable, os.path.join(REPO, relpath)] + argv,
         env=env,
         cwd=REPO,
         stdout=subprocess.PIPE,

@@ -140,8 +140,10 @@ class TestVmemModel:
 
     def test_budget_override(self):
         (spec,) = fwd_specs(2, 512, 512, 64, block_q=256, block_k=256)
-        assert ka.analyze([spec], vmem_budget=1 << 30).ok()
-        over = ka.analyze([spec], vmem_budget=1 << 16)
+        assert ka.analyze(
+            [spec], device_kind=V5E, vmem_budget=1 << 30
+        ).ok()
+        over = ka.analyze([spec], device_kind=V5E, vmem_budget=1 << 16)
         assert over.by_rule("kernel-vmem-overflow")
 
 

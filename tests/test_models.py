@@ -3,6 +3,7 @@ standalone_gpt/standalone_bert pipeline smoke tests, test_gpt_minimal /
 test_bert_minimal), ResNet forward, and the driver entry points."""
 
 import importlib.util
+import os
 
 import jax
 import jax.numpy as jnp
@@ -410,7 +411,11 @@ class TestResNet:
 class TestGraftEntry:
     def _load(self):
         spec = importlib.util.spec_from_file_location(
-            "__graft_entry__", "/root/repo/__graft_entry__.py"
+            "__graft_entry__",
+            os.path.join(
+                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                "__graft_entry__.py",
+            ),
         )
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)

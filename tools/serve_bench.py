@@ -63,7 +63,7 @@ plain-decode reference (``docs/serving.md`` "Speculative decoding").
 
 Usage::
 
-    python tools/serve_bench.py                  # small CPU run
+    python tools/serve_bench.py                  # small default run
     python tools/serve_bench.py --requests 32 --rate 50 --json out.json
     python tools/serve_bench.py --speculate 4 --json out.json
     python tools/serve_bench.py --spans spans.json --json out.json
@@ -79,8 +79,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 #: pinned acceptance tolerances on last-position logits vs the unpaged
 #: f32 reference (tests/test_serve.py pins the same numbers)
@@ -640,6 +638,9 @@ def main():
 
         args.ops_port = ops_port_from_env()
 
+    from apex_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     cfg, model, params, engine, registry = build_engine(args)
     lint_errors = {
         name: len(rep.errors()) for name, rep in engine.reports.items()

@@ -30,8 +30,16 @@ Usage::
                                                                # (bench --hlo-out)
 
 Exit code 0; the machine-readable artifact (``--json``) carries the
-fractions, bucket shares, roofline rows, and the MFU agreement — what
-the verify_tier1.sh PERF pass asserts on.
+device, the fractions, bucket shares, roofline rows, and the MFU
+agreement — what the verify_tier1.sh PERF pass and
+``tests_tpu/test_step_profile.py`` assert on.
+
+A measurement tool: it runs on whatever device JAX hands it.  On one
+with no entry in the ``meter.py`` peak table (the CPU) the trace ->
+attribution -> fractions path still runs, and everything that needs a
+peak — the roofline, the MFU, the cost model's time shares — reads
+"not measured": a roofline against an assumed chip is not a
+measurement.
 """
 
 from __future__ import annotations
@@ -45,9 +53,9 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+NOT_MEASURED = "not measured"
 
 
 def _load_resilient_module():
@@ -72,6 +80,7 @@ def profile_resilient(args):
 
     from apex_tpu import observability as obs
     from apex_tpu.observability import attribution as A
+    from apex_tpu.observability.meter import UnknownDeviceError
 
     mod = _load_resilient_module()
     t = mod.build_training(accum=args.accum, wire=args.wire)
@@ -87,7 +96,15 @@ def profile_resilient(args):
     hlo_update = apply_update.lower(
         scaled, state, loss
     ).compile().as_text()
-    cost = A.attribute_cost_model([hlo_grads, hlo_update])
+    try:
+        cost = A.attribute_cost_model([hlo_grads, hlo_update])
+        hlo_map, cost_weights = cost.bucket_map(), cost.bucket_fractions()
+    except UnknownDeviceError as e:
+        # the op -> bucket join needs no peak; the cost model's times do
+        print(f"[step_profile] {e}: roofline and MFU {NOT_MEASURED}",
+              file=sys.stderr)
+        cost, cost_weights = None, None
+        hlo_map = A.hlo_bucket_map([hlo_grads, hlo_update])
     if args.hlo_out:
         with open(args.hlo_out, "w") as f:
             f.write(hlo_grads)
@@ -102,8 +119,9 @@ def profile_resilient(args):
     # real cross-check that the trace covers the same milliseconds the
     # wall clock paid, not an algebraic identity.
     meter = obs.StepMeter(
-        tokens_per_step=t["rows"], flops_per_step=cost.total_flops,
-        peak_flops=cost.peak_flops,
+        tokens_per_step=t["rows"],
+        flops_per_step=cost.total_flops if cost is not None else 0.0,
+        peak_flops=cost.peak_flops if cost is not None else None,
     )
     state, _ = apply_update(scaled, state, loss)  # warmup apply too
 
@@ -124,13 +142,12 @@ def profile_resilient(args):
 
     trace = A.load_trace_dir(trace_dir)
     measured = A.attribute_trace(
-        trace, hlo_map=cost.bucket_map(),
-        cost_weights=cost.bucket_fractions(),
+        trace, hlo_map=hlo_map, cost_weights=cost_weights,
     )
     # the trace's own per-step clock (median same-op period): the
     # independent measurement the MFU cross-check compares against the
     # meter's host perf_counter ticks
-    trace_step_s = A.trace_step_period(trace, hlo_map=cost.bucket_map())
+    trace_step_s = A.trace_step_period(trace, hlo_map=hlo_map)
     return cost, measured, meter, trace_dir, trace_step_s
 
 
@@ -147,7 +164,7 @@ def profile_hlo(args):
     return A.attribute_cost_model(texts), None, None, None
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser(
         description="step-time attribution + roofline "
         "(docs/observability.md)"
@@ -170,34 +187,43 @@ def main():
     ap.add_argument("--metrics-out", metavar="FILE", default=None,
                     help="append the attribution fractions as "
                     "bench-schema JSONL (the observability sink)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if bool(args.target) == bool(args.hlo):
         ap.error("exactly one of --target / --hlo is required")
 
+    import jax
+
     from apex_tpu import observability as obs
     from apex_tpu.observability import attribution as A
+    from apex_tpu.observability.meter import UnknownDeviceError
 
+    d0 = jax.devices()[0]
+    device = {"platform": d0.platform, "kind": d0.device_kind}
     if args.target:
         cost, measured, meter, trace_dir, trace_step_s = \
             profile_resilient(args)
     else:
-        cost, measured, meter, trace_dir = profile_hlo(args)
+        try:
+            cost, measured, meter, trace_dir = profile_hlo(args)
+        except UnknownDeviceError as e:
+            sys.exit(f"step_profile: --hlo is the cost model alone, and {e}")
         trace_step_s = 0.0
 
     src = measured if measured is not None else cost
     fractions = src.fractions()
     frac_sum = sum(fractions.values())
     print(
-        "step fractions (%s): compute=%.3f collective=%.3f "
+        "step fractions (%s on %s): compute=%.3f collective=%.3f "
         "host_stall=%.3f  (sum=%.3f)"
         % (
             measured.source if measured is not None else "cost model",
+            device["kind"],
             fractions["compute"], fractions["collective"],
             fractions["host_stall"], frac_sum,
         )
     )
-    cost_fr = cost.fractions()
-    if measured is not None:
+    cost_fr = cost.fractions() if cost is not None else NOT_MEASURED
+    if measured is not None and cost is not None:
         print(
             "cost-model cross-check: collective=%.3f (measured %.3f); "
             "host stall is invisible to the compiled program"
@@ -211,21 +237,29 @@ def main():
     # second denominator sneaks back in (a diverging peak table, a
     # different FLOP model), which is exactly the drift it guards.
     step_time = meter.step_time if meter is not None else cost.est_step_time
-    rows = A.roofline_report(
-        cost, step_time_s=step_time, measured=measured
-    )
-    print()
-    print(A.render_roofline(rows))
-    roofline_mfu = rows[-1].pct_peak
-    meter_mfu = meter.mfu if meter is not None else roofline_mfu
-    agreement = (
-        abs(roofline_mfu - meter_mfu) / meter_mfu if meter_mfu > 0 else 0.0
-    )
-    print(
-        "\nMFU: roofline=%.4f meter=%.4f (delta %.2f%%; one "
-        "denominator by design: observability.meter)"
-        % (roofline_mfu, meter_mfu, 100 * agreement)
-    )
+    if cost is not None:
+        rows = A.roofline_report(
+            cost, step_time_s=step_time, measured=measured
+        )
+        print()
+        print(A.render_roofline(rows))
+        roofline_mfu = rows[-1].pct_peak
+        meter_mfu = meter.mfu if meter is not None else roofline_mfu
+        agreement = (
+            abs(roofline_mfu - meter_mfu) / meter_mfu
+            if meter_mfu > 0 else 0.0
+        )
+        print(
+            "\nMFU: roofline=%.4f meter=%.4f (delta %.2f%%; one "
+            "denominator by design: observability.meter)"
+            % (roofline_mfu, meter_mfu, 100 * agreement)
+        )
+        roofline = [r._asdict() for r in rows]
+        mfu = {"roofline": roofline_mfu, "meter": meter_mfu,
+               "agreement": agreement}
+    else:
+        print(f"\nroofline: {NOT_MEASURED}\nMFU: {NOT_MEASURED}")
+        roofline = mfu = NOT_MEASURED
     # the genuinely independent comparison, as a diagnostic: the
     # trace's own per-step clock (median same-op period) vs the host
     # ticks.  Large skew is NOT an error — an async runtime batching
@@ -261,17 +295,19 @@ def main():
     if args.json:
         payload = {
             "target": args.target or "hlo",
+            "device": device,
             "source": measured.source if measured is not None else "cost-model",
             "fractions": fractions,
             "fraction_sum": frac_sum,
             "cost_fractions": cost_fr,
             "bucket_fractions": src.bucket_fractions(),
-            "cost_buckets": cost.buckets,
+            "cost_buckets": (
+                cost.buckets if cost is not None else NOT_MEASURED
+            ),
             "step_time_ms": step_time * 1e3,
             "trace_step_ms": trace_step_s * 1e3,
-            "roofline": [r._asdict() for r in rows],
-            "mfu": {"roofline": roofline_mfu, "meter": meter_mfu,
-                    "agreement": agreement},
+            "roofline": roofline,
+            "mfu": mfu,
             "health_events": [ev._asdict() for ev in events],
             "trace_dir": trace_dir,
         }

@@ -73,8 +73,10 @@
 #      and the train3d rows' REQUIRED dp/tp >= 2 shapes);
 #   3. tools/step_profile.py --target resilient emits
 #      compute/collective/host-stall fractions summing to 1 +- 0.02
-#      with roofline-vs-StepMeter MFU agreement within 5% (the ISSUE 6
-#      acceptance line).
+#      (the ISSUE 6 acceptance line).  The CPU has no peak on record,
+#      so its roofline and MFU must read "not measured" here; the
+#      roofline-vs-StepMeter MFU agreement within 5% is asserted where
+#      it can be measured, in tests_tpu/test_step_profile.py.
 #
 # A SERVE stage drives the inference path end to end
 # (docs/serving.md): the serve example trains a tiny GPT with the
@@ -271,7 +273,7 @@ for r in recs:
     assert list(r)[:4] == ["metric", "value", "unit", "vs_baseline"], r
     assert "step" in r, f"telemetry line without step key: {r}"
 metrics = {r["metric"] for r in recs}
-for need in ("train/step_time_ms", "train/mfu", "train/goodput",
+for need in ("train/step_time_ms", "train/goodput",
              "train/loss", "amp/loss_scale", "guard/skipped"):
     assert need in metrics, f"missing metric {need}; have {sorted(metrics)}"
 final = [r for r in recs if r["metric"] == "train/goodput" and "skipped" in r]
@@ -837,7 +839,6 @@ if [ "${T1_SKIP_PERF:-0}" != "1" ]; then
     if [ "$perf_rc" -eq 0 ]; then
         PERF_OUT="$(mktemp /tmp/_t1_perf.XXXXXX.jsonl)"
         timeout -k 10 300 env JAX_PLATFORMS=cpu XLA_FLAGS="" \
-            APEX_TPU_BENCH_WATCHDOG_S=0 \
             python bench.py --config smoke --metrics-out "$PERF_OUT" \
             2>&1 | tail -n 2 | tee -a "$LOG"
         perf_rc=${PIPESTATUS[0]}
@@ -852,7 +853,6 @@ if [ "${T1_SKIP_PERF:-0}" != "1" ]; then
                 SC_REUSE="$SC_JSON"
             fi
             timeout -k 10 300 env JAX_PLATFORMS=cpu XLA_FLAGS="" \
-                APEX_TPU_BENCH_WATCHDOG_S=0 \
                 APEX_TPU_SERVE_CHAOS_ARTIFACT="$SC_REUSE" \
                 python bench.py --config serve --metrics-out "$PERF_OUT" \
                 2>&1 | tail -n 2 | tee -a "$LOG"
@@ -866,7 +866,6 @@ if [ "${T1_SKIP_PERF:-0}" != "1" ]; then
         if [ "$perf_rc" -eq 0 ]; then
             timeout -k 10 300 env JAX_PLATFORMS=cpu \
                 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-                APEX_TPU_BENCH_WATCHDOG_S=0 \
                 python bench.py --config train3d --lint \
                 --metrics-out "$PERF_OUT" \
                 2>&1 | tail -n 2 | tee -a "$LOG"
@@ -886,7 +885,6 @@ if [ "${T1_SKIP_PERF:-0}" != "1" ]; then
                 GP_REUSE="$GP_JSON"
             fi
             timeout -k 10 420 env JAX_PLATFORMS=cpu XLA_FLAGS="" \
-                APEX_TPU_BENCH_WATCHDOG_S=0 \
                 APEX_TPU_GOODPUT_ARTIFACT="$GP_REUSE" \
                 python bench.py --config goodput --metrics-out "$PERF_OUT" \
                 2>&1 | tail -n 2 | tee -a "$LOG"
@@ -914,7 +912,6 @@ if [ "${T1_SKIP_PERF:-0}" != "1" ]; then
                 CN_REUSE="$CN_JSON"
             fi
             timeout -k 10 600 env JAX_PLATFORMS=cpu XLA_FLAGS="" \
-                APEX_TPU_BENCH_WATCHDOG_S=0 \
                 APEX_TPU_FLEET_ARTIFACT="$FL_REUSE" \
                 APEX_TPU_CANARY_ARTIFACT="$CN_REUSE" \
                 python bench.py --config fleet --metrics-out "$PERF_OUT" \
@@ -937,7 +934,7 @@ if [ "${T1_SKIP_PERF:-0}" != "1" ]; then
                 "$PERF_OUT)" | tee -a "$LOG"
         fi
     fi
-    # 3. the ISSUE 6 acceptance line: attribution fractions + MFU
+    # 3. the ISSUE 6 acceptance line: attribution fractions
     if [ "$perf_rc" -eq 0 ]; then
         SP_JSON="$(mktemp /tmp/_t1_stepprof.XXXXXX.json)"
         timeout -k 10 420 env JAX_PLATFORMS=cpu XLA_FLAGS="" \
@@ -950,11 +947,11 @@ import json, sys
 p = json.load(open(sys.argv[1]))
 assert abs(p["fraction_sum"] - 1.0) <= 0.02, p["fraction_sum"]
 assert set(p["fractions"]) == {"compute", "collective", "host_stall"}
-assert p["mfu"]["agreement"] <= 0.05, p["mfu"]
-assert p["roofline"][-1]["bucket"] == "total"
+assert p["device"]["platform"] == "cpu", p["device"]
+assert p["mfu"] == p["roofline"] == "not measured", (p["mfu"], p["roofline"])
 print(f"step_profile OK: fractions sum={p['fraction_sum']:.3f} "
-      f"(source={p['source']}), mfu agreement="
-      f"{p['mfu']['agreement']:.4f}")
+      f"(source={p['source']}); roofline and MFU not measured on "
+      f"{p['device']['kind']}")
 PYEOF
             perf_rc=${PIPESTATUS[0]}
         fi
