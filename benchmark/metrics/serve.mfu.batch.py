@@ -1,0 +1,5 @@
+"""Whole serving step's share of the chip's bf16 peak: model FLOPs
+(benchmark/flops.py) of every prompt token prefilled and every token
+decoded inside the window, over window x peak."""
+
+from benchmark.readers import serve_mfu as read  # noqa: F401
