@@ -20,8 +20,7 @@ session over bench logs:
 - :mod:`apex_tpu.observability.export` — JSONL (bench.py line schema),
   CSV, and TensorBoard-event sinks behind one
   :class:`~apex_tpu.observability.export.Reporter` ``report()`` API.
-- :mod:`apex_tpu.observability.trace` — NVTX-style annotation hooks
-  (absorbing ``apex_tpu/utils/profiling.py``) plus
+- :mod:`apex_tpu.observability.trace` — ``annotate`` / ``trace`` plus
   :class:`~apex_tpu.observability.trace.TraceScheduler`: "profile
   steps N..N+K to this dir" via ``APEX_TPU_TRACE_STEPS``, no script
   edits.
@@ -34,7 +33,10 @@ session over bench logs:
   observer protocol, health events and profiler-window markers —
   merged into one Perfetto timeline by
   :class:`~apex_tpu.observability.export.TimelineSink` /
-  ``tools/timeline.py``.
+  ``tools/timeline.py``.  ``SpanRecorder.phase()`` is the host-span
+  primitive (ring entry + profiler ``TraceAnnotation``);
+  :func:`~apex_tpu.observability.spans.process_recorder` is the ring
+  the serving host loop always writes.
 - :mod:`apex_tpu.observability.flight` —
   :class:`~apex_tpu.observability.flight.FlightRecorder`: a ring
   buffer of the last N steps' telemetry + event log, dumped
@@ -133,6 +135,7 @@ from apex_tpu.observability.canary import (  # noqa: F401
 from apex_tpu.observability.spans import (  # noqa: F401
     SpanRecorder,
     monotonic_to_epoch,
+    process_recorder,
     wall_clock_anchor,
 )
 from apex_tpu.observability.attribution import (  # noqa: F401
@@ -212,9 +215,6 @@ from apex_tpu.observability import trace  # noqa: F401
 from apex_tpu.observability.trace import (  # noqa: F401
     TraceScheduler,
     annotate,
-    nvtx_range,
-    range_pop,
-    range_push,
 )
 
 __all__ = [
@@ -241,6 +241,7 @@ __all__ = [
     "ServeFaultRule",
     "SpecAcceptanceRule",
     "SpanRecorder",
+    "process_recorder",
     "wall_clock_anchor",
     "monotonic_to_epoch",
     "CanaryAnalyzer",
@@ -302,8 +303,5 @@ __all__ = [
     "bench_record",
     "TraceScheduler",
     "annotate",
-    "nvtx_range",
-    "range_push",
-    "range_pop",
     "trace",  # the submodule (holding the trace() context manager)
 ]

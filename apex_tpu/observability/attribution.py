@@ -18,6 +18,10 @@ per-op-category buckets, from TWO sources that must agree:
    ("XLA Ops" tracks) or the per-thunk spans the CPU runtime emits.
    Measured: it knows time exactly, including the gaps no op accounts
    for (host stall: dispatch latency, blocked fetches, input waits).
+   The stall is split by the innermost host annotation under each gap
+   (the serving loop's ``serve/*`` / ``engine/*`` phases, which
+   :meth:`~apex_tpu.observability.spans.SpanRecorder.phase` writes
+   into the trace's host plane on the device trace's clock).
 
 Where both exist, disagreement IS the finding: a measured collective
 fraction far above the cost model's means the overlap the schedule
@@ -325,16 +329,20 @@ class TraceAttribution:
     last-op-end; ``stall_ms`` is the part of the span no op interval
     covers (merged-union gaps): dispatch latency, host sync points,
     input waits — the time the program paid that no kernel explains.
+    ``stall_by_phase_ms`` splits ``stall_ms`` by the innermost host
+    annotation open during each gap (``"unannotated"`` for the rest).
     """
 
     def __init__(self, bucket_ms: Dict[str, float], span_ms: float,
                  covered_ms: float, events: int,
-                 source: str = "device-ops"):
+                 source: str = "device-ops",
+                 stall_by_phase_ms: Optional[Mapping[str, float]] = None):
         self.bucket_ms = {b: bucket_ms.get(b, 0.0) for b in BUCKETS}
         self.span_ms = span_ms
         self.covered_ms = min(covered_ms, span_ms) if span_ms > 0 else 0.0
         self.events = events
         self.source = source
+        self.stall_by_phase_ms = dict(stall_by_phase_ms or {})
 
     @property
     def busy_ms(self) -> float:
@@ -377,8 +385,12 @@ class TraceAttribution:
         }
 
 
-def _merged_coverage(intervals: List[Tuple[float, float]]) -> float:
-    """Total length of the union of [start, end) intervals."""
+def _merged_coverage(
+    intervals: List[Tuple[float, float]],
+    gaps: Optional[List[Tuple[float, float]]] = None,
+) -> float:
+    """Total length of the union of [start, end) intervals; the
+    uncovered stretches between them are appended to ``gaps``."""
     if not intervals:
         return 0.0
     intervals.sort()
@@ -387,11 +399,81 @@ def _merged_coverage(intervals: List[Tuple[float, float]]) -> float:
     for s, e in intervals[1:]:
         if s > cur_e:
             total += cur_e - cur_s
+            if gaps is not None:
+                gaps.append((cur_e, s))
             cur_s, cur_e = s, e
         else:
             cur_e = max(cur_e, e)
     total += cur_e - cur_s
     return total
+
+
+#: host annotations the stall is split by — the serving loop's phases
+HOST_PHASE_PREFIXES = ("serve/", "engine/")
+UNANNOTATED = "unannotated"
+
+
+def _innermost_segments(
+    spans: List[Tuple[float, float, str]]
+) -> List[Tuple[float, float, str]]:
+    """Nested ``(start, end, name)`` spans flattened to disjoint
+    segments, each named after the innermost span open over it (a
+    span's self time).  A span that outlives the one around it is
+    clipped to it."""
+    out: List[Tuple[float, float, str]] = []
+    stack: List[Tuple[float, str]] = []   # (end, name)
+    cursor = 0.0
+
+    def close_until(t: float) -> None:
+        nonlocal cursor
+        while stack and stack[-1][0] <= t:
+            end, name = stack.pop()
+            if end > cursor:
+                out.append((cursor, end, name))
+                cursor = end
+
+    for start, end, name in sorted(spans, key=lambda x: (x[0], -x[1])):
+        close_until(start)
+        if stack:
+            if start > cursor:
+                out.append((cursor, start, stack[-1][1]))
+            end = min(end, stack[-1][0])
+        cursor = max(cursor, start)
+        stack.append((end, name))
+    close_until(float("inf"))
+    return out
+
+
+def _stall_by_phase(
+    trace: Mapping, gaps: List[Tuple[float, float]]
+) -> Dict[str, float]:
+    """Milliseconds of ``gaps`` (device-idle stretches, trace µs) under
+    each innermost host annotation whose name starts with one of
+    :data:`HOST_PHASE_PREFIXES`; what no such annotation covers is
+    ``unannotated``."""
+    spans = [
+        (float(e["ts"]), float(e["ts"]) + float(e["dur"]), e["name"])
+        for e in trace.get("traceEvents", [])
+        if e.get("ph") == "X" and e.get("dur")
+        and str(e.get("name", "")).startswith(HOST_PHASE_PREFIXES)
+    ]
+    segs = _innermost_segments(spans)
+    out: Dict[str, float] = {}
+    i = 0
+    for g0, g1 in gaps:
+        while i < len(segs) and segs[i][1] <= g0:
+            i += 1
+        named, j = 0.0, i
+        while j < len(segs) and segs[j][0] < g1:
+            over = min(g1, segs[j][1]) - max(g0, segs[j][0])
+            if over > 0:
+                out[segs[j][2]] = out.get(segs[j][2], 0.0) + over / 1e3
+                named += over
+            j += 1
+        rest = (g1 - g0) - named
+        if rest > 0:
+            out[UNANNOTATED] = out.get(UNANNOTATED, 0.0) + rest / 1e3
+    return out
 
 
 def _event_is_op(name: str, hlo_map: Optional[Mapping[str, str]]) -> bool:
@@ -515,6 +597,11 @@ def attribute_trace(
     busy split falls back to ``cost_weights`` (the cost model's bucket
     shares) — pass them whenever available so the degraded mode stays
     attributed.
+
+    The gaps between device operations are split by the innermost host
+    annotation (``serve/*``, ``engine/*``: the serving loop's phases)
+    open over them: ``stall_by_phase_ms`` on the result, summing to
+    ``stall_ms``.
     """
     selected, source = _select_op_events(trace, hlo_map)
     bucket_ms: Dict[str, float] = {b: 0.0 for b in BUCKETS}
@@ -529,14 +616,16 @@ def attribute_trace(
         bucket_ms[_bucket_event(e.get("name", ""), hlo_map)] += dur / 1e3
 
     span_ms = (tmax - tmin) / 1e3 if tmax > tmin else 0.0
-    covered_ms = _merged_coverage(intervals) / 1e3
+    gaps: List[Tuple[float, float]] = []
+    covered_ms = _merged_coverage(intervals, gaps) / 1e3
     if source == "executor-spans" and covered_ms > 0:
         weights = dict(cost_weights or {"other": 1.0})
         wsum = sum(weights.values()) or 1.0
         for b in BUCKETS:
             bucket_ms[b] = covered_ms * weights.get(b, 0.0) / wsum
     return TraceAttribution(
-        bucket_ms, span_ms, covered_ms, len(selected), source
+        bucket_ms, span_ms, covered_ms, len(selected), source,
+        _stall_by_phase(trace, gaps),
     )
 
 

@@ -47,22 +47,41 @@ Armed three ways, mirroring the flight recorder: explicitly
 (``APEX_TPU_SPANS=N[:DIR]`` inside any ``run_resilient`` loop), or by
 tools (``tools/serve_bench.py --spans``).  See
 ``docs/observability.md`` ("Request tracing & timeline").
+
+**Host phases** (:meth:`SpanRecorder.phase`) are the one way the
+serving host loop names what it is doing: a context manager that
+records a span with an ``id`` and the ``parent`` id of the phase open
+around it on the same thread, and enters a
+``jax.profiler.TraceAnnotation`` of the same name, so one call lands
+in the ring (host clock) and, while a profiler session is on, in the
+trace's host plane (the device trace's clock).  The scheduler and the
+engine write their phases to the recorder attached with ``spans=``
+and, when none is, to :func:`process_recorder` — a bounded ring the
+process always keeps, read after a stall, a crash or a benchmark run
+(``process_recorder().dump()``).  The phase vocabulary is in
+``docs/serving.md`` ("Host phases").
 """
 
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
+import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+import jax
 
 __all__ = [
     "ENV_SPANS",
     "DEFAULT_SPANS_DIR",
     "DEFAULT_CAPACITY",
+    "PROCESS_CAPACITY",
     "TRACK_REQUESTS",
     "TRACK_ENGINE",
+    "TRACK_SCHED",
     "TRACK_TRAIN",
     "TRACK_HEALTH",
     "TRACK_TRACE",
@@ -77,15 +96,21 @@ __all__ = [
     "wall_clock_anchor",
     "monotonic_to_epoch",
     "SpanRecorder",
+    "process_recorder",
+    "host_recorder",
 ]
 
 ENV_SPANS = "APEX_TPU_SPANS"
 DEFAULT_SPANS_DIR = "/tmp/apex_tpu_spans"
 DEFAULT_CAPACITY = 4096
+#: the always-on process ring: ~10 phases a decode step, so the last
+#: few thousand scheduler steps
+PROCESS_CAPACITY = 32768
 
 # -- track names (one Perfetto track per source) ----------------------------
 TRACK_REQUESTS = "serve/requests"
 TRACK_ENGINE = "serve/engine"
+TRACK_SCHED = "serve/scheduler"
 TRACK_TRAIN = "train"
 TRACK_HEALTH = "health"
 TRACK_TRACE = "trace"
@@ -173,13 +198,63 @@ def parse_spans_spec(spec: str) -> Tuple[int, Optional[str]]:
         raise ValueError(str(e).replace(ENV_FLIGHT, ENV_SPANS)) from None
 
 
+class _Phase:
+    """One open host phase — what :meth:`SpanRecorder.phase` returns.
+    ``set(**args)`` adds args known only once the work is done (counts
+    measured where the work happens); ``drop()`` leaves the phase out
+    of the ring (an admission attempt that ran no prefill) — not out of
+    a profiler trace, whose annotation was entered with the phase."""
+
+    __slots__ = ("rec", "name", "track", "args", "id", "parent", "t0",
+                 "keep", "_ann")
+
+    def __init__(self, rec, name, track, args):
+        self.rec, self.name, self.track, self.args = rec, name, track, args
+        self.keep = True
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+
+    def drop(self) -> None:
+        self.keep = False
+
+    def __enter__(self):
+        rec = self.rec
+        stack = rec._open_phases()
+        self.parent = stack[-1].id if stack else None
+        self.id = next(rec._phase_ids)
+        stack.append(self)
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = rec.clock()
+        return self
+
+    def __exit__(self, *exc):
+        rec = self.rec
+        t1 = rec.clock()
+        self._ann.__exit__(*exc)
+        rec._open_phases().pop()
+        if self.keep:
+            entry = {
+                "name": self.name, "track": self.track,
+                "t0": self.t0, "t1": t1,
+                "id": self.id, "parent": self.parent,
+            }
+            if self.args:
+                entry["args"] = self.args
+            rec._append(entry)
+        return False
+
+
 class SpanRecorder:
     """Bounded ring of spans + instants with a request state machine.
 
     Generic surface::
 
-        rec.span("engine/decode", t0, t1, track=TRACK_ENGINE, iter=7)
+        rec.span("ckpt/write", t0, t1, track=TRACK_TRAIN, step=7)
         rec.instant("train/rollback", t, track=TRACK_TRAIN, step=120)
+        with rec.phase("engine/decode", track=TRACK_ENGINE, iter=7):
+            ...   # a span with id/parent + a profiler TraceAnnotation
 
     Request lifecycle surface (validated)::
 
@@ -220,6 +295,12 @@ class SpanRecorder:
         self._ring: collections.deque = collections.deque(maxlen=capacity)
         self._seq = 0
         self._appended = 0
+        # replicas stepped from several threads share one ring: the
+        # sequence numbers are taken under a lock, the stack of open
+        # phases is per thread
+        self._lock = threading.Lock()
+        self._phase_ids = itertools.count()
+        self._tls = threading.local()
         # rid -> (state, t_opened, open_args)
         self._open_req: Dict[Any, Tuple[str, float, Dict[str, Any]]] = {}
         # observer-bridge state
@@ -249,10 +330,35 @@ class SpanRecorder:
         return self.clock()
 
     def _append(self, entry: Dict[str, Any]) -> None:
-        entry["seq"] = self._seq
-        self._seq += 1
-        self._appended += 1
-        self._ring.append(entry)
+        with self._lock:
+            entry["seq"] = self._seq
+            self._seq += 1
+            self._appended += 1
+            self._ring.append(entry)
+
+    def _open_phases(self) -> List[_Phase]:
+        """This thread's stack of open phases."""
+        try:
+            return self._tls.stack
+        except AttributeError:
+            stack = self._tls.stack = []
+            return stack
+
+    def phase(self, name: str, *, track: str = TRACK_SCHED,
+              **args) -> _Phase:
+        """A named host interval as a context manager::
+
+            with rec.phase("serve/admit", rid=7) as ph:
+                ...
+                ph.set(bucket=128)
+
+        Entering reads the clock and enters
+        ``jax.profiler.TraceAnnotation(name)`` (inert without a
+        profiler session); leaving appends one span entry that carries
+        an ``id`` and the ``parent`` id of the phase open around it on
+        this thread.  A phase that raises still closes, and the
+        exception goes on."""
+        return _Phase(self, name, track, args)
 
     def span(
         self,
@@ -517,7 +623,9 @@ class SpanRecorder:
         return self._appended - len(self._ring)
 
     def snapshot(self) -> List[Dict[str, Any]]:
-        return [dict(e) for e in self._ring]
+        with self._lock:        # another thread may be appending
+            entries = list(self._ring)
+        return [dict(e) for e in entries]
 
     def header(self) -> Dict[str, Any]:
         host = {"id": 0, "count": 1}
@@ -572,3 +680,27 @@ class SpanRecorder:
         os.replace(tmp, path)
         self.dumps.append(path)
         return path
+
+
+_PROCESS: Optional[SpanRecorder] = None
+_PROCESS_LOCK = threading.Lock()
+
+
+def process_recorder() -> SpanRecorder:
+    """The ring this process always keeps (made on first use,
+    :data:`PROCESS_CAPACITY` entries, never written to disk by itself).
+    The serving host loop records its phases here when no recorder was
+    attached with ``spans=``; ``process_recorder().dump()`` after a
+    stall or a crash says what the host did last."""
+    global _PROCESS
+    if _PROCESS is None:
+        with _PROCESS_LOCK:
+            if _PROCESS is None:
+                _PROCESS = SpanRecorder(PROCESS_CAPACITY)
+    return _PROCESS
+
+
+def host_recorder(attached: Optional[SpanRecorder]) -> SpanRecorder:
+    """Where the serving host loop writes its phases: the recorder
+    attached with ``spans=``, else the process ring."""
+    return attached if attached is not None else process_recorder()

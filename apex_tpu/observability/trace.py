@@ -1,17 +1,17 @@
 """Tracing hooks + scheduled on-chip profiling windows.
 
-The annotation half (moved here from ``apex_tpu/utils/profiling.py``,
-which remains as a deprecation shim) is the TPU analog of the
-reference's NVTX ranges:
+The annotation half is the TPU analog of the reference's NVTX ranges:
 
 - :func:`annotate` (``jax.named_scope``) names a region of the *traced*
   computation — the name lands in HLO metadata and therefore in the XLA
   op-profile / Perfetto trace for every kernel fused from that region.
-- :func:`nvtx_range` / :func:`range_push` / :func:`range_pop` name a
-  span on the *host* timeline (``jax.profiler.TraceAnnotation``), for
-  dispatch-side bracketing exactly like NVTX.
 - :func:`trace` wraps a block in ``jax.profiler.trace`` and writes a
   TensorBoard/Perfetto-viewable profile directory (bench.py --trace).
+
+A span on the *host* timeline is a
+:meth:`~apex_tpu.observability.spans.SpanRecorder.phase`: it enters a
+``jax.profiler.TraceAnnotation`` and records the same interval in the
+span ring.
 
 All hooks are zero-cost when no profiler is attached: ``named_scope``
 only adds HLO metadata at trace time and ``TraceAnnotation`` is a no-op
@@ -38,15 +38,12 @@ from __future__ import annotations
 import contextlib
 import os
 import re
-from typing import Iterator, List, Optional, Tuple
+from typing import Iterator, Optional, Tuple
 
 import jax
 
 __all__ = [
     "annotate",
-    "nvtx_range",
-    "range_push",
-    "range_pop",
     "trace",
     "parse_trace_spec",
     "window_dir",
@@ -59,9 +56,6 @@ ENV_TRACE_STEPS = "APEX_TPU_TRACE_STEPS"
 ENV_TRACE_DIR = "APEX_TPU_TRACE_DIR"
 DEFAULT_TRACE_DIR = "/tmp/apex_tpu_trace"
 
-# module-level stack for the push/pop API (host-side spans, NVTX-style)
-_RANGE_STACK: List[contextlib.AbstractContextManager] = []
-
 
 def annotate(name: str):
     """Name a traced-computation region (``jax.named_scope``).
@@ -70,27 +64,6 @@ def annotate(name: str):
     XLA profiler attributes fused kernels to it.
     """
     return jax.named_scope(name)
-
-
-@contextlib.contextmanager
-def nvtx_range(name: str) -> Iterator[None]:
-    """Host-timeline span (≙ ``torch.cuda.nvtx.range`` context manager)."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-def range_push(name: str) -> None:
-    """≙ ``torch.cuda.nvtx.range_push`` — begin a host-timeline span."""
-    cm = jax.profiler.TraceAnnotation(name)
-    cm.__enter__()
-    _RANGE_STACK.append(cm)
-
-
-def range_pop() -> None:
-    """≙ ``torch.cuda.nvtx.range_pop`` — end the innermost span."""
-    if not _RANGE_STACK:
-        raise RuntimeError("range_pop() without matching range_push()")
-    _RANGE_STACK.pop().__exit__(None, None, None)
 
 
 @contextlib.contextmanager

@@ -58,6 +58,7 @@ import numpy as np
 
 from apex_tpu.models.gpt import GptConfig
 from apex_tpu.observability.metrics import board
+from apex_tpu.observability.spans import TRACK_ENGINE, host_recorder
 from apex_tpu.resilience import chaos
 from apex_tpu.serve import cache as cache_lib
 from apex_tpu.serve import model as model_lib
@@ -254,9 +255,13 @@ class InferenceEngine:
         # the fused sampler's key chain: one fold per engine call
         self._rng_base = jax.random.PRNGKey(self.serve.sample_seed)
         #: optional :class:`~apex_tpu.observability.spans.SpanRecorder`
-        #: — when set, every prefill/decode call records an
-        #: ``engine/prefill`` / ``engine/decode`` span (the scheduler
-        #: attaches its recorder here automatically)
+        #: (the scheduler attaches its recorder here automatically).
+        #: Every prefill/decode call records an ``engine/stage`` phase
+        #: (numpy → device arguments, rng folds, retrace sentinel) and
+        #: an ``engine/prefill`` / ``engine/decode`` phase (compiled
+        #: call → first host read) — here, or with none attached in
+        #: the process ring (:func:`~apex_tpu.observability.spans.
+        #: process_recorder`)
         self.spans = None
         #: monotonically increasing call counters — the correlation
         #: ids linking a request's span chain to the engine batch
@@ -724,6 +729,13 @@ class InferenceEngine:
             return fault
         raise chaos.InjectedFault(site, call_idx, fault.mode)
 
+    def _phase(self, name: str, **args):
+        """A host phase on the attached recorder, else on the process
+        ring (docs/serving.md "Host phases")."""
+        return host_recorder(self.spans).phase(
+            name, track=TRACK_ENGINE, **args
+        )
+
     def _sample_key(self, idx: int):
         """Deterministic per-call PRNG key for the fused sampler."""
         return jax.random.fold_in(self._rng_base, idx)
@@ -748,38 +760,33 @@ class InferenceEngine:
         poison = self._chaos_gate(chaos.SERVE_PREFILL, self.prefill_calls)
         n = len(prompt_ids)
         bucket = self.bucket_for(n)
-        np_b = bucket // self.serve.page_size
-        tokens = np.zeros((bucket, 1), np.int32)
-        tokens[:n, 0] = np.asarray(prompt_ids, np.int32)
-        ids = np.full((np_b,), cache_lib.NULL_PAGE, np.int32)
-        ids[: len(page_ids)] = np.asarray(page_ids, np.int32)
-        compiled = self._get_prefill(bucket)
         name = f"prefill_{bucket}"
-        args = (
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(n, jnp.int32), jnp.asarray(ids),
-            jnp.asarray(temperature, jnp.float32),
-            self._sample_key(self.prefill_calls),
-        )
-        self._sentinels[name].observe(*args)
-        self.prefill_calls += 1
-        rec = self.spans
-        t0 = rec.now() if rec is not None else None
-        logits, next_token, finite, self.cache = compiled(*args)
-        # logits stay ON DEVICE (lazy jax.Array): only the sampled
-        # token and the scalar finite screen cross to the host — the
-        # logits matrix is (V,)/(B, V) and most callers never read it
-        first = int(next_token)
-        self.last_prefill_finite = bool(finite) and poison is None
-        if rec is not None:
-            # int(next_token) above synced, so the span covers the real
-            # device time, not just the async dispatch
-            from apex_tpu.observability.spans import TRACK_ENGINE
-
-            rec.span(
-                "engine/prefill", t0, rec.now(), track=TRACK_ENGINE,
-                bucket=bucket, tokens=n, call=self.prefill_calls,
+        with self._phase("engine/stage", program=name):
+            np_b = bucket // self.serve.page_size
+            tokens = np.zeros((bucket, 1), np.int32)
+            tokens[:n, 0] = np.asarray(prompt_ids, np.int32)
+            ids = np.full((np_b,), cache_lib.NULL_PAGE, np.int32)
+            ids[: len(page_ids)] = np.asarray(page_ids, np.int32)
+            compiled = self._get_prefill(bucket)
+            args = (
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(n, jnp.int32), jnp.asarray(ids),
+                jnp.asarray(temperature, jnp.float32),
+                self._sample_key(self.prefill_calls),
             )
+            self._sentinels[name].observe(*args)
+        self.prefill_calls += 1
+        # int(next_token) syncs, so the phase covers the real device
+        # time, not just the async dispatch
+        with self._phase("engine/prefill", bucket=bucket, tokens=n,
+                         call=self.prefill_calls):
+            logits, next_token, finite, self.cache = compiled(*args)
+            # logits stay ON DEVICE (lazy jax.Array): only the sampled
+            # token and the scalar finite screen cross to the host —
+            # the logits matrix is (V,)/(B, V) and most callers never
+            # read it
+            first = int(next_token)
+            self.last_prefill_finite = bool(finite) and poison is None
         return logits, first
 
     def chunk_prefill(self, chunk_ids, offset, page_table_row,
@@ -799,41 +806,38 @@ class InferenceEngine:
         poison = self._chaos_gate(chaos.SERVE_PREFILL, self.prefill_calls)
         n = len(chunk_ids)
         bucket = self.bucket_for(n)
-        np_b = bucket // self.serve.page_size
-        tokens = np.zeros((bucket, 1), np.int32)
-        tokens[:n, 0] = np.asarray(chunk_ids, np.int32)
-        ids = np.full((np_b,), cache_lib.NULL_PAGE, np.int32)
-        ids[: len(chunk_page_ids)] = np.asarray(chunk_page_ids, np.int32)
-        table = np.full(
-            (self.serve.max_pages_per_seq,), cache_lib.NULL_PAGE, np.int32
-        )
-        table[: len(page_table_row)] = np.asarray(
-            page_table_row, np.int32
-        )
-        compiled = self._get_chunk(bucket)
         name = f"chunk_prefill_{bucket}"
-        args = (
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(n, jnp.int32), jnp.asarray(offset, jnp.int32),
-            jnp.asarray(ids), jnp.asarray(table),
-            jnp.asarray(temperature, jnp.float32),
-            self._sample_key(self.prefill_calls),
-        )
-        self._sentinels[name].observe(*args)
-        self.prefill_calls += 1
-        rec = self.spans
-        t0 = rec.now() if rec is not None else None
-        logits, next_token, finite, self.cache = compiled(*args)
-        first = int(next_token)
-        self.last_prefill_finite = bool(finite) and poison is None
-        if rec is not None:
-            from apex_tpu.observability.spans import TRACK_ENGINE
-
-            rec.span(
-                "engine/prefill", t0, rec.now(), track=TRACK_ENGINE,
-                bucket=bucket, tokens=n, offset=int(offset),
-                call=self.prefill_calls, chunked=True,
+        with self._phase("engine/stage", program=name):
+            np_b = bucket // self.serve.page_size
+            tokens = np.zeros((bucket, 1), np.int32)
+            tokens[:n, 0] = np.asarray(chunk_ids, np.int32)
+            ids = np.full((np_b,), cache_lib.NULL_PAGE, np.int32)
+            ids[: len(chunk_page_ids)] = np.asarray(
+                chunk_page_ids, np.int32
             )
+            table = np.full(
+                (self.serve.max_pages_per_seq,), cache_lib.NULL_PAGE,
+                np.int32,
+            )
+            table[: len(page_table_row)] = np.asarray(
+                page_table_row, np.int32
+            )
+            compiled = self._get_chunk(bucket)
+            args = (
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(n, jnp.int32), jnp.asarray(offset, jnp.int32),
+                jnp.asarray(ids), jnp.asarray(table),
+                jnp.asarray(temperature, jnp.float32),
+                self._sample_key(self.prefill_calls),
+            )
+            self._sentinels[name].observe(*args)
+        self.prefill_calls += 1
+        with self._phase("engine/prefill", bucket=bucket, tokens=n,
+                         offset=int(offset), call=self.prefill_calls,
+                         chunked=True):
+            logits, next_token, finite, self.cache = compiled(*args)
+            first = int(next_token)
+            self.last_prefill_finite = bool(finite) and poison is None
         return logits, first
 
     def fork_page(self, src: int, dst: int) -> None:
@@ -868,33 +872,36 @@ class InferenceEngine:
         paths bit-identical.  None keeps the legacy per-iteration key
         chain (one fold per call, split per slot)."""
         poison = self._chaos_gate(chaos.SERVE_DECODE, self.decode_iters)
-        compiled = self._get_decode()
-        if streams is None:
-            rng = jax.vmap(jax.random.fold_in, (None, 0))(
-                self._sample_key(self.decode_iters),
-                jnp.arange(self.serve.max_batch, dtype=jnp.uint32),
+        with self._phase("engine/stage", program="decode"):
+            compiled = self._get_decode()
+            if streams is None:
+                rng = jax.vmap(jax.random.fold_in, (None, 0))(
+                    self._sample_key(self.decode_iters),
+                    jnp.arange(self.serve.max_batch, dtype=jnp.uint32),
+                )
+            else:
+                rng = spec_lib._fold_each(
+                    self._stream_keys(streams),
+                    jnp.asarray(gens, jnp.int32),
+                )
+            args = (
+                self.params,
+                self.cache,
+                jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(lengths, jnp.int32),
+                jnp.asarray(page_tables, jnp.int32),
+                jnp.zeros((self.serve.max_batch,), jnp.float32)
+                if temps is None else jnp.asarray(temps, jnp.float32),
+                rng,
             )
-        else:
-            rng = spec_lib._fold_each(
-                self._stream_keys(streams), jnp.asarray(gens, jnp.int32)
-            )
-        args = (
-            self.params,
-            self.cache,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(lengths, jnp.int32),
-            jnp.asarray(page_tables, jnp.int32),
-            jnp.zeros((self.serve.max_batch,), jnp.float32)
-            if temps is None else jnp.asarray(temps, jnp.float32),
-            rng,
-        )
-        self._sentinels["decode"].observe(*args)
+            self._sentinels["decode"].observe(*args)
         self.decode_iters += 1
-        rec = self.spans
-        t0 = rec.now() if rec is not None else None
-        logits, next_tokens, finite, self.cache = compiled(*args)
-        out = np.asarray(next_tokens)
-        finite_np = np.array(finite)
+        # np.asarray(next_tokens) syncs — real device time
+        with self._phase("engine/decode", iter=self.decode_iters,
+                         batch=int((np.asarray(lengths) > 0).sum())):
+            logits, next_tokens, finite, self.cache = compiled(*args)
+            out = np.asarray(next_tokens)
+            finite_np = np.array(finite)
         if poison is not None:
             # an injected poisoned-logits fault: flag the first LIVE
             # slot exactly as the in-step screen would flag a real
@@ -904,15 +911,6 @@ class InferenceEngine:
             if live.size:
                 finite_np[live[0]] = False
         self.last_decode_finite = finite_np
-        if rec is not None:
-            # np.asarray(next_tokens) above synced — real device time
-            from apex_tpu.observability.spans import TRACK_ENGINE
-
-            rec.span(
-                "engine/decode", t0, rec.now(), track=TRACK_ENGINE,
-                iter=self.decode_iters,
-                batch=int((np.asarray(lengths) > 0).sum()),
-            )
         return logits, out
 
     def reset_cache(self) -> None:
@@ -1067,69 +1065,72 @@ class InferenceEngine:
         self.spec_rounds += 1
         fault = self._chaos_gate(chaos.SERVE_DRAFT, round_idx)
         poison = self._chaos_gate(chaos.SERVE_DECODE, self.decode_iters)
-        tok = jnp.asarray(tokens, jnp.int32)
-        lens = jnp.asarray(lengths, jnp.int32)
-        temps_j = (jnp.zeros((s.max_batch,), jnp.float32)
-                   if temps is None else jnp.asarray(temps, jnp.float32))
-        keys = self._stream_keys(streams)
-        gens_j = jnp.asarray(gens, jnp.int32)
-        d_args = (
-            self.draft_params, self.draft_cache, tok, lens,
-            jnp.asarray(draft_tables, jnp.int32), temps_j, keys, gens_j,
-        )
-        compiled = self._get_draft()
-        self._sentinels["draft_decode"].observe(*d_args)
-        d_tokens, d_probs, d_finite, self.draft_cache = compiled(*d_args)
-        bad = jnp.logical_not(d_finite)
-        if fault is not None:
-            bad = jnp.ones_like(bad)
-        if spec.k:
-            # a faulted/non-finite draft must not smuggle a token into
-            # the stream: pin its proposals to one fixed id and claim
-            # the matching point-mass draft distribution — the
-            # rejection sampler preserves the target distribution for
-            # ANY claimed q consistent with how d was drawn, and greedy
-            # only ever emits the argmax chain, so a poisoned round
-            # degrades to ~zero acceptance instead of corruption
-            pin = jnp.full_like(d_tokens, self.cfg.vocab_size - 1)
-            d_tokens = jnp.where(bad[:, None], pin, d_tokens)
-            d_probs = jnp.where(
-                bad[None, :, None],
-                jax.nn.one_hot(
-                    jnp.transpose(pin), self.cfg.vocab_size,
-                    dtype=jnp.float32,
-                ),
-                d_probs,
+        with self._phase("engine/stage", program="spec"):
+            # the draft program's dispatch is part of staging the
+            # verify call: its device time is waited for under
+            # engine/decode, at the first host read
+            tok = jnp.asarray(tokens, jnp.int32)
+            lens = jnp.asarray(lengths, jnp.int32)
+            temps_j = (jnp.zeros((s.max_batch,), jnp.float32)
+                       if temps is None
+                       else jnp.asarray(temps, jnp.float32))
+            keys = self._stream_keys(streams)
+            gens_j = jnp.asarray(gens, jnp.int32)
+            d_args = (
+                self.draft_params, self.draft_cache, tok, lens,
+                jnp.asarray(draft_tables, jnp.int32), temps_j, keys,
+                gens_j,
             )
-        v_args = (
-            self.params, self.cache, tok, d_tokens, lens,
-            jnp.asarray(page_tables, jnp.int32), temps_j, d_probs,
-            keys, gens_j,
-        )
-        compiled = self._get_verify()
-        self._sentinels["verify"].observe(*v_args)
+            compiled = self._get_draft()
+            self._sentinels["draft_decode"].observe(*d_args)
+            d_tokens, d_probs, d_finite, self.draft_cache = compiled(
+                *d_args
+            )
+            bad = jnp.logical_not(d_finite)
+            if fault is not None:
+                bad = jnp.ones_like(bad)
+            if spec.k:
+                # a faulted/non-finite draft must not smuggle a token
+                # into the stream: pin its proposals to one fixed id
+                # and claim the matching point-mass draft distribution
+                # — the rejection sampler preserves the target
+                # distribution for ANY claimed q consistent with how d
+                # was drawn, and greedy only ever emits the argmax
+                # chain, so a poisoned round degrades to ~zero
+                # acceptance instead of corruption
+                pin = jnp.full_like(d_tokens, self.cfg.vocab_size - 1)
+                d_tokens = jnp.where(bad[:, None], pin, d_tokens)
+                d_probs = jnp.where(
+                    bad[None, :, None],
+                    jax.nn.one_hot(
+                        jnp.transpose(pin), self.cfg.vocab_size,
+                        dtype=jnp.float32,
+                    ),
+                    d_probs,
+                )
+            v_args = (
+                self.params, self.cache, tok, d_tokens, lens,
+                jnp.asarray(page_tables, jnp.int32), temps_j, d_probs,
+                keys, gens_j,
+            )
+            compiled = self._get_verify()
+            self._sentinels["verify"].observe(*v_args)
         self.decode_iters += 1
-        rec = self.spans
-        t0 = rec.now() if rec is not None else None
-        out_tokens, n_accept, finite, self.cache = compiled(*v_args)
-        out = np.asarray(out_tokens)
-        acc = np.asarray(n_accept)
-        finite_np = np.array(finite)
+        live_n = int((np.asarray(lengths) > 0).sum())
+        # np.asarray(out_tokens) syncs — real device time
+        with self._phase("engine/decode", iter=self.decode_iters,
+                         batch=live_n, spec=True,
+                         drafted=spec.k * live_n) as ph:
+            out_tokens, n_accept, finite, self.cache = compiled(*v_args)
+            out = np.asarray(out_tokens)
+            acc = np.asarray(n_accept)
+            finite_np = np.array(finite)
+            ph.set(accepted=int(acc.sum()))
         if poison is not None:
             live = np.flatnonzero(np.asarray(lengths) > 0)
             if live.size:
                 finite_np[live[0]] = False
         self.last_decode_finite = finite_np
-        if rec is not None:
-            # np.asarray(out_tokens) above synced — real device time
-            from apex_tpu.observability.spans import TRACK_ENGINE
-
-            live_n = int((np.asarray(lengths) > 0).sum())
-            rec.span(
-                "engine/decode", t0, rec.now(), track=TRACK_ENGINE,
-                iter=self.decode_iters, batch=live_n, spec=True,
-                drafted=spec.k * live_n, accepted=int(acc.sum()),
-            )
         return out, acc, finite_np
 
     def rollback(self, starts, counts, page_tables) -> None:

@@ -133,6 +133,16 @@ when TTFT is dominated by starved admission.  With a
 ``queued → admitted → prefill → decode[i] → done|shed(reason)`` with
 engine decode-iteration correlation ids — the per-request causal
 record ``tools/timeline.py`` merges into one Perfetto timeline.
+
+Host phases: what the host does inside ``step()`` is named by
+:meth:`~apex_tpu.observability.spans.SpanRecorder.phase` —
+``serve/step`` and under it ``serve/admit``, ``serve/chunks``,
+``serve/batch``, ``serve/retire``, ``serve/publish``, with the engine's
+``engine/stage`` / ``engine/prefill`` / ``engine/decode`` between them
+(docs/serving.md "Host phases").  They go to the attached recorder and,
+with none attached, to the ring every process keeps
+(:func:`~apex_tpu.observability.spans.process_recorder`); the request
+lifecycle above is recorded on an attached recorder only.
 """
 
 from __future__ import annotations
@@ -151,6 +161,7 @@ from apex_tpu.observability.ometrics import (
     DEFAULT_LATENCY_BUCKETS_MS,
     Histogram,
 )
+from apex_tpu.observability.spans import host_recorder
 from apex_tpu.resilience import chaos
 from apex_tpu.serve.cache import NULL_PAGE, PrefixCache
 
@@ -499,6 +510,7 @@ class ContinuousBatchingScheduler:
         self.completed: List[Request] = []
         self.shed: List[Request] = []
         self._step = 0
+        self._riders = 0       # slots that rode this step's decode calls
         # tokens/s over a sliding window of (time, cumulative tokens)
         self._tokens_out = 0
         self._window: Deque = collections.deque(maxlen=window)
@@ -844,6 +856,20 @@ class ContinuousBatchingScheduler:
         slot = self._free_slot()
         if slot is None:
             return False
+        # one serve/admit phase per admission that runs a prefill; an
+        # attempt that ran none (the head waits for pages, re-admits
+        # past its first token, sheds, or parks for chunked prefill)
+        # leaves no phase
+        with self._phase("serve/admit") as ph:
+            calls = self.engine.prefill_calls
+            progressed = self._admit_head(slot, ph)
+            if self.engine.prefill_calls == calls:
+                ph.drop()
+        return progressed
+
+    def _admit_head(self, slot: int, ph) -> bool:
+        """:meth:`_admit_one` past the free-slot check, inside its
+        ``serve/admit`` phase ``ph``."""
         # chaos: the serve.admission site — a transient admission-path
         # fault leaves the head queued (retried next iteration), never
         # kills the process
@@ -974,6 +1000,8 @@ class ContinuousBatchingScheduler:
             # iterations — a long cold prompt no longer stalls running
             # streams, and a cache hit re-runs only its final chunk
             return self._start_chunked_prefill(req, slot)
+        ph.set(rid=req.rid, bucket=self.engine.bucket_for(len(req.prompt)),
+               prompt_tokens=len(req.prompt))
         try:
             _, first = self.engine.prefill(
                 req.prompt, pages, temperature=req.temperature
@@ -1045,8 +1073,12 @@ class ContinuousBatchingScheduler:
             self._finish_prefill(req, slot, first)
 
     def _advance_prefills(self) -> None:
-        for i, req in enumerate(self.slots):
-            if req is not None and req.status == PREFILLING:
+        work = [(i, req) for i, req in enumerate(self.slots)
+                if req is not None and req.status == PREFILLING]
+        if not work:
+            return
+        with self._phase("serve/chunks"):
+            for i, req in work:
                 self._advance_prefill(req, i)
 
     def _finish_prefill(self, req: Request, slot: int, first: int) -> bool:
@@ -1179,49 +1211,50 @@ class ContinuousBatchingScheduler:
         """One plain (single-token) decode iteration.  ``only`` limits
         the pass to the given slot indices (the non-speculative side of
         a mixed batch); ``None`` rides every running slot."""
-        b = len(self.slots)
-        tokens = np.zeros((b,), np.int32)
-        lengths = np.zeros((b,), np.int32)
-        temps = np.zeros((b,), np.float32)
-        streams = np.zeros((b,), np.uint32)
-        gens = np.zeros((b,), np.int32)
-        tables = np.full(
-            (b, self.serve.max_pages_per_seq), NULL_PAGE, np.int32
-        )
-        for i, req in enumerate(self.slots):
-            if req is None or req.status == PREFILLING:
-                # a prefilling slot rides no decode iteration — its
-                # context advances one chunk per step instead
-                continue
-            if only is not None and i not in only:
-                continue
-            if not self._ensure_growth_page(req):
-                # pool exhausted mid-decode: shed the youngest running
-                # request (least sunk cost) and retry this one
-                victims = sorted(
-                    self.running, key=lambda r: r.submitted_at or 0.0
-                )
-                victim = victims[-1]
-                v_slot = self.slots.index(victim)
-                self.slots[v_slot] = None
-                self._shed_request(victim, SHED_GROWTH_VICTIM)
-                # the victim's row may already be staged for this
-                # iteration — clear it so the decode never touches its
-                # (now freed) pages
-                tokens[v_slot] = 0
-                lengths[v_slot] = 0
-                tables[v_slot] = NULL_PAGE
-                if victim is req or not self._ensure_growth_page(req):
-                    if self.slots[i] is req:
-                        self.slots[i] = None
-                        self._shed_request(req, SHED_POOL_EXHAUSTED)
+        with self._phase("serve/batch"):
+            b = len(self.slots)
+            tokens = np.zeros((b,), np.int32)
+            lengths = np.zeros((b,), np.int32)
+            temps = np.zeros((b,), np.float32)
+            streams = np.zeros((b,), np.uint32)
+            gens = np.zeros((b,), np.int32)
+            tables = np.full(
+                (b, self.serve.max_pages_per_seq), NULL_PAGE, np.int32
+            )
+            for i, req in enumerate(self.slots):
+                if req is None or req.status == PREFILLING:
+                    # a prefilling slot rides no decode iteration — its
+                    # context advances one chunk per step instead
                     continue
-            tokens[i] = req.tokens[-1]
-            lengths[i] = req.ctx_len + 1  # context incl. the fed token
-            temps[i] = req.temperature
-            streams[i] = self._stream(req)
-            gens[i] = len(req.tokens) - 1
-            tables[i] = self._page_table_row(req)
+                if only is not None and i not in only:
+                    continue
+                if not self._ensure_growth_page(req):
+                    # pool exhausted mid-decode: shed the youngest running
+                    # request (least sunk cost) and retry this one
+                    victims = sorted(
+                        self.running, key=lambda r: r.submitted_at or 0.0
+                    )
+                    victim = victims[-1]
+                    v_slot = self.slots.index(victim)
+                    self.slots[v_slot] = None
+                    self._shed_request(victim, SHED_GROWTH_VICTIM)
+                    # the victim's row may already be staged for this
+                    # iteration — clear it so the decode never touches its
+                    # (now freed) pages
+                    tokens[v_slot] = 0
+                    lengths[v_slot] = 0
+                    tables[v_slot] = NULL_PAGE
+                    if victim is req or not self._ensure_growth_page(req):
+                        if self.slots[i] is req:
+                            self.slots[i] = None
+                            self._shed_request(req, SHED_POOL_EXHAUSTED)
+                        continue
+                tokens[i] = req.tokens[-1]
+                lengths[i] = req.ctx_len + 1  # context incl. the fed token
+                temps[i] = req.temperature
+                streams[i] = self._stream(req)
+                gens[i] = len(req.tokens) - 1
+                tables[i] = self._page_table_row(req)
         if not lengths.any():
             return
         t0 = self.clock()
@@ -1236,54 +1269,56 @@ class ContinuousBatchingScheduler:
             # bounded retry while the engine rebuilds under supervision
             self._on_engine_fault(e)
             return
-        elapsed_ms = 1e3 * (self.clock() - t0)
-        finite = self.engine.last_decode_finite
-        self._count("serve/decode_steps")
-        # engine-numbered iteration id: the correlation key linking a
-        # request's decode span to the engine batch iterations it rode
-        it = getattr(self.engine, "decode_iters", None)
-        for i, req in enumerate(self.slots):
-            if req is None or req.status == PREFILLING:
-                continue
-            if only is not None and i not in only:
-                continue
-            if finite is not None and not bool(finite[i]):
-                # poisoned-request quarantine: a non-finite logits row
-                # evicts ONLY the offending slot — its token is
-                # garbage, its KV is suspect — while the rest of the
-                # batch keeps its tokens from this very iteration
-                self.slots[i] = None
-                self._shed_request(req, SHED_POISONED)
-                continue
-            timeout_ms = (
-                req.decode_timeout_ms
-                if req.decode_timeout_ms is not None
-                else self.decode_timeout_ms
-            )
-            if timeout_ms is not None and elapsed_ms > timeout_ms:
-                # a hung iteration (per-request budget): discard this
-                # request's token from the suspect step — the KV append
-                # is positionally idempotent, so the retried decode
-                # rewrites the same slot — and re-admit with the prefix
-                # preserved
-                self._count("serve/decode_timeouts")
-                self.slots[i] = None
-                self._send_to_retry(
-                    req, f"decode_timeout:{elapsed_ms:.0f}ms"
+        with self._phase("serve/retire"):
+            elapsed_ms = 1e3 * (self.clock() - t0)
+            finite = self.engine.last_decode_finite
+            self._riders += int((lengths > 0).sum())
+            self._count("serve/decode_steps")
+            # engine-numbered iteration id: the correlation key linking a
+            # request's decode span to the engine batch iterations it rode
+            it = getattr(self.engine, "decode_iters", None)
+            for i, req in enumerate(self.slots):
+                if req is None or req.status == PREFILLING:
+                    continue
+                if only is not None and i not in only:
+                    continue
+                if finite is not None and not bool(finite[i]):
+                    # poisoned-request quarantine: a non-finite logits row
+                    # evicts ONLY the offending slot — its token is
+                    # garbage, its KV is suspect — while the rest of the
+                    # batch keeps its tokens from this very iteration
+                    self.slots[i] = None
+                    self._shed_request(req, SHED_POISONED)
+                    continue
+                timeout_ms = (
+                    req.decode_timeout_ms
+                    if req.decode_timeout_ms is not None
+                    else self.decode_timeout_ms
                 )
-                continue
-            if it is not None:
-                if req.first_decode_iter is None:
-                    req.first_decode_iter = it
-                req.last_decode_iter = it
-            req.ctx_len += 1
-            req.tokens.append(int(next_tokens[i]))
-            self._tokens_out += 1
-            self._count("serve/tokens_out")
-            if self._finished(req):
-                self.slots[i] = None
-                self._retire(req, DONE)
-                self._count("serve/completed")
+                if timeout_ms is not None and elapsed_ms > timeout_ms:
+                    # a hung iteration (per-request budget): discard this
+                    # request's token from the suspect step — the KV append
+                    # is positionally idempotent, so the retried decode
+                    # rewrites the same slot — and re-admit with the prefix
+                    # preserved
+                    self._count("serve/decode_timeouts")
+                    self.slots[i] = None
+                    self._send_to_retry(
+                        req, f"decode_timeout:{elapsed_ms:.0f}ms"
+                    )
+                    continue
+                if it is not None:
+                    if req.first_decode_iter is None:
+                        req.first_decode_iter = it
+                    req.last_decode_iter = it
+                req.ctx_len += 1
+                req.tokens.append(int(next_tokens[i]))
+                self._tokens_out += 1
+                self._count("serve/tokens_out")
+                if self._finished(req):
+                    self.slots[i] = None
+                    self._retire(req, DONE)
+                    self._count("serve/completed")
 
     # -- speculative decoding ---------------------------------------------
     def _stream(self, req: Request) -> int:
@@ -1303,26 +1338,27 @@ class ContinuousBatchingScheduler:
         whose window cannot be provisioned, streams near the context
         ceiling — rides plain decode.  Mixed batches are the steady
         state, not an edge case."""
-        k = self.engine.spec.k
-        spec_idx: List[int] = []
-        plain_idx: List[int] = []
-        for i, req in enumerate(self.slots):
-            if req is None or req.status == PREFILLING:
-                continue
-            if (
-                req.spec_ok
-                and req.draft_pages
-                and req.ctx_len + 1 + k <= self.serve.max_context
-            ):
-                spec_idx.append(i)
-            else:
-                plain_idx.append(i)
-        for i in list(spec_idx):
-            if not self._ensure_spec_span(self.slots[i]):
-                # cannot provision the whole window: demote for THIS
-                # round only — the pool may free up by the next one
-                spec_idx.remove(i)
-                plain_idx.append(i)
+        with self._phase("serve/batch"):
+            k = self.engine.spec.k
+            spec_idx: List[int] = []
+            plain_idx: List[int] = []
+            for i, req in enumerate(self.slots):
+                if req is None or req.status == PREFILLING:
+                    continue
+                if (
+                    req.spec_ok
+                    and req.draft_pages
+                    and req.ctx_len + 1 + k <= self.serve.max_context
+                ):
+                    spec_idx.append(i)
+                else:
+                    plain_idx.append(i)
+            for i in list(spec_idx):
+                if not self._ensure_spec_span(self.slots[i]):
+                    # cannot provision the whole window: demote for THIS
+                    # round only — the pool may free up by the next one
+                    spec_idx.remove(i)
+                    plain_idx.append(i)
         if spec_idx:
             self._spec_round(spec_idx, k)
         if plain_idx:
@@ -1335,27 +1371,28 @@ class ContinuousBatchingScheduler:
         the token plain decode would have produced; the rejected tail's
         KV (target and draft) is truncated afterwards so no stale entry
         outlives the round."""
-        b = len(self.slots)
-        tokens = np.zeros((b,), np.int32)
-        lengths = np.zeros((b,), np.int32)
-        temps = np.zeros((b,), np.float32)
-        streams = np.zeros((b,), np.uint32)
-        gens = np.zeros((b,), np.int32)
-        tables = np.full(
-            (b, self.serve.max_pages_per_seq), NULL_PAGE, np.int32
-        )
-        dtables = np.full(
-            (b, self.serve.max_pages_per_seq), NULL_PAGE, np.int32
-        )
-        for i in idx:
-            req = self.slots[i]
-            tokens[i] = req.tokens[-1]
-            lengths[i] = req.ctx_len + 1  # context incl. the fed token
-            temps[i] = req.temperature
-            streams[i] = self._stream(req)
-            gens[i] = len(req.tokens) - 1
-            tables[i] = self._page_table_row(req)
-            dtables[i, : len(req.draft_pages)] = req.draft_pages
+        with self._phase("serve/batch"):
+            b = len(self.slots)
+            tokens = np.zeros((b,), np.int32)
+            lengths = np.zeros((b,), np.int32)
+            temps = np.zeros((b,), np.float32)
+            streams = np.zeros((b,), np.uint32)
+            gens = np.zeros((b,), np.int32)
+            tables = np.full(
+                (b, self.serve.max_pages_per_seq), NULL_PAGE, np.int32
+            )
+            dtables = np.full(
+                (b, self.serve.max_pages_per_seq), NULL_PAGE, np.int32
+            )
+            for i in idx:
+                req = self.slots[i]
+                tokens[i] = req.tokens[-1]
+                lengths[i] = req.ctx_len + 1  # context incl. the fed token
+                temps[i] = req.temperature
+                streams[i] = self._stream(req)
+                gens[i] = len(req.tokens) - 1
+                tables[i] = self._page_table_row(req)
+                dtables[i, : len(req.draft_pages)] = req.draft_pages
         t0 = self.clock()
         try:
             out, acc, finite = self.engine.spec_step(
@@ -1374,93 +1411,100 @@ class ContinuousBatchingScheduler:
         except Exception as e:
             self._on_engine_fault(e)
             return
-        elapsed_ms = 1e3 * (self.clock() - t0)
-        self._count("serve/decode_steps")
-        self._count("serve/spec_rounds")
-        it = getattr(self.engine, "decode_iters", None)
-        rb_starts = np.zeros((b,), np.int32)
-        rb_counts = np.zeros((b,), np.int32)
-        drafted = accepted = emitted = slot_steps = 0
-        for i in idx:
-            req = self.slots[i]
-            slot_steps += 1
-            if finite is not None and not bool(finite[i]):
-                # poisoned VERIFY output — the target's own logits are
-                # garbage, same quarantine as a poisoned plain step
-                self.slots[i] = None
-                self._shed_request(req, SHED_POISONED)
-                continue
-            timeout_ms = (
-                req.decode_timeout_ms
-                if req.decode_timeout_ms is not None
-                else self.decode_timeout_ms
-            )
-            if timeout_ms is not None and elapsed_ms > timeout_ms:
-                self._count("serve/decode_timeouts")
-                self.slots[i] = None
-                self._send_to_retry(
-                    req, f"decode_timeout:{elapsed_ms:.0f}ms"
+        with self._phase("serve/retire"):
+            elapsed_ms = 1e3 * (self.clock() - t0)
+            self._riders += len(idx)
+            self._count("serve/decode_steps")
+            self._count("serve/spec_rounds")
+            it = getattr(self.engine, "decode_iters", None)
+            rb_starts = np.zeros((b,), np.int32)
+            rb_counts = np.zeros((b,), np.int32)
+            drafted = accepted = emitted = slot_steps = 0
+            for i in idx:
+                req = self.slots[i]
+                slot_steps += 1
+                if finite is not None and not bool(finite[i]):
+                    # poisoned VERIFY output — the target's own logits are
+                    # garbage, same quarantine as a poisoned plain step
+                    self.slots[i] = None
+                    self._shed_request(req, SHED_POISONED)
+                    continue
+                timeout_ms = (
+                    req.decode_timeout_ms
+                    if req.decode_timeout_ms is not None
+                    else self.decode_timeout_ms
                 )
-                continue
-            if it is not None:
-                if req.first_decode_iter is None:
-                    req.first_decode_iter = it
-                req.last_decode_iter = it
-            a = int(acc[i])
-            drafted += k
-            accepted += a
-            start_ctx = req.ctx_len
-            n_emit = 0
-            for t in out[i, : a + 1]:
-                req.ctx_len += 1
-                req.tokens.append(int(t))
-                n_emit += 1
-                self._tokens_out += 1
+                if timeout_ms is not None and elapsed_ms > timeout_ms:
+                    self._count("serve/decode_timeouts")
+                    self.slots[i] = None
+                    self._send_to_retry(
+                        req, f"decode_timeout:{elapsed_ms:.0f}ms"
+                    )
+                    continue
+                if it is not None:
+                    if req.first_decode_iter is None:
+                        req.first_decode_iter = it
+                    req.last_decode_iter = it
+                a = int(acc[i])
+                drafted += k
+                accepted += a
+                start_ctx = req.ctx_len
+                n_emit = 0
+                for t in out[i, : a + 1]:
+                    req.ctx_len += 1
+                    req.tokens.append(int(t))
+                    n_emit += 1
+                    self._tokens_out += 1
+                    if self._finished(req):
+                        break
+                emitted += n_emit
+                self._count("serve/tokens_out", n_emit)
                 if self._finished(req):
-                    break
-            emitted += n_emit
-            self._count("serve/tokens_out", n_emit)
-            if self._finished(req):
-                self.slots[i] = None
-                self._retire(req, DONE)
-                self._count("serve/completed")
-            else:
-                # the round wrote target KV at [start_ctx, start_ctx+k];
-                # everything past the new context is a rejected draft's
-                # residue and is truncated below (slots that retired or
-                # shed keep counts 0 — the rollback masks them to the
-                # null page)
-                stale = start_ctx + k + 1 - req.ctx_len
-                if stale > 0:
-                    rb_starts[i] = req.ctx_len
-                    rb_counts[i] = stale
-        if rb_counts.any():
-            self.engine.rollback(rb_starts, rb_counts, tables)
-            self.engine.draft_rollback(rb_starts, rb_counts, dtables)
-            self._count(
-                "serve/spec_rollbacks", int((rb_counts > 0).sum())
-            )
-        self._count("serve/spec_drafted", drafted)
-        self._count("serve/spec_accepted", accepted)
-        if drafted > accepted:
-            self._count("serve/spec_rejected", drafted - accepted)
-        if self._spec_window is not None:
-            self._spec_window.append(
-                (drafted, accepted, emitted, slot_steps)
-            )
-            if len(self._spec_window) == self._spec_window.maxlen:
-                tot_d = sum(w[0] for w in self._spec_window)
-                tot_a = sum(w[1] for w in self._spec_window)
-                if tot_d and (
-                    tot_a / tot_d < self.engine.spec.min_accept_rate
-                ):
-                    # degradation ladder: speculation is costing more
-                    # than it saves — fall back to plain decode until
-                    # an operator resume() re-arms it
-                    self._spec_fallback = True
-                    self._count("serve/spec_fallbacks")
+                    self.slots[i] = None
+                    self._retire(req, DONE)
+                    self._count("serve/completed")
+                else:
+                    # the round wrote target KV at [start_ctx, start_ctx+k];
+                    # everything past the new context is a rejected draft's
+                    # residue and is truncated below (slots that retired or
+                    # shed keep counts 0 — the rollback masks them to the
+                    # null page)
+                    stale = start_ctx + k + 1 - req.ctx_len
+                    if stale > 0:
+                        rb_starts[i] = req.ctx_len
+                        rb_counts[i] = stale
+            if rb_counts.any():
+                self.engine.rollback(rb_starts, rb_counts, tables)
+                self.engine.draft_rollback(rb_starts, rb_counts, dtables)
+                self._count(
+                    "serve/spec_rollbacks", int((rb_counts > 0).sum())
+                )
+            self._count("serve/spec_drafted", drafted)
+            self._count("serve/spec_accepted", accepted)
+            if drafted > accepted:
+                self._count("serve/spec_rejected", drafted - accepted)
+            if self._spec_window is not None:
+                self._spec_window.append(
+                    (drafted, accepted, emitted, slot_steps)
+                )
+                if len(self._spec_window) == self._spec_window.maxlen:
+                    tot_d = sum(w[0] for w in self._spec_window)
+                    tot_a = sum(w[1] for w in self._spec_window)
+                    if tot_d and (
+                        tot_a / tot_d < self.engine.spec.min_accept_rate
+                    ):
+                        # degradation ladder: speculation is costing more
+                        # than it saves — fall back to plain decode until
+                        # an operator resume() re-arms it
+                        self._spec_fallback = True
+                        self._count("serve/spec_fallbacks")
 
     # -- metrics ----------------------------------------------------------
+    def _phase(self, name: str, **args):
+        """A host phase (docs/serving.md "Host phases") on the attached
+        recorder, else on the process ring every deployment keeps."""
+        return host_recorder(self.spans).phase(name, **args)
+
     def _count(self, name: str, n: float = 1.0) -> None:
         if self._mstate is not None:
             self._mstate = self.registry.update(self._mstate, {name: n})
@@ -1493,76 +1537,91 @@ class ContinuousBatchingScheduler:
         self._mstate = self.registry.update(self._mstate, updates)
 
     def _publish(self) -> None:
-        now = self.clock()
-        self._window.append((now, self._tokens_out))
-        tps = 0.0
-        if len(self._window) >= 2:
-            (t0, n0), (t1, n1) = self._window[0], self._window[-1]
-            if t1 > t0:
-                tps = (n1 - n0) / (t1 - t0)
-        self._gauge("serve/queue_depth", len(self.queue))
-        self._gauge("serve/batch_fill", self.batch_fill())
-        self._gauge("serve/page_occupancy", self.pool.occupancy())
-        self._gauge("serve/tokens_per_s", tps)
-        if self.prefix is not None:
-            self._gauge(
-                "serve/prefix_cached_pages",
-                float(len(self.prefix.cached_pages())),
-            )
-        if self._spec_window:
-            tot_d = sum(w[0] for w in self._spec_window)
-            tot_a = sum(w[1] for w in self._spec_window)
-            tot_e = sum(w[2] for w in self._spec_window)
-            tot_s = sum(w[3] for w in self._spec_window)
-            self._gauge(
-                "serve/spec_accept_rate", tot_a / tot_d if tot_d else 0.0
-            )
-            self._gauge(
-                "serve/spec_tokens_per_step",
-                tot_e / tot_s if tot_s else 0.0,
-            )
-        self._publish_attribution()
-        if self._mstate is not None:
-            self.registry.observe(self._step, self._mstate)
+        with self._phase("serve/publish"):
+            now = self.clock()
+            self._window.append((now, self._tokens_out))
+            tps = 0.0
+            if len(self._window) >= 2:
+                (t0, n0), (t1, n1) = self._window[0], self._window[-1]
+                if t1 > t0:
+                    tps = (n1 - n0) / (t1 - t0)
+            self._gauge("serve/queue_depth", len(self.queue))
+            self._gauge("serve/batch_fill", self.batch_fill())
+            self._gauge("serve/page_occupancy", self.pool.occupancy())
+            self._gauge("serve/tokens_per_s", tps)
+            if self.prefix is not None:
+                self._gauge(
+                    "serve/prefix_cached_pages",
+                    float(len(self.prefix.cached_pages())),
+                )
+            if self._spec_window:
+                tot_d = sum(w[0] for w in self._spec_window)
+                tot_a = sum(w[1] for w in self._spec_window)
+                tot_e = sum(w[2] for w in self._spec_window)
+                tot_s = sum(w[3] for w in self._spec_window)
+                self._gauge(
+                    "serve/spec_accept_rate", tot_a / tot_d if tot_d else 0.0
+                )
+                self._gauge(
+                    "serve/spec_tokens_per_step",
+                    tot_e / tot_s if tot_s else 0.0,
+                )
+            self._publish_attribution()
+            if self._mstate is not None:
+                self.registry.observe(self._step, self._mstate)
 
     # -- the iteration ----------------------------------------------------
     def step(self) -> None:
         """One continuous-batching iteration: admit (prefill) into free
         slots, then one decode pass over the running batch."""
-        # admit until slots or pages run out — each prefill slots in
-        # between decode iterations by construction
-        while self._admit_one():
-            pass
-        if self.queue:
-            # admission gave up with requests still queued: they are
-            # resource-blocked (no slot / pool cannot cover the head)
-            # from here until the next admission attempt — the
-            # queue_wait TTFT component.  Only pre-first-token requests
-            # accrue it: a retrying request past its first token is in
-            # RECOVERY wait, which must not pollute TTFT attribution
-            # (the components would stop summing to the measured TTFT).
-            now = self.clock()
-            for r in self.queue:
-                if r.first_token_at is None and r.blocked_since is None:
-                    r.blocked_since = now
-        if self.prefix is not None and chaos.active(
-            chaos.SERVE_PREFIX_EVICT, self._step
-        ) is not None:
-            # forced full eviction sweep (the ``serve.prefix_evict``
-            # chaos drill): every idle cached run is reclaimed at once
-            # — borrowed pages MUST survive (refcount > 1 is never
-            # evictable) and the ledger must stay exact, proven by the
-            # leak check right here
-            self._count("serve/prefix_evict_faults")
-            freed = self.prefix.evict()
-            if freed:
-                self._count("serve/prefix_evictions", freed)
-            if self.leak_checks:
-                self.leak_check()
-        self._advance_prefills()
-        self._decode_once()
-        self._step += 1
-        self._publish()
+        with self._phase("serve/step", step=self._step) as ph:
+            prefills = self.engine.prefill_calls
+            tokens = self._tokens_out
+            retired = len(self.completed) + len(self.shed)
+            self._riders = 0
+            # admit until slots or pages run out — each prefill slots in
+            # between decode iterations by construction
+            while self._admit_one():
+                pass
+            if self.queue:
+                # admission gave up with requests still queued: they are
+                # resource-blocked (no slot / pool cannot cover the head)
+                # from here until the next admission attempt — the
+                # queue_wait TTFT component.  Only pre-first-token requests
+                # accrue it: a retrying request past its first token is in
+                # RECOVERY wait, which must not pollute TTFT attribution
+                # (the components would stop summing to the measured TTFT).
+                now = self.clock()
+                for r in self.queue:
+                    if r.first_token_at is None and r.blocked_since is None:
+                        r.blocked_since = now
+            if self.prefix is not None and chaos.active(
+                chaos.SERVE_PREFIX_EVICT, self._step
+            ) is not None:
+                # forced full eviction sweep (the ``serve.prefix_evict``
+                # chaos drill): every idle cached run is reclaimed at once
+                # — borrowed pages MUST survive (refcount > 1 is never
+                # evictable) and the ledger must stay exact, proven by the
+                # leak check right here
+                self._count("serve/prefix_evict_faults")
+                freed = self.prefix.evict()
+                if freed:
+                    self._count("serve/prefix_evictions", freed)
+                if self.leak_checks:
+                    self.leak_check()
+            self._advance_prefills()
+            self._decode_once()
+            self._step += 1
+            self._publish()
+            # counts measured where the work happens: the engine's
+            # call counter, the decode batches, the token and retire
+            # ledgers
+            ph.set(
+                prefills=self.engine.prefill_calls - prefills,
+                riders=self._riders,
+                tokens=self._tokens_out - tokens,
+                retired=len(self.completed) + len(self.shed) - retired,
+            )
         if self._rebuild_pending and not self.pending:
             # idle point reached in a caller-driven step() loop: run
             # the owed rebuild now, off the traffic path (run()/drain()

@@ -5,12 +5,6 @@ keeps re-exporting them (``apex_tpu.utils.trace`` is used throughout
 bench.py and the tools) so callers need not care where they moved.
 """
 
-from apex_tpu.observability.trace import (
-    annotate,
-    nvtx_range,
-    range_pop,
-    range_push,
-    trace,
-)
+from apex_tpu.observability.trace import annotate, trace
 
-__all__ = ["annotate", "nvtx_range", "range_push", "range_pop", "trace"]
+__all__ = ["annotate", "trace"]

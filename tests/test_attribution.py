@@ -97,6 +97,47 @@ class TestTraceFixtures:
         assert fr["host_stall"] == pytest.approx(0.1)
         assert meas.bucket_ms["matmul"] == pytest.approx(0.675)
 
+    def test_stall_split_by_innermost_host_phase(self):
+        """Idle gaps between device operations go to the innermost
+        serve/* / engine/* annotation open over them, on the trace's
+        own clock; what none covers is `unannotated`; the split sums to
+        the stall."""
+        def op(ts, dur, name):
+            return {"ph": "X", "pid": 1, "tid": 1, "ts": ts, "dur": dur,
+                    "name": name}
+
+        def host(ts, dur, name):
+            return {"ph": "X", "pid": 2, "tid": 7, "ts": ts, "dur": dur,
+                    "name": name}
+
+        trace = {"traceEvents": [
+            {"ph": "M", "pid": 1, "name": "process_name",
+             "args": {"name": "/device:TPU:0"}},
+            {"ph": "M", "pid": 1, "tid": 1, "name": "thread_name",
+             "args": {"name": "XLA Ops"}},
+            {"ph": "M", "pid": 2, "name": "process_name",
+             "args": {"name": "/host:CPU"}},
+            op(0, 1000, "fusion.1"),          # gap 1000..5000
+            op(5000, 1000, "fusion.2"),       # gap 6000..9000
+            op(9000, 1000, "fusion.3"),
+            host(500, 6000, "serve/step"),    # 500..6500
+            host(2000, 2000, "engine/stage"),  # 2000..4000, inside it
+            host(2100, 100, "$scheduler.py:1 _count"),   # not a phase
+            host(4500, 1000, "engine/decode"),  # 4500..5500
+        ]}
+        meas = A.attribute_trace(trace)
+        assert meas.stall_ms == pytest.approx(7.0)
+        by = meas.stall_by_phase_ms
+        # gap 1: step 1000-2000 and 4000-4500, stage 2000-4000,
+        # decode 4500-5000; gap 2: step 6000-6500, nothing after
+        assert by["serve/step"] == pytest.approx(1.0 + 0.5 + 0.5)
+        assert by["engine/stage"] == pytest.approx(2.0)
+        assert by["engine/decode"] == pytest.approx(0.5)
+        assert by[A.UNANNOTATED] == pytest.approx(2.5)
+        assert sum(by.values()) == pytest.approx(meas.stall_ms)
+        # fractions() is untouched
+        assert meas.fractions()["host_stall"] == pytest.approx(0.7)
+
     def test_empty_trace_is_all_zero_not_nan(self):
         meas = A.attribute_trace({"traceEvents": []})
         fr = meas.fractions()

@@ -614,24 +614,25 @@ def test_trace_scheduler_rearms_after_rollback_rewind(tmp_path):
     assert calls2 == ["start", "stop"]
 
 
-def test_profiling_shim_still_exports():
-    """apex_tpu.utils.profiling stays import-compatible after the move,
-    and the package attribute `observability.trace` is the SUBMODULE
-    (the trace() function is deliberately not re-exported — it would
-    shadow the submodule)."""
+def test_trace_submodule_not_shadowed():
+    """The package attribute `observability.trace` is the SUBMODULE (the
+    trace() function is deliberately not re-exported — it would shadow
+    the submodule), `apex_tpu.utils` keeps its two aliases, and the
+    NVTX-style push/pop hooks and their shim are gone: a host span is
+    `SpanRecorder.phase()`."""
     import importlib
     import types
 
     import apex_tpu.observability as obs
+    import apex_tpu.utils as utils
 
-    profiling = importlib.import_module("apex_tpu.utils.profiling")
     obs_trace = obs.trace
     assert isinstance(obs_trace, types.ModuleType)
     assert obs_trace is sys.modules["apex_tpu.observability.trace"]
-
-    for name in ("annotate", "nvtx_range", "range_push", "range_pop",
-                 "trace"):
-        assert getattr(profiling, name) is getattr(obs_trace, name)
-    import apex_tpu.utils as utils
-
     assert utils.trace is obs_trace.trace
+    assert utils.annotate is obs_trace.annotate
+    for name in ("nvtx_range", "range_push", "range_pop"):
+        for mod in (obs, obs_trace, utils):
+            assert not hasattr(mod, name)
+    with pytest.raises(ModuleNotFoundError):
+        importlib.import_module("apex_tpu.utils.profiling")

@@ -737,6 +737,334 @@ class TestSchedulerSpans:
 
 
 # ---------------------------------------------------------------------------
+# host phases: SpanRecorder.phase(), the process ring, the vocabulary
+# ---------------------------------------------------------------------------
+
+
+class _Tick:
+    """A clock that advances 1.0 per read."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1.0
+        return self.t
+
+
+def _since(ring, seq0):
+    return [e for e in ring.snapshot() if e["seq"] >= seq0]
+
+
+def _tree(entries):
+    """(steps in order, children by parent id) of phase entries."""
+    phases = [e for e in entries if "id" in e]
+    kids = {}
+    for e in phases:
+        kids.setdefault(e["parent"], []).append(e)
+    return [e for e in phases if e["name"] == "serve/step"], kids
+
+
+class TestPhasePrimitive:
+    def test_phase_nests_and_records_id_and_parent(self):
+        rec = SpanRecorder(capacity=16, clock=_Tick())
+        with rec.phase("serve/step", step=3) as outer:
+            with rec.phase("serve/admit", rid=7) as inner:
+                inner.set(bucket=8)
+            with rec.phase("engine/decode", track=TRACK_ENGINE):
+                pass
+            outer.set(tokens=2)
+        admit, decode, step = rec.snapshot()     # appended as they close
+        assert [e["name"] for e in (admit, decode, step)] == [
+            "serve/admit", "engine/decode", "serve/step"]
+        assert step["parent"] is None
+        assert admit["parent"] == decode["parent"] == step["id"]
+        assert len({admit["id"], decode["id"], step["id"]}) == 3
+        assert admit["args"] == {"rid": 7, "bucket": 8}
+        assert step["args"] == {"step": 3, "tokens": 2}
+        assert "args" not in decode
+        assert step["track"] == "serve/scheduler"
+        assert decode["track"] == TRACK_ENGINE
+        assert step["t0"] < admit["t0"] < admit["t1"] < step["t1"]
+        # the stack is empty again: the next phase is a root
+        with rec.phase("serve/step"):
+            pass
+        assert rec.snapshot()[-1]["parent"] is None
+
+    def test_phase_that_raises_closes_and_reraises(self):
+        rec = SpanRecorder(capacity=16, clock=_Tick())
+        with pytest.raises(KeyError):
+            with rec.phase("serve/step"):
+                with rec.phase("engine/prefill", track=TRACK_ENGINE):
+                    raise KeyError("boom")
+        names = [e["name"] for e in rec.snapshot()]
+        assert names == ["engine/prefill", "serve/step"]
+        with rec.phase("serve/publish"):
+            pass
+        assert rec.snapshot()[-1]["parent"] is None
+
+    def test_dropped_phase_leaves_no_entry_but_keeps_the_stack(self):
+        rec = SpanRecorder(capacity=16, clock=_Tick())
+        with rec.phase("serve/step") as step:
+            with rec.phase("serve/admit") as ph:
+                ph.drop()
+            with rec.phase("serve/batch"):
+                pass
+        assert [e["name"] for e in rec.snapshot()] == [
+            "serve/batch", "serve/step"]
+        assert rec.snapshot()[0]["parent"] == step.id
+
+    def test_ring_stays_at_capacity_and_counts_the_dropped(self):
+        from apex_tpu.observability.spans import PROCESS_CAPACITY
+
+        rec = SpanRecorder(PROCESS_CAPACITY)
+        for i in range(100_000):
+            with rec.phase("serve/batch"):
+                pass
+        assert len(rec.snapshot()) == PROCESS_CAPACITY == 32768
+        assert rec.dropped == 100_000 - PROCESS_CAPACITY
+        assert rec.snapshot()[-1]["seq"] == 99_999
+
+    def test_threads_share_a_ring_and_keep_their_own_stacks(self):
+        """Replicas stepped from several threads: no entry is lost, no
+        sequence number repeats, and a phase's parent is the phase open
+        around it on ITS thread."""
+        import threading
+
+        rec = SpanRecorder(capacity=100_000)
+        workers, rounds = 16, 200
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            def work(k):
+                for i in range(rounds):
+                    with rec.phase("serve/step", worker=k):
+                        with rec.phase("serve/batch", worker=k):
+                            pass
+
+            threads = [threading.Thread(target=work, args=(k,))
+                       for k in range(workers)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(old)
+        snap = rec.snapshot()
+        assert len(snap) == 2 * workers * rounds and rec.dropped == 0
+        assert len({e["seq"] for e in snap}) == len(snap)
+        assert len({e["id"] for e in snap}) == len(snap)
+        by_id = {e["id"]: e for e in snap}
+        for e in snap:
+            if e["name"] == "serve/batch":
+                parent = by_id[e["parent"]]
+                assert parent["name"] == "serve/step"
+                assert parent["args"]["worker"] == e["args"]["worker"]
+            else:
+                assert e["parent"] is None
+
+    def test_phase_lands_in_the_profiler_trace_too(self, tmp_path):
+        """With a profiler session on, the same call writes the name
+        into the trace's host plane (the device trace's clock)."""
+        from jax.profiler import ProfileData
+
+        rec = SpanRecorder(capacity=16)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with rec.phase("serve/step"):
+                with rec.phase("engine/stage", track=TRACK_ENGINE):
+                    jnp.ones((4,)).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        found = []
+        for root, _dirs, files in os.walk(tmp_path):
+            found += [os.path.join(root, f) for f in files
+                      if f.endswith(".xplane.pb")]
+        assert found
+        spans = {}
+        for plane in ProfileData.from_file(found[0]).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for e in line.events:
+                        if e.name in ("serve/step", "engine/stage"):
+                            spans[e.name] = (e.start_ns,
+                                             e.start_ns + e.duration_ns)
+        assert set(spans) == {"serve/step", "engine/stage"}
+        (s0, s1), (c0, c1) = spans["serve/step"], spans["engine/stage"]
+        assert s0 <= c0 and c1 <= s1
+        # and the ring holds the same two, nested the same way
+        stage, step = rec.snapshot()
+        assert stage["parent"] == step["id"]
+
+
+class TestSchedulerPhases:
+    """The vocabulary of docs/serving.md "Host phases", on a tiny
+    engine."""
+
+    def _stepwise(self, engine, spans=None, n=3, max_new=3, **kw):
+        """Run a load one step at a time; per step, what it did as seen
+        from outside."""
+        from apex_tpu.serve import ContinuousBatchingScheduler, Request
+
+        sched = ContinuousBatchingScheduler(
+            engine, registry=None, spans=spans, **kw)
+        rs = np.random.RandomState(1)
+        reqs = [sched.submit(Request(
+            prompt=[int(t) for t in rs.randint(0, 64, size=6)],
+            max_new_tokens=max_new)) for _ in range(n)]
+        did = []
+        while sched.pending:
+            before = (engine.prefill_calls, engine.decode_iters,
+                      sum(len(r.tokens) for r in reqs),
+                      len(sched.completed) + len(sched.shed))
+            sched.step()
+            after = (engine.prefill_calls, engine.decode_iters,
+                     sum(len(r.tokens) for r in reqs),
+                     len(sched.completed) + len(sched.shed))
+            did.append(tuple(a - b for a, b in zip(after, before)))
+        return sched, did
+
+    def test_no_recorder_one_serve_step_per_step_in_the_process_ring(
+            self, engine):
+        from apex_tpu.observability import process_recorder
+
+        ring = process_recorder()
+        seq0 = ring._seq
+        sched, did = self._stepwise(engine)
+        assert sched.spans is None and engine.spans is None
+        steps, kids = _tree(_since(ring, seq0))
+        assert len(steps) == len(did) >= 3
+        for step, (prefills, decodes, tokens, retired) in zip(steps, did):
+            args = step["args"]
+            assert args["prefills"] == prefills
+            assert args["tokens"] == tokens
+            assert args["retired"] == retired
+            mine = kids.get(step["id"], [])
+            dec = [c for c in mine if c["name"] == "engine/decode"]
+            assert len(dec) == decodes
+            assert args["riders"] == sum(c["args"]["batch"] for c in dec)
+            assert len([c for c in mine
+                        if c["name"] == "serve/admit"]) == prefills
+            assert [c["name"] for c in mine][-1] == "serve/publish"
+        assert [s["args"]["step"] for s in steps] == list(range(len(did)))
+        assert sum(s["args"]["tokens"] for s in steps) == 9
+
+    def test_engine_stage_before_every_engine_call(self, engine):
+        from apex_tpu.observability import process_recorder
+
+        ring = process_recorder()
+        seq0 = ring._seq
+        self._stepwise(engine)
+        phases = sorted((e for e in _since(ring, seq0) if "id" in e),
+                        key=lambda e: e["t0"])
+        calls = 0
+        for e in phases:
+            if e["name"] not in ("engine/prefill", "engine/decode"):
+                continue
+            calls += 1
+            sibs = [p for p in phases if p["parent"] == e["parent"]
+                    and p["t1"] <= e["t0"]]
+            assert sibs and sibs[-1]["name"] == "engine/stage"
+            want = "decode" if e["name"] == "engine/decode" else (
+                f"prefill_{e['args']['bucket']}")
+            assert sibs[-1]["args"]["program"] == want
+        assert calls >= 5
+        by_id = {e["id"]: e for e in phases}
+        for e in phases:
+            if e["name"] == "engine/prefill":
+                admit = by_id[e["parent"]]
+                assert admit["name"] == "serve/admit"
+                assert admit["args"]["bucket"] == e["args"]["bucket"]
+                assert admit["args"]["prompt_tokens"] == e["args"]["tokens"]
+
+    def test_self_times_of_a_step_sum_to_its_duration(self, engine):
+        """Children lie inside their parent and do not overlap, so each
+        phase's self time (duration less its children's) is >= 0 and a
+        step's self times add up to its duration."""
+        rec = SpanRecorder(capacity=4096)
+        self._stepwise(engine, spans=rec)
+        engine.spans = None
+        steps, kids = _tree(rec.snapshot())
+        assert steps
+        eps = 1e-9
+        for step in steps:
+            total, todo = 0.0, [step]
+            while todo:
+                e = todo.pop()
+                mine = sorted(kids.get(e["id"], []), key=lambda c: c["t0"])
+                for a, b in zip(mine, mine[1:]):
+                    assert a["t1"] <= b["t0"] + eps
+                for c in mine:
+                    assert e["t0"] - eps <= c["t0"] <= c["t1"] <= e["t1"] + eps
+                own = (e["t1"] - e["t0"]) - sum(
+                    c["t1"] - c["t0"] for c in mine)
+                assert own >= -eps
+                total += own
+                todo += mine
+            assert total == pytest.approx(step["t1"] - step["t0"], abs=1e-6)
+
+    def test_attached_recorder_gets_the_phases_not_the_process_ring(
+            self, engine):
+        from apex_tpu.observability import process_recorder
+
+        ring = process_recorder()
+        seq0 = ring._seq
+        rec = SpanRecorder(capacity=4096)
+        _sched, did = self._stepwise(engine, spans=rec)
+        engine.spans = None
+        assert _since(ring, seq0) == []
+        names = _names(rec)
+        assert names["serve/step"] == len(did)
+        assert names["serve/publish"] == len(did)
+        assert names["engine/stage"] == (
+            names["engine/prefill"] + names["engine/decode"])
+        assert names["serve/batch"] == names["serve/retire"] == (
+            names["engine/decode"])
+        # the request lifecycle is still there, beside the phases
+        assert names["req/done"] == 3
+
+    def test_request_lifecycle_never_reaches_the_process_ring(
+            self, engine, monkeypatch):
+        from apex_tpu.observability import process_recorder
+
+        ring = process_recorder()
+
+        def refuse(*a, **k):
+            raise AssertionError("request_event on the process ring")
+
+        monkeypatch.setattr(ring, "request_event", refuse)
+        monkeypatch.setattr(ring, "instant", refuse)
+        seq0 = ring._seq
+        sched, _ = self._stepwise(engine)
+        assert len(sched.completed) == 3
+        got = _since(ring, seq0)
+        assert got and all(e["name"].startswith(("serve/", "engine/"))
+                           for e in got)
+        assert ring.open_requests == {}
+
+    def test_admission_without_a_prefill_leaves_no_admit_phase(self):
+        """Chunked mode parks the request at admission and prefills it
+        chunk by chunk: the chunks' stage + prefill phases sit under
+        serve/chunks, and no serve/admit is recorded."""
+        eng = tiny_engine()
+        rec = SpanRecorder(capacity=4096)
+        sched, _ = self._stepwise(eng, spans=rec, n=2,
+                                  prefill_chunk_tokens=8)
+        eng.spans = None
+        assert len(sched.completed) == 2
+        names = _names(rec)
+        assert "serve/admit" not in names
+        by_id = {e["id"]: e for e in rec.snapshot() if "id" in e}
+        prefills = [e for e in by_id.values()
+                    if e["name"] == "engine/prefill"]
+        assert prefills and all(e["args"]["chunked"] for e in prefills)
+        assert {by_id[e["parent"]]["name"] for e in prefills} == {
+            "serve/chunks"}
+        assert names["serve/chunks"] <= names["serve/step"]
+
+
+# ---------------------------------------------------------------------------
 # watchdog: queue-wait fraction rule
 # ---------------------------------------------------------------------------
 

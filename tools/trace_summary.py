@@ -265,7 +265,15 @@ def print_attribution(trace: dict, hlo_path: str | None) -> None:
                   f"({meas.bucket_ms[bucket]:.2f} ms)")
     print(f"  span={meas.span_ms:.1f}ms busy={meas.busy_ms:.1f}ms "
           f"stall={meas.stall_ms:.1f}ms "
-          "(tools/step_profile.py adds the roofline)\n")
+          "(tools/step_profile.py adds the roofline)")
+    # the stall by the innermost host phase open over each idle gap
+    # (serve/* and engine/* annotations, docs/serving.md "Host phases")
+    for phase, ms in sorted(
+        meas.stall_by_phase_ms.items(), key=lambda kv: -kv[1]
+    ):
+        print(f"  stall under {phase:<18} {ms:9.2f} ms "
+              f"({100 * ms / meas.span_ms:5.2f}% of span)")
+    print()
 
 
 if __name__ == "__main__":
