@@ -166,6 +166,7 @@ def check(
     expect_sharding=None,
     expect_plan=None,
     hbm_budget=None,
+    expect_pool=None,
     publish: bool = False,
     name: Optional[str] = None,
     **kwargs,
@@ -185,7 +186,9 @@ def check(
     expectation schema); ``expect_sharding`` (mesh + regex→
     PartitionSpec rules) arms spec conformance, ``expect_plan`` (the
     per-mesh-axis collective plan) arms the resharding rule, and
-    ``hbm_budget`` (bytes) arms the static peak-HBM gate — schemas in
+    ``hbm_budget`` (bytes) arms the static peak-HBM gate, and
+    ``expect_pool`` (the KV pool's plane shapes) the
+    ``memory-pool-copy`` gate — schemas in
     :mod:`apex_tpu.analysis.sharding` and :mod:`apex_tpu.analysis
     .memory`.  Compilation happens once, AOT — nothing is
     executed and no buffer is consumed (donation only affects the
@@ -236,6 +239,7 @@ def check(
         expect_sharding=expect_sharding,
         expect_plan=expect_plan,
         hbm_budget=hbm_budget,
+        expect_pool=expect_pool,
     )
     report = _run(graph, rules, target)
     report.hlo_text = hlo_text
@@ -261,13 +265,14 @@ def lint_hlo(
     expect_sharding=None,
     expect_plan=None,
     hbm_budget=None,
+    expect_pool=None,
     rules=None,
     name: str = "",
 ) -> Report:
     """Run the HLO-level passes (host transfers, donation aliasing,
     collective consistency, sharding conformance, resharding, memory
-    budget) over compiled-module text — for callers that already paid
-    the compile (``bench.py --lint`` reuses the ``--hlo-out``
+    budget, KV-pool copies) over compiled-module text — for callers that
+    already paid the compile (``bench.py --lint`` reuses the ``--hlo-out``
     executable's text instead of compiling twice; the serve engine
     lints the executable it just built)."""
     graph = StepGraph(
@@ -277,6 +282,7 @@ def lint_hlo(
         expect_sharding=expect_sharding,
         expect_plan=expect_plan,
         hbm_budget=hbm_budget,
+        expect_pool=expect_pool,
     )
     wanted = rules if rules is not None else (
         "transfer", "donation", "collective",

@@ -168,6 +168,16 @@ RULES: Dict[str, Tuple[str, str, str]] = {
         "donate the update buffers, lower the batch/context, or "
         "raise the budget if the device really has the headroom",
     ),
+    "memory-pool-copy": (
+        ERROR,
+        "a program that takes the serving KV pool materializes a buffer "
+        "shaped like the pool or one layer of it (a relayout copy, a "
+        "scan's slice of an xs pool, the restacked ys): each is a pass "
+        "over HBM per call that the step's arithmetic never asked for",
+        "carry the pool through the layer loop (never scan it as "
+        "xs/ys), keep its minor dimension a multiple of 128 lanes, and "
+        "write whole pages at [layer, page_ids] (serve/cache.py)",
+    ),
     "sharding-implicit-replication": (
         WARNING,
         "a pjit/jit call site passes in_shardings=None — every array "

@@ -54,6 +54,7 @@ __all__ = [
     "categorize_buffer",
     "estimate_peak",
     "memory_pass",
+    "pool_copy_findings",
     "publish_peak",
 ]
 
@@ -268,23 +269,100 @@ def memory_pass(graph) -> List[Finding]:
     ``memory-budget`` ERROR naming the top live buffers — OOM caught
     at lint time, with attribution, instead of at step 0 with a stack
     trace."""
+    out = pool_copy_findings(graph)
     if graph.hlo_text is None or graph.hbm_budget is None:
-        return []
+        return out
     budget = int(graph.hbm_budget)
     est = estimate_peak(graph.hlo_text)
     if est["peak_bytes"] <= budget:
-        return []
+        return out
     top = ", ".join(
         f"{b['category']}:{b['name']}={b['bytes'] / (1 << 20):.1f}MiB"
         for b in est["buffers"][:5]
     )
-    return [make_finding(
+    return out + [make_finding(
         "memory-budget",
         path=f"instruction #{est['peak_index']}",
         message=(
             f"static peak HBM {est['peak_bytes'] / (1 << 20):.1f} MiB "
             f"exceeds the {budget / (1 << 20):.1f} MiB budget "
             f"(top live buffers: {top})"
+        ),
+    )]
+
+
+#: ops that rewrite part of their first operand where it lies — the
+#: only way a serving program may produce a pool-shaped result
+_IN_PLACE_OPS = frozenset(("scatter", "dynamic-update-slice"))
+
+
+def _dims_key(dims) -> tuple:
+    """Shape identity up to transposition and unit dims."""
+    return tuple(sorted(int(d) for d in dims if int(d) != 1))
+
+
+def pool_copy_findings(graph) -> List[Finding]:
+    """The KV pool's one-buffer gate: a program that takes the serving
+    pool (``graph.expect_pool["shapes"]``, each ``(L, P, R, page,
+    W)``) may materialize nothing the shape of the pool or of one
+    layer of it, except by updating it in place.
+
+    Every pool-sized relayout (``copy``), every layer-sized slice a
+    scan makes of an ``xs`` pool and the restacked ``ys`` buffer is an
+    instruction of the compiled module with such a result; the
+    page-granular writes of ``serve/cache.py`` are ``scatter`` /
+    ``dynamic-update-slice`` (fusions), which XLA performs on the
+    donated buffer.  Shapes compare up to transposition and unit
+    dims, whatever the element type (a relayout may transpose, an
+    upcast may widen).  XLA's total ``temp_size_in_bytes`` cannot
+    stand in for this: a step legitimately holds weight casts and
+    sampler activations far larger than a layer of the pool."""
+    want = graph.expect_pool
+    if graph.hlo_text is None or not want:
+        return []
+    targets = set()
+    for shape in want["shapes"]:
+        targets |= {_dims_key(shape), _dims_key(shape[1:])}
+    comps, _entry = hlo_lib.parse_computations(graph.hlo_text)
+    fused = {
+        c for instrs in comps.values() for ins in instrs
+        if ins["opcode"] == "fusion" for c in ins["called"]
+    }
+
+    def pool_shaped(ins):
+        return _dims_key(hlo_lib.shape_dims(ins["shape"])) in targets
+
+    def in_place(ins):
+        if ins["opcode"] in _IN_PLACE_OPS:
+            return True
+        return ins["opcode"] == "fusion" and any(
+            inner["opcode"] in _IN_PLACE_OPS and pool_shaped(inner)
+            for c in ins["called"] for inner in comps.get(c, [])
+        )
+
+    held = [
+        ins
+        for name, instrs in comps.items() if name not in fused
+        for ins in instrs
+        if ins["opcode"] not in _ALIAS_OPS
+        and ins["opcode"] not in ("while", "conditional", "call")
+        and pool_shaped(ins) and not in_place(ins)
+    ]
+    if not held:
+        return []
+    total = sum(hlo_lib.shape_bytes(ins["shape"]) for ins in held)
+    shown = ", ".join(
+        f"%{ins['name']} = {ins['shape'].split('{')[0]} {ins['opcode']}"
+        for ins in held[:4]
+    )
+    return [make_finding(
+        "memory-pool-copy",
+        path=f"%{held[0]['name']}",
+        severity=want.get("severity"),
+        message=(
+            f"{len(held)} instruction(s) materialize "
+            f"{total / (1 << 20):.1f} MiB shaped like the KV pool or "
+            f"one layer of it: {shown}"
         ),
     )]
 

@@ -66,6 +66,10 @@ class StepGraph:
     expect_plan: Optional[dict] = None
     #: static peak-HBM budget in bytes (apex_tpu.analysis.memory)
     hbm_budget: Optional[int] = None
+    #: the serving KV pool this program takes: {"shapes": [(L, P, R,
+    #: page, W), ...], "severity": str?} — arms the memory-pool-copy
+    #: rule (apex_tpu.analysis.memory.pool_copy_findings)
+    expect_pool: Optional[dict] = None
     #: source substrate for the host-side passes: [(package-relative
     #: path, source text), ...] — built by
     #: apex_tpu.analysis.purity.collect_sources; the concurrency and

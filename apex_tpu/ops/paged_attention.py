@@ -11,9 +11,9 @@ implementations behind the usual :mod:`apex_tpu.ops._dispatch` policy:
   ``take``, no host transfer);
 - **Pallas path** (:func:`apex_tpu.ops.pallas.decode_attention.
   paged_decode_fwd`) — reads the pages IN PLACE through
-  scalar-prefetched page-table indexing: no gather materialization,
-  O(live tokens) HBM traffic, with the per-layer query RoPE rotation
-  and the int8-KV dequant fused into the same kernel.
+  scalar-prefetched (layer, page-table) indexing of the whole pool: no
+  gather materialization, no layer slice, with the per-layer query RoPE
+  rotation and the int8-KV dequant fused into the same kernel.
 
 Both paths share the semantics: positions ``>= lengths[b]`` are masked,
 an idle slot (``lengths[b] == 0``) returns exactly zeros, and RoPE is
@@ -30,28 +30,71 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops import _dispatch
-from apex_tpu.ops.pallas.decode_attention import paged_decode_fwd
+from apex_tpu.ops.pallas.decode_attention import (
+    heads_per_row,
+    paged_decode_fwd,
+)
 from apex_tpu.ops.pallas.flash_attention import MASK_VALUE
 from apex_tpu.ops.rope import rotate_half
 
 __all__ = [
+    "gather_history",
+    "heads_per_row",
     "paged_decode_attention",
     "paged_decode_attention_reference",
 ]
 
 
+def _as_pool(k_pages, v_pages, k_scale, v_scale, layer):
+    """Normalize the operands to the serving layout: the whole pool
+    ``(L, P, H/G, page, D*G)`` (scales ``(L, P, H/G, page, G)``) plus a
+    layer index.  A 4-D ``(P, H, page, D)`` page array (scales ``(P, H,
+    page)``) is a one-layer pool at ``G = 1`` — a free reshape."""
+    if k_pages.ndim == 5:
+        if layer is None:
+            raise ValueError("a 5-D KV pool needs its layer index")
+        return k_pages, v_pages, k_scale, v_scale, layer
+    if layer is not None:
+        raise ValueError("layer indexes a 5-D pool; pages here are 4-D")
+    if k_scale is not None:
+        k_scale, v_scale = k_scale[None, ..., None], v_scale[None, ..., None]
+    return k_pages[None], v_pages[None], k_scale, v_scale, 0
+
+
+def gather_history(pool, scale, layer, page_table, heads):
+    """One layer's pages of ``pool`` through ``page_table`` (B, NP) as a
+    contiguous f32 history ``(B, H, NP*page, D)`` (dequantized when
+    ``scale`` is given)."""
+    b, np_ = page_table.shape
+    hg, page, dg = pool.shape[2:]
+    g = heads // hg
+    x = pool[layer, page_table].astype(jnp.float32)  # (B, NP, H/G, page, D*G)
+    if scale is not None:
+        x = x * jnp.repeat(
+            scale[layer, page_table].astype(jnp.float32), dg // g, axis=-1
+        )
+    # lanes back into (G, D); heads (H/G, G) and positions (NP, page) join
+    x = x.reshape(b, np_, hg, page, g, dg // g)
+    return jnp.transpose(x, (0, 2, 4, 1, 3, 5)).reshape(
+        b, heads, np_ * page, dg // g
+    )
+
+
 def paged_decode_attention_reference(
     q, k_pages, v_pages, page_table, lengths, *,
-    scale: Optional[float] = None,
+    layer=None, scale: Optional[float] = None,
     k_scale=None, v_scale=None, rope_cos=None, rope_sin=None,
 ):
     """Gather-then-attend jnp composition — the correctness reference.
 
     Same signature and semantics as :func:`paged_decode_attention`.
     """
+    k_pages, v_pages, k_scale, v_scale, layer = _as_pool(
+        k_pages, v_pages, k_scale, v_scale, layer
+    )
     b, h, d = q.shape
-    page = k_pages.shape[2]
     np_ = page_table.shape[1]
+    page = k_pages.shape[3]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     qf = q.astype(jnp.float32)
@@ -59,18 +102,8 @@ def paged_decode_attention_reference(
         cos = rope_cos.astype(jnp.float32)[:, None, :]  # (B, 1, D)
         sin = rope_sin.astype(jnp.float32)[:, None, :]
         qf = qf * cos + rotate_half(qf) * sin
-    # gather: (B, NP, H, page, D) -> (B, H, NP*page, D)
-    k = jnp.take(k_pages, page_table, axis=0).astype(jnp.float32)
-    v = jnp.take(v_pages, page_table, axis=0).astype(jnp.float32)
-    if k_scale is not None:
-        k = k * jnp.take(k_scale, page_table, axis=0).astype(
-            jnp.float32
-        )[..., None]
-        v = v * jnp.take(v_scale, page_table, axis=0).astype(
-            jnp.float32
-        )[..., None]
-    k = jnp.moveaxis(k, 1, 2).reshape(b, h, np_ * page, d)
-    v = jnp.moveaxis(v, 1, 2).reshape(b, h, np_ * page, d)
+    k = gather_history(k_pages, k_scale, layer, page_table, h)
+    v = gather_history(v_pages, v_scale, layer, page_table, h)
     s = jnp.einsum("bhd,bhtd->bht", qf, k) * scale
     pos = jnp.arange(np_ * page, dtype=jnp.int32)
     valid = pos[None, :] < lengths[:, None]  # (B, T)
@@ -87,17 +120,20 @@ def paged_decode_attention_reference(
 
 def paged_decode_attention(
     q, k_pages, v_pages, page_table, lengths, *,
-    scale: Optional[float] = None,
+    layer=None, scale: Optional[float] = None,
     k_scale=None, v_scale=None, rope_cos=None, rope_sin=None,
 ):
     """Single-query attention over the paged KV cache.
 
     - ``q`` (B, H, D): the current token's query rows (PRE-RoPE when
       ``rope_cos``/``rope_sin`` are given — the rotation fuses here);
-    - ``k_pages``/``v_pages`` (P, H, page, D): the shared page pool
-      (f32/bf16, or int8 codes with ``k_scale``/``v_scale`` (P, H,
-      page) blockwise f32 scales — the ``parallel/comm.py`` codec
-      layout at ``block = D``);
+    - ``k_pages``/``v_pages``: the serving pool ``(L, P, H/G, page,
+      D*G)`` read at ``layer`` (``G`` heads side by side in a lane row:
+      :func:`heads_per_row`), or plain ``(P, H, page, D)`` pages with
+      no ``layer``.  f32/bf16, or int8 codes with ``k_scale``/
+      ``v_scale`` blockwise f32 scales ``(L, P, H/G, page, G)`` /
+      ``(P, H, page)`` — the ``parallel/comm.py`` codec layout at
+      ``block = D``;
     - ``page_table`` (B, NP) int32; ``lengths`` (B,) int32: live KV
       positions per sequence including the current token.
 
@@ -108,6 +144,9 @@ def paged_decode_attention(
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
+    k_pages, v_pages, k_scale, v_scale, layer = _as_pool(
+        k_pages, v_pages, k_scale, v_scale, layer
+    )
     args = (q, k_pages, v_pages, page_table, lengths)
     kw = dict(
         scale=scale, k_scale=k_scale, v_scale=v_scale,
@@ -115,8 +154,8 @@ def paged_decode_attention(
     )
     if _dispatch.use_pallas():
         _dispatch.record_path("paged_decode_attention", "pallas")
-        out = paged_decode_fwd(*args, **kw)
+        out = paged_decode_fwd(*args, layer, **kw)
     else:
         _dispatch.record_path("paged_decode_attention", "jnp")
-        out = paged_decode_attention_reference(*args, **kw)
+        out = paged_decode_attention_reference(*args, layer=layer, **kw)
     return jax.lax.stop_gradient(out)

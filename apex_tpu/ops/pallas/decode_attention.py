@@ -8,8 +8,11 @@ shared pool, addressed by a per-sequence page table.  This kernel reads
 the pages IN PLACE via scalar-prefetched page-table indexing
 (``pltpu.PrefetchScalarGridSpec``: the BlockSpec index map looks the
 page id up before the DMA issues), so decode attention never gathers
-the history into a contiguous buffer — memory stays O(live tokens) and
-the HBM traffic is exactly one read of each live page.
+the history into a contiguous buffer — memory stays O(live tokens).
+Dead pages (beyond ``length``) are skipped in COMPUTE only: their page
+table entries still drive the index map, so each distinct dead entry is
+still fetched (consecutive null-page entries re-use the resident block).
+Clamping the walk to the live pages is a named follow-up, not done here.
 
 Reuses the flash-attention block machinery: the same online-softmax
 (running max / sum / accumulator in VMEM scratch across the page grid
@@ -30,9 +33,18 @@ same lane-broadcast scratch layout.  Differences, all decode-specific:
   attention is HBM-bound, so the page reads — not the flops — set the
   roofline.
 
-Page layout is ``(P, H, page, D)`` (heads OUTSIDE the page dim): the
-in-kernel q·K and p·V contractions are then head-batched over the
-leading block axis with no transposes.  Positions ``>= length`` (the
+The operand is the WHOLE pool ``(L, P, H/G, page, D·G)`` plus a layer
+index (a third scalar-prefetch operand): the serving programs carry the
+pool through their layer loop as one buffer and never slice a layer out
+(docs/serving.md "The KV pool").  ``G`` heads sit side by side in one
+lane row so the minor dimension is lane-dense (``G = 128 // D`` when
+``D < 128``; :func:`heads_per_row`) — read here
+from the operands' shapes (``G = H // pool.shape[2]``), never from a
+flag.  Heads stay OUTSIDE the page dim: the q·K and p·V contractions
+are head-row-batched over the leading block axis with no transposes,
+and the ``G`` heads of a row are taken apart by masking the query's
+lanes (scores) and selecting each head's lanes of the ``p·V`` product;
+softmax state is per head.  Positions ``>= length`` (the
 padded tail of the last live page) mask at ``MASK_VALUE``; pages whose
 base position is beyond ``length`` are dead and skipped entirely
 (``pl.when``), so a sequence pays only ``ceil(length / page)`` page
@@ -63,7 +75,7 @@ from apex_tpu.ops.pallas.flash_attention import (
 # kernel body), so serving can never drift from the training rotation
 from apex_tpu.ops.rope import rotate_half
 
-__all__ = ["paged_decode_fwd", "kernel_specs"]
+__all__ = ["paged_decode_fwd", "kernel_specs", "heads_per_row"]
 
 
 # ---------------------------------------------------------------------------
@@ -73,39 +85,37 @@ __all__ = ["paged_decode_fwd", "kernel_specs"]
 
 
 def _decode_plan(
-    b, h, d, p_, page, np_, dtype, kv_dtype, *, has_scales, has_rope,
+    b, h, d, layers, p_, page, np_, dtype, kv_dtype, *,
+    groups, has_scales, has_rope,
 ):
+    hg, dg = h // groups, d * groups
+
+    def row(b, j, pt, ln, ly):
+        return (b, 0, 0, 0)
+
+    def page_of(b, j, pt, ln, ly):
+        return (ly[0], pt[b, j], 0, 0, 0)
+
+    pool = (layers, p_, hg, page, dg)
     in_specs = [
-        pl.BlockSpec((1, 1, h, d), lambda b, j, pt, ln: (b, 0, 0, 0)),
-        pl.BlockSpec(
-            (1, h, page, d), lambda b, j, pt, ln: (pt[b, j], 0, 0, 0)
-        ),
-        pl.BlockSpec(
-            (1, h, page, d), lambda b, j, pt, ln: (pt[b, j], 0, 0, 0)
-        ),
+        pl.BlockSpec((1, 1, hg, dg), row),
+        pl.BlockSpec((1, 1, hg, page, dg), page_of),
+        pl.BlockSpec((1, 1, hg, page, dg), page_of),
     ]
     in_names = ["q", "k_pages", "v_pages"]
-    in_shapes = [(b, 1, h, d), (p_, h, page, d), (p_, h, page, d)]
+    in_shapes = [(b, 1, hg, dg), pool, pool]
     in_dtypes = [dtype, kv_dtype, kv_dtype]
     if has_scales:
-        in_specs += [
-            pl.BlockSpec(
-                (1, h, page), lambda b, j, pt, ln: (pt[b, j], 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, h, page), lambda b, j, pt, ln: (pt[b, j], 0, 0)
-            ),
-        ]
+        in_specs += [pl.BlockSpec((1, 1, hg, page, groups), page_of)] * 2
         in_names += ["k_scale", "v_scale"]
-        in_shapes += [(p_, h, page), (p_, h, page)]
+        in_shapes += [pool[:-1] + (groups,)] * 2
         in_dtypes += [jnp.float32, jnp.float32]
     if has_rope:
         in_specs += [
-            pl.BlockSpec((1, 1, d), lambda b, j, pt, ln: (b, 0, 0)),
-            pl.BlockSpec((1, 1, d), lambda b, j, pt, ln: (b, 0, 0)),
-        ]
+            pl.BlockSpec((1, 1, dg), lambda b, j, pt, ln, ly: (b, 0, 0))
+        ] * 2
         in_names += ["rope_cos", "rope_sin"]
-        in_shapes += [(b, 1, d), (b, 1, d)]
+        in_shapes += [(b, 1, dg), (b, 1, dg)]
         in_dtypes += [dtype, dtype]
     return dict(
         grid=(b, np_),
@@ -113,18 +123,27 @@ def _decode_plan(
         in_names=in_names,
         in_shapes=in_shapes,
         in_dtypes=in_dtypes,
-        out_specs=[pl.BlockSpec(
-            (1, 1, h, d), lambda b, j, pt, ln: (b, 0, 0, 0)
-        )],
+        out_specs=[pl.BlockSpec((1, 1, hg, dg), row)],
         out_names=["o"],
-        out_shape=[jax.ShapeDtypeStruct((b, 1, h, d), dtype)],
+        out_shape=[jax.ShapeDtypeStruct((b, 1, hg, dg), dtype)],
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),
-            pltpu.VMEM((h, _LANES), jnp.float32),
-            pltpu.VMEM((h, _LANES), jnp.float32),
+            pltpu.VMEM((hg, dg), jnp.float32),
+            pltpu.VMEM((groups, hg, _LANES), jnp.float32),
+            pltpu.VMEM((groups, hg, _LANES), jnp.float32),
         ],
         dimension_semantics=("parallel", "arbitrary"),
     )
+
+
+def heads_per_row(num_heads: int, head_dim: int) -> int:
+    """``G``: how many heads the KV pool lays side by side in one lane
+    row — as many as fill the 128 lanes when ``head_dim`` is narrower
+    and ``num_heads`` divides evenly, else 1.  A minor dimension that is
+    a multiple of 128 lanes is what lets XLA:TPU keep the pool in plain
+    row-major layout, the one layout every serving program and this
+    kernel agree on (docs/serving.md "The KV pool")."""
+    g = _LANES // head_dim if _LANES % head_dim == 0 else 1
+    return g if num_heads % g == 0 else 1
 
 
 def kernel_specs(
@@ -132,12 +151,13 @@ def kernel_specs(
     kv_wire="f32", rope=True, page_table=None,
 ):
     """Export the paged-decode kernel's :class:`introspect.KernelSpec`
-    without compiling.  The page-table indirection is resolved against
-    ``page_table`` (B, pages_per_seq) when given, else a synthetic
-    round-robin table over ``pool_pages`` — either way the index maps
-    under analysis are the REAL scalar-prefetch maps, evaluated on a
-    concrete table (the coverage pass proves every referenced page id
-    stays inside the pool)."""
+    without compiling (a one-layer pool in the serving layout).  The
+    page-table indirection is resolved against ``page_table`` (B,
+    pages_per_seq) when given, else a synthetic round-robin table over
+    ``pool_pages`` — either way the index maps under analysis are the
+    REAL scalar-prefetch maps, evaluated on a concrete table (the
+    coverage pass proves every referenced page id stays inside the
+    pool)."""
     import numpy as np
 
     dtype = jnp.dtype(dtype)
@@ -149,8 +169,10 @@ def kernel_specs(
         ) + 1  # skip the reserved null page 0, like live allocations
     page_table = np.asarray(page_table)
     lengths = np.full((b,), pages_per_seq * page, np.int32)
+    layer = np.zeros((1,), np.int32)
     plan = _decode_plan(
-        b, h, d, pool_pages, page, pages_per_seq, dtype, kv_dtype,
+        b, h, d, 1, pool_pages, page, pages_per_seq, dtype, kv_dtype,
+        groups=heads_per_row(h, d),
         has_scales=kv_wire == "int8", has_rope=rope,
     )
     # close the scalar-prefetch operands over the concrete table so the
@@ -159,7 +181,7 @@ def kernel_specs(
         plan[key] = [
             pl.BlockSpec(
                 spec.block_shape,
-                (lambda m: lambda b, j: m(b, j, page_table, lengths))(
+                (lambda m: lambda b, j: m(b, j, page_table, lengths, layer))(
                     spec.index_map
                 ),
             )
@@ -178,13 +200,34 @@ def kernel_specs(
     return [spec]
 
 
+def _rotate_half_rows(x, d):
+    """:func:`rotate_half` inside each ``d``-lane head of a lane row."""
+    return jnp.concatenate(
+        [rotate_half(x[:, i:i + d]) for i in range(0, x.shape[-1], d)],
+        axis=-1,
+    )
+
+
 def _decode_kernel(
-    pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, cos_ref, sin_ref,
-    o_ref, acc_ref, m_ref, l_ref,
-    *, scale, page, np_, rope, prec,
+    pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+    cos_ref, sin_ref, o_ref, acc_ref, m_ref, l_ref,
+    *, scale, page, np_, groups, rope, prec,
 ):
+    del layer_ref  # consumed by the page index map
     b = pl.program_id(0)
     j = pl.program_id(1)
+    hg, dg = acc_ref.shape
+    d = dg // groups
+    # lane -> which of the row's heads it belongs to
+    head_of = jax.lax.broadcasted_iota(jnp.int32, (1, dg), 1) // d
+
+    def spread(per_head):
+        """``groups`` per-head columns ``(..., 1)`` -> one lane row
+        ``(..., D*G)``, each head's value across its own ``D`` lanes."""
+        out = per_head[0]
+        for g in range(1, groups):
+            out = jnp.where(head_of == g, per_head[g], out)
+        return out
 
     @pl.when(j == 0)
     def _init():
@@ -199,43 +242,50 @@ def _decode_kernel(
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (H, D)
+        q = q_ref[0, 0].astype(jnp.float32)  # (H/G, D*G)
         if rope:
-            cos = cos_ref[0].astype(jnp.float32)  # (1, D)
+            cos = cos_ref[0].astype(jnp.float32)  # (1, D*G)
             sin = sin_ref[0].astype(jnp.float32)
-            q = q * cos + rotate_half(q) * sin
-        k = k_ref[0].astype(jnp.float32)  # (H, page, D)
-        v = v_ref[0].astype(jnp.float32)
+            q = q * cos + _rotate_half_rows(q, d) * sin
+        k = k_ref[0, 0].astype(jnp.float32)  # (H/G, page, D*G)
+        v = v_ref[0, 0].astype(jnp.float32)
         if ks_ref is not None:
             # blockwise int8 codes: one f32 scale per (head, token) row
-            k = k * ks_ref[0].astype(jnp.float32)[..., None]
-            v = v * vs_ref[0].astype(jnp.float32)[..., None]
-        # head-batched mat-vec on the VPU: s[h, t] = q[h, :] . k[h, t, :]
-        s = jax.lax.dot_general(
-            q[:, None, :], k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32, precision=prec,
-        )[:, 0, :] * scale  # (H, page)
-        pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * page
-        s = jnp.where(pos < length, s, MASK_VALUE)
+            ks = ks_ref[0, 0].astype(jnp.float32)  # (H/G, page, G)
+            vs = vs_ref[0, 0].astype(jnp.float32)
+            k = k * spread([ks[..., g:g + 1] for g in range(groups)])
+            v = v * spread([vs[..., g:g + 1] for g in range(groups)])
+        pos = jax.lax.broadcasted_iota(jnp.int32, (hg, page), 1) + j * page
+        alphas, pvs = [], []
+        for g in range(groups):
+            # one head of every row: its query lanes, the others zeroed
+            qg = q if groups == 1 else jnp.where(head_of == g, q, 0.0)
+            # row-batched mat-vec: s[h, t] = q[h, :] . k[h, t, :]
+            s = jax.lax.dot_general(
+                qg[:, None, :], k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32, precision=prec,
+            )[:, 0, :] * scale  # (H/G, page)
+            s = jnp.where(pos < length, s, MASK_VALUE)
 
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # (H, page)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # o[h, d] += p[h, :] . v[h, :, d]
-        pv = jax.lax.dot_general(
-            p[:, None, :], v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32, precision=prec,
-        )[:, 0, :]  # (H, D)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            m_prev = m_ref[g, :, :1]
+            l_prev = l_ref[g, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)  # (H/G, page)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            # o[h, :] += p[h, :] . v[h, :, :] (this head's lanes kept)
+            pvs.append(jax.lax.dot_general(
+                p[:, None, :], v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32, precision=prec,
+            )[:, 0, :])  # (H/G, D*G)
+            alphas.append(alpha)
+            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        acc_ref[...] = acc_ref[...] * spread(alphas) + spread(pvs)
 
     @pl.when(j == np_ - 1)
     def _finalize():
-        l = l_ref[:, :1]
+        l = spread([l_ref[g, :, :1] for g in range(groups)])
         # an idle slot (length 0) never accumulated: l == 0 there, and
         # the contract is zeros, not 0/0
         o = jnp.where(l > 0, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0)
@@ -243,8 +293,8 @@ def _decode_kernel(
 
 
 def _decode_entry(*refs, has_scales, has_rope, **kw):
-    pt_ref, len_ref, q_ref, k_ref, v_ref = refs[:5]
-    i = 5
+    pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref = refs[:6]
+    i = 6
     ks_ref = vs_ref = cos_ref = sin_ref = None
     if has_scales:
         ks_ref, vs_ref = refs[i], refs[i + 1]
@@ -254,27 +304,29 @@ def _decode_entry(*refs, has_scales, has_rope, **kw):
         i += 2
     o_ref, acc_ref, m_ref, l_ref = refs[i:]
     _decode_kernel(
-        pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+        pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         cos_ref, sin_ref, o_ref, acc_ref, m_ref, l_ref, **kw
     )
 
 
 @functools.partial(jax.jit, static_argnames=("scale",))
 def paged_decode_fwd(
-    q, k_pages, v_pages, page_table, lengths, *,
+    q, k_pages, v_pages, page_table, lengths, layer, *,
     scale, k_scale=None, v_scale=None, rope_cos=None, rope_sin=None,
 ):
-    """Single-query attention over the paged KV cache.
+    """Single-query attention over one layer of the paged KV pool.
 
     - ``q`` (B, H, D): the current token's (pre-RoPE) query rows;
-    - ``k_pages`` / ``v_pages`` (P, H, page, D): the shared page pool —
-      f32/bf16, or int8 codes when ``k_scale``/``v_scale`` (P, H, page)
-      carry the blockwise f32 scales;
+    - ``k_pages`` / ``v_pages`` (L, P, H/G, page, D*G): the whole pool
+      in the serving layout (module docstring) — f32/bf16, or int8
+      codes when ``k_scale``/``v_scale`` (L, P, H/G, page, G) carry the
+      blockwise f32 scales;
     - ``page_table`` (B, NP) int32: page ids per sequence in context
       order (entries beyond the live count may point anywhere — dead
       pages are skipped by ``lengths``);
     - ``lengths`` (B,) int32: live KV positions per sequence, INCLUDING
       the current token (whose k/v the caller appended before calling);
+    - ``layer`` () int32: which layer of the pool to read;
     - ``rope_cos`` / ``rope_sin`` (B, D): the rotation rows of each
       sequence's current position — fused onto ``q`` in-kernel.
 
@@ -282,7 +334,12 @@ def paged_decode_fwd(
     exactly zero.
     """
     b, h, d = q.shape
-    p_, _, page, _ = k_pages.shape
+    layers, p_, hg, page, dg = k_pages.shape
+    groups = h // hg
+    if hg * groups != h or dg != d * groups:
+        raise ValueError(
+            f"pool rows {(hg, dg)} do not hold {h} heads of {d} lanes"
+        )
     np_ = page_table.shape[1]
     has_scales = k_scale is not None
     has_rope = rope_cos is not None
@@ -297,19 +354,22 @@ def paged_decode_fwd(
             f"{rope_cos.shape} and {rope_sin.shape}"
         )
 
-    # q as (B, 1, H, D) so its block carries an (H, D) tile per program
+    # q as (B, 1, H/G, D*G): a token's (H, D) row IS its lane rows
     plan = _decode_plan(
-        b, h, d, p_, page, np_, q.dtype, k_pages.dtype,
-        has_scales=has_scales, has_rope=has_rope,
+        b, h, d, layers, p_, page, np_, q.dtype, k_pages.dtype,
+        groups=groups, has_scales=has_scales, has_rope=has_rope,
     )
-    args = [q[:, None], k_pages, v_pages]
+    args = [q.reshape(b, 1, hg, dg), k_pages, v_pages]
     if has_scales:
         args += [k_scale, v_scale]
     if has_rope:
-        args += [rope_cos[:, None], rope_sin[:, None]]
+        args += [
+            jnp.tile(rope_cos, (1, groups))[:, None],
+            jnp.tile(rope_sin, (1, groups))[:, None],
+        ]
 
     kernel = functools.partial(
-        _decode_entry, scale=scale, page=page, np_=np_,
+        _decode_entry, scale=scale, page=page, np_=np_, groups=groups,
         rope=has_rope, has_scales=has_scales, has_rope=has_rope,
         # f32 queries get true-f32 products like the flash kernel's: at
         # DEFAULT the compiled kernel sat 3.5e-3 abs off the f32
@@ -317,7 +377,7 @@ def paged_decode_fwd(
         prec=_dot_precision(q.dtype),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=plan["grid"],
         in_specs=plan["in_specs"],
         out_specs=plan["out_specs"][0],
@@ -331,9 +391,12 @@ def paged_decode_fwd(
             dimension_semantics=plan["dimension_semantics"],
         ),
         interpret=pallas_interpret(),
+        # the trace's name for the custom call (benchmark/readers.py)
+        name="paged_decode_fwd",
     )(
         jnp.asarray(page_table, jnp.int32),
         jnp.asarray(lengths, jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
         *args,
     )
-    return out[:, 0]
+    return out.reshape(b, h, d)

@@ -9,14 +9,19 @@ motivates the fused read side) splits the cache into fixed-size
 **pages** drawn from one shared pool:
 
 - **device side** — one pool per layer, stacked: ``k``/``v`` arrays of
-  shape ``(L, P, H, page, D)`` (heads OUTSIDE the page dim — the layout
-  :func:`apex_tpu.ops.paged_decode_attention` contracts with no
-  transposes).  With ``kv_wire="int8"`` the pools hold blockwise int8
-  codes plus f32 scale planes ``(L, P, H, page)`` — one scale per
-  (head, token) row at ``block = head_dim``, the exact
-  ``parallel/comm.py`` codec (:func:`~apex_tpu.parallel.comm.
-  quantize_blocks`), so the KV wire format is the same code the
-  gradient wire uses.
+  shape ``(L, P, H/G, page, D*G)`` — heads OUTSIDE the page dim (the
+  layout :func:`apex_tpu.ops.paged_decode_attention` contracts with no
+  transposes), ``G`` heads side by side in one 128-lane row
+  (:func:`~apex_tpu.ops.paged_attention.heads_per_row`: ``G = 2`` at
+  ``head_dim`` 64, 1 from 128 up).  A lane-dense minor dimension is what
+  lets XLA:TPU keep the pool in plain row-major layout — the one layout
+  the kernel, the appends and the prompt writes all agree on, so no
+  serving program ever relays the pool (docs/serving.md "The KV pool").
+  With ``kv_wire="int8"`` the pools hold blockwise int8 codes plus f32
+  scale planes ``(L, P, H/G, page, G)`` — one scale per (head, token)
+  row at ``block = head_dim``, the exact ``parallel/comm.py`` codec
+  (:func:`~apex_tpu.parallel.comm.quantize_blocks`), so the KV wire
+  format is the same code the gradient wire uses.
 - **host side** — :class:`PagePool`, a free-list allocator.  Page 0 is
   the reserved **null page**: page-table entries beyond a sequence's
   live count point at it, padded prefill tails scatter into it, and
@@ -29,9 +34,12 @@ returns its pages to the free list with zero compaction — occupancy is
 exactly ``live_pages / usable_pages`` at all times.
 
 The device-side write helpers here are pure functions meant to be
-called INSIDE the engine's jitted step programs; the engine donates the
-cache arrays so the scatters update pages in place
-(``analysis.check``'s donation lint proves the aliasing at build).
+called INSIDE the engine's jitted step programs, on the WHOLE pool with
+a layer index: the programs carry the pool through their layer loop and
+every write is a scatter of whole pages at ``[layer, page_ids]``, the
+one form XLA:TPU performs in place on the donated buffer (the engine's
+``memory-pool-copy`` lint proves at build that no program holds a
+layer-sized temporary).
 """
 
 from __future__ import annotations
@@ -43,6 +51,7 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from apex_tpu.ops.paged_attention import heads_per_row
 from apex_tpu.parallel import comm
 
 __all__ = [
@@ -53,7 +62,9 @@ __all__ = [
     "init_kv_pages",
     "encode_kv",
     "pack_prompt_pages",
-    "write_prompt_pages",
+    "write_pages",
+    "append_rows",
+    "write_prompt_kv",
     "append_token_kv",
 ]
 
@@ -461,22 +472,24 @@ def init_kv_pages(
     dtype=jnp.bfloat16,
     kv_wire: str = "f32",
 ) -> dict:
-    """Fresh zeroed pool arrays: ``{"k", "v"}`` of ``(L, P, H, page,
-    D)``, plus ``{"k_scale", "v_scale"}`` ``(L, P, H, page)`` f32 planes
-    under ``kv_wire="int8"`` (codes then carry dtype int8)."""
+    """Fresh zeroed pool arrays: ``{"k", "v"}`` of ``(L, P, H/G, page,
+    D*G)`` with ``G = heads_per_row(H, D)``, plus ``{"k_scale",
+    "v_scale"}`` ``(L, P, H/G, page, G)`` f32 planes under
+    ``kv_wire="int8"`` (codes then carry dtype int8)."""
     if kv_wire not in ("f32", "int8"):
         raise ValueError(f"kv_wire must be 'f32' or 'int8', got {kv_wire!r}")
-    shape = (num_layers, num_pages, num_heads, page_size, head_dim)
+    g = heads_per_row(num_heads, head_dim)
+    rows = (num_layers, num_pages, num_heads // g, page_size)
     store = jnp.int8 if kv_wire == "int8" else dtype
     cache = {
-        "k": jnp.zeros(shape, store),
-        "v": jnp.zeros(shape, store),
+        "k": jnp.zeros(rows + (head_dim * g,), store),
+        "v": jnp.zeros(rows + (head_dim * g,), store),
     }
     if kv_wire == "int8":
         # two DISTINCT buffers: the engine donates the whole cache
         # tree, and donating one shared buffer twice is a runtime error
-        cache["k_scale"] = jnp.ones(shape[:-1], jnp.float32)
-        cache["v_scale"] = jnp.ones(shape[:-1], jnp.float32)
+        cache["k_scale"] = jnp.ones(rows + (g,), jnp.float32)
+        cache["v_scale"] = jnp.ones(rows + (g,), jnp.float32)
     return cache
 
 
@@ -490,28 +503,80 @@ def encode_kv(x):
     return codes, scale[..., 0]
 
 
-def pack_prompt_pages(kv, page_size: int):
-    """``(S, H, D)`` per-position rows -> ``(NP, H, page, D)`` page
+def pack_prompt_pages(rows, page_size: int):
+    """``(S, R, W)`` per-position rows -> ``(NP, R, page, W)`` page
     blocks (``S`` must be a page multiple — prefill buckets are)."""
-    s, h, d = kv.shape
+    s, r, w = rows.shape
     if s % page_size:
         raise ValueError(f"prompt length {s} is not a page multiple")
     return jnp.transpose(
-        kv.reshape(s // page_size, page_size, h, d), (0, 2, 1, 3)
+        rows.reshape(s // page_size, page_size, r, w), (0, 2, 1, 3)
     )
 
 
-def write_prompt_pages(pages, new, page_ids):
-    """Scatter layer-stacked page blocks ``new`` ``(L, NP, H, page,
-    D[, ...])`` into the pool ``pages`` ``(L, P, H, page, D[, ...])`` at
-    ``page_ids`` ``(NP,)``.  Entries pointing at the null page dump the
-    padded tail there (never read back)."""
-    return pages.at[:, page_ids].set(new.astype(pages.dtype))
+def write_pages(pool, layer, page_ids, blocks):
+    """Scatter whole page blocks ``(NP, R, page, W)`` into layer
+    ``layer`` of ``pool`` ``(L, P, R, page, W)`` at ``page_ids``
+    ``(NP,)``.  Entries pointing at the null page dump a padded tail
+    there (never read back)."""
+    return pool.at[layer, page_ids].set(blocks.astype(pool.dtype))
 
 
-def append_token_kv(pages, rows, page_ids, slots):
-    """Scatter one token's rows ``(B, H, D[, ...])`` into ``pages``
-    ``(P, H, page, D[, ...])`` at ``(page_ids[b], slots[b])`` per
-    sequence — the per-layer decode append (idle slots target the null
-    page)."""
-    return pages.at[page_ids, :, slots].set(rows.astype(pages.dtype))
+def append_rows(pool, layer, page_ids, slots, rows):
+    """Put one row ``(B, R, W)`` per sequence into layer ``layer`` of
+    ``pool`` at ``(page_ids[b], slots[b])`` by read-modify-write of the
+    batch's tail pages: gather them, select the new row in, scatter the
+    whole pages back.  A per-row scatter ``pool.at[l, page, :, slot]``
+    makes XLA:TPU relay the whole pool around it.  Idle slots all
+    target the null page (duplicate indices — any winner is fine, it is
+    never read); no two live sequences share a tail page (the
+    scheduler's copy-on-write fork)."""
+    pages = pool[layer, page_ids]  # (B, R, page, W)
+    at_slot = (
+        jnp.arange(pool.shape[3], dtype=slots.dtype)[None, :]
+        == slots[:, None]
+    )[:, None, :, None]
+    pages = jnp.where(at_slot, rows.astype(pool.dtype)[:, :, None, :], pages)
+    return write_pages(pool, layer, page_ids, pages)
+
+
+def _planes(kv, k, v):
+    """K/V rows ``(..., H, D)`` as the pool's planes ``{name: (...,
+    H/G, W*G)}``: lane rows of ``G`` heads (a free reshape), int8 codes
+    and their scales when ``kv`` carries scale planes."""
+    g = k.shape[-2] // kv["k"].shape[2]
+    planes = {"k": k, "v": v}
+    if "k_scale" in kv:
+        planes["k"], k_scale = encode_kv(k)
+        planes["v"], v_scale = encode_kv(v)
+        planes["k_scale"] = k_scale[..., None]
+        planes["v_scale"] = v_scale[..., None]
+    return {
+        name: x.reshape(
+            x.shape[:-2] + (x.shape[-2] // g, g * x.shape[-1])
+        )
+        for name, x in planes.items()
+    }
+
+
+def write_prompt_kv(kv, layer, page_ids, k, v):
+    """Write one layer's prompt K/V ``(S, H, D)`` (``S`` a page
+    multiple) into pages ``page_ids`` ``(S/page,)`` of every plane of
+    the pool dict ``kv``."""
+    page_size = kv["k"].shape[3]
+    return dict(kv, **{
+        name: write_pages(
+            kv[name], layer, page_ids, pack_prompt_pages(rows, page_size)
+        )
+        for name, rows in _planes(kv, k, v).items()
+    })
+
+
+def append_token_kv(kv, layer, page_ids, slots, k, v):
+    """Append one token's K/V ``(B, H, D)`` per sequence to layer
+    ``layer`` of every plane of the pool dict ``kv`` at
+    ``(page_ids[b], slots[b])`` — the per-layer decode append."""
+    return dict(kv, **{
+        name: append_rows(kv[name], layer, page_ids, slots, rows)
+        for name, rows in _planes(kv, k, v).items()
+    })

@@ -15,9 +15,12 @@ the proofs about them:
   build (``lint_hlo`` on the one AOT-compiled module + ``lint_jaxpr``
   on a re-trace — the split-entry API exists exactly so the lint does
   not pay a second compile): transfer-free (no host round-trip inside
-  a latency-critical step), donation-aliased (the KV pool updates in
-  place — a dropped donation would double cache memory per step), plus
-  the standard f64 screens.  Any ERROR finding fails the build;
+  a latency-critical step), donation-aliased (the pool's output is
+  the donated buffer — a dropped donation would double cache memory
+  per step), pool-copy-free (``memory-pool-copy``: nothing shaped like
+  the pool or a layer of it is materialized between entry and exit —
+  docs/serving.md "The KV pool"), plus the standard f64 screens.  Any
+  ERROR finding fails the build;
   reports stay on :attr:`reports` and publish to the observability
   board.  ``engine.lint()`` / ``tools/graph_lint.py --target serve``
   re-prove the same through the full :func:`analysis.check` path.
@@ -308,7 +311,6 @@ class InferenceEngine:
                 self.cfg, params, kv_pages, tokens, length, page_ids,
                 temp, rng,
                 page_size=s.page_size,
-                kv_wire=s.kv_wire,
                 top_k=s.top_k,
             )
 
@@ -334,7 +336,6 @@ class InferenceEngine:
                 self.cfg, params, kv_pages, tokens, length, offset,
                 chunk_page_ids, page_table, temp, rng,
                 page_size=s.page_size,
-                kv_wire=s.kv_wire,
                 top_k=s.top_k,
             )
 
@@ -359,7 +360,7 @@ class InferenceEngine:
             return model_lib.decode_body(
                 self.cfg, params, kv_pages, tokens, lengths, page_tables,
                 temps, rng,
-                page_size=s.page_size, kv_wire=s.kv_wire, top_k=s.top_k,
+                page_size=s.page_size, top_k=s.top_k,
             )
 
         fn.__name__ = "serve_decode"
@@ -401,8 +402,7 @@ class InferenceEngine:
             return spec_lib.draft_body(
                 dcfg, params, kv_pages, tokens, lengths, page_tables,
                 temps, stream_keys, gens,
-                k=k, page_size=s.page_size, kv_wire=s.kv_wire,
-                top_k=s.top_k,
+                k=k, page_size=s.page_size, top_k=s.top_k,
             )
 
         fn.__name__ = "serve_draft_decode"
@@ -428,7 +428,7 @@ class InferenceEngine:
                 self.cfg, params, kv_pages, tokens, draft_tokens,
                 lengths, page_tables, temps, draft_probs, stream_keys,
                 gens,
-                page_size=s.page_size, kv_wire=s.kv_wire, top_k=s.top_k,
+                page_size=s.page_size, top_k=s.top_k,
             )
 
         fn.__name__ = "serve_verify"
@@ -455,7 +455,7 @@ class InferenceEngine:
         def fn(kv_pages, starts, counts, page_tables):
             return spec_lib.rollback_body(
                 kv_pages, starts, counts, page_tables,
-                k=kmax, page_size=s.page_size, kv_wire=s.kv_wire,
+                k=kmax, page_size=s.page_size,
             )
 
         fn.__name__ = name
@@ -477,7 +477,6 @@ class InferenceEngine:
                 dcfg, params, kv_pages, tokens, length, page_ids,
                 temp, rng,
                 page_size=s.page_size,
-                kv_wire=s.kv_wire,
                 top_k=s.top_k,
             )
 
@@ -492,6 +491,26 @@ class InferenceEngine:
             self._rng_base,
         )
         return fn, args
+
+    def _pool_intent(self, cache) -> dict:
+        """The ``memory-pool-copy`` intent for a program that takes
+        ``cache``: the pool stays one buffer in one layout from entry
+        to exit (docs/serving.md "The KV pool").  An ERROR where it
+        costs, on the TPU at the bf16/f32 wire; a WARNING for the int8
+        wire's scale planes (minor dimension G, not lane-dense:
+        XLA:TPU still relays them, PERF.md section 7) and for the CPU
+        compiler's own copy insertion."""
+        from apex_tpu import analysis
+
+        strict = (
+            jax.default_backend() == "tpu" and self.serve.kv_wire != "int8"
+        )
+        return {
+            "shapes": [
+                leaf.shape for leaf in jax.tree_util.tree_leaves(cache)
+            ],
+            "severity": None if strict else analysis.WARNING,
+        }
 
     def _compile(self, name: str, fn, args, *, donate: int = 1):
         from apex_tpu import analysis
@@ -512,10 +531,15 @@ class InferenceEngine:
                 hlo_text,
                 donated=len(jax.tree_util.tree_leaves(args[donate])),
                 hbm_budget=self.serve.hbm_budget_bytes,
+                expect_pool=self._pool_intent(args[donate]),
                 name=f"serve/{name}",
             )
             est = analysis.memory.estimate_peak(hlo_text)
             analysis.memory.publish_peak(est, prefix=f"serve/hbm/{name}")
+            board.set(
+                f"serve/hbm/{name}/temp_bytes",
+                int(compiled.memory_analysis().temp_size_in_bytes),
+            )
             board.set("serve/peak_hbm_bytes", max(
                 int(board.get("serve/peak_hbm_bytes") or 0),
                 est["peak_bytes"],
@@ -682,6 +706,7 @@ class InferenceEngine:
             jax.jit(fn, donate_argnums=(1,)), *args,
             donate_argnums=(1,),
             hbm_budget=self.serve.hbm_budget_bytes,
+            expect_pool=self._pool_intent(self.cache),
             name=f"serve/prefill_{bucket}",
         )
         fn, args = self._decode_fn()
@@ -689,6 +714,7 @@ class InferenceEngine:
             jax.jit(fn, donate_argnums=(1,)), *args,
             donate_argnums=(1,),
             hbm_budget=self.serve.hbm_budget_bytes,
+            expect_pool=self._pool_intent(self.cache),
             name="serve/decode",
         )
         analysis.attach_shard_sections(report, [

@@ -37,7 +37,10 @@ import jax.numpy as jnp
 from apex_tpu.models.gpt import GptConfig, _rope_cos_sin
 from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.layer_norm import fused_layer_norm_affine
-from apex_tpu.ops.paged_attention import paged_decode_attention
+from apex_tpu.ops.paged_attention import (
+    gather_history,
+    paged_decode_attention,
+)
 from apex_tpu.ops.rope import fused_apply_rotary_pos_emb_cached, rotate_half
 from apex_tpu.parallel import comm
 from apex_tpu.serve import cache as cache_lib
@@ -308,7 +311,12 @@ def prefill_body(
     kv_pages)`` — ``finite`` is the in-step non-finite screen
     (``isfinite(logits).all()``): the quarantine evidence the scheduler
     reads WITHOUT paying the (V,) device→host logits copy.
+
+    ``page_size`` and ``kv_wire`` restate what the pool's shape and
+    planes say (the writes read them there); they stay for callers that
+    pass them (``benchmark/rehearse_compile.py``).
     """
+    del page_size, kv_wire
     params = dequantize_params(params)
     tree = _tree(params)
     x = _embed(tree["word_embeddings"], tokens, cfg.dtype)  # (S, 1, h)
@@ -324,39 +332,20 @@ def prefill_body(
     bp = tree["layers"]["block"]
 
     def layer(carry, xs):
-        x, new = _prefill_block(cfg, xs, carry, cos, sin)
-        return x, new
+        x, kv = carry
+        lp, l = xs
+        x, (k, v) = _prefill_block(cfg, lp, x, cos, sin)
+        # (1, H, S, D) -> per-position rows (S, H, D) -> this layer's
+        # pages, written here so the pool stays the loop's carry
+        kv = cache_lib.write_prompt_kv(
+            kv, l, page_ids,
+            jnp.transpose(k[0], (1, 0, 2)), jnp.transpose(v[0], (1, 0, 2)),
+        )
+        return (x, kv), None
 
-    x, (k_all, v_all) = jax.lax.scan(layer, x, bp)
-    # (L, 1, H, S, D) -> per-position rows (L, S, H, D) -> page blocks
-    k_all = jnp.transpose(k_all[:, 0], (0, 2, 1, 3))
-    v_all = jnp.transpose(v_all[:, 0], (0, 2, 1, 3))
-    k_blocks = jax.vmap(
-        lambda t: cache_lib.pack_prompt_pages(t, page_size)
-    )(k_all)
-    v_blocks = jax.vmap(
-        lambda t: cache_lib.pack_prompt_pages(t, page_size)
-    )(v_all)
-    if kv_wire == "int8":
-        k_codes, k_scale = cache_lib.encode_kv(k_blocks)
-        v_codes, v_scale = cache_lib.encode_kv(v_blocks)
-        kv_pages = dict(
-            kv_pages,
-            k=cache_lib.write_prompt_pages(kv_pages["k"], k_codes, page_ids),
-            v=cache_lib.write_prompt_pages(kv_pages["v"], v_codes, page_ids),
-            k_scale=cache_lib.write_prompt_pages(
-                kv_pages["k_scale"], k_scale, page_ids
-            ),
-            v_scale=cache_lib.write_prompt_pages(
-                kv_pages["v_scale"], v_scale, page_ids
-            ),
-        )
-    else:
-        kv_pages = dict(
-            kv_pages,
-            k=cache_lib.write_prompt_pages(kv_pages["k"], k_blocks, page_ids),
-            v=cache_lib.write_prompt_pages(kv_pages["v"], v_blocks, page_ids),
-        )
+    (x, kv_pages), _ = jax.lax.scan(
+        layer, (x, dict(kv_pages)), (bp, jnp.arange(cfg.num_layers))
+    )
 
     h_last = jax.lax.dynamic_slice_in_dim(
         x[:, 0], jnp.maximum(length - 1, 0), 1, 0
@@ -376,12 +365,6 @@ def prefill_body(
 # ---------------------------------------------------------------------------
 
 
-def _dequant_rows(codes, scale):
-    """(..., page, D) int8 codes + (..., page) f32 scales -> f32 rows
-    (the comm codec at block = D: one scale per row)."""
-    return codes.astype(jnp.float32) * scale[..., None]
-
-
 def chunk_prefill_body(
     cfg: GptConfig,
     params,
@@ -396,7 +379,6 @@ def chunk_prefill_body(
     rng=None,        # PRNG key for the fused sampler
     *,
     page_size: int,
-    kv_wire: str = "f32",
     top_k: int = 0,
 ):
     """One page-multiple prefill chunk with **carry-in KV offset**: the
@@ -435,7 +417,6 @@ def chunk_prefill_body(
         x = x + rows[:, None, :].astype(cfg.dtype)
 
     bp = tree["layers"]["block"]
-    int8 = kv_wire == "int8"
     t_ctx = page_table.shape[0] * page_size
     # carry-in mask: gathered row t is absolute position t of this
     # sequence; only positions before the chunk are valid carry
@@ -449,16 +430,10 @@ def chunk_prefill_body(
     )[None]                                            # (1, C, T+C)
     scale = head_dim**-0.5
     big_neg = jnp.asarray(jnp.finfo(jnp.float32).min, jnp.float32)
-    xs = (bp, kv_pages["k"], kv_pages["v"]) + (
-        (kv_pages["k_scale"], kv_pages["v_scale"]) if int8 else ()
-    )
 
-    def layer(x, xs):
-        if int8:
-            lp, k_l, v_l, ks_l, vs_l = xs
-        else:
-            lp, k_l, v_l = xs
-            ks_l = vs_l = None
+    def layer(carry, xs):
+        x, kv = carry
+        lp, l = xs
         y = _layer_norm(x, lp["ln_attn"], cfg.layer_norm_eps)
         qkv = _linear(y, lp["qkv"], cfg.dtype)
         qkv = qkv.reshape(c, 1, heads, 3, head_dim)
@@ -469,20 +444,14 @@ def chunk_prefill_body(
             q = fused_apply_rotary_pos_emb_cached(q, cos_rows, sin_rows)
             k = fused_apply_rotary_pos_emb_cached(k, cos_rows, sin_rows)
         # carry-in K/V: dense gather of the whole page table, read
-        # through the cache wire (exactly how decode reads it)
-        if int8:
-            k_ctx = _dequant_rows(k_l[page_table], ks_l[page_table])
-            v_ctx = _dequant_rows(v_l[page_table], vs_l[page_table])
-        else:
-            k_ctx = k_l[page_table].astype(jnp.float32)
-            v_ctx = v_l[page_table].astype(jnp.float32)
-        # (NP, H, page, D) -> (H, T, D) in absolute position order
-        k_ctx = jnp.transpose(k_ctx, (1, 0, 2, 3)).reshape(
-            heads, t_ctx, head_dim
-        )
-        v_ctx = jnp.transpose(v_ctx, (1, 0, 2, 3)).reshape(
-            heads, t_ctx, head_dim
-        )
+        # through the cache wire (exactly how decode reads it), as
+        # (H, T, D) f32 in absolute position order
+        k_ctx = gather_history(
+            kv["k"], kv.get("k_scale"), l, page_table[None], heads
+        )[0]
+        v_ctx = gather_history(
+            kv["v"], kv.get("v_scale"), l, page_table[None], heads
+        )[0]
         # in-chunk keys stay exact (the same in-flight numerics the
         # monolithic prefill uses for every prompt position)
         kf = k[0].astype(jnp.float32)                  # (H, C, D)
@@ -501,29 +470,15 @@ def chunk_prefill_body(
         x = _mlp(x, lp, cfg)
         # write the chunk's K/V pages (null entries dump cached pages'
         # re-runs into write-only garbage)
-        k_rows = jnp.transpose(k[0], (1, 0, 2))        # (C, H, D)
-        v_rows = jnp.transpose(v[0], (1, 0, 2))
-        k_blocks = cache_lib.pack_prompt_pages(k_rows, page_size)
-        v_blocks = cache_lib.pack_prompt_pages(v_rows, page_size)
-        if int8:
-            k_codes, k_sc = cache_lib.encode_kv(k_blocks)
-            v_codes, v_sc = cache_lib.encode_kv(v_blocks)
-            k_l = k_l.at[chunk_page_ids].set(k_codes.astype(k_l.dtype))
-            v_l = v_l.at[chunk_page_ids].set(v_codes.astype(v_l.dtype))
-            ks_l = ks_l.at[chunk_page_ids].set(k_sc)
-            vs_l = vs_l.at[chunk_page_ids].set(v_sc)
-            return x, (k_l, v_l, ks_l, vs_l)
-        k_l = k_l.at[chunk_page_ids].set(k_blocks.astype(k_l.dtype))
-        v_l = v_l.at[chunk_page_ids].set(v_blocks.astype(v_l.dtype))
-        return x, (k_l, v_l)
-
-    x, new = jax.lax.scan(layer, x, xs)
-    if int8:
-        kv_pages = dict(
-            kv_pages, k=new[0], v=new[1], k_scale=new[2], v_scale=new[3]
+        kv = cache_lib.write_prompt_kv(
+            kv, l, chunk_page_ids,
+            jnp.transpose(k[0], (1, 0, 2)), jnp.transpose(v[0], (1, 0, 2)),
         )
-    else:
-        kv_pages = dict(kv_pages, k=new[0], v=new[1])
+        return (x, kv), None
+
+    (x, kv_pages), _ = jax.lax.scan(
+        layer, (x, dict(kv_pages)), (bp, jnp.arange(cfg.num_layers))
+    )
 
     h_last = jax.lax.dynamic_slice_in_dim(
         x[:, 0], jnp.maximum(length - 1, 0), 1, 0
@@ -552,7 +507,6 @@ def _decode_step(
     page_tables,  # (B, NP) int32
     *,
     page_size: int,
-    kv_wire: str = "f32",
 ):
     """The shared decode compute: embed the token column, append each
     layer's K/V at this position's page slot, run the fused paged
@@ -580,17 +534,10 @@ def _decode_step(
         x = x + rows.astype(cfg.dtype)
 
     bp = tree["layers"]["block"]
-    int8 = kv_wire == "int8"
-    xs = (bp, kv_pages["k"], kv_pages["v"]) + (
-        (kv_pages["k_scale"], kv_pages["v_scale"]) if int8 else ()
-    )
 
-    def layer(x, xs):
-        if int8:
-            lp, k_l, v_l, ks_l, vs_l = xs
-        else:
-            lp, k_l, v_l = xs
-            ks_l = vs_l = None
+    def layer(carry, xs):
+        x, kv = carry
+        lp, l = xs
         y = _layer_norm(x, lp["ln_attn"], cfg.layer_norm_eps)
         qkv = _linear(y, lp["qkv"], cfg.dtype).reshape(
             b, heads, 3, head_dim
@@ -598,35 +545,24 @@ def _decode_step(
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # (B, H, D)
         if cfg.rotary:
             k = _rope_rows(k, cos_rows, sin_rows)
-        if int8:
-            k_codes, k_sc = cache_lib.encode_kv(k)
-            v_codes, v_sc = cache_lib.encode_kv(v)
-            k_l = cache_lib.append_token_kv(k_l, k_codes, page_ids, slots)
-            v_l = cache_lib.append_token_kv(v_l, v_codes, page_ids, slots)
-            ks_l = cache_lib.append_token_kv(ks_l, k_sc, page_ids, slots)
-            vs_l = cache_lib.append_token_kv(vs_l, v_sc, page_ids, slots)
-        else:
-            k_l = cache_lib.append_token_kv(k_l, k, page_ids, slots)
-            v_l = cache_lib.append_token_kv(v_l, v, page_ids, slots)
+        kv = cache_lib.append_token_kv(kv, l, page_ids, slots, k, v)
         ctx = paged_decode_attention(
-            q, k_l, v_l, page_tables, lengths,
-            scale=head_dim**-0.5,
-            k_scale=ks_l, v_scale=vs_l,
+            q, kv["k"], kv["v"], page_tables, lengths,
+            layer=l, scale=head_dim**-0.5,
+            k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"),
             rope_cos=cos_rows if cfg.rotary else None,
             rope_sin=sin_rows if cfg.rotary else None,
         )
         ctx = ctx.astype(cfg.dtype).reshape(b, heads * head_dim)
         x = x + _linear(ctx, lp["out"], cfg.dtype)
         x = _mlp(x, lp, cfg)
-        return x, (k_l, v_l, ks_l, vs_l) if int8 else (k_l, v_l)
+        return (x, kv), None
 
-    x, new = jax.lax.scan(layer, x, xs)
-    if int8:
-        kv_pages = dict(
-            kv_pages, k=new[0], v=new[1], k_scale=new[2], v_scale=new[3]
-        )
-    else:
-        kv_pages = dict(kv_pages, k=new[0], v=new[1])
+    # the pool is the loop's CARRY (indexed by layer), never its
+    # xs/ys: a scanned-over pool is sliced and restacked every layer
+    (x, kv_pages), _ = jax.lax.scan(
+        layer, (x, dict(kv_pages)), (bp, jnp.arange(cfg.num_layers))
+    )
 
     h = _layer_norm(x, tree["ln_f"], cfg.layer_norm_eps)
     logits = _logits(tree, h, cfg.dtype)  # (B, V) f32
@@ -660,12 +596,15 @@ def decode_body(
     its KV pages or a numerically blown state) flags ONLY its own
     slot, so the scheduler's quarantine can evict the offender without
     touching the rest of the batch or reading the (B, V) logits back.
+    ``kv_wire`` restates what the pool's planes say and stays for
+    callers that pass it (``benchmark/rehearse_compile.py``).
     """
+    del kv_wire
     params = dequantize_params(params)
     tree = _tree(params)
     logits, kv_pages = _decode_step(
         cfg, tree, kv_pages, tokens, lengths, page_tables,
-        page_size=page_size, kv_wire=kv_wire,
+        page_size=page_size,
     )
     if rng is None:
         next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.models.gpt import GptConfig
+from apex_tpu.serve import cache as cache_lib
 from apex_tpu.serve import model as model_lib
 
 __all__ = [
@@ -275,7 +276,7 @@ def speculative_verify(ver_logits, draft_tokens, draft_probs, temps,
 
 def draft_body(cfg: GptConfig, params, kv_pages: dict, tokens, lengths,
                page_tables, temps, stream_keys, gens, *, k: int,
-               page_size: int, kv_wire: str = "f32", top_k: int = 0):
+               page_size: int, top_k: int = 0):
     """``k+1``-step proposal scan over the draft model.  Step ``j``
     feeds the current token at length ``lengths + j`` (writing its
     draft KV) and samples the next proposal from the draft distribution
@@ -295,7 +296,7 @@ def draft_body(cfg: GptConfig, params, kv_pages: dict, tokens, lengths,
         eff = jnp.where(lengths > 0, lengths + j, 0)
         logits, kv = model_lib._decode_step(
             cfg, tree, kv, cur, eff, page_tables,
-            page_size=page_size, kv_wire=kv_wire,
+            page_size=page_size,
         )
         keys = _fold_each(_fold_each(stream_keys, gens + j), DRAFT_TAG)
         nxt = model_lib.sample_tokens(logits, temps, keys, top_k=top_k)
@@ -314,8 +315,7 @@ def draft_body(cfg: GptConfig, params, kv_pages: dict, tokens, lengths,
 
 def verify_body(cfg: GptConfig, params, kv_pages: dict, tokens,
                 draft_tokens, lengths, page_tables, temps, draft_probs,
-                stream_keys, gens, *, page_size: int,
-                kv_wire: str = "f32", top_k: int = 0):
+                stream_keys, gens, *, page_size: int, top_k: int = 0):
     """ONE target program scoring ``k+1`` positions: a scan of the
     plain decode step (:func:`~apex_tpu.serve.model._decode_step` —
     same function, same shapes, same paged-attention kernel) over the
@@ -338,7 +338,7 @@ def verify_body(cfg: GptConfig, params, kv_pages: dict, tokens,
         eff = jnp.where(lengths > 0, lengths + j, 0)
         logits, kv = model_lib._decode_step(
             cfg, tree, kv, jnp.take(cols, j, axis=1), eff, page_tables,
-            page_size=page_size, kv_wire=kv_wire,
+            page_size=page_size,
         )
         return kv, logits
 
@@ -354,7 +354,7 @@ def verify_body(cfg: GptConfig, params, kv_pages: dict, tokens,
 
 
 def rollback_body(kv_pages: dict, starts, counts, page_tables, *,
-                  k: int, page_size: int, kv_wire: str = "f32"):
+                  k: int, page_size: int):
     """Per-slot KV-length truncation: zero the rows of positions
     ``[starts[b], starts[b] + counts[b])`` through slot ``b``'s page
     table (codes to 0; int8 scale planes back to the init value 1.0).
@@ -365,6 +365,7 @@ def rollback_body(kv_pages: dict, starts, counts, page_tables, *,
     safe next to a borrowed prefix-cache run."""
     b = starts.shape[0]
     width = page_tables.shape[1]
+    layers = kv_pages["k"].shape[0]
 
     def zero_step(kv, j):
         pos = starts + j
@@ -374,15 +375,22 @@ def rollback_body(kv_pages: dict, starts, counts, page_tables, *,
             live, page_tables[jnp.arange(b), page_idx], 0
         )
         slots = pos % page_size
-        out = {}
-        for name, arr in kv.items():
-            fill = 1.0 if name.endswith("_scale") else 0
-            upd = jnp.full(
-                (b, arr.shape[0], arr.shape[2]) + arr.shape[4:],
-                fill, arr.dtype,
-            )
-            out[name] = arr.at[:, page_ids, :, slots].set(upd)
-        return out, None
+
+        def zero_layer(l, kv):
+            # page-granular like the decode append (cache.append_rows:
+            # a per-row scatter relays the whole pool)
+            return {
+                name: cache_lib.append_rows(
+                    arr, l, page_ids, slots,
+                    jnp.full(
+                        (b, arr.shape[2], arr.shape[4]),
+                        1.0 if name.endswith("_scale") else 0, arr.dtype,
+                    ),
+                )
+                for name, arr in kv.items()
+            }
+
+        return jax.lax.fori_loop(0, layers, zero_layer, kv), None
 
     kv_pages, _ = jax.lax.scan(
         zero_step, dict(kv_pages), jnp.arange(max(k, 1))

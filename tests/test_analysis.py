@@ -331,7 +331,8 @@ def test_every_rule_is_cataloged_and_catalog_is_complete():
         "collective-count", "collective-bytes", "collective-dtype",
         "sharding-replicated", "sharding-mismatch",
         "sharding-unverified", "reshard-unplanned", "reshard-plan",
-        "memory-budget", "sharding-implicit-replication",
+        "memory-budget", "memory-pool-copy",
+        "sharding-implicit-replication",
         "sharding-missing-constraint",
         "kernel-vmem-overflow", "kernel-tile-misaligned",
         "kernel-grid-oob", "kernel-block-race", "kernel-dead-tiles",
@@ -835,6 +836,124 @@ ENTRY %main (p0: f32[256,64], p1: f32[64,64]) -> f32[256,64] {
             rules=("memory",),
         )
         assert report.rule_ids() == ["memory-budget"]
+
+    # -- the serving KV pool's one-buffer gate (memory-pool-copy) ---------
+
+    _POOL = (3, 16, 2, 8, 128)  # (L, P, H/G, page, D*G)
+
+    @staticmethod
+    def _pool_xs_ys(pool, rows, page_ids, slots):
+        """PLANTED: the donated pool scanned as xs/ys — each layer is
+        sliced out, updated and restacked into another buffer."""
+        def layer(x, xs):
+            pages, r = xs
+            pages = pages.at[page_ids, :, slots].set(r)
+            return x + pages[page_ids].sum(), pages
+
+        return jax.lax.scan(layer, jnp.float32(0), (pool, rows))
+
+    @staticmethod
+    def _pool_carried(pool, rows, page_ids, slots):
+        """The serving form: the pool is the loop's carry, written
+        page-granular at ``[layer, page_ids]`` (serve/cache.py)."""
+        from apex_tpu.serve import cache as cache_lib
+
+        def layer(carry, xs):
+            x, pool = carry
+            r, l = xs
+            pool = cache_lib.append_rows(pool, l, page_ids, slots, r)
+            return (x + pool[l, page_ids].sum(), pool), None
+
+        (x, pool), _ = jax.lax.scan(
+            layer, (jnp.float32(0), pool),
+            (rows, jnp.arange(pool.shape[0])),
+        )
+        return x, pool
+
+    def _pool_args(self):
+        l, _p, r, _page, w = self._POOL
+        return (
+            jnp.zeros(self._POOL, jnp.float32),
+            jnp.ones((l, 4, r, w), jnp.float32),
+            jnp.asarray([1, 5, 0, 0], jnp.int32),
+            jnp.asarray([0, 3, 0, 0], jnp.int32),
+        )
+
+    def test_planted_pool_scanned_as_xs_ys_is_caught(self):
+        report = analysis.check(
+            self._pool_xs_ys, *self._pool_args(), donate_argnums=(0,),
+            expect_pool={"shapes": [self._POOL]}, rules=("memory",),
+        )
+        assert report.rule_ids() == ["memory-pool-copy"]
+        assert report.errors(), report.render()
+        assert "shaped like the KV pool" in report.findings[0].message
+
+    def test_carried_pool_with_page_writes_is_clean(self):
+        report = analysis.check(
+            self._pool_carried, *self._pool_args(), donate_argnums=(0,),
+            expect_pool={"shapes": [self._POOL]},
+            rules=("memory", "donation"),
+        )
+        assert report.findings == [], report.render()
+        # unarmed (no expect_pool) the planted program is quiet too
+        quiet = analysis.check(
+            self._pool_xs_ys, *self._pool_args(), donate_argnums=(0,),
+            rules=("memory",),
+        )
+        assert quiet.findings == []
+
+    def test_pool_rule_severity_and_transposed_copies(self):
+        """A relayout may print the pool transposed; the intent's
+        severity downgrades the finding (the int8 scale planes)."""
+        hlo = """
+HloModule m, is_scheduled=true
+
+ENTRY %main (p0: bf16[3,16,2,8,128]) -> bf16[3,16,2,8,128] {
+  %p0 = bf16[3,16,2,8,128]{4,3,2,1,0} parameter(0)
+  %t = bf16[16,3,2,8,128]{4,3,2,1,0} transpose(%p0), dimensions={1,0,2,3,4}
+  ROOT %c = bf16[3,16,2,8,128]{4,3,2,1,0} copy(%t)
+}
+"""
+        want = {"shapes": [self._POOL], "severity": analysis.WARNING}
+        report = analysis.lint_hlo(hlo, expect_pool=want, rules=("memory",))
+        assert report.rule_ids() == ["memory-pool-copy"]
+        assert report.errors() == []
+        assert "2 instruction(s)" in report.findings[0].message
+
+    def test_engine_programs_pass_the_pool_rule(self):
+        """Every program the engine compiles (decode, prefill, chunked
+        prefill, fork, speculative draft / verify / rollback) builds
+        under ``verify=True``; off the TPU the rule is a WARNING, and
+        only the CPU compiler's own copy insertion around the chunk
+        program's read-then-write of the pool trips it."""
+        from apex_tpu.models.gpt import GptConfig, GptModel
+        from apex_tpu.observability.metrics import board
+        from apex_tpu.serve import InferenceEngine, ServeConfig
+        from apex_tpu.serve.spec import SpecConfig
+
+        cfg = GptConfig(
+            vocab_size=64, hidden_size=128, num_layers=2, num_heads=2,
+            intermediate_size=64, max_seq_len=128, dtype=jnp.float32,
+        )
+        params = GptModel(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((8, 1), jnp.int32)
+        )
+        eng = InferenceEngine(
+            cfg, params,
+            ServeConfig(page_size=8, num_pages=32, max_batch=2,
+                        max_pages_per_seq=8, verify=True),
+            spec=SpecConfig(draft_params=None, k=2),
+        )
+        assert eng.cache["k"].shape == (2, 32, 1, 8, 128)  # G = 2
+        eng.build(buckets=(16,), chunked=True)
+        flagged = {
+            name for name, report in eng.reports.items()
+            if report.by_rule("memory-pool-copy")
+        }
+        assert flagged <= {"chunk_prefill_16"}, flagged
+        assert len(eng.reports) == 9
+        for name in eng.reports:
+            assert board.get(f"serve/hbm/{name}/temp_bytes") is not None
 
     def test_memory_budget_watchdog_rule(self):
         from apex_tpu.observability import MemoryBudgetRule

@@ -802,6 +802,89 @@ class TestPagedDecodeAttention:
         f32 = paged_decode_attention(q, kp, vp, table, lengths)
         assert float(jnp.abs(out - f32).max()) < 5e-2
 
+    @staticmethod
+    def _to_pool(pages, g):
+        """Per-head pages ``(L, P, H, page, W)`` -> the serving layout
+        ``(L, P, H/G, page, W*G)``: ``G`` heads side by side in a row."""
+        l, p, h, page, w = pages.shape
+        return jnp.transpose(
+            pages.reshape(l, p, h // g, g, page, w), (0, 1, 2, 4, 3, 5)
+        ).reshape(l, p, h // g, page, g * w)
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["f32kv", "int8kv"])
+    @pytest.mark.parametrize("rope", [False, True], ids=["plain", "rope"])
+    @pytest.mark.parametrize("h,d,g", [
+        (2, 128, 1), (4, 64, 2), (8, 32, 4), (3, 64, 1),
+    ])
+    def test_pool_layout_matches_per_head_pages(
+        self, force_pallas, h, d, g, rope, int8
+    ):
+        """The kernel over the WHOLE pool ``(L, P, H/G, page, D*G)`` at
+        a layer index == the reference over that layer's plain
+        ``(P, H, page, D)`` pages, for every ``G`` the shapes give
+        (``heads_per_row``; 3 heads of 64 lanes do not pair up), with
+        and without fused RoPE and int8 scales."""
+        from apex_tpu.ops.paged_attention import (
+            heads_per_row,
+            paged_decode_attention,
+            paged_decode_attention_reference,
+        )
+        from apex_tpu.serve.cache import encode_kv
+
+        assert heads_per_row(h, d) == g
+        rs = np.random.RandomState(h * d)
+        layers, pool, page, np_, b = 2, 10, 8, 3, 3
+        kp = jnp.asarray(rs.randn(layers, pool, h, page, d), jnp.float32)
+        vp = jnp.asarray(rs.randn(layers, pool, h, page, d), jnp.float32)
+        q = jnp.asarray(rs.randn(b, h, d), jnp.float32)
+        table = jnp.asarray(
+            rs.permutation(pool - 1)[: b * np_].reshape(b, np_) + 1,
+            jnp.int32,
+        )
+        lengths = jnp.asarray([19, 8, 0], jnp.int32)
+        kw, pool_kw = {}, {}
+        if rope:
+            kw["rope_cos"] = jnp.asarray(rs.randn(b, d), jnp.float32)
+            kw["rope_sin"] = jnp.asarray(rs.randn(b, d), jnp.float32)
+        if int8:
+            kp, ks = encode_kv(kp)
+            vp, vs = encode_kv(vp)
+            kw.update(k_scale=ks[1], v_scale=vs[1])
+            pool_kw.update(
+                k_scale=self._to_pool(ks[..., None], g),
+                v_scale=self._to_pool(vs[..., None], g),
+            )
+        want = paged_decode_attention_reference(
+            q, kp[1], vp[1], table, lengths, **kw
+        )
+        kw.update(pool_kw)
+        got = paged_decode_attention(
+            q, self._to_pool(kp, g), self._to_pool(vp, g), table, lengths,
+            layer=jnp.asarray(1, jnp.int32), **kw
+        )
+        assert _dispatch.last_paths()["paged_decode_attention"] == "pallas"
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+        assert float(jnp.abs(got[2]).max()) == 0.0  # the idle slot
+        # the jnp path reads the same layout
+        ref = paged_decode_attention_reference(
+            q, self._to_pool(kp, g), self._to_pool(vp, g), table, lengths,
+            layer=1, **kw
+        )
+        np.testing.assert_allclose(ref, want, atol=2e-6, rtol=2e-6)
+
+    def test_pool_operands_are_checked(self, force_pallas):
+        from apex_tpu.ops.paged_attention import paged_decode_attention
+
+        q, kp, vp, table, lengths = self._paged_case(6)
+        with pytest.raises(ValueError, match="needs its layer index"):
+            paged_decode_attention(q, kp[None], vp[None], table, lengths)
+        with pytest.raises(ValueError, match="pages here are 4-D"):
+            paged_decode_attention(q, kp, vp, table, lengths, layer=0)
+        with pytest.raises(ValueError, match="do not hold"):
+            paged_decode_attention(
+                q, kp[None, :, :3], vp[None, :, :3], table, lengths, layer=0
+            )
+
     def test_idle_slot_returns_zeros(self, force_pallas):
         from apex_tpu.ops.paged_attention import paged_decode_attention
 

@@ -116,36 +116,129 @@ class TestPagePool:
 # ---------------------------------------------------------------------------
 
 
-class TestCacheWrites:
-    def test_prompt_pages_roundtrip(self):
-        rs = np.random.RandomState(0)
-        s, h, d, page = 16, 2, 8, 4
-        kv = jnp.asarray(rs.randn(1, s, h, d), jnp.float32)  # one layer
-        pages = jnp.zeros((1, 10, h, page, d), jnp.float32)
-        ids = jnp.asarray([3, 5, 2, 7], jnp.int32)
-        blocks = jax.vmap(
-            lambda t: cache_lib.pack_prompt_pages(t, page)
-        )(kv)
-        out = cache_lib.write_prompt_pages(pages, blocks, ids)
-        # gather back in table order and compare to the original rows
-        got = jnp.moveaxis(out[0][ids], 1, 0).reshape(h, s, d)
-        np.testing.assert_array_equal(
-            np.asarray(got), np.asarray(jnp.transpose(kv[0], (1, 0, 2)))
-        )
+def _read_history(kv, layer, table, heads):
+    """One layer's K history ``(B, H, T, D)`` f32 through ``table``."""
+    from apex_tpu.ops.paged_attention import gather_history
 
-    def test_append_token_roundtrip(self):
+    return np.asarray(gather_history(
+        kv["k"], kv.get("k_scale"), layer, jnp.asarray(table), heads
+    ))
+
+
+class TestCacheWrites:
+    """The pool helpers: whole pages at ``[layer, page_ids]``, in the
+    lane-dense layout ``(L, P, H/G, page, D*G)``."""
+
+    @pytest.mark.parametrize("h,d,g", [
+        (2, 128, 1), (4, 64, 2), (4, 32, 4), (3, 64, 1),
+    ])
+    def test_pool_shape_follows_heads_and_head_dim(self, h, d, g):
+        kv = cache_lib.init_kv_pages(2, 5, h, 4, d, dtype=jnp.float32)
+        assert kv["k"].shape == kv["v"].shape == (2, 5, h // g, 4, d * g)
+        q = cache_lib.init_kv_pages(2, 5, h, 4, d, kv_wire="int8")
+        assert q["k"].dtype == jnp.int8
+        assert q["k_scale"].shape == (2, 5, h // g, 4, g)
+
+    @pytest.mark.parametrize("h,d", [(2, 128), (4, 64), (3, 64)])
+    def test_prompt_pages_roundtrip(self, h, d):
+        """pack -> write at [layer, page_ids] -> read back in table
+        order; the other layer and the other pages stay untouched."""
+        rs = np.random.RandomState(0)
+        s, page = 16, 4
+        k = jnp.asarray(rs.randn(s, h, d), jnp.float32)
+        kv = cache_lib.init_kv_pages(2, 10, h, page, d, dtype=jnp.float32)
+        ids = jnp.asarray([3, 5, 2, 7], jnp.int32)
+        out = cache_lib.write_prompt_kv(kv, 1, ids, k, -k)
+        got = _read_history(out, 1, np.asarray(ids)[None], h)[0]
+        np.testing.assert_array_equal(
+            got, np.asarray(jnp.transpose(k, (1, 0, 2)))
+        )
+        np.testing.assert_array_equal(
+            np.asarray(out["v"]), -np.asarray(out["k"])
+        )
+        assert not np.asarray(out["k"][0]).any()
+        untouched = [p for p in range(10) if p not in (3, 5, 2, 7)]
+        assert not np.asarray(out["k"][1, untouched]).any()
+
+    def test_prompt_tail_lands_in_null_page(self):
+        """Null entries of ``page_ids`` dump their page (a padded tail,
+        a borrowed page's re-run) into page 0 and nowhere else."""
+        rs = np.random.RandomState(7)
+        k = jnp.asarray(rs.randn(8, 4, 64), jnp.float32)
+        kv = cache_lib.init_kv_pages(1, 6, 4, 4, 64, dtype=jnp.float32)
+        out = cache_lib.write_prompt_kv(
+            kv, 0, jnp.asarray([4, NULL_PAGE], jnp.int32), k, k
+        )
+        got = _read_history(out, 0, np.asarray([[4]]), 4)[0]
+        np.testing.assert_array_equal(
+            got, np.asarray(jnp.transpose(k[:4], (1, 0, 2)))
+        )
+        assert not np.asarray(out["k"][0, [1, 2, 3, 5]]).any()
+
+    @pytest.mark.parametrize("h,d", [(2, 128), (4, 64), (4, 32)])
+    def test_append_token_roundtrip(self, h, d):
+        """The read-modify-write append puts each row at its (page,
+        slot) and keeps every other row of the touched pages."""
         rs = np.random.RandomState(1)
-        h, d, page = 2, 8, 4
-        pages = jnp.zeros((6, h, page, d), jnp.float32)
+        page = 4
+        kv = cache_lib.init_kv_pages(2, 6, h, page, d, dtype=jnp.float32)
+        kv = {n: jnp.asarray(rs.randn(*a.shape), a.dtype)
+              for n, a in kv.items()}
         rows = jnp.asarray(rs.randn(3, h, d), jnp.float32)
         pids = jnp.asarray([1, 4, 2], jnp.int32)
         slots = jnp.asarray([0, 3, 1], jnp.int32)
-        out = cache_lib.append_token_kv(pages, rows, pids, slots)
+        out = cache_lib.append_token_kv(kv, 1, pids, slots, rows, 2 * rows)
+        before = _read_history(kv, 1, np.arange(6)[None], h)[0]
+        after = _read_history(out, 1, np.arange(6)[None], h)[0]
+        want = before.copy()
         for b in range(3):
-            np.testing.assert_array_equal(
-                np.asarray(out[pids[b], :, slots[b]]),
-                np.asarray(rows[b]),
-            )
+            want[:, int(pids[b]) * page + int(slots[b])] = rows[b]
+        np.testing.assert_array_equal(after, want)
+        np.testing.assert_array_equal(
+            np.asarray(out["k"][0]), np.asarray(kv["k"][0])
+        )
+        got_v = np.asarray(out["v"][1]) - np.asarray(kv["v"][1])
+        assert np.count_nonzero(got_v) == 3 * h * d
+
+    def test_idle_slots_share_the_null_page(self):
+        """Idle slots all append into page 0 (duplicate scatter
+        indices): the live slot's row still lands, and only the null
+        page takes the garbage."""
+        rs = np.random.RandomState(2)
+        h, d, page = 4, 64, 4
+        kv = cache_lib.init_kv_pages(1, 5, h, page, d, dtype=jnp.float32)
+        rows = jnp.asarray(rs.randn(4, h, d), jnp.float32)
+        pids = jnp.asarray([NULL_PAGE, 3, NULL_PAGE, NULL_PAGE], jnp.int32)
+        slots = jnp.asarray([0, 2, 0, 0], jnp.int32)
+        out = cache_lib.append_token_kv(kv, 0, pids, slots, rows, rows)
+        got = _read_history(out, 0, np.asarray([[3]]), h)[0]
+        np.testing.assert_array_equal(got[:, 2], np.asarray(rows[1]))
+        assert not np.asarray(out["k"][0, [1, 2, 4]]).any()
+        assert np.count_nonzero(got) == h * d
+
+    def test_int8_planes_write_and_append(self):
+        """Under the int8 wire the same helpers carry codes and scale
+        planes: what is read back is the codec's rounding of the rows."""
+        rs = np.random.RandomState(3)
+        h, d, page = 4, 64, 4
+        kv = cache_lib.init_kv_pages(2, 6, h, page, d, kv_wire="int8")
+        k = jnp.asarray(rs.randn(8, h, d) * 3.0, jnp.float32)
+        out = cache_lib.write_prompt_kv(
+            kv, 1, jnp.asarray([2, 5], jnp.int32), k, k
+        )
+        row = jnp.asarray(rs.randn(1, h, d), jnp.float32)
+        out = cache_lib.append_token_kv(
+            out, 1, jnp.asarray([5], jnp.int32),
+            jnp.asarray([1], jnp.int32), row, row,
+        )
+        want = np.asarray(jnp.transpose(k, (1, 0, 2))).copy()
+        want[:, page + 1] = np.asarray(row[0])
+        got = _read_history(out, 1, np.asarray([[2, 5]]), h)[0]
+        step = np.abs(want).max(axis=-1, keepdims=True) / 127.0
+        assert (np.abs(got - want) <= step + 1e-6).all()
+        np.testing.assert_array_equal(
+            np.asarray(out["v"]), np.asarray(out["k"])
+        )
 
     def test_int8_encode_roundtrip(self):
         rs = np.random.RandomState(2)

@@ -1,0 +1,126 @@
+"""Compile-only checks against XLA:TPU for a described (not attached)
+v5e chip — what interpret mode and the CPU compiler cannot show: the
+layouts XLA:TPU assigns and the temporaries it holds.  Nothing runs; no
+time, rate or result comes from here.
+
+All such tests live in THIS file and describe the topology inside a
+fixture: only one process may load libtpu, and a module that touched it
+while being imported would give the xdist workers different tests to
+collect.  Skipped, not failed, where no TPU compiler can be described.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from apex_tpu import analysis
+from apex_tpu.models.gpt import GptConfig, GptModel
+from apex_tpu.ops import _dispatch
+from apex_tpu.ops.pallas import decode_attention, flash_attention, layer_norm
+from apex_tpu.serve import cache as cache_lib
+from apex_tpu.serve import model as model_lib
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def lower_as_chip(monkeypatch):
+    """Kernels on and in Mosaic (not interpret) mode while lowering —
+    ``jax.default_backend()`` still says cpu here."""
+    monkeypatch.setattr(_dispatch, "use_pallas", lambda: True)
+    for mod in (_dispatch, decode_attention, flash_attention, layer_norm):
+        monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
+
+
+#: GPT-2 Large as benchmark/configs/gpt2-large.json serves it
+GPT2_LARGE = dict(
+    vocab_size=50257, hidden_size=1280, num_layers=36, num_heads=20,
+    intermediate_size=5120, max_seq_len=1024, rotary=False,
+    dtype=jnp.bfloat16,
+)
+PAGE, PAGES, SLOTS, PAGES_PER_SEQ = 16, 1201, 32, 64
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill_1024"])
+def test_serving_step_never_moves_the_kv_pool(
+    one_chip, lower_as_chip, program
+):
+    """At the benchmark's shapes the pool is one buffer in one layout
+    from entry to exit: no instruction of the compiled program
+    materializes the pool or a layer of it (`memory-pool-copy`), and
+    XLA's temporaries beyond the bf16 cast of the weight stack stay
+    under one layer's K slice (49 MB; the xs/ys programs held 5.67 GB
+    and 4.30 GB)."""
+    cfg = GptConfig(**GPT2_LARGE)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip
+            ),
+            tree,
+        )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        GptModel(cfg).init, jax.random.PRNGKey(0), jnp.zeros((8, 1), jnp.int32)
+    ))
+    cache = on_chip(jax.eval_shape(lambda: cache_lib.init_kv_pages(
+        cfg.num_layers, PAGES, cfg.num_heads, PAGE,
+        cfg.hidden_size // cfg.num_heads, dtype=cfg.dtype,
+    )))
+    assert cache["k"].shape == (36, PAGES, 10, PAGE, 128)
+    if program == "serve_decode":
+        def fn(params, kv, tokens, lengths, tables, temps, rng):
+            return model_lib.decode_body(
+                cfg, params, kv, tokens, lengths, tables, temps, rng,
+                page_size=PAGE)
+        args = (
+            arg((SLOTS,), jnp.int32), arg((SLOTS,), jnp.int32),
+            arg((SLOTS, PAGES_PER_SEQ), jnp.int32),
+            arg((SLOTS,), jnp.float32), arg((SLOTS, 2), jnp.uint32),
+        )
+    else:
+        def fn(params, kv, tokens, length, page_ids, temp, rng):
+            return model_lib.prefill_body(
+                cfg, params, kv, tokens, length, page_ids, temp, rng,
+                page_size=PAGE)
+        args = (
+            arg((1024, 1), jnp.int32), arg((), jnp.int32),
+            arg((1024 // PAGE,), jnp.int32), arg((), jnp.float32),
+            arg((2,), jnp.uint32),
+        )
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args
+    ).compile()
+
+    text = compiled.as_text()
+    report = analysis.lint_hlo(
+        text, donated=2, rules=("memory", "donation"),
+        expect_pool={"shapes": [x.shape for x in cache.values()]},
+    )
+    assert report.findings == [], report.render()
+    if program == "serve_decode":
+        assert "paged_decode_fwd" in text  # the trace's name for the kernel
+
+    layer_bytes = cache["k"].size * cache["k"].dtype.itemsize // cfg.num_layers
+    stack = params["params"]["layers"]
+    weights_cast = sum(
+        x.size * 2 for x in jax.tree_util.tree_leaves(stack) if x.ndim > 2
+    )
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp - weights_cast < layer_bytes, (temp, weights_cast, layer_bytes)

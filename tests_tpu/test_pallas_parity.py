@@ -530,18 +530,21 @@ def test_flash_bwd_independent_dq_tiles_on_chip():
 
 
 @pytest.mark.parametrize("kv_int8", [False, True])
-def test_paged_decode_attention_on_chip(kv_int8):
+@pytest.mark.parametrize("d", [128, 64])
+def test_paged_decode_attention_on_chip(kv_int8, d):
     """Compiled page-walk kernel (scalar-prefetched page-table index
     maps + fused q-RoPE + optional in-kernel int8 dequant) vs the jnp
     gather reference, on the real chip.  Shapes chosen tile-aligned:
-    page=128 rows x D=128 lanes, H=8 heads."""
+    page=128 rows, H=8 heads, D=128 lanes (one head a lane row) and
+    D=64 (two heads side by side in the row, the serving pool's layout
+    for GPT-2's heads: ``heads_per_row``)."""
     from apex_tpu.ops.paged_attention import (
         paged_decode_attention,
         paged_decode_attention_reference,
     )
     from apex_tpu.serve.cache import encode_kv
 
-    b, h, d, page, pool, np_ = 2, 8, 128, 128, 8, 2
+    b, h, page, pool, np_ = 2, 8, 128, 8, 2
     rs = np.random.RandomState(0)
     k_pages = jnp.asarray(rs.randn(pool, h, page, d), jnp.float32)
     v_pages = jnp.asarray(rs.randn(pool, h, page, d), jnp.float32)
@@ -575,3 +578,47 @@ def test_paged_decode_attention_on_chip(kv_int8):
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5
     )
+
+
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_paged_decode_pool_layout_on_chip(kv_int8):
+    """The serving form at GPT-2 Large's row shape: the whole bf16 pool
+    ``(L, P, H/2, 16, 128)`` read at a layer index through the engine's
+    own helpers (``init_kv_pages`` / ``write_prompt_kv``), Mosaic
+    kernel against the jnp reference on the same pool."""
+    from apex_tpu.ops.paged_attention import (
+        paged_decode_attention,
+        paged_decode_attention_reference,
+    )
+    from apex_tpu.serve import cache as cache_lib
+
+    b, h, d, page, np_, layers = 4, 20, 64, 16, 8, 3
+    rs = np.random.RandomState(1)
+    kv = cache_lib.init_kv_pages(
+        layers, 1 + b * np_, h, page, d, dtype=jnp.bfloat16,
+        kv_wire="int8" if kv_int8 else "f32",
+    )
+    assert kv["k"].shape == (layers, 1 + b * np_, h // 2, page, 2 * d)
+    table = jnp.arange(1, 1 + b * np_, dtype=jnp.int32).reshape(b, np_)
+    for layer in range(layers):
+        for seq in range(b):
+            k = jnp.asarray(rs.randn(np_ * page, h, d), jnp.bfloat16)
+            v = jnp.asarray(rs.randn(np_ * page, h, d), jnp.bfloat16)
+            kv = cache_lib.write_prompt_kv(kv, layer, table[seq], k, v)
+    q = jnp.asarray(rs.randn(b, h, d), jnp.bfloat16)
+    lengths = jnp.asarray([np_ * page, 37, 1, 0], jnp.int32)
+    args = (q, kv["k"], kv["v"], table, lengths)
+    kw = dict(layer=jnp.asarray(1, jnp.int32),
+              k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
+    _dispatch.set_use_pallas(True)
+    try:
+        got = paged_decode_attention(*args, **kw)
+        assert _dispatch.last_paths()["paged_decode_attention"] == "pallas"
+    finally:
+        _dispatch.set_use_pallas(None)
+    want = paged_decode_attention_reference(*args, **kw)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=2e-2, rtol=2e-2,
+    )
+    assert not np.asarray(got[3], np.float32).any()
