@@ -99,7 +99,9 @@ class Fleet:
             else MetricRegistry(fetch_every=1)
         )
         declare_fleet_metrics(self.registry)
-        self._mstate = self.registry.init()
+        # host numbers, like the scheduler's: the router's ledger is
+        # counted by Python code, never inside a compiled step
+        self._mstate = self.registry.host_init()
         self.router = Router(clock=clock, spans=spans, count=self._count)
         self.replica_factory = replica_factory
         self.replicas: List[EngineReplica] = []
@@ -124,12 +126,10 @@ class Fleet:
 
     # -- plumbing ----------------------------------------------------------
     def _count(self, name: str, n: float = 1.0) -> None:
-        self._mstate = self.registry.update(self._mstate, {name: n})
+        self.registry.host_update(self._mstate, {name: n})
 
     def _gauge(self, name: str, value: float) -> None:
-        self._mstate = self.registry.update(
-            self._mstate, {name: float(value)}
-        )
+        self.registry.host_update(self._mstate, {name: value})
 
     def _note(self, event: HealthEvent) -> None:
         self.health_events.append(event)

@@ -19,6 +19,15 @@ the functional-JAX way:
   so a value is at most ``2 * fetch_every`` steps stale and the host
   never blocks on the device between fetches.
 
+**Host-side event counters** — things a Python loop counts between
+two compiled programs (the serving scheduler's tokens, admissions,
+sheds; the fleet router's ledger) — never touch the device:
+:meth:`MetricRegistry.host_init` gives a dict of Python floats,
+:meth:`MetricRegistry.host_update` folds into it in place by the same
+declared kinds, and :meth:`MetricRegistry.observe` takes that dict like
+any other state.  ``update`` outside a jit would launch a transfer and
+a tiny program per value (0.4-0.6 ms each on the chip's host, PERF.md).
+
 Host-side-only values (wall-clock timings, static config) go on the
 module-level :data:`board` — a plain gauge dictionary with no device
 involvement — which ``apex_tpu.parallel.comm`` uses to publish the
@@ -57,6 +66,11 @@ class MetricRegistry:
 
     ``update`` raises ``KeyError`` on an undeclared name — a typo'd
     metric must fail at trace time, not vanish silently.
+
+    ``update`` is for jitted steps.  Events counted by host code are
+    folded in Python — ``state = reg.host_init()``, then
+    ``reg.host_update(state, {"serve/tokens_out": 1})`` — and handed to
+    ``observe`` the same way.
     """
 
     def __init__(self, *, fetch_every: int = 32):
@@ -119,6 +133,15 @@ class MetricRegistry:
     def names(self):
         return tuple(self._kinds)
 
+    def _declared_kind(self, name: str) -> str:
+        kind = self._kinds.get(name)
+        if kind is None:
+            raise KeyError(
+                f"metric {name!r} not declared on this registry "
+                f"(have {sorted(self._kinds)})"
+            )
+        return kind
+
     # -- device side -------------------------------------------------------
     def init(self) -> Dict[str, jax.Array]:
         """Fresh device state: one f32 scalar per declared metric
@@ -143,12 +166,7 @@ class MetricRegistry:
         """
         out = dict(state)
         for name, value in values.items():
-            kind = self._kinds.get(name)
-            if kind is None:
-                raise KeyError(
-                    f"metric {name!r} not declared on this registry "
-                    f"(have {sorted(self._kinds)})"
-                )
+            kind = self._declared_kind(name)
             v = jnp.asarray(value, jnp.float32)
             if kind == "counter":
                 out[name] = out[name] + v
@@ -160,12 +178,40 @@ class MetricRegistry:
                 out[name] = v
         return out
 
+    # -- host-side event counters ------------------------------------------
+    def host_init(self) -> Dict[str, float]:
+        """Fresh HOST state: one Python float per declared metric
+        (``min``/``max`` seed at ±inf), for :meth:`host_update`."""
+        seeds = {"min": float("inf"), "max": float("-inf")}
+        return {
+            name: seeds.get(kind, 0.0) for name, kind in self._kinds.items()
+        }
+
+    def host_update(
+        self, state: Dict[str, float], values: Mapping[str, Any]
+    ) -> None:
+        """Fold ``values`` into a :meth:`host_init` state IN PLACE, by
+        the declared kinds — plain Python arithmetic, no device contact
+        (:meth:`observe` copies the dict it is handed)."""
+        for name, value in values.items():
+            kind = self._declared_kind(name)
+            v = float(value)
+            if kind == "counter":
+                state[name] += v
+            elif kind == "min":
+                state[name] = min(state[name], v)
+            elif kind == "max":
+                state[name] = max(state[name], v)
+            else:
+                state[name] = v
+
     # -- host side ---------------------------------------------------------
     def observe(self, step: int, state: Mapping[str, Any]) -> None:
-        """Stash the step's device state; fetch on the cadence.
+        """Stash the step's state; fetch on the cadence.
 
-        Called once per step with CONCRETE arrays (outside jit).  Cheap
-        on off-cadence steps: one tuple assignment, no device contact.
+        Called once per step with CONCRETE arrays (outside jit) or with
+        a :meth:`host_update` dict of Python floats.  Cheap on
+        off-cadence steps: one dict copy, no device contact.
         """
         self._pending = (int(step), dict(state))
         if step % self.fetch_every == 0:

@@ -52,6 +52,8 @@ owns admission/shedding/SLOs, and both feed the same
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import math
 import time
 from typing import Dict, Optional, Tuple
 
@@ -150,6 +152,55 @@ class ServeConfig:
         return _default_buckets(self.page_size, self.max_context)
 
 
+class _HostArgs:
+    """The host arguments of one step program, as ONE int32 vector.
+
+    A host-to-device transfer costs the serving loop about 0.2 ms on
+    the chip's host however small it is (PERF.md section 5: six small
+    arrays in one ``device_put`` 1.13 ms, the same words as one vector
+    0.25 ms, as one numpy argument of the compiled call 0.07 ms), so a
+    call's tokens, lengths, tables, temperatures and scalars ride one
+    vector that the compiled call takes as its only host argument:
+    :meth:`pack` lays the fields end to end on the host (every field
+    is 32 bits wide; floats and unsigned keep their bits),
+    :meth:`unpack` slices and bitcasts them back at the head of the
+    compiled program.
+    """
+
+    def __init__(self, *fields):
+        #: ``((shape, dtype), ...)`` in argument order
+        self.fields = tuple((tuple(s), np.dtype(d)) for s, d in fields)
+        if any(d.itemsize != 4 for _, d in self.fields):
+            raise ValueError("host argument fields must be 32 bits wide")
+        sizes = [math.prod(s) for s, _ in self.fields]
+        ends = list(itertools.accumulate(sizes))
+        #: each field's ``(start, end)`` in the vector
+        self.spans = tuple((hi - n, hi) for n, hi in zip(sizes, ends))
+        self.size = ends[-1]
+
+    def pack(self, *values) -> np.ndarray:
+        out = np.empty((self.size,), np.int32)
+        for (_, dtype), (lo, hi), value in zip(
+            self.fields, self.spans, values, strict=True
+        ):
+            out[lo:hi] = (
+                np.ascontiguousarray(value, dtype).reshape(-1).view(np.int32)
+            )
+        return out
+
+    def unpack(self, packed):
+        """Traced: the fields back out of the vector, in order."""
+        return tuple(
+            jax.lax.bitcast_convert_type(
+                packed[lo:hi].reshape(shape), dtype
+            )
+            for (shape, dtype), (lo, hi) in zip(self.fields, self.spans)
+        )
+
+    def example(self):
+        return jnp.zeros((self.size,), jnp.int32)
+
+
 class InferenceEngine:
     """AOT prefill/decode over the paged cache for a GPT param tree.
 
@@ -197,6 +248,7 @@ class InferenceEngine:
             dtype=cfg.dtype,
             kv_wire=self.serve.kv_wire,
         )
+        self._layouts: Dict[Tuple[str, int], _HostArgs] = {}
         self._prefill: Dict[int, object] = {}
         self._chunk: Dict[int, object] = {}
         self._decode = None
@@ -255,12 +307,17 @@ class InferenceEngine:
                 dtype=dcfg.dtype,
                 kv_wire=self.serve.kv_wire,
             )
-        # the fused sampler's key chain: one fold per engine call
-        self._rng_base = jax.random.PRNGKey(self.serve.sample_seed)
+        # the fused sampler's base key: on the device once, an argument
+        # of every step program, which folds the call's integers (call
+        # index; stream seed and emission index) into it IN-PROGRAM
+        self._rng_base = jax.device_put(
+            jax.random.PRNGKey(self.serve.sample_seed)
+        )
         #: optional :class:`~apex_tpu.observability.spans.SpanRecorder`
         #: (the scheduler attaches its recorder here automatically).
         #: Every prefill/decode call records an ``engine/stage`` phase
-        #: (numpy → device arguments, rng folds, retrace sentinel) and
+        #: (the call's host arguments packed into one numpy vector,
+        #: retrace sentinel) and
         #: an ``engine/prefill`` / ``engine/decode`` phase (compiled
         #: call → first host read) — here, or with none attached in
         #: the process ring (:func:`~apex_tpu.observability.spans.
@@ -302,132 +359,170 @@ class InferenceEngine:
             board.set("serve/spec_k", self.spec.k)
             board.set("serve/spec_mode", self.spec.mode)
 
-    def _prefill_fn(self, bucket: int):
-        s = self.serve
-        np_ = bucket // s.page_size
+    def _host_args(self, kind: str, bucket: int = 0) -> _HostArgs:
+        """The :class:`_HostArgs` layout of a program kind — shared by
+        the builder (``unpack`` at the program's head) and the serving
+        call (``pack``), so the two cannot drift."""
+        key = (kind, bucket)
+        if key not in self._layouts:
+            s = self.serve
+            i32, u32, f32 = np.int32, np.uint32, np.float32
+            slots = ((s.max_batch,), i32)
+            tables = ((s.max_batch, s.max_pages_per_seq), i32)
+            prompt = (
+                ((bucket, 1), i32),                  # tokens
+                ((bucket // s.page_size,), i32),     # page ids
+            )
+            self._layouts[key] = _HostArgs(*{
+                # tokens, page ids, length, call index, temperature
+                "prefill": prompt + (((), i32), ((), i32), ((), f32)),
+                # tokens, chunk page ids, page table row, length,
+                # offset, call index, temperature
+                "chunk_prefill": prompt + (
+                    ((s.max_pages_per_seq,), i32),
+                    ((), i32), ((), i32), ((), i32), ((), f32),
+                ),
+                # tokens, lengths, page tables, temperatures, and the
+                # two integers each slot's sampling key is folded from
+                "decode": (
+                    slots, slots, tables, ((s.max_batch,), f32),
+                    ((s.max_batch,), u32), slots,
+                ),
+                # ... for the verify program the host's verdict on the
+                # round's draft (0 under a serve.draft fault) rides along
+                "verify": (
+                    slots, slots, tables, ((s.max_batch,), f32), ((), i32),
+                    ((s.max_batch,), u32), slots,
+                ),
+                # starts, counts, page tables
+                "rollback": (slots, slots, tables),
+                # source page, destination page
+                "fork_page": (((), i32), ((), i32)),
+            }[kind])
+        return self._layouts[key]
 
-        def fn(params, kv_pages, tokens, length, page_ids, temp, rng):
+    def _prefill_program(self, cfg: GptConfig, bucket: int):
+        """The target's and the draft's prefill: one body, two model
+        configs."""
+        s = self.serve
+        host = self._host_args("prefill", bucket)
+
+        def fn(params, kv_pages, base_key, packed):
+            tokens, page_ids, length, call, temp = host.unpack(packed)
+            # the call's key is folded HERE from its index: the host
+            # folds nothing
             return model_lib.prefill_body(
-                self.cfg, params, kv_pages, tokens, length, page_ids,
-                temp, rng,
+                cfg, params, kv_pages, tokens, length, page_ids, temp,
+                model_lib.fold_in(base_key, call),
                 page_size=s.page_size,
                 top_k=s.top_k,
             )
 
+        return fn, host.example()
+
+    def _prefill_fn(self, bucket: int):
+        fn, packed = self._prefill_program(self.cfg, bucket)
         fn.__name__ = f"serve_prefill_{bucket}"
-        args = (
-            self.params,
-            self.cache,
-            jnp.zeros((bucket, 1), jnp.int32),
-            jnp.asarray(1, jnp.int32),
-            jnp.zeros((np_,), jnp.int32),
-            jnp.zeros((), jnp.float32),
-            self._rng_base,
-        )
-        return fn, args
+        return fn, (self.params, self.cache, self._rng_base, packed)
 
     def _chunk_fn(self, bucket: int):
         s = self.serve
-        np_ = bucket // s.page_size
+        host = self._host_args("chunk_prefill", bucket)
 
-        def fn(params, kv_pages, tokens, length, offset, chunk_page_ids,
-               page_table, temp, rng):
+        def fn(params, kv_pages, base_key, packed):
+            (tokens, chunk_page_ids, page_table, length, offset, call,
+             temp) = host.unpack(packed)
             return model_lib.chunk_prefill_body(
                 self.cfg, params, kv_pages, tokens, length, offset,
-                chunk_page_ids, page_table, temp, rng,
+                chunk_page_ids, page_table, temp,
+                model_lib.fold_in(base_key, call),
                 page_size=s.page_size,
                 top_k=s.top_k,
             )
 
         fn.__name__ = f"serve_chunk_prefill_{bucket}"
-        args = (
-            self.params,
-            self.cache,
-            jnp.zeros((bucket, 1), jnp.int32),
-            jnp.asarray(1, jnp.int32),
-            jnp.asarray(0, jnp.int32),
-            jnp.zeros((np_,), jnp.int32),
-            jnp.zeros((s.max_pages_per_seq,), jnp.int32),
-            jnp.zeros((), jnp.float32),
-            self._rng_base,
-        )
+        args = (self.params, self.cache, self._rng_base, host.example())
         return fn, args
 
     def _decode_fn(self):
         s = self.serve
+        host = self._host_args("decode")
 
-        def fn(params, kv_pages, tokens, lengths, page_tables, temps, rng):
+        def fn(params, kv_pages, base_key, packed):
+            tokens, lengths, page_tables, temps, streams, gens = (
+                host.unpack(packed)
+            )
+            # per-slot keys fold_in(fold_in(base, streams[b]), gens[b]),
+            # folded HERE from the two integer vectors the host packs
             return model_lib.decode_body(
                 self.cfg, params, kv_pages, tokens, lengths, page_tables,
-                temps, rng,
+                temps, spec_lib.slot_keys(base_key, streams, gens),
                 page_size=s.page_size, top_k=s.top_k,
             )
 
         fn.__name__ = "serve_decode"
-        args = (
-            self.params,
-            self.cache,
-            jnp.zeros((s.max_batch,), jnp.int32),
-            jnp.zeros((s.max_batch,), jnp.int32),
-            jnp.zeros((s.max_batch, s.max_pages_per_seq), jnp.int32),
-            jnp.zeros((s.max_batch,), jnp.float32),
-            jnp.zeros((s.max_batch, 2), jnp.uint32),
-        )
+        args = (self.params, self.cache, self._rng_base, host.example())
         return fn, args
 
     def _fork_fn(self):
-        def fn(kv_pages, src, dst):
+        host = self._host_args("fork_page")
+
+        def fn(kv_pages, packed):
             # copy-on-write fork: duplicate one page's rows (codes AND
             # scale planes under the int8 wire) across every layer
+            src, dst = host.unpack(packed)
             return {
                 name: arr.at[:, dst].set(arr[:, src])
                 for name, arr in kv_pages.items()
             }
 
         fn.__name__ = "serve_fork_page"
-        args = (
-            self.cache,
-            jnp.asarray(0, jnp.int32),
-            jnp.asarray(0, jnp.int32),
-        )
-        return fn, args
+        return fn, (self.cache, host.example())
 
     def _draft_fn(self):
         s = self.serve
         k = self.spec.k
         dcfg = self._draft_cfg
+        host = self._host_args("decode")
 
-        def fn(params, kv_pages, tokens, lengths, page_tables, temps,
-               stream_keys, gens):
+        def fn(params, kv_pages, base_key, packed):
+            tokens, lengths, page_tables, temps, streams, gens = (
+                host.unpack(packed)
+            )
             return spec_lib.draft_body(
                 dcfg, params, kv_pages, tokens, lengths, page_tables,
-                temps, stream_keys, gens,
+                temps, spec_lib.stream_keys(base_key, streams), gens,
                 k=k, page_size=s.page_size, top_k=s.top_k,
             )
 
         fn.__name__ = "serve_draft_decode"
         args = (
-            self.draft_params,
-            self.draft_cache,
-            jnp.zeros((s.max_batch,), jnp.int32),
-            jnp.zeros((s.max_batch,), jnp.int32),
-            jnp.zeros((s.max_batch, s.max_pages_per_seq), jnp.int32),
-            jnp.zeros((s.max_batch,), jnp.float32),
-            jnp.zeros((s.max_batch, 2), jnp.uint32),
-            jnp.zeros((s.max_batch,), jnp.int32),
+            self.draft_params, self.draft_cache, self._rng_base,
+            host.example(),
         )
         return fn, args
 
     def _verify_fn(self):
         s = self.serve
         k = self.spec.k
+        host = self._host_args("verify")
 
-        def fn(params, kv_pages, tokens, draft_tokens, lengths,
-               page_tables, temps, draft_probs, stream_keys, gens):
+        def fn(params, kv_pages, base_key, draft_tokens, draft_probs,
+               draft_finite, packed):
+            (tokens, lengths, page_tables, temps, draft_ok, streams,
+             gens) = host.unpack(packed)
+            if k:
+                # draft_finite is the draft program's own screen (still
+                # on the device), draft_ok the host's verdict on the
+                # whole round (0 under a serve.draft fault)
+                draft_tokens, draft_probs = spec_lib.pin_failed_drafts(
+                    draft_tokens, draft_probs,
+                    draft_finite & (draft_ok != 0), self.cfg.vocab_size,
+                )
             return spec_lib.verify_body(
                 self.cfg, params, kv_pages, tokens, draft_tokens,
-                lengths, page_tables, temps, draft_probs, stream_keys,
-                gens,
+                lengths, page_tables, temps, draft_probs,
+                spec_lib.stream_keys(base_key, streams), gens,
                 page_size=s.page_size, top_k=s.top_k,
             )
 
@@ -435,14 +530,11 @@ class InferenceEngine:
         args = (
             self.params,
             self.cache,
-            jnp.zeros((s.max_batch,), jnp.int32),
+            self._rng_base,
             jnp.zeros((s.max_batch, k), jnp.int32),
-            jnp.zeros((s.max_batch,), jnp.int32),
-            jnp.zeros((s.max_batch, s.max_pages_per_seq), jnp.int32),
-            jnp.zeros((s.max_batch,), jnp.float32),
             jnp.zeros((k, s.max_batch, self.cfg.vocab_size), jnp.float32),
-            jnp.zeros((s.max_batch, 2), jnp.uint32),
-            jnp.zeros((s.max_batch,), jnp.int32),
+            jnp.ones((s.max_batch,), jnp.bool_),
+            host.example(),
         )
         return fn, args
 
@@ -451,45 +543,22 @@ class InferenceEngine:
         # the stale span after a round is [new ctx, old ctx + k]: at
         # most k + 1 rows when nothing was accepted
         kmax = self.spec.k + 1
+        host = self._host_args("rollback")
 
-        def fn(kv_pages, starts, counts, page_tables):
+        def fn(kv_pages, packed):
+            starts, counts, page_tables = host.unpack(packed)
             return spec_lib.rollback_body(
                 kv_pages, starts, counts, page_tables,
                 k=kmax, page_size=s.page_size,
             )
 
         fn.__name__ = name
-        args = (
-            cache,
-            jnp.zeros((s.max_batch,), jnp.int32),
-            jnp.zeros((s.max_batch,), jnp.int32),
-            jnp.zeros((s.max_batch, s.max_pages_per_seq), jnp.int32),
-        )
-        return fn, args
+        return fn, (cache, host.example())
 
     def _draft_prefill_fn(self, bucket: int):
-        s = self.serve
-        np_ = bucket // s.page_size
-        dcfg = self._draft_cfg
-
-        def fn(params, kv_pages, tokens, length, page_ids, temp, rng):
-            return model_lib.prefill_body(
-                dcfg, params, kv_pages, tokens, length, page_ids,
-                temp, rng,
-                page_size=s.page_size,
-                top_k=s.top_k,
-            )
-
+        fn, packed = self._prefill_program(self._draft_cfg, bucket)
         fn.__name__ = f"serve_draft_prefill_{bucket}"
-        args = (
-            self.draft_params,
-            self.draft_cache,
-            jnp.zeros((bucket, 1), jnp.int32),
-            jnp.asarray(1, jnp.int32),
-            jnp.zeros((np_,), jnp.int32),
-            jnp.zeros((), jnp.float32),
-            self._rng_base,
-        )
+        args = (self.draft_params, self.draft_cache, self._rng_base, packed)
         return fn, args
 
     def _pool_intent(self, cache) -> dict:
@@ -762,18 +831,10 @@ class InferenceEngine:
             name, track=TRACK_ENGINE, **args
         )
 
-    def _sample_key(self, idx: int):
-        """Deterministic per-call PRNG key for the fused sampler."""
-        return jax.random.fold_in(self._rng_base, idx)
-
-    def _stream_keys(self, streams):
-        """Per-slot stream keys: ``fold_in(engine base, stream seed)``
-        — a function of request IDENTITY, never of call counters, so a
-        speculative rollback replays the same draws and a ``k = 0``
-        spec stream equals the plain one (spec.py "RNG discipline")."""
-        return jax.vmap(jax.random.fold_in, (None, 0))(
-            self._rng_base, jnp.asarray(streams, jnp.uint32)
-        )
+    def _temps(self, temps):
+        if temps is None:
+            return np.zeros((self.serve.max_batch,), np.float32)
+        return np.asarray(temps, np.float32)
 
     def prefill(self, prompt_ids, page_ids, *,
                 temperature: float = 0.0) -> Tuple[np.ndarray, int]:
@@ -795,10 +856,10 @@ class InferenceEngine:
             ids[: len(page_ids)] = np.asarray(page_ids, np.int32)
             compiled = self._get_prefill(bucket)
             args = (
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(n, jnp.int32), jnp.asarray(ids),
-                jnp.asarray(temperature, jnp.float32),
-                self._sample_key(self.prefill_calls),
+                self.params, self.cache, self._rng_base,
+                self._host_args("prefill", bucket).pack(
+                    tokens, ids, n, self.prefill_calls, temperature
+                ),
             )
             self._sentinels[name].observe(*args)
         self.prefill_calls += 1
@@ -850,11 +911,11 @@ class InferenceEngine:
             )
             compiled = self._get_chunk(bucket)
             args = (
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(n, jnp.int32), jnp.asarray(offset, jnp.int32),
-                jnp.asarray(ids), jnp.asarray(table),
-                jnp.asarray(temperature, jnp.float32),
-                self._sample_key(self.prefill_calls),
+                self.params, self.cache, self._rng_base,
+                self._host_args("chunk_prefill", bucket).pack(
+                    tokens, ids, table, n, offset, self.prefill_calls,
+                    temperature,
+                ),
             )
             self._sentinels[name].observe(*args)
         self.prefill_calls += 1
@@ -872,11 +933,7 @@ class InferenceEngine:
         int8 KV wire) through one tiny compiled donated program — the
         device half of the scheduler's shared-tail-page fork."""
         compiled = self._get_fork()
-        args = (
-            self.cache,
-            jnp.asarray(src, jnp.int32),
-            jnp.asarray(dst, jnp.int32),
-        )
+        args = (self.cache, self._host_args("fork_page").pack(src, dst))
         self._sentinels["fork_page"].observe(*args)
         self.cache = compiled(*args)
 
@@ -896,29 +953,22 @@ class InferenceEngine:
         ``fold_in(stream_key, gen)`` — the same key a ``k = 0``
         speculative round would consume, which is what makes the two
         paths bit-identical.  None keeps the legacy per-iteration key
-        chain (one fold per call, split per slot)."""
+        chain, ``fold_in(fold_in(base, iteration), slot)``: the same
+        two folds of the same program, fed ``(iteration, slot index)``
+        in place of ``(stream seed, emission index)``."""
         poison = self._chaos_gate(chaos.SERVE_DECODE, self.decode_iters)
         with self._phase("engine/stage", program="decode"):
             compiled = self._get_decode()
             if streams is None:
-                rng = jax.vmap(jax.random.fold_in, (None, 0))(
-                    self._sample_key(self.decode_iters),
-                    jnp.arange(self.serve.max_batch, dtype=jnp.uint32),
-                )
-            else:
-                rng = spec_lib._fold_each(
-                    self._stream_keys(streams),
-                    jnp.asarray(gens, jnp.int32),
-                )
+                b = self.serve.max_batch
+                streams = np.full((b,), self.decode_iters, np.uint32)
+                gens = np.arange(b, dtype=np.int32)
             args = (
-                self.params,
-                self.cache,
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(lengths, jnp.int32),
-                jnp.asarray(page_tables, jnp.int32),
-                jnp.zeros((self.serve.max_batch,), jnp.float32)
-                if temps is None else jnp.asarray(temps, jnp.float32),
-                rng,
+                self.params, self.cache, self._rng_base,
+                self._host_args("decode").pack(
+                    tokens, lengths, page_tables, self._temps(temps),
+                    streams, gens,
+                ),
             )
             self._sentinels["decode"].observe(*args)
         self.decode_iters += 1
@@ -1059,10 +1109,10 @@ class InferenceEngine:
         compiled = self._get_draft_prefill(bucket)
         name = f"draft_prefill_{bucket}"
         args = (
-            self.draft_params, self.draft_cache, jnp.asarray(tokens),
-            jnp.asarray(n, jnp.int32), jnp.asarray(ids),
-            jnp.zeros((), jnp.float32),
-            jax.random.fold_in(self._rng_base, self.draft_prefill_calls),
+            self.draft_params, self.draft_cache, self._rng_base,
+            self._host_args("prefill", bucket).pack(
+                tokens, ids, n, self.draft_prefill_calls, 0.0
+            ),
         )
         self._sentinels[name].observe(*args)
         self.draft_prefill_calls += 1
@@ -1082,7 +1132,6 @@ class InferenceEngine:
         depends on the draft) and the ``serve.decode`` site for the
         verify step exactly like :meth:`decode`."""
         spec = self.spec
-        s = self.serve
         round_idx = self.spec_rounds
         # the round cursor advances on ATTEMPTS, and before the chaos
         # gate: a raise-mode serve.draft fault must burn its round
@@ -1094,50 +1143,30 @@ class InferenceEngine:
         with self._phase("engine/stage", program="spec"):
             # the draft program's dispatch is part of staging the
             # verify call: its device time is waited for under
-            # engine/decode, at the first host read
-            tok = jnp.asarray(tokens, jnp.int32)
-            lens = jnp.asarray(lengths, jnp.int32)
-            temps_j = (jnp.zeros((s.max_batch,), jnp.float32)
-                       if temps is None
-                       else jnp.asarray(temps, jnp.float32))
-            keys = self._stream_keys(streams)
-            gens_j = jnp.asarray(gens, jnp.int32)
+            # engine/decode, at the first host read.  The draft's
+            # proposals, distributions and finite screen stay on the
+            # device, and the verify program itself pins the proposals
+            # of a failed draft (spec.pin_failed_drafts) — draft_ok is
+            # the host's half of that verdict (a serve.draft fault)
+            temps = self._temps(temps)
             d_args = (
-                self.draft_params, self.draft_cache, tok, lens,
-                jnp.asarray(draft_tables, jnp.int32), temps_j, keys,
-                gens_j,
+                self.draft_params, self.draft_cache, self._rng_base,
+                self._host_args("decode").pack(
+                    tokens, lengths, draft_tables, temps, streams, gens
+                ),
             )
             compiled = self._get_draft()
             self._sentinels["draft_decode"].observe(*d_args)
             d_tokens, d_probs, d_finite, self.draft_cache = compiled(
                 *d_args
             )
-            bad = jnp.logical_not(d_finite)
-            if fault is not None:
-                bad = jnp.ones_like(bad)
-            if spec.k:
-                # a faulted/non-finite draft must not smuggle a token
-                # into the stream: pin its proposals to one fixed id
-                # and claim the matching point-mass draft distribution
-                # — the rejection sampler preserves the target
-                # distribution for ANY claimed q consistent with how d
-                # was drawn, and greedy only ever emits the argmax
-                # chain, so a poisoned round degrades to ~zero
-                # acceptance instead of corruption
-                pin = jnp.full_like(d_tokens, self.cfg.vocab_size - 1)
-                d_tokens = jnp.where(bad[:, None], pin, d_tokens)
-                d_probs = jnp.where(
-                    bad[None, :, None],
-                    jax.nn.one_hot(
-                        jnp.transpose(pin), self.cfg.vocab_size,
-                        dtype=jnp.float32,
-                    ),
-                    d_probs,
-                )
             v_args = (
-                self.params, self.cache, tok, d_tokens, lens,
-                jnp.asarray(page_tables, jnp.int32), temps_j, d_probs,
-                keys, gens_j,
+                self.params, self.cache, self._rng_base, d_tokens,
+                d_probs, d_finite,
+                self._host_args("verify").pack(
+                    tokens, lengths, page_tables, temps,
+                    fault is None, streams, gens,
+                ),
             )
             compiled = self._get_verify()
             self._sentinels["verify"].observe(*v_args)
@@ -1168,9 +1197,7 @@ class InferenceEngine:
         compiled = self._get_rollback()
         args = (
             self.cache,
-            jnp.asarray(starts, jnp.int32),
-            jnp.asarray(counts, jnp.int32),
-            jnp.asarray(page_tables, jnp.int32),
+            self._host_args("rollback").pack(starts, counts, page_tables),
         )
         self._sentinels["rollback"].observe(*args)
         self.cache = compiled(*args)
@@ -1180,9 +1207,7 @@ class InferenceEngine:
         compiled = self._get_draft_rollback()
         args = (
             self.draft_cache,
-            jnp.asarray(starts, jnp.int32),
-            jnp.asarray(counts, jnp.int32),
-            jnp.asarray(page_tables, jnp.int32),
+            self._host_args("rollback").pack(starts, counts, page_tables),
         )
         self._sentinels["draft_rollback"].observe(*args)
         self.draft_cache = compiled(*args)

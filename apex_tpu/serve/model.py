@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from apex_tpu.models.gpt import GptConfig, _rope_cos_sin
 from apex_tpu.ops.attention import flash_attention
@@ -222,6 +223,49 @@ def _is_key_batch(rng, logits) -> bool:
     if jnp.issubdtype(rng.dtype, jax.dtypes.prng_key):
         return rng.ndim == 1 and rng.shape[0] == logits.shape[0]
     return rng.ndim == 2 and rng.shape[0] == logits.shape[0]
+
+
+#: Threefry-2x32 (Salmon et al., "Parallel random numbers: as easy as
+#: 1, 2, 3"): rotation schedule and key-schedule parity constant
+_THREEFRY_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
+_THREEFRY_PARITY = 0x1BD11BDA
+
+
+def fold_in(keys, data):
+    """``jax.random.fold_in`` for raw threefry keys, inside a compiled
+    step: ``keys`` ``(..., 2)`` uint32, ``data`` ``(...)`` any 32-bit
+    integer, bit-for-bit the key ``jax.random.fold_in(keys[i],
+    data[i])`` gives (``tests/test_serve_hostloop.py``).
+
+    Written out in ``lax`` primitives because every step program folds
+    its own sampling keys (the host folds none: docs/serving.md "Host
+    phases") and ``jax.random.fold_in`` is expensive to LOWER: XLA:TPU
+    gets the hash unrolled, and the rule that unrolls it re-traces
+    ~250 jit-wrapped ``jnp`` operations for every module it appears in
+    — 0.55 s of set-up a program on the chip's host, against 0.2 s for
+    the rest of a 36-layer program (PERF.md section 6).  These ~130
+    primitive binds lower in milliseconds."""
+    lax = jax.lax
+    data = lax.convert_element_type(data, jnp.uint32)
+    keys = jnp.broadcast_to(keys, data.shape + (2,))
+    k0, k1 = keys[..., 0], keys[..., 1]
+    ks = (k0, k1, lax.bitwise_xor(lax.bitwise_xor(k0, k1),
+                                  np.uint32(_THREEFRY_PARITY)))
+    # fold_in hashes the count words (0, data) under the key
+    x0, x1 = k0, lax.add(data, k1)
+    for group in range(5):
+        for rot in _THREEFRY_ROTATIONS[group % 2]:
+            x0 = lax.add(x0, x1)
+            x1 = lax.bitwise_or(
+                lax.shift_left(x1, np.uint32(rot)),
+                lax.shift_right_logical(x1, np.uint32(32 - rot)),
+            )
+            x1 = lax.bitwise_xor(x0, x1)
+        x0 = lax.add(x0, ks[(group + 1) % 3])
+        x1 = lax.add(
+            lax.add(x1, ks[(group + 2) % 3]), np.uint32(group + 1)
+        )
+    return jnp.stack([x0, x1], axis=-1)
 
 
 def sample_tokens(logits, temps, rng, *, top_k: int = 0):

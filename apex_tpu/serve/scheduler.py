@@ -545,10 +545,14 @@ class ContinuousBatchingScheduler:
             help="TTFT distribution over admitted requests",
         )
         self._published_done = 0
+        # the metric state is host numbers (Python floats folded by
+        # MetricRegistry.host_update): these are events this loop
+        # counts between two compiled programs, and a device-side
+        # counter would launch a transfer and a program per event
         self._mstate = None
         if self.registry is not None:
             declare_serve_metrics(self.registry)
-            self._mstate = self.registry.init()
+            self._mstate = self.registry.host_init()
 
     # -- bookkeeping ------------------------------------------------------
     @property
@@ -1507,13 +1511,11 @@ class ContinuousBatchingScheduler:
 
     def _count(self, name: str, n: float = 1.0) -> None:
         if self._mstate is not None:
-            self._mstate = self.registry.update(self._mstate, {name: n})
+            self.registry.host_update(self._mstate, {name: n})
 
     def _gauge(self, name: str, value) -> None:
         if self._mstate is not None and value is not None:
-            self._mstate = self.registry.update(
-                self._mstate, {name: float(value)}
-            )
+            self.registry.host_update(self._mstate, {name: value})
 
     def _publish_attribution(self) -> None:
         """Percentile gauges over the recent completion window — one
@@ -1534,7 +1536,7 @@ class ContinuousBatchingScheduler:
         updates["serve/ttft_queue_wait_fraction"] = attr[
             "queue_wait_fraction"
         ]
-        self._mstate = self.registry.update(self._mstate, updates)
+        self.registry.host_update(self._mstate, updates)
 
     def _publish(self) -> None:
         with self._phase("serve/publish"):

@@ -76,6 +76,9 @@ __all__ = [
     "ACCEPT_TAG",
     "target_probs",
     "speculative_verify",
+    "stream_keys",
+    "slot_keys",
+    "pin_failed_drafts",
     "draft_body",
     "verify_body",
     "rollback_body",
@@ -172,12 +175,47 @@ def target_probs(logits, temps, *, top_k: int = 0):
 
 
 def _fold_each(keys, data):
-    """Per-slot ``fold_in`` over a ``(B, 2)`` key batch."""
-    return jax.vmap(jax.random.fold_in)(
-        keys, jnp.broadcast_to(jnp.asarray(data, jnp.uint32),
-                               (keys.shape[0],))
-        if jnp.ndim(data) == 0 else jnp.asarray(data, jnp.uint32)
+    """Per-slot ``fold_in`` over a ``(B, 2)`` key batch (``data`` a
+    scalar tag or a ``(B,)`` vector)."""
+    data = jnp.broadcast_to(jnp.asarray(data), (keys.shape[0],))
+    return model_lib.fold_in(keys, data)
+
+
+def stream_keys(base_key, streams):
+    """Per-slot stream keys ``fold_in(engine base key, stream seed)`` —
+    a function of request IDENTITY, never of call counters, so a
+    speculative rollback replays the same draws and a ``k = 0`` spec
+    stream equals the plain one ("RNG discipline" above).  Traced
+    INSIDE the step programs: the host hands over the integer seeds
+    ``(B,)`` and folds nothing."""
+    return model_lib.fold_in(base_key, streams)
+
+
+def slot_keys(base_key, streams, gens):
+    """The plain decode program's per-slot sampling keys:
+    ``fold_in(fold_in(base, streams[b]), gens[b])`` — the RAW emission
+    key a ``k = 0`` speculative round consumes."""
+    return _fold_each(stream_keys(base_key, streams), gens)
+
+
+def pin_failed_drafts(draft_tokens, draft_probs, draft_ok, vocab: int):
+    """A faulted / non-finite draft must not smuggle a token into the
+    stream: pin the proposals of slots with ``draft_ok`` False to one
+    fixed id and claim the matching point-mass draft distribution — the
+    rejection sampler preserves the target distribution for ANY
+    claimed q consistent with how d was drawn, and greedy only ever
+    emits the argmax chain, so a poisoned round degrades to ~zero
+    acceptance instead of corruption.  Healthy slots pass through
+    bit-for-bit.  Runs at the head of the verify program."""
+    bad = jnp.logical_not(draft_ok)
+    pin = jnp.full_like(draft_tokens, vocab - 1)
+    tokens = jnp.where(bad[:, None], pin, draft_tokens)
+    probs = jnp.where(
+        bad[None, :, None],
+        jax.nn.one_hot(jnp.transpose(pin), vocab, dtype=jnp.float32),
+        draft_probs,
     )
+    return tokens, probs
 
 
 def _residual_sample(p, q, keys):
