@@ -52,10 +52,11 @@ owns admission/shedding/SLOs, and both feed the same
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import math
 import time
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -201,6 +202,234 @@ class _HostArgs:
         return jnp.zeros((self.size,), jnp.int32)
 
 
+# ---------------------------------------------------------------------------
+# the program table: what each kind of step program IS, written once
+# ---------------------------------------------------------------------------
+
+
+_INT, _FLOAT = ((), np.int32), ((), np.float32)
+
+
+def _per_slot(s: ServeConfig, dtype=np.int32):
+    return ((s.max_batch,), dtype)
+
+
+def _tables(s: ServeConfig):
+    return ((s.max_batch, s.max_pages_per_seq), np.int32)
+
+
+def _prompt(s: ServeConfig, bucket: int):
+    # tokens, page ids
+    return (((bucket, 1), np.int32), ((bucket // s.page_size,), np.int32))
+
+
+def _prefill_fields(s, bucket):
+    # tokens, page ids, length, call index, temperature
+    return _prompt(s, bucket) + (_INT, _INT, _FLOAT)
+
+
+def _chunk_fields(s, bucket):
+    # tokens, chunk page ids, page table row, length, offset, call
+    # index, temperature
+    row = ((s.max_pages_per_seq,), np.int32)
+    return _prompt(s, bucket) + (row, _INT, _INT, _INT, _FLOAT)
+
+
+def _decode_fields(s, bucket):
+    # tokens, lengths, page tables, temperatures, and the two integers
+    # each slot's sampling key is folded from
+    return (
+        _per_slot(s), _per_slot(s), _tables(s), _per_slot(s, np.float32),
+        _per_slot(s, np.uint32), _per_slot(s),
+    )
+
+
+def _verify_fields(s, bucket):
+    # decode's, with the host's verdict on the round's draft (0 under a
+    # serve.draft fault) before the key integers
+    decode = _decode_fields(s, bucket)
+    return decode[:4] + (_INT,) + decode[4:]
+
+
+def _rollback_fields(s, bucket):
+    # starts, counts, page tables
+    return (_per_slot(s), _per_slot(s), _tables(s))
+
+
+def _fork_fields(s, bucket):
+    # source page, destination page
+    return (_INT, _INT)
+
+
+# The traced bodies, ``body(engine, host, *device_args, packed)``:
+# ``host`` is the kind's layout, ``packed`` the call's one host argument.
+
+
+def _prefill_step(eng, host, params, kv_pages, base_key, packed, *,
+                  draft: bool = False):
+    """The target's and the draft's prefill: one body, two model
+    configs.  The call's key is folded HERE from its index: the host
+    folds nothing."""
+    tokens, page_ids, length, call, temp = host.unpack(packed)
+    return model_lib.prefill_body(
+        eng._draft_cfg if draft else eng.cfg, params, kv_pages, tokens,
+        length, page_ids, temp, model_lib.fold_in(base_key, call),
+        page_size=eng.serve.page_size, top_k=eng.serve.top_k,
+    )
+
+
+def _chunk_step(eng, host, params, kv_pages, base_key, packed):
+    (tokens, chunk_page_ids, page_table, length, offset, call,
+     temp) = host.unpack(packed)
+    return model_lib.chunk_prefill_body(
+        eng.cfg, params, kv_pages, tokens, length, offset,
+        chunk_page_ids, page_table, temp,
+        model_lib.fold_in(base_key, call),
+        page_size=eng.serve.page_size, top_k=eng.serve.top_k,
+    )
+
+
+def _decode_step(eng, host, params, kv_pages, base_key, packed):
+    tokens, lengths, page_tables, temps, streams, gens = (
+        host.unpack(packed)
+    )
+    # per-slot keys fold_in(fold_in(base, streams[b]), gens[b]), folded
+    # HERE from the two integer vectors the host packs
+    return model_lib.decode_body(
+        eng.cfg, params, kv_pages, tokens, lengths, page_tables,
+        temps, model_lib.slot_keys(base_key, streams, gens),
+        page_size=eng.serve.page_size, top_k=eng.serve.top_k,
+    )
+
+
+def _fork_step(eng, host, kv_pages, packed):
+    # copy-on-write fork: duplicate one page's rows (codes AND scale
+    # planes under the int8 wire) across every layer
+    src, dst = host.unpack(packed)
+    return {
+        name: arr.at[:, dst].set(arr[:, src])
+        for name, arr in kv_pages.items()
+    }
+
+
+def _draft_step(eng, host, params, kv_pages, base_key, packed):
+    tokens, lengths, page_tables, temps, streams, gens = (
+        host.unpack(packed)
+    )
+    return spec_lib.draft_body(
+        eng._draft_cfg, params, kv_pages, tokens, lengths, page_tables,
+        temps, model_lib.stream_keys(base_key, streams), gens,
+        k=eng.spec.k, page_size=eng.serve.page_size,
+        top_k=eng.serve.top_k,
+    )
+
+
+def _verify_step(eng, host, params, kv_pages, base_key, draft_tokens,
+                 draft_probs, draft_finite, packed):
+    (tokens, lengths, page_tables, temps, draft_ok, streams,
+     gens) = host.unpack(packed)
+    if eng.spec.k:
+        # draft_finite is the draft program's own screen (still on the
+        # device), draft_ok the host's verdict on the whole round (0
+        # under a serve.draft fault)
+        draft_tokens, draft_probs = spec_lib.pin_failed_drafts(
+            draft_tokens, draft_probs,
+            draft_finite & (draft_ok != 0), eng.cfg.vocab_size,
+        )
+    return spec_lib.verify_body(
+        eng.cfg, params, kv_pages, tokens, draft_tokens,
+        lengths, page_tables, temps, draft_probs,
+        model_lib.stream_keys(base_key, streams), gens,
+        page_size=eng.serve.page_size, top_k=eng.serve.top_k,
+    )
+
+
+def _rollback_step(eng, host, kv_pages, packed):
+    starts, counts, page_tables = host.unpack(packed)
+    # the stale span after a round is [new ctx, old ctx + k]: at most
+    # k + 1 rows when nothing was accepted
+    return spec_lib.rollback_body(
+        kv_pages, starts, counts, page_tables,
+        k=eng.spec.k + 1, page_size=eng.serve.page_size,
+    )
+
+
+def _target_args(eng):
+    return (eng.params, eng.cache, eng._rng_base)
+
+
+def _draft_args(eng):
+    return (eng.draft_params, eng.draft_cache, eng._rng_base)
+
+
+def _verify_args(eng):
+    s, k = eng.serve, eng.spec.k
+    return _target_args(eng) + (
+        jnp.zeros((s.max_batch, k), jnp.int32),
+        jnp.zeros((k, s.max_batch, eng.cfg.vocab_size), jnp.float32),
+        jnp.ones((s.max_batch,), jnp.bool_),
+    )
+
+
+@dataclasses.dataclass(frozen=True)
+class _Program:
+    """One KIND of step program.  ``kind`` is its name on the board, in
+    :attr:`InferenceEngine.compile_counts` and on its retrace sentinel
+    (a per-bucket kind's programs are ``<kind>_<bucket>``); the jitted
+    function is ``serve_<that name>`` — the module name a device trace
+    shows (``jit_serve_decode``)."""
+
+    kind: str
+    #: ``(serve, bucket) -> _HostArgs fields``: the host arguments
+    fields: Callable
+    #: the traced ``body(engine, host, *device_args, packed)``
+    body: Callable
+    #: ``engine -> device arguments`` (example = the live ones)
+    device_args: Callable
+    #: which device argument is donated: the pool
+    donate: int = 1
+    per_bucket: bool = False
+    #: exists only on an engine with a SpecConfig
+    spec: bool = False
+    #: warmed by ``build(chunked=True)`` only
+    chunked: bool = False
+    #: ``rebuild()`` compiles a replacement (the incumbent serves until
+    #: it is ready) ...
+    rebuilt: bool = False
+    #: ... and ``rebuild(full=True)`` drops these, to recompile on
+    #: next use
+    dropped: bool = False
+
+
+def _program_name(kind: str, bucket: Optional[int] = None) -> str:
+    return kind if bucket is None else f"{kind}_{bucket}"
+
+
+#: every program the engine can compile, in ``build()``'s order (the
+#: per-bucket kinds bucket by bucket, then the rest)
+_PROGRAMS: Dict[str, _Program] = {p.kind: p for p in (
+    _Program("prefill", _prefill_fields, _prefill_step, _target_args,
+             per_bucket=True, dropped=True),
+    _Program("chunk_prefill", _chunk_fields, _chunk_step, _target_args,
+             per_bucket=True, chunked=True, dropped=True),
+    _Program("draft_prefill", _prefill_fields,
+             functools.partial(_prefill_step, draft=True), _draft_args,
+             per_bucket=True, spec=True, dropped=True),
+    _Program("fork_page", _fork_fields, _fork_step,
+             lambda eng: (eng.cache,), donate=0, chunked=True),
+    _Program("decode", _decode_fields, _decode_step, _target_args,
+             rebuilt=True),
+    _Program("draft_decode", _decode_fields, _draft_step, _draft_args,
+             spec=True, rebuilt=True),
+    _Program("verify", _verify_fields, _verify_step, _verify_args,
+             spec=True, rebuilt=True),
+    _Program("rollback", _rollback_fields, _rollback_step,
+             lambda eng: (eng.cache,), donate=0, spec=True),
+    _Program("draft_rollback", _rollback_fields, _rollback_step,
+             lambda eng: (eng.draft_cache,), donate=0, spec=True),
+)}
+
+
 class InferenceEngine:
     """AOT prefill/decode over the paged cache for a GPT param tree.
 
@@ -239,20 +468,11 @@ class InferenceEngine:
         self.pool = cache_lib.PagePool(
             self.serve.num_pages, self.serve.page_size
         )
-        self.cache = cache_lib.init_kv_pages(
-            cfg.num_layers,
-            self.serve.num_pages,
-            cfg.num_heads,
-            self.serve.page_size,
-            cfg.hidden_size // cfg.num_heads,
-            dtype=cfg.dtype,
-            kv_wire=self.serve.kv_wire,
-        )
-        self._layouts: Dict[Tuple[str, int], _HostArgs] = {}
-        self._prefill: Dict[int, object] = {}
-        self._chunk: Dict[int, object] = {}
-        self._decode = None
-        self._fork = None
+        self.cache = self._new_cache(cfg)
+        self._layouts: Dict[Tuple[str, Optional[int]], _HostArgs] = {}
+        #: the compiled executables, ``(kind, bucket or None)`` ->
+        #: executable: every program of :data:`_PROGRAMS` built so far
+        self._programs: Dict[Tuple[str, Optional[int]], object] = {}
         #: speculative decoding (docs/serving.md "Speculative
         #: decoding"): None = plain serving; a SpecConfig adds the
         #: draft model's params + KV pool and the draft/verify/rollback
@@ -262,11 +482,6 @@ class InferenceEngine:
         self._draft_cfg: Optional[GptConfig] = None
         self.draft_params = None
         self.draft_cache = None
-        self._draft_prefill: Dict[int, object] = {}
-        self._draft_decode = None
-        self._verify = None
-        self._rollback = None
-        self._draft_rollback = None
         #: speculative round counter — the ``serve.draft`` chaos index
         self.spec_rounds = 0
         self.draft_prefill_calls = 0
@@ -298,15 +513,7 @@ class InferenceEngine:
             # the draft KV pool mirrors the target's page geometry so
             # ONE PagePool's page ids index both (draft pages ride the
             # "draft" namespace; only the per-page row shapes differ)
-            self.draft_cache = cache_lib.init_kv_pages(
-                dcfg.num_layers,
-                self.serve.num_pages,
-                dcfg.num_heads,
-                self.serve.page_size,
-                dcfg.hidden_size // dcfg.num_heads,
-                dtype=dcfg.dtype,
-                kv_wire=self.serve.kv_wire,
-            )
+            self.draft_cache = self._new_cache(dcfg)
         # the fused sampler's base key: on the device once, an argument
         # of every step program, which folds the call's integers (call
         # index; stream seed and emission index) into it IN-PROGRAM
@@ -346,6 +553,17 @@ class InferenceEngine:
         self._sentinels: Dict[str, object] = {}
         self._publish_build_gauges()
 
+    def _new_cache(self, cfg: GptConfig) -> dict:
+        """A zeroed KV pool for ``cfg`` at this engine's page geometry
+        (the draft's mirrors the target's, so ONE PagePool's page ids
+        index both)."""
+        s = self.serve
+        return cache_lib.init_kv_pages(
+            cfg.num_layers, s.num_pages, cfg.num_heads, s.page_size,
+            cfg.hidden_size // cfg.num_heads,
+            dtype=cfg.dtype, kv_wire=s.kv_wire,
+        )
+
     # -- build ------------------------------------------------------------
     def _publish_build_gauges(self) -> None:
         s = self.serve
@@ -359,207 +577,35 @@ class InferenceEngine:
             board.set("serve/spec_k", self.spec.k)
             board.set("serve/spec_mode", self.spec.mode)
 
-    def _host_args(self, kind: str, bucket: int = 0) -> _HostArgs:
+    def _host_args(self, kind: str,
+                   bucket: Optional[int] = None) -> _HostArgs:
         """The :class:`_HostArgs` layout of a program kind — shared by
         the builder (``unpack`` at the program's head) and the serving
         call (``pack``), so the two cannot drift."""
         key = (kind, bucket)
         if key not in self._layouts:
-            s = self.serve
-            i32, u32, f32 = np.int32, np.uint32, np.float32
-            slots = ((s.max_batch,), i32)
-            tables = ((s.max_batch, s.max_pages_per_seq), i32)
-            prompt = (
-                ((bucket, 1), i32),                  # tokens
-                ((bucket // s.page_size,), i32),     # page ids
+            self._layouts[key] = _HostArgs(
+                *_PROGRAMS[kind].fields(self.serve, bucket)
             )
-            self._layouts[key] = _HostArgs(*{
-                # tokens, page ids, length, call index, temperature
-                "prefill": prompt + (((), i32), ((), i32), ((), f32)),
-                # tokens, chunk page ids, page table row, length,
-                # offset, call index, temperature
-                "chunk_prefill": prompt + (
-                    ((s.max_pages_per_seq,), i32),
-                    ((), i32), ((), i32), ((), i32), ((), f32),
-                ),
-                # tokens, lengths, page tables, temperatures, and the
-                # two integers each slot's sampling key is folded from
-                "decode": (
-                    slots, slots, tables, ((s.max_batch,), f32),
-                    ((s.max_batch,), u32), slots,
-                ),
-                # ... for the verify program the host's verdict on the
-                # round's draft (0 under a serve.draft fault) rides along
-                "verify": (
-                    slots, slots, tables, ((s.max_batch,), f32), ((), i32),
-                    ((s.max_batch,), u32), slots,
-                ),
-                # starts, counts, page tables
-                "rollback": (slots, slots, tables),
-                # source page, destination page
-                "fork_page": (((), i32), ((), i32)),
-            }[kind])
         return self._layouts[key]
 
-    def _prefill_program(self, cfg: GptConfig, bucket: int):
-        """The target's and the draft's prefill: one body, two model
-        configs."""
-        s = self.serve
-        host = self._host_args("prefill", bucket)
+    def _kinds(self):
+        """The program kinds this engine has, in :data:`_PROGRAMS`'
+        order."""
+        return [
+            prog for prog in _PROGRAMS.values()
+            if self.spec is not None or not prog.spec
+        ]
 
-        def fn(params, kv_pages, base_key, packed):
-            tokens, page_ids, length, call, temp = host.unpack(packed)
-            # the call's key is folded HERE from its index: the host
-            # folds nothing
-            return model_lib.prefill_body(
-                cfg, params, kv_pages, tokens, length, page_ids, temp,
-                model_lib.fold_in(base_key, call),
-                page_size=s.page_size,
-                top_k=s.top_k,
-            )
-
-        return fn, host.example()
-
-    def _prefill_fn(self, bucket: int):
-        fn, packed = self._prefill_program(self.cfg, bucket)
-        fn.__name__ = f"serve_prefill_{bucket}"
-        return fn, (self.params, self.cache, self._rng_base, packed)
-
-    def _chunk_fn(self, bucket: int):
-        s = self.serve
-        host = self._host_args("chunk_prefill", bucket)
-
-        def fn(params, kv_pages, base_key, packed):
-            (tokens, chunk_page_ids, page_table, length, offset, call,
-             temp) = host.unpack(packed)
-            return model_lib.chunk_prefill_body(
-                self.cfg, params, kv_pages, tokens, length, offset,
-                chunk_page_ids, page_table, temp,
-                model_lib.fold_in(base_key, call),
-                page_size=s.page_size,
-                top_k=s.top_k,
-            )
-
-        fn.__name__ = f"serve_chunk_prefill_{bucket}"
-        args = (self.params, self.cache, self._rng_base, host.example())
-        return fn, args
-
-    def _decode_fn(self):
-        s = self.serve
-        host = self._host_args("decode")
-
-        def fn(params, kv_pages, base_key, packed):
-            tokens, lengths, page_tables, temps, streams, gens = (
-                host.unpack(packed)
-            )
-            # per-slot keys fold_in(fold_in(base, streams[b]), gens[b]),
-            # folded HERE from the two integer vectors the host packs
-            return model_lib.decode_body(
-                self.cfg, params, kv_pages, tokens, lengths, page_tables,
-                temps, spec_lib.slot_keys(base_key, streams, gens),
-                page_size=s.page_size, top_k=s.top_k,
-            )
-
-        fn.__name__ = "serve_decode"
-        args = (self.params, self.cache, self._rng_base, host.example())
-        return fn, args
-
-    def _fork_fn(self):
-        host = self._host_args("fork_page")
-
-        def fn(kv_pages, packed):
-            # copy-on-write fork: duplicate one page's rows (codes AND
-            # scale planes under the int8 wire) across every layer
-            src, dst = host.unpack(packed)
-            return {
-                name: arr.at[:, dst].set(arr[:, src])
-                for name, arr in kv_pages.items()
-            }
-
-        fn.__name__ = "serve_fork_page"
-        return fn, (self.cache, host.example())
-
-    def _draft_fn(self):
-        s = self.serve
-        k = self.spec.k
-        dcfg = self._draft_cfg
-        host = self._host_args("decode")
-
-        def fn(params, kv_pages, base_key, packed):
-            tokens, lengths, page_tables, temps, streams, gens = (
-                host.unpack(packed)
-            )
-            return spec_lib.draft_body(
-                dcfg, params, kv_pages, tokens, lengths, page_tables,
-                temps, spec_lib.stream_keys(base_key, streams), gens,
-                k=k, page_size=s.page_size, top_k=s.top_k,
-            )
-
-        fn.__name__ = "serve_draft_decode"
-        args = (
-            self.draft_params, self.draft_cache, self._rng_base,
-            host.example(),
-        )
-        return fn, args
-
-    def _verify_fn(self):
-        s = self.serve
-        k = self.spec.k
-        host = self._host_args("verify")
-
-        def fn(params, kv_pages, base_key, draft_tokens, draft_probs,
-               draft_finite, packed):
-            (tokens, lengths, page_tables, temps, draft_ok, streams,
-             gens) = host.unpack(packed)
-            if k:
-                # draft_finite is the draft program's own screen (still
-                # on the device), draft_ok the host's verdict on the
-                # whole round (0 under a serve.draft fault)
-                draft_tokens, draft_probs = spec_lib.pin_failed_drafts(
-                    draft_tokens, draft_probs,
-                    draft_finite & (draft_ok != 0), self.cfg.vocab_size,
-                )
-            return spec_lib.verify_body(
-                self.cfg, params, kv_pages, tokens, draft_tokens,
-                lengths, page_tables, temps, draft_probs,
-                spec_lib.stream_keys(base_key, streams), gens,
-                page_size=s.page_size, top_k=s.top_k,
-            )
-
-        fn.__name__ = "serve_verify"
-        args = (
-            self.params,
-            self.cache,
-            self._rng_base,
-            jnp.zeros((s.max_batch, k), jnp.int32),
-            jnp.zeros((k, s.max_batch, self.cfg.vocab_size), jnp.float32),
-            jnp.ones((s.max_batch,), jnp.bool_),
-            host.example(),
-        )
-        return fn, args
-
-    def _rollback_fn(self, cache, name: str):
-        s = self.serve
-        # the stale span after a round is [new ctx, old ctx + k]: at
-        # most k + 1 rows when nothing was accepted
-        kmax = self.spec.k + 1
-        host = self._host_args("rollback")
-
-        def fn(kv_pages, packed):
-            starts, counts, page_tables = host.unpack(packed)
-            return spec_lib.rollback_body(
-                kv_pages, starts, counts, page_tables,
-                k=kmax, page_size=s.page_size,
-            )
-
-        fn.__name__ = name
-        return fn, (cache, host.example())
-
-    def _draft_prefill_fn(self, bucket: int):
-        fn, packed = self._prefill_program(self._draft_cfg, bucket)
-        fn.__name__ = f"serve_draft_prefill_{bucket}"
-        args = (self.draft_params, self.draft_cache, self._rng_base, packed)
-        return fn, args
+    def _trace_args(self, kind: str, bucket: Optional[int] = None):
+        """``(fn, example args)`` of one program: the kind's body bound
+        to this engine and its layout, named as the device trace will
+        show it."""
+        prog = _PROGRAMS[kind]
+        host = self._host_args(kind, bucket)
+        fn = functools.partial(prog.body, self, host)
+        fn.__name__ = f"serve_{_program_name(kind, bucket)}"
+        return fn, (*prog.device_args(self), host.example())
 
     def _pool_intent(self, cache) -> dict:
         """The ``memory-pool-copy`` intent for a program that takes
@@ -638,20 +684,16 @@ class InferenceEngine:
         warms every chunk-prefill bucket and the COW fork program —
         a prefix-cache/chunked-prefill deployment should pay those
         compiles at build, not inside the first cache hit's TTFT."""
+        warmed = [
+            prog for prog in self._kinds() if chunked or not prog.chunked
+        ]
         for b in buckets if buckets is not None else self.serve.buckets():
-            self._get_prefill(b)
-            if chunked:
-                self._get_chunk(b)
-            if self.spec is not None:
-                self._get_draft_prefill(b)
-        if chunked:
-            self._get_fork()
-        self._get_decode()
-        if self.spec is not None:
-            self._get_draft()
-            self._get_verify()
-            self._get_rollback()
-            self._get_draft_rollback()
+            for prog in warmed:
+                if prog.per_bucket:
+                    self._program(prog.kind, b)
+        for prog in warmed:
+            if not prog.per_bucket:
+                self._program(prog.kind)
         return self
 
     def rebuild(self, *, full: bool = False):
@@ -675,87 +717,31 @@ class InferenceEngine:
         """
         self.rebuilds += 1
         if full:
-            self._prefill.clear()
-            self._chunk.clear()
-            self._draft_prefill.clear()
-            for name in list(self._sentinels):
-                if name.startswith(
-                    ("prefill", "chunk_prefill", "draft_prefill")
-                ):
-                    del self._sentinels[name]
-        fn, args = self._decode_fn()
-        self._decode = self._compile("decode", fn, args)
-        if self.spec is not None:
-            fn, args = self._draft_fn()
-            self._draft_decode = self._compile("draft_decode", fn, args)
-            fn, args = self._verify_fn()
-            self._verify = self._compile("verify", fn, args)
+            for key in [
+                k for k in self._programs if _PROGRAMS[k[0]].dropped
+            ]:
+                del self._programs[key]
+                del self._sentinels[_program_name(*key)]
+        for prog in self._kinds():
+            if prog.rebuilt:
+                self._programs[prog.kind, None] = self._compile(
+                    prog.kind, *self._trace_args(prog.kind),
+                    donate=prog.donate,
+                )
         board.set("serve/engine_rebuilds", self.rebuilds)
         return self
 
-    def _get_prefill(self, bucket: int):
-        if bucket not in self._prefill:
-            fn, args = self._prefill_fn(bucket)
-            self._prefill[bucket] = self._compile(
-                f"prefill_{bucket}", fn, args
+    def _program(self, kind: str, bucket: Optional[int] = None):
+        """The compiled executable of one program, compiled (and
+        verified) on first use."""
+        key = (kind, bucket)
+        if key not in self._programs:
+            self._programs[key] = self._compile(
+                _program_name(kind, bucket),
+                *self._trace_args(kind, bucket),
+                donate=_PROGRAMS[kind].donate,
             )
-        return self._prefill[bucket]
-
-    def _get_chunk(self, bucket: int):
-        if bucket not in self._chunk:
-            fn, args = self._chunk_fn(bucket)
-            self._chunk[bucket] = self._compile(
-                f"chunk_prefill_{bucket}", fn, args
-            )
-        return self._chunk[bucket]
-
-    def _get_fork(self):
-        if self._fork is None:
-            fn, args = self._fork_fn()
-            self._fork = self._compile("fork_page", fn, args, donate=0)
-        return self._fork
-
-    def _get_decode(self):
-        if self._decode is None:
-            fn, args = self._decode_fn()
-            self._decode = self._compile("decode", fn, args)
-        return self._decode
-
-    def _get_draft(self):
-        if self._draft_decode is None:
-            fn, args = self._draft_fn()
-            self._draft_decode = self._compile("draft_decode", fn, args)
-        return self._draft_decode
-
-    def _get_verify(self):
-        if self._verify is None:
-            fn, args = self._verify_fn()
-            self._verify = self._compile("verify", fn, args)
-        return self._verify
-
-    def _get_rollback(self):
-        if self._rollback is None:
-            fn, args = self._rollback_fn(self.cache, "serve_rollback")
-            self._rollback = self._compile("rollback", fn, args, donate=0)
-        return self._rollback
-
-    def _get_draft_rollback(self):
-        if self._draft_rollback is None:
-            fn, args = self._rollback_fn(
-                self.draft_cache, "serve_draft_rollback"
-            )
-            self._draft_rollback = self._compile(
-                "draft_rollback", fn, args, donate=0
-            )
-        return self._draft_rollback
-
-    def _get_draft_prefill(self, bucket: int):
-        if bucket not in self._draft_prefill:
-            fn, args = self._draft_prefill_fn(bucket)
-            self._draft_prefill[bucket] = self._compile(
-                f"draft_prefill_{bucket}", fn, args
-            )
-        return self._draft_prefill[bucket]
+        return self._programs[key]
 
     @property
     def retraces(self) -> int:
@@ -770,7 +756,7 @@ class InferenceEngine:
         from apex_tpu import analysis
 
         bucket = bucket or self.serve.buckets()[0]
-        fn, args = self._prefill_fn(bucket)
+        fn, args = self._trace_args("prefill", bucket)
         report = analysis.check(
             jax.jit(fn, donate_argnums=(1,)), *args,
             donate_argnums=(1,),
@@ -778,7 +764,7 @@ class InferenceEngine:
             expect_pool=self._pool_intent(self.cache),
             name=f"serve/prefill_{bucket}",
         )
-        fn, args = self._decode_fn()
+        fn, args = self._trace_args("decode")
         dec = analysis.check(
             jax.jit(fn, donate_argnums=(1,)), *args,
             donate_argnums=(1,),
@@ -854,7 +840,7 @@ class InferenceEngine:
             tokens[:n, 0] = np.asarray(prompt_ids, np.int32)
             ids = np.full((np_b,), cache_lib.NULL_PAGE, np.int32)
             ids[: len(page_ids)] = np.asarray(page_ids, np.int32)
-            compiled = self._get_prefill(bucket)
+            compiled = self._program("prefill", bucket)
             args = (
                 self.params, self.cache, self._rng_base,
                 self._host_args("prefill", bucket).pack(
@@ -909,7 +895,7 @@ class InferenceEngine:
             table[: len(page_table_row)] = np.asarray(
                 page_table_row, np.int32
             )
-            compiled = self._get_chunk(bucket)
+            compiled = self._program("chunk_prefill", bucket)
             args = (
                 self.params, self.cache, self._rng_base,
                 self._host_args("chunk_prefill", bucket).pack(
@@ -932,7 +918,7 @@ class InferenceEngine:
         ``dst`` across every layer (codes AND scale planes under the
         int8 KV wire) through one tiny compiled donated program — the
         device half of the scheduler's shared-tail-page fork."""
-        compiled = self._get_fork()
+        compiled = self._program("fork_page")
         args = (self.cache, self._host_args("fork_page").pack(src, dst))
         self._sentinels["fork_page"].observe(*args)
         self.cache = compiled(*args)
@@ -958,7 +944,7 @@ class InferenceEngine:
         in place of ``(stream seed, emission index)``."""
         poison = self._chaos_gate(chaos.SERVE_DECODE, self.decode_iters)
         with self._phase("engine/stage", program="decode"):
-            compiled = self._get_decode()
+            compiled = self._program("decode")
             if streams is None:
                 b = self.serve.max_batch
                 streams = np.full((b,), self.decode_iters, np.uint32)
@@ -1003,27 +989,9 @@ class InferenceEngine:
             raise RuntimeError(
                 f"reset_cache with {self.pool.in_use} pages in use"
             )
-        cfg = self.cfg
-        self.cache = cache_lib.init_kv_pages(
-            cfg.num_layers,
-            self.serve.num_pages,
-            cfg.num_heads,
-            self.serve.page_size,
-            cfg.hidden_size // cfg.num_heads,
-            dtype=cfg.dtype,
-            kv_wire=self.serve.kv_wire,
-        )
+        self.cache = self._new_cache(self.cfg)
         if self.draft_cache is not None:
-            dcfg = self._draft_cfg
-            self.draft_cache = cache_lib.init_kv_pages(
-                dcfg.num_layers,
-                self.serve.num_pages,
-                dcfg.num_heads,
-                self.serve.page_size,
-                dcfg.hidden_size // dcfg.num_heads,
-                dtype=dcfg.dtype,
-                kv_wire=self.serve.kv_wire,
-            )
+            self.draft_cache = self._new_cache(self._draft_cfg)
 
     def probe_stream(self, prompt_ids, max_new_tokens: int):
         """Golden-probe hook (:mod:`apex_tpu.observability.canary`):
@@ -1106,11 +1074,11 @@ class InferenceEngine:
         tokens[:n, 0] = np.asarray(prompt_ids, np.int32)
         ids = np.full((np_b,), cache_lib.NULL_PAGE, np.int32)
         ids[: len(page_ids)] = np.asarray(page_ids, np.int32)
-        compiled = self._get_draft_prefill(bucket)
+        compiled = self._program("draft_prefill", bucket)
         name = f"draft_prefill_{bucket}"
         args = (
             self.draft_params, self.draft_cache, self._rng_base,
-            self._host_args("prefill", bucket).pack(
+            self._host_args("draft_prefill", bucket).pack(
                 tokens, ids, n, self.draft_prefill_calls, 0.0
             ),
         )
@@ -1151,11 +1119,11 @@ class InferenceEngine:
             temps = self._temps(temps)
             d_args = (
                 self.draft_params, self.draft_cache, self._rng_base,
-                self._host_args("decode").pack(
+                self._host_args("draft_decode").pack(
                     tokens, lengths, draft_tables, temps, streams, gens
                 ),
             )
-            compiled = self._get_draft()
+            compiled = self._program("draft_decode")
             self._sentinels["draft_decode"].observe(*d_args)
             d_tokens, d_probs, d_finite, self.draft_cache = compiled(
                 *d_args
@@ -1168,7 +1136,7 @@ class InferenceEngine:
                     fault is None, streams, gens,
                 ),
             )
-            compiled = self._get_verify()
+            compiled = self._program("verify")
             self._sentinels["verify"].observe(*v_args)
         self.decode_iters += 1
         live_n = int((np.asarray(lengths) > 0).sum())
@@ -1194,7 +1162,7 @@ class InferenceEngine:
         compiled truncation program — spec.py :func:`~apex_tpu.serve.
         spec.rollback_body`).  The scheduler COW-forked any shared tail
         page BEFORE the round, so every touched page is private."""
-        compiled = self._get_rollback()
+        compiled = self._program("rollback")
         args = (
             self.cache,
             self._host_args("rollback").pack(starts, counts, page_tables),
@@ -1204,10 +1172,12 @@ class InferenceEngine:
 
     def draft_rollback(self, starts, counts, page_tables) -> None:
         """:meth:`rollback` for the draft KV pool (draft page ids)."""
-        compiled = self._get_draft_rollback()
+        compiled = self._program("draft_rollback")
         args = (
             self.draft_cache,
-            self._host_args("rollback").pack(starts, counts, page_tables),
+            self._host_args("draft_rollback").pack(
+                starts, counts, page_tables
+            ),
         )
         self._sentinels["draft_rollback"].observe(*args)
         self.draft_cache = compiled(*args)

@@ -2,9 +2,12 @@
 
 The training stack's :class:`apex_tpu.models.gpt.GptModel` is a flax
 module built for ``value_and_grad`` over a full sequence; serving needs
-the same weights driven through two different dataflows — a one-shot
-**prefill** that also emits every position's K/V for the cache, and a
-single-token **decode** that appends to and reads from the paged cache.
+the same weights driven through three attention dataflows — a one-shot
+**prefill** that also emits every position's K/V for the cache, a
+**chunked prefill** that reads the earlier positions back from it, and
+a single-token **decode** that appends to and reads from the paged
+cache — over ONE head, block, layer loop and tail (``_embed_at``,
+``_block``, ``_layers``, ``_final_logits`` / ``_sample_tail``).
 This module is the functional re-expression of ``GptBlock`` /
 ``GptModel`` over the ``GptModel.init`` parameter tree (the scanned
 stack's leaves carry a leading ``num_layers`` axis, which maps directly
@@ -52,6 +55,8 @@ __all__ = [
     "PackedWeight",
     "quantize_params",
     "dequantize_params",
+    "stream_keys",
+    "slot_keys",
     "sample_tokens",
     "prefill_body",
     "chunk_prefill_body",
@@ -75,13 +80,16 @@ def validate_config(cfg: GptConfig) -> GptConfig:
     return cfg
 
 
+def _head_dim(cfg: GptConfig) -> int:
+    return cfg.hidden_size // cfg.num_heads
+
+
 def rope_tables(cfg: GptConfig):
     """Cached f32 cos/sin ``(max_seq_len, head_dim)`` in the model's
     rotate_half layout (None for non-rotary configs)."""
     if not cfg.rotary:
         return None, None
-    head_dim = cfg.hidden_size // cfg.num_heads
-    return _rope_cos_sin(cfg.max_seq_len, head_dim)
+    return _rope_cos_sin(cfg.max_seq_len, _head_dim(cfg))
 
 
 # ---------------------------------------------------------------------------
@@ -268,6 +276,23 @@ def fold_in(keys, data):
     return jnp.stack([x0, x1], axis=-1)
 
 
+def stream_keys(base_key, streams):
+    """Per-slot stream keys ``fold_in(engine base key, stream seed)`` —
+    a function of request IDENTITY, never of call counters, so a
+    speculative rollback replays the same draws and a ``k = 0`` spec
+    stream equals the plain one (``serve/spec.py`` "RNG discipline").
+    Traced INSIDE the step programs: the host hands over the integer
+    seeds ``(B,)`` and folds nothing."""
+    return fold_in(base_key, streams)
+
+
+def slot_keys(base_key, streams, gens):
+    """The plain decode program's per-slot sampling keys:
+    ``fold_in(fold_in(base, streams[b]), gens[b])`` — the RAW emission
+    key a ``k = 0`` speculative round consumes."""
+    return fold_in(stream_keys(base_key, streams), gens)
+
+
 def sample_tokens(logits, temps, rng, *, top_k: int = 0):
     """Sample next tokens INSIDE the compiled step — the host never
     round-trips the logits ("LLM Inference Acceleration via Efficient
@@ -304,30 +329,130 @@ def sample_tokens(logits, temps, rng, *, top_k: int = 0):
 
 
 # ---------------------------------------------------------------------------
+# the model, written once: head, block, layer loop, tail
+# ---------------------------------------------------------------------------
+#
+# Prefill, chunked prefill and decode are three ATTENTION DATAFLOWS over
+# one model (docs/serving.md "One block, three dataflows").  A dataflow
+# supplies ``attend(kv, layer, q, k, v) -> (ctx, kv)``: ``q, k, v`` are
+# the block's projections ``(*rows, H, D)`` (``rows`` is ``(S, 1)`` for
+# the prompt dataflows, ``(B,)`` for decode); ``ctx`` comes back one
+# context row per input row, in row order, heads still apart (the block
+# flattens it to ``x``'s shape); ``kv`` is the pool with this layer's
+# K/V written.
+
+
+def _rows_at(table, positions):
+    """Rows of a per-position ``table`` at ``positions``: a gather for
+    an int32 vector, a static slice (no gather in the program) for a
+    ``range`` — rows that sit at their own index, a prompt from its
+    start."""
+    if isinstance(positions, range):
+        return table[positions.start:positions.stop]
+    return jnp.take(table, positions, axis=0)
+
+
+def _embed_at(cfg: GptConfig, tree, tokens, positions):
+    """The model's head: token embeddings at absolute ``positions``,
+    one per row of ``tokens`` (an int32 vector, or a ``range``:
+    :func:`_rows_at`).  Learned positions are added here; rotary
+    configs get their f32 ``(cos, sin)`` rows back instead, for the
+    dataflow's ``attend`` to rotate with.  Returns ``(x, rope)``,
+    ``rope`` None without RoPE."""
+    x = _embed(tree["word_embeddings"], tokens, cfg.dtype)
+    if cfg.rotary:
+        # a range needs no row past its end
+        rows = (
+            positions.stop if isinstance(positions, range)
+            else cfg.max_seq_len
+        )
+        cos, sin = _rope_cos_sin(rows, _head_dim(cfg))
+        return x, (_rows_at(cos, positions), _rows_at(sin, positions))
+    rows = _rows_at(tree["position_embeddings"], positions)
+    # (N, hidden) over x's unit axes: (S, 1, hidden) for a prompt
+    rows = jnp.expand_dims(rows, range(1, x.ndim - 1))
+    return x + rows.astype(cfg.dtype), None
+
+
+def _block(cfg: GptConfig, lp, x, kv, layer, attend):
+    """One pre-LN decoder block over ``x`` ``(*rows, hidden)`` — THE
+    block: every step body applies a layer through this function and no
+    other way (``tests/test_serve.py`` pins it).  Returns the new
+    hidden and the pool ``attend`` handed back."""
+    y = _layer_norm(x, lp["ln_attn"], cfg.layer_norm_eps)
+    qkv = _linear(y, lp["qkv"], cfg.dtype).reshape(
+        *x.shape[:-1], cfg.num_heads, 3, _head_dim(cfg)
+    )
+    ctx, kv = attend(kv, layer, *(qkv[..., i, :] for i in range(3)))
+    x = x + _linear(ctx.reshape(x.shape), lp["out"], cfg.dtype)
+    return _mlp(x, lp, cfg), kv
+
+
+def _layers(cfg: GptConfig, tree, x, kv_pages, attend):
+    """The layer loop.  The pool is the loop's CARRY (indexed by
+    layer), never its xs/ys: a scanned-over pool is sliced and
+    restacked every layer (docs/serving.md "The KV pool")."""
+
+    def layer(carry, xs):
+        lp, l = xs
+        return _block(cfg, lp, *carry, l, attend), None
+
+    (x, kv_pages), _ = jax.lax.scan(
+        layer, (x, dict(kv_pages)),
+        (tree["layers"]["block"], jnp.arange(cfg.num_layers)),
+    )
+    return x, kv_pages
+
+
+def _final_logits(cfg: GptConfig, tree, h):
+    """Final LayerNorm and the tied-embedding logits of rows ``h``."""
+    h = _layer_norm(h, tree["ln_f"], cfg.layer_norm_eps)
+    return _logits(tree, h, cfg.dtype)
+
+
+def _sample_tail(logits, temps, rng, top_k):
+    """The fused tail of every step program: the next token (argmax
+    without a key) and the in-step non-finite screen over each row's
+    logits.  Returns ``(logits, next_tokens, finite)``."""
+    if rng is None:
+        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    else:
+        next_tokens = sample_tokens(logits, temps, rng, top_k=top_k)
+    return logits, next_tokens, jnp.isfinite(logits).all(axis=-1)
+
+
+def _prompt_tail(cfg: GptConfig, tree, x, length, temp, rng, top_k):
+    """The prompt dataflows' tail: the LAST LIVE row of ``x``
+    ``(S, 1, hidden)`` only."""
+    h_last = jax.lax.dynamic_slice_in_dim(
+        x[:, 0], jnp.maximum(length - 1, 0), 1, 0
+    )  # (1, hidden)
+    logits = _final_logits(cfg, tree, h_last)[0]  # (V,) f32
+    return _sample_tail(logits, temp, rng, top_k)
+
+
+def _prompt_heads(q, k, v, rope):
+    """A prompt dataflow's ``q, k, v`` ``(S, 1, H, D)`` as ``(1, H, S,
+    D)``, ``q`` and ``k`` rotated at the rows' positions."""
+    q, k, v = (jnp.transpose(t, (1, 2, 0, 3)) for t in (q, k, v))
+    if rope is not None:
+        q = fused_apply_rotary_pos_emb_cached(q, *rope)
+        k = fused_apply_rotary_pos_emb_cached(k, *rope)
+    return q, k, v
+
+
+def _write_prompt(kv, layer, page_ids, k, v):
+    """``k, v`` ``(1, H, S, D)`` as per-position rows ``(S, H, D)``
+    into this layer's pages."""
+    return cache_lib.write_prompt_kv(
+        kv, layer, page_ids,
+        jnp.transpose(k[0], (1, 0, 2)), jnp.transpose(v[0], (1, 0, 2)),
+    )
+
+
+# ---------------------------------------------------------------------------
 # prefill: full-sequence forward that also yields per-position K/V
 # ---------------------------------------------------------------------------
-
-
-def _prefill_block(cfg: GptConfig, bp, x, cos, sin):
-    """One decoder block over ``x`` (S, B, hidden); returns the new
-    hidden and this layer's rotated K + V as ``(B, H, S, D)``."""
-    heads = cfg.num_heads
-    head_dim = cfg.hidden_size // heads
-    y = _layer_norm(x, bp["ln_attn"], cfg.layer_norm_eps)
-    qkv = _linear(y, bp["qkv"], cfg.dtype)
-    s, b = qkv.shape[0], qkv.shape[1]
-    qkv = qkv.reshape(s, b, heads, 3, head_dim)
-    q, k, v = (
-        jnp.transpose(qkv[:, :, :, i], (1, 2, 0, 3)) for i in range(3)
-    )
-    if cfg.rotary:
-        q = fused_apply_rotary_pos_emb_cached(q, cos, sin)
-        k = fused_apply_rotary_pos_emb_cached(k, cos, sin)
-    ctx = flash_attention(q, k, v, causal=True, scale=head_dim**-0.5)
-    ctx = jnp.transpose(ctx, (2, 0, 1, 3)).reshape(s, b, heads * head_dim)
-    attn = _linear(ctx, bp["out"], cfg.dtype)
-    x = x + attn
-    return _mlp(x, bp, cfg), (k, v)
 
 
 def prefill_body(
@@ -361,47 +486,21 @@ def prefill_body(
     pass them (``benchmark/rehearse_compile.py``).
     """
     del page_size, kv_wire
-    params = dequantize_params(params)
-    tree = _tree(params)
-    x = _embed(tree["word_embeddings"], tokens, cfg.dtype)  # (S, 1, h)
-    s = tokens.shape[0]
-    head_dim = cfg.hidden_size // cfg.num_heads
-    cos = sin = None
-    if cfg.rotary:
-        cos, sin = _rope_cos_sin(s, head_dim)
-    else:
-        pos = tree["position_embeddings"][:s]
-        x = x + pos[:, None, :].astype(cfg.dtype)
+    tree = _tree(dequantize_params(params))
+    x, rope = _embed_at(cfg, tree, tokens, range(tokens.shape[0]))
 
-    bp = tree["layers"]["block"]
-
-    def layer(carry, xs):
-        x, kv = carry
-        lp, l = xs
-        x, (k, v) = _prefill_block(cfg, lp, x, cos, sin)
-        # (1, H, S, D) -> per-position rows (S, H, D) -> this layer's
-        # pages, written here so the pool stays the loop's carry
-        kv = cache_lib.write_prompt_kv(
-            kv, l, page_ids,
-            jnp.transpose(k[0], (1, 0, 2)), jnp.transpose(v[0], (1, 0, 2)),
+    def attend(kv, l, q, k, v):
+        q, k, v = _prompt_heads(q, k, v, rope)
+        ctx = flash_attention(
+            q, k, v, causal=True, scale=_head_dim(cfg)**-0.5
         )
-        return (x, kv), None
+        return (
+            jnp.transpose(ctx, (2, 0, 1, 3)),
+            _write_prompt(kv, l, page_ids, k, v),
+        )
 
-    (x, kv_pages), _ = jax.lax.scan(
-        layer, (x, dict(kv_pages)), (bp, jnp.arange(cfg.num_layers))
-    )
-
-    h_last = jax.lax.dynamic_slice_in_dim(
-        x[:, 0], jnp.maximum(length - 1, 0), 1, 0
-    )  # (1, hidden)
-    h_last = _layer_norm(h_last, tree["ln_f"], cfg.layer_norm_eps)
-    logits = _logits(tree, h_last, cfg.dtype)[0]  # (V,) f32
-    if rng is None:
-        next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        next_token = sample_tokens(logits, temp, rng, top_k=top_k)
-    finite = jnp.isfinite(logits).all()
-    return logits, next_token, finite, kv_pages
+    x, kv_pages = _layers(cfg, tree, x, kv_pages, attend)
+    return *_prompt_tail(cfg, tree, x, length, temp, rng, top_k), kv_pages
 
 
 # ---------------------------------------------------------------------------
@@ -444,23 +543,12 @@ def chunk_prefill_body(
     kv_pages)`` for the LAST live chunk position (only the final chunk's
     token is consumed; earlier chunks run for their KV writes).
     """
-    params = dequantize_params(params)
-    tree = _tree(params)
-    x = _embed(tree["word_embeddings"], tokens, cfg.dtype)  # (C, 1, h)
+    tree = _tree(dequantize_params(params))
     c = tokens.shape[0]
     heads = cfg.num_heads
-    head_dim = cfg.hidden_size // heads
-    positions = offset + jnp.arange(c, dtype=jnp.int32)
-    cos_rows = sin_rows = None
-    if cfg.rotary:
-        cos_t, sin_t = _rope_cos_sin(cfg.max_seq_len, head_dim)
-        cos_rows = jnp.take(cos_t, positions, axis=0)  # (C, D)
-        sin_rows = jnp.take(sin_t, positions, axis=0)
-    else:
-        rows = jnp.take(tree["position_embeddings"], positions, axis=0)
-        x = x + rows[:, None, :].astype(cfg.dtype)
-
-    bp = tree["layers"]["block"]
+    x, rope = _embed_at(
+        cfg, tree, tokens, offset + jnp.arange(c, dtype=jnp.int32)
+    )
     t_ctx = page_table.shape[0] * page_size
     # carry-in mask: gathered row t is absolute position t of this
     # sequence; only positions before the chunk are valid carry
@@ -472,21 +560,11 @@ def chunk_prefill_body(
         [jnp.broadcast_to(carry_valid[None, :], (c, t_ctx)), causal],
         axis=1,
     )[None]                                            # (1, C, T+C)
-    scale = head_dim**-0.5
+    scale = _head_dim(cfg)**-0.5
     big_neg = jnp.asarray(jnp.finfo(jnp.float32).min, jnp.float32)
 
-    def layer(carry, xs):
-        x, kv = carry
-        lp, l = xs
-        y = _layer_norm(x, lp["ln_attn"], cfg.layer_norm_eps)
-        qkv = _linear(y, lp["qkv"], cfg.dtype)
-        qkv = qkv.reshape(c, 1, heads, 3, head_dim)
-        q, k, v = (
-            jnp.transpose(qkv[:, :, :, i], (1, 2, 0, 3)) for i in range(3)
-        )  # (1, H, C, D)
-        if cfg.rotary:
-            q = fused_apply_rotary_pos_emb_cached(q, cos_rows, sin_rows)
-            k = fused_apply_rotary_pos_emb_cached(k, cos_rows, sin_rows)
+    def attend(kv, l, q, k, v):
+        q, k, v = _prompt_heads(q, k, v, rope)         # (1, H, C, D)
         # carry-in K/V: dense gather of the whole page table, read
         # through the cache wire (exactly how decode reads it), as
         # (H, T, D) f32 in absolute position order
@@ -507,34 +585,15 @@ def chunk_prefill_body(
         scores = jnp.where(mask, scores, big_neg)
         probs = jax.nn.softmax(scores, axis=-1)
         ctx = jnp.einsum("hct,htd->hcd", probs, v_all)  # (H, C, D)
-        ctx = jnp.transpose(ctx, (1, 0, 2)).reshape(
-            c, 1, heads * head_dim
-        ).astype(cfg.dtype)
-        x = x + _linear(ctx, lp["out"], cfg.dtype)
-        x = _mlp(x, lp, cfg)
-        # write the chunk's K/V pages (null entries dump cached pages'
-        # re-runs into write-only garbage)
-        kv = cache_lib.write_prompt_kv(
-            kv, l, chunk_page_ids,
-            jnp.transpose(k[0], (1, 0, 2)), jnp.transpose(v[0], (1, 0, 2)),
+        # null entries dump cached pages' re-runs into write-only
+        # garbage
+        return (
+            jnp.transpose(ctx, (1, 0, 2)),
+            _write_prompt(kv, l, chunk_page_ids, k, v),
         )
-        return (x, kv), None
 
-    (x, kv_pages), _ = jax.lax.scan(
-        layer, (x, dict(kv_pages)), (bp, jnp.arange(cfg.num_layers))
-    )
-
-    h_last = jax.lax.dynamic_slice_in_dim(
-        x[:, 0], jnp.maximum(length - 1, 0), 1, 0
-    )  # (1, hidden)
-    h_last = _layer_norm(h_last, tree["ln_f"], cfg.layer_norm_eps)
-    logits = _logits(tree, h_last, cfg.dtype)[0]  # (V,) f32
-    if rng is None:
-        next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        next_token = sample_tokens(logits, temp, rng, top_k=top_k)
-    finite = jnp.isfinite(logits).all()
-    return logits, next_token, finite, kv_pages
+    x, kv_pages = _layers(cfg, tree, x, kv_pages, attend)
+    return *_prompt_tail(cfg, tree, x, length, temp, rng, top_k), kv_pages
 
 
 # ---------------------------------------------------------------------------
@@ -560,57 +619,28 @@ def _decode_step(
     same shapes, same kernels — which is precisely why a greedy
     speculative stream is bit-identical to the sequential baseline by
     construction.  Returns ``(logits (B, V) f32, kv_pages)``."""
-    b = tokens.shape[0]
-    heads = cfg.num_heads
-    head_dim = cfg.hidden_size // heads
-    x = _embed(tree["word_embeddings"], tokens, cfg.dtype)  # (B, hidden)
-
     pos = jnp.maximum(lengths - 1, 0)  # this token's position; idle -> 0
-    page_ids = page_tables[jnp.arange(b), pos // page_size]  # (B,)
+    x, rope = _embed_at(cfg, tree, tokens, pos)
+    page_ids = page_tables[jnp.arange(tokens.shape[0]), pos // page_size]
     slots = pos % page_size
-    cos_rows = sin_rows = None
-    if cfg.rotary:
-        cos_t, sin_t = _rope_cos_sin(cfg.max_seq_len, head_dim)
-        cos_rows = jnp.take(cos_t, pos, axis=0)  # (B, D)
-        sin_rows = jnp.take(sin_t, pos, axis=0)
-    else:
-        rows = jnp.take(tree["position_embeddings"], pos, axis=0)
-        x = x + rows.astype(cfg.dtype)
+    cos_rows, sin_rows = rope or (None, None)
 
-    bp = tree["layers"]["block"]
-
-    def layer(carry, xs):
-        x, kv = carry
-        lp, l = xs
-        y = _layer_norm(x, lp["ln_attn"], cfg.layer_norm_eps)
-        qkv = _linear(y, lp["qkv"], cfg.dtype).reshape(
-            b, heads, 3, head_dim
-        )
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # (B, H, D)
-        if cfg.rotary:
+    def attend(kv, l, q, k, v):
+        # K is rotated here; the kernel rotates Q (and dequantizes the
+        # int8 wire) itself
+        if rope is not None:
             k = _rope_rows(k, cos_rows, sin_rows)
         kv = cache_lib.append_token_kv(kv, l, page_ids, slots, k, v)
         ctx = paged_decode_attention(
             q, kv["k"], kv["v"], page_tables, lengths,
-            layer=l, scale=head_dim**-0.5,
+            layer=l, scale=_head_dim(cfg)**-0.5,
             k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"),
-            rope_cos=cos_rows if cfg.rotary else None,
-            rope_sin=sin_rows if cfg.rotary else None,
+            rope_cos=cos_rows, rope_sin=sin_rows,
         )
-        ctx = ctx.astype(cfg.dtype).reshape(b, heads * head_dim)
-        x = x + _linear(ctx, lp["out"], cfg.dtype)
-        x = _mlp(x, lp, cfg)
-        return (x, kv), None
+        return ctx, kv
 
-    # the pool is the loop's CARRY (indexed by layer), never its
-    # xs/ys: a scanned-over pool is sliced and restacked every layer
-    (x, kv_pages), _ = jax.lax.scan(
-        layer, (x, dict(kv_pages)), (bp, jnp.arange(cfg.num_layers))
-    )
-
-    h = _layer_norm(x, tree["ln_f"], cfg.layer_norm_eps)
-    logits = _logits(tree, h, cfg.dtype)  # (B, V) f32
-    return logits, kv_pages
+    x, kv_pages = _layers(cfg, tree, x, kv_pages, attend)
+    return _final_logits(cfg, tree, x), kv_pages  # (B, V) f32
 
 
 def decode_body(
@@ -644,15 +674,8 @@ def decode_body(
     callers that pass it (``benchmark/rehearse_compile.py``).
     """
     del kv_wire
-    params = dequantize_params(params)
-    tree = _tree(params)
     logits, kv_pages = _decode_step(
-        cfg, tree, kv_pages, tokens, lengths, page_tables,
-        page_size=page_size,
+        cfg, _tree(dequantize_params(params)), kv_pages, tokens, lengths,
+        page_tables, page_size=page_size,
     )
-    if rng is None:
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        next_tokens = sample_tokens(logits, temps, rng, top_k=top_k)
-    finite = jnp.isfinite(logits).all(axis=-1)
-    return logits, next_tokens, finite, kv_pages
+    return *_sample_tail(logits, temps, rng, top_k), kv_pages

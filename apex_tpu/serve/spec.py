@@ -76,8 +76,6 @@ __all__ = [
     "ACCEPT_TAG",
     "target_probs",
     "speculative_verify",
-    "stream_keys",
-    "slot_keys",
     "pin_failed_drafts",
     "draft_body",
     "verify_body",
@@ -174,30 +172,6 @@ def target_probs(logits, temps, *, top_k: int = 0):
     return jax.nn.softmax(scaled, axis=-1)
 
 
-def _fold_each(keys, data):
-    """Per-slot ``fold_in`` over a ``(B, 2)`` key batch (``data`` a
-    scalar tag or a ``(B,)`` vector)."""
-    data = jnp.broadcast_to(jnp.asarray(data), (keys.shape[0],))
-    return model_lib.fold_in(keys, data)
-
-
-def stream_keys(base_key, streams):
-    """Per-slot stream keys ``fold_in(engine base key, stream seed)`` —
-    a function of request IDENTITY, never of call counters, so a
-    speculative rollback replays the same draws and a ``k = 0`` spec
-    stream equals the plain one ("RNG discipline" above).  Traced
-    INSIDE the step programs: the host hands over the integer seeds
-    ``(B,)`` and folds nothing."""
-    return model_lib.fold_in(base_key, streams)
-
-
-def slot_keys(base_key, streams, gens):
-    """The plain decode program's per-slot sampling keys:
-    ``fold_in(fold_in(base, streams[b]), gens[b])`` — the RAW emission
-    key a ``k = 0`` speculative round consumes."""
-    return _fold_each(stream_keys(base_key, streams), gens)
-
-
 def pin_failed_drafts(draft_tokens, draft_probs, draft_ok, vocab: int):
     """A faulted / non-finite draft must not smuggle a token into the
     stream: pin the proposals of slots with ``draft_ok`` False to one
@@ -256,7 +230,7 @@ def speculative_verify(ver_logits, draft_tokens, draft_probs, temps,
     if k == 0:
         bonus = model_lib.sample_tokens(
             ver_logits[0], temps,
-            _fold_each(stream_keys, gens), top_k=top_k,
+            model_lib.fold_in(stream_keys, gens), top_k=top_k,
         )
         return bonus[:, None], jnp.zeros((b,), jnp.int32)
 
@@ -272,7 +246,10 @@ def speculative_verify(ver_logits, draft_tokens, draft_probs, temps,
     q_d = jax.vmap(lambda qj, dj: qj[rows, dj])(draft_probs, d_cols)
 
     def u_at(j):
-        keys = _fold_each(_fold_each(stream_keys, gens + j), ACCEPT_TAG)
+        keys = model_lib.fold_in(
+            model_lib.fold_in(stream_keys, gens + j),
+            jnp.full_like(gens, ACCEPT_TAG),
+        )
         return jax.vmap(lambda kk: jax.random.uniform(kk, ()))(keys)
 
     u = jnp.stack([u_at(j) for j in range(k)])               # (k, B)
@@ -288,7 +265,7 @@ def speculative_verify(ver_logits, draft_tokens, draft_probs, temps,
     # each emission index j consumes the RAW key fold_in(stream, g+j)
     corrections = []
     for j in range(k + 1):
-        keys = _fold_each(stream_keys, gens + j)
+        keys = model_lib.fold_in(stream_keys, gens + j)
         if j < k:
             corrections.append(_residual_sample(p[j], draft_probs[j], keys))
         else:
@@ -336,7 +313,10 @@ def draft_body(cfg: GptConfig, params, kv_pages: dict, tokens, lengths,
             cfg, tree, kv, cur, eff, page_tables,
             page_size=page_size,
         )
-        keys = _fold_each(_fold_each(stream_keys, gens + j), DRAFT_TAG)
+        keys = model_lib.fold_in(
+            model_lib.fold_in(stream_keys, gens + j),
+            jnp.full_like(gens, DRAFT_TAG),
+        )
         nxt = model_lib.sample_tokens(logits, temps, keys, top_k=top_k)
         q = target_probs(logits, temps, top_k=top_k)
         fin = jnp.isfinite(logits).all(axis=-1)

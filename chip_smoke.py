@@ -469,8 +469,10 @@ def phase_serve(
         "static_peak_hbm_estimate": board.get("serve/peak_hbm_bytes"),
         "xla_memory_analysis": {
             name: _xla_total_bytes(exe)
-            for name, exe in [("decode", engine._decode)] + [
-                (f"prefill_{b}", exe) for b, exe in engine._prefill.items()
+            for name, exe in [("decode", engine._programs["decode", None])] + [
+                (f"prefill_{b}", exe)
+                for (kind, b), exe in engine._programs.items()
+                if kind == "prefill"
             ]
         },
     }

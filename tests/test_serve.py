@@ -1819,3 +1819,198 @@ class TestSpeculativeDecoding:
                        check_every=1)
         wd2.on_step(1)
         assert wd2.events == []
+
+
+# ---------------------------------------------------------------------------
+# one block, three dataflows; one program table (ISSUE 33)
+# ---------------------------------------------------------------------------
+
+
+def _head_and_tail(cfg, params, tokens, positions):
+    """Plain-jnp logits of the model WITHOUT its layers: embedding,
+    learned positions, final LayerNorm, tied logits — what a step body
+    must compute when the block returns its input."""
+    tree = params["params"]
+    emb = tree["word_embeddings"]["weight"]
+    x = emb[jnp.asarray(tokens)]
+    if not cfg.rotary:
+        x = x + tree["position_embeddings"][jnp.asarray(positions)]
+    mean = x.mean(-1, keepdims=True)
+    var = ((x - mean) ** 2).mean(-1, keepdims=True)
+    h = (x - mean) / jnp.sqrt(var + cfg.layer_norm_eps)
+    h = h * tree["ln_f"]["scale"] + tree["ln_f"]["bias"]
+    return np.asarray(h @ emb.T)
+
+
+def _run_step_body(body, cfg, params):
+    """Drive one of the five step bodies on the tiny configuration;
+    returns ``(got, want)``: what the body computed from its logits and
+    the same from :func:`_head_and_tail` at the rows' tokens and
+    positions."""
+    from apex_tpu.serve import spec as spec_lib
+
+    ps, b, k = 8, 2, 2
+    kv = cache_lib.init_kv_pages(
+        cfg.num_layers, 16, cfg.num_heads, ps,
+        cfg.hidden_size // cfg.num_heads, dtype=cfg.dtype,
+    )
+    rs = np.random.RandomState(33)
+    if body in ("prefill", "chunk_prefill"):
+        n, offset = 11, 16 if body == "chunk_prefill" else 0
+        tokens = np.zeros((16, 1), np.int32)
+        tokens[:n, 0] = rs.randint(0, cfg.vocab_size, size=n)
+        if body == "prefill":
+            logits, _, _, _ = serve_model.prefill_body(
+                cfg, params, kv, jnp.asarray(tokens), jnp.int32(n),
+                jnp.asarray([1, 2], jnp.int32), page_size=ps,
+            )
+        else:
+            logits, _, _, _ = serve_model.chunk_prefill_body(
+                cfg, params, kv, jnp.asarray(tokens), jnp.int32(n),
+                jnp.int32(offset), jnp.asarray([3, 4], jnp.int32),
+                jnp.asarray([1, 2, 3, 4, 0, 0, 0, 0], jnp.int32),
+                page_size=ps,
+            )
+        want = _head_and_tail(
+            cfg, params, tokens[n - 1, 0], offset + n - 1
+        )
+        return np.asarray(logits), want
+    tokens = rs.randint(0, cfg.vocab_size, size=b).astype(np.int32)
+    lengths = np.array([5, 12], np.int32)
+    tables = np.array([[1, 2, 0, 0], [3, 4, 5, 0]], np.int32)
+    if body == "decode":
+        logits, _, _, _ = serve_model.decode_body(
+            cfg, params, kv, jnp.asarray(tokens), jnp.asarray(lengths),
+            jnp.asarray(tables), page_size=ps,
+        )
+        return np.asarray(logits), _head_and_tail(
+            cfg, params, tokens, lengths - 1
+        )
+    keys = jnp.asarray(rs.randint(0, 2**31, size=(b, 2)), jnp.uint32)
+    gens = jnp.zeros((b,), jnp.int32)
+    if body == "draft":
+        # temperature 1: the proposals' distributions ARE softmax(logits)
+        drafts, probs, _, _ = spec_lib.draft_body(
+            cfg, params, kv, jnp.asarray(tokens), jnp.asarray(lengths),
+            jnp.asarray(tables), jnp.ones((b,), jnp.float32), keys, gens,
+            k=k, page_size=ps,
+        )
+        cols = np.concatenate([tokens[:, None], np.asarray(drafts)], 1)
+        want = np.stack([
+            jax.nn.softmax(_head_and_tail(
+                cfg, params, cols[:, j], lengths - 1 + j
+            ), axis=-1)
+            for j in range(k)
+        ])
+        return np.asarray(probs), want
+    assert body == "verify"
+    drafts = rs.randint(0, cfg.vocab_size, size=(b, k)).astype(np.int32)
+    # greedy: the emitted columns are the argmax of each position's logits
+    out, _, _, _ = spec_lib.verify_body(
+        cfg, params, kv, jnp.asarray(tokens), jnp.asarray(drafts),
+        jnp.asarray(lengths), jnp.asarray(tables),
+        jnp.zeros((b,), jnp.float32),
+        jnp.zeros((k, b, cfg.vocab_size), jnp.float32), keys, gens,
+        page_size=ps,
+    )
+    cols = np.concatenate([tokens[:, None], drafts], 1)
+    want = np.stack([
+        _head_and_tail(cfg, params, cols[:, j], lengths - 1 + j).argmax(-1)
+        for j in range(k + 1)
+    ], axis=1)
+    return np.asarray(out), want
+
+
+class TestOneBlock:
+    """Every step body applies a layer through ``serve.model._block``
+    and no other way."""
+
+    @pytest.mark.parametrize("rotary", [True, False])
+    @pytest.mark.parametrize(
+        "body", ["prefill", "chunk_prefill", "decode", "draft", "verify"]
+    )
+    def test_every_body_goes_through_the_one_block(
+        self, monkeypatch, body, rotary
+    ):
+        cfg = tiny_cfg(rotary=rotary)
+        params = GptModel(cfg).init(
+            jax.random.PRNGKey(1), jnp.zeros((8, 1), jnp.int32)
+        )
+        real, calls = serve_model._block, []
+
+        def counting(*args):
+            calls.append(args)
+            return real(*args)
+
+        monkeypatch.setattr(serve_model, "_block", counting)
+        got, want = _run_step_body(body, cfg, params)
+        # lax.scan traces the layer once: one call a layer loop
+        assert len(calls) == 1
+        assert not np.allclose(got, want, atol=1e-3)  # the layers matter
+
+        monkeypatch.setattr(
+            serve_model, "_block",
+            lambda cfg, lp, x, kv, layer, attend: (x, kv),
+        )
+        got, want = _run_step_body(body, cfg, params)
+        if body == "verify":
+            np.testing.assert_array_equal(got, want)
+        else:
+            np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+#: what ``build(chunked=True)`` on a speculative engine compiles, in
+#: order — the keys of ``compile_counts`` (the benchmark's
+#: ``fault_counters`` compare them), the sentinel names, and (behind
+#: ``jit_serve_``) the module names a device trace shows
+PROGRAMS_AT_PR32 = [
+    "prefill_8", "chunk_prefill_8", "draft_prefill_8",
+    "prefill_16", "chunk_prefill_16", "draft_prefill_16",
+    "fork_page", "decode", "draft_decode", "verify", "rollback",
+    "draft_rollback",
+]
+
+
+class TestProgramTable:
+    @pytest.mark.parametrize("full", [False, True])
+    def test_build_and_rebuild_keep_todays_programs(self, gpt, full):
+        import re
+
+        eng = make_spec_engine(gpt, k=2, prefill_buckets=(8, 16))
+        eng.build(chunked=True)
+        assert list(eng.compile_counts) == PROGRAMS_AT_PR32
+        assert set(eng.compile_counts.values()) == {1}
+        assert sorted(eng._sentinels) == sorted(PROGRAMS_AT_PR32)
+        assert {
+            re.search(r"HloModule (\S+?),", exe.as_text()).group(1)
+            for exe in eng._programs.values()
+        } == {f"jit_serve_{name}" for name in PROGRAMS_AT_PR32}
+
+        before = dict(eng._programs)
+        eng.rebuild(full=full)
+        rebuilt = {"decode", "draft_decode", "verify"}
+        dropped = {
+            name for name in PROGRAMS_AT_PR32
+            if "prefill" in name
+        } if full else set()
+        for (kind, bucket), exe in before.items():
+            name = kind if bucket is None else f"{kind}_{bucket}"
+            assert eng.compile_counts[name] == 1 + (name in rebuilt)
+            if name in dropped:
+                assert (kind, bucket) not in eng._programs
+                assert name not in eng._sentinels
+            else:
+                assert name in eng._sentinels
+                # a recompiled program is a NEW executable, the rest
+                # are the ones build() made
+                same = eng._programs[kind, bucket] is exe
+                assert same == (name not in rebuilt)
+        # a dropped bucket recompiles on next use
+        eng._program("prefill", 8)
+        assert eng.compile_counts["prefill_8"] == 1 + full
+
+    def test_plain_engine_has_no_speculative_or_chunked_program(self, gpt):
+        eng = make_engine(gpt, prefill_buckets=(8,)).build()
+        assert list(eng.compile_counts) == ["prefill_8", "decode"]
+        eng.rebuild()
+        assert eng.compile_counts == {"prefill_8": 1, "decode": 2}

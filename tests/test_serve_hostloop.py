@@ -206,13 +206,8 @@ class _HostPathGuard:
                     real_put(a) if a is host[0] else a for a in args
                 ))
 
-        for name in ("_decode", "_fork", "_draft_decode", "_verify",
-                     "_rollback", "_draft_rollback"):
-            if getattr(eng, name) is not None:
-                setattr(eng, name, OneHostArg(getattr(eng, name)))
-        for table in (eng._prefill, eng._chunk, eng._draft_prefill):
-            for bucket, compiled in table.items():
-                table[bucket] = OneHostArg(compiled)
+        for key, compiled in eng._programs.items():
+            eng._programs[key] = OneHostArg(compiled)
 
         def no_put(*_a, **_k):
             raise AssertionError("the host path called jax.device_put")
@@ -369,12 +364,14 @@ class TestKeysUnchanged:
         )
 
     def test_in_program_keys_equal_the_eager_folds(self):
+        from apex_tpu.serve import model as model_lib
+
         base = jax.random.PRNGKey(SAMPLE_SEED)
         streams = np.array([0, 1, 104, 0x7FFFFFFF, 2**32 - 1], np.uint32)
         gens = np.array([0, 7, 255, 1024, 2**31 - 1], np.int32)
-        got = np.asarray(jax.jit(spec_lib.slot_keys)(base, streams, gens))
+        got = np.asarray(jax.jit(model_lib.slot_keys)(base, streams, gens))
         stream_keys = np.asarray(
-            jax.jit(spec_lib.stream_keys)(base, streams)
+            jax.jit(model_lib.stream_keys)(base, streams)
         )
         for i, (s, g) in enumerate(zip(streams, gens)):
             sk = jax.random.fold_in(base, int(s))
