@@ -1,7 +1,7 @@
 """AOT-compiled inference engine — prefill/decode executables + cache.
 
-The engine owns the three device-side pieces of the serving stack and
-the proofs about them:
+The engine owns the device-side pieces of the serving stack and the
+proofs about them:
 
 - **step programs** — one prefill executable per bucket shape and ONE
   decode executable for the full slot array, compiled ahead of time
@@ -28,6 +28,11 @@ the proofs about them:
   optionally on the blockwise int8 KV wire, and optionally int8-packed
   weights (:func:`apex_tpu.serve.model.quantize_params`) dequantized
   inside the compiled step.
+- **weights** — :attr:`InferenceEngine.params` is the tree the caller
+  installed; the programs take its *step tree*
+  (:func:`apex_tpu.serve.model.step_params`: the block's matmul
+  weights and biases cast to the compute dtype once per installed tree,
+  not at the head of every program — docs/serving.md "Weights").
 - **failure surface** — every step program computes an in-step
   non-finite screen over its logits (:attr:`last_prefill_finite` /
   :attr:`last_decode_finite` — the scheduler's poisoned-request
@@ -355,11 +360,11 @@ def _rollback_step(eng, host, kv_pages, packed):
 
 
 def _target_args(eng):
-    return (eng.params, eng.cache, eng._rng_base)
+    return (eng.step_params, eng.cache, eng._rng_base)
 
 
 def _draft_args(eng):
-    return (eng.draft_params, eng.draft_cache, eng._rng_base)
+    return (eng.draft_step_params, eng.draft_cache, eng._rng_base)
 
 
 def _verify_args(eng):
@@ -462,9 +467,17 @@ class InferenceEngine:
         if cfg.hidden_size % cfg.num_heads:
             raise ValueError("num_heads must divide hidden_size")
         self.registry = registry
-        self.params = params
-        if self.serve.weight_wire == "int8":
-            self.params = model_lib.quantize_params(params)
+        #: how many step trees this engine has derived
+        #: (``serve/weights/casts``): one per installed weight tree
+        self.weight_casts = 0
+        self._draft_params = None
+        #: the trees the step programs take (docs/serving.md
+        #: "Weights"): :attr:`params` / :attr:`draft_params` with the
+        #: block's matmul weights and biases cast to the compute dtype
+        #: once, at install.  The draft's is the target's on a self-draft
+        #: engine.
+        self.step_params = self.draft_step_params = None
+        self.params = self._on_wire(params)
         self.pool = cache_lib.PagePool(
             self.serve.num_pages, self.serve.page_size
         )
@@ -480,7 +493,6 @@ class InferenceEngine:
         #: program
         self.spec = spec
         self._draft_cfg: Optional[GptConfig] = None
-        self.draft_params = None
         self.draft_cache = None
         #: speculative round counter — the ``serve.draft`` chaos index
         self.spec_rounds = 0
@@ -501,15 +513,8 @@ class InferenceEngine:
             if dcfg.hidden_size % dcfg.num_heads:
                 raise ValueError("draft num_heads must divide hidden_size")
             self._draft_cfg = dcfg
-            if spec.draft_params is None:
-                # self-draft: share the (possibly wire-packed) weights
-                self.draft_params = self.params
-            elif self.serve.weight_wire == "int8":
-                self.draft_params = model_lib.quantize_params(
-                    spec.draft_params
-                )
-            else:
-                self.draft_params = spec.draft_params
+            # None = self-draft: share the (possibly wire-packed) weights
+            self.update_draft_params(spec.draft_params)
             # the draft KV pool mirrors the target's page geometry so
             # ONE PagePool's page ids index both (draft pages ride the
             # "draft" namespace; only the per-page row shapes differ)
@@ -563,6 +568,57 @@ class InferenceEngine:
             cfg.hidden_size // cfg.num_heads,
             dtype=cfg.dtype, kv_wire=s.kv_wire,
         )
+
+    # -- weights ----------------------------------------------------------
+    def _on_wire(self, tree):
+        if self.serve.weight_wire == "int8":
+            return model_lib.quantize_params(tree)
+        return tree
+
+    def _install(self, cfg: GptConfig, tree):
+        """The step tree of a weight tree being installed: what every
+        program of :data:`_PROGRAMS` is traced, compiled and called on
+        (:func:`apex_tpu.serve.model.step_params`, docs/serving.md
+        "Weights").  Same avals whatever the values, so a swap keeps
+        every AOT executable valid."""
+        self.weight_casts += 1
+        return model_lib.step_params(cfg, tree)
+
+    @property
+    def params(self):
+        """The installed weight tree: the object the caller handed in
+        (wire-packed at construction under ``weight_wire="int8"``), so a
+        rollback can hand the incumbent back verbatim.  Assigning
+        installs a tree: it is stored as it is and
+        :attr:`step_params`, the tree the step programs take, is
+        derived from it once."""
+        return self._params
+
+    @params.setter
+    def params(self, tree) -> None:
+        self._params = tree
+        self.step_params = self._install(self.cfg, tree)
+        self._publish_weight_gauges()
+
+    @property
+    def draft_params(self):
+        """The draft model's installed weight tree (the target's own on
+        a self-draft engine); :meth:`update_draft_params` installs one,
+        :attr:`draft_step_params` is what the draft programs take."""
+        return self._draft_params
+
+    def _publish_weight_gauges(self) -> None:
+        installed = {id(leaf) for leaf in jax.tree_util.tree_leaves(
+            (self._params, self._draft_params)
+        )}
+        # id-keyed: a self-draft's aliased step tree counts once
+        derived = {
+            id(leaf): leaf.nbytes for leaf in jax.tree_util.tree_leaves(
+                (self.step_params, self.draft_step_params)
+            ) if id(leaf) not in installed
+        }
+        board.set("serve/weights/step_bytes", sum(derived.values()))
+        board.set("serve/weights/casts", self.weight_casts)
 
     # -- build ------------------------------------------------------------
     def _publish_build_gauges(self) -> None:
@@ -842,7 +898,7 @@ class InferenceEngine:
             ids[: len(page_ids)] = np.asarray(page_ids, np.int32)
             compiled = self._program("prefill", bucket)
             args = (
-                self.params, self.cache, self._rng_base,
+                self.step_params, self.cache, self._rng_base,
                 self._host_args("prefill", bucket).pack(
                     tokens, ids, n, self.prefill_calls, temperature
                 ),
@@ -897,7 +953,7 @@ class InferenceEngine:
             )
             compiled = self._program("chunk_prefill", bucket)
             args = (
-                self.params, self.cache, self._rng_base,
+                self.step_params, self.cache, self._rng_base,
                 self._host_args("chunk_prefill", bucket).pack(
                     tokens, ids, table, n, offset, self.prefill_calls,
                     temperature,
@@ -950,7 +1006,7 @@ class InferenceEngine:
                 streams = np.full((b,), self.decode_iters, np.uint32)
                 gens = np.arange(b, dtype=np.int32)
             args = (
-                self.params, self.cache, self._rng_base,
+                self.step_params, self.cache, self._rng_base,
                 self._host_args("decode").pack(
                     tokens, lengths, page_tables, self._temps(temps),
                     streams, gens,
@@ -1077,7 +1133,7 @@ class InferenceEngine:
         compiled = self._program("draft_prefill", bucket)
         name = f"draft_prefill_{bucket}"
         args = (
-            self.draft_params, self.draft_cache, self._rng_base,
+            self.draft_step_params, self.draft_cache, self._rng_base,
             self._host_args("draft_prefill", bucket).pack(
                 tokens, ids, n, self.draft_prefill_calls, 0.0
             ),
@@ -1118,7 +1174,7 @@ class InferenceEngine:
             # the host's half of that verdict (a serve.draft fault)
             temps = self._temps(temps)
             d_args = (
-                self.draft_params, self.draft_cache, self._rng_base,
+                self.draft_step_params, self.draft_cache, self._rng_base,
                 self._host_args("draft_decode").pack(
                     tokens, lengths, draft_tables, temps, streams, gens
                 ),
@@ -1129,7 +1185,7 @@ class InferenceEngine:
                 *d_args
             )
             v_args = (
-                self.params, self.cache, self._rng_base, d_tokens,
+                self.step_params, self.cache, self._rng_base, d_tokens,
                 d_probs, d_finite,
                 self._host_args("verify").pack(
                     tokens, lengths, page_tables, temps,
@@ -1195,8 +1251,12 @@ class InferenceEngine:
             raise ValueError("engine has no speculative config")
         if draft_params is None:
             if self.spec.draft_params is None:
-                self.draft_params = self.params
-        elif self.serve.weight_wire == "int8":
-            self.draft_params = model_lib.quantize_params(draft_params)
+                # the target's step tree too: nothing is cast twice
+                self._draft_params = self._params
+                self.draft_step_params = self.step_params
         else:
-            self.draft_params = draft_params
+            self._draft_params = self._on_wire(draft_params)
+            self.draft_step_params = self._install(
+                self._draft_cfg, self._draft_params
+            )
+        self._publish_weight_gauges()

@@ -29,10 +29,15 @@ engine validates).  **Weight wires**: :func:`quantize_params` /
 blockwise int8 code of ``parallel/comm.py`` (small leaves — biases, LN
 affines — stay exact, mirroring ``sync_gradients``'s ``min_size``
 rule); the engine dequantizes inside the compiled step, so the param
-HBM footprint is the wire footprint.
+HBM footprint is the wire footprint.  **The step tree**
+(:func:`step_params`) is what the engine hands the bodies: the block's
+matmul weights and biases in the compute dtype, cast once when a tree
+is installed.  The bodies take either tree, bit for bit.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +60,7 @@ __all__ = [
     "PackedWeight",
     "quantize_params",
     "dequantize_params",
+    "step_params",
     "stream_keys",
     "slot_keys",
     "sample_tokens",
@@ -162,6 +168,57 @@ def dequantize_params(params):
         lambda leaf: leaf.unpack() if _is_packed(leaf) else leaf,
         params, is_leaf=_is_packed,
     )
+
+
+#: where the leaves sit that the step tree holds in the compute dtype:
+#: the block's four matrices and their biases, which ``_linear`` reads
+#: through ``.astype(cfg.dtype)`` and no other way.  XLA hoists those
+#: casts out of the layer loop as passes of their own, so casting ahead
+#: leaves the rest of the program as it was, bit for bit.  NOT the
+#: token table and the learned positions, though ``_embed`` / ``_logits``
+#: / ``_embed_at`` read them the same way: XLA:TPU fuses their casts
+#: into the consumers (no pass, no temporary) and may keep the excess
+#: precision, so a table rounded ahead of the program is another
+#: computation there — on the chip it moved every GPT-2 Large logit, by
+#: up to 0.05 (PERF.md section 6, PR 34).  Nor LayerNorm's scales and
+#: biases: the fused LayerNorm reads them in f32.
+_COMPUTE_DTYPE_KEYS = frozenset(("qkv", "out", "fc1", "fc2"))
+
+
+@functools.partial(jax.jit, static_argnames="dtype")
+def _cast_leaves(leaves, dtype):
+    return [leaf.astype(dtype) for leaf in leaves]
+
+
+def step_params(cfg: GptConfig, params):
+    """The tree the step programs take — the *step tree* of ``params``:
+    the block's matmul weights and biases already in ``cfg.dtype``,
+    cast ONCE here (one compiled launch for all of them) where the
+    programs would cast the whole stack again at the head of every call
+    (docs/serving.md "Weights").  The conversion is the bodies' own
+    hoisted pass, so every logit is bit-identical on either tree.
+
+    What is not cast is the caller's array itself, not a copy: the
+    embedding tables and LayerNorm leaves (:data:`_COMPUTE_DTYPE_KEYS`
+    says why), ``PackedWeight`` leaves (the int8 wire stays packed; the
+    step dequantizes it), and any leaf already in ``cfg.dtype`` — an
+    f32-compute config gets its own tree back."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(
+        params, is_leaf=_is_packed
+    )
+    cast = [
+        i for i, (path, leaf) in enumerate(leaves)
+        if not _is_packed(leaf) and leaf.dtype != cfg.dtype
+        and _COMPUTE_DTYPE_KEYS.intersection(
+            getattr(key, "key", None) for key in path
+        )
+    ]
+    if not cast:
+        return params
+    out = [leaf for _, leaf in leaves]
+    for i, leaf in zip(cast, _cast_leaves([out[i] for i in cast], cfg.dtype)):
+        out[i] = leaf
+    return jax.tree_util.tree_unflatten(treedef, out)
 
 
 # ---------------------------------------------------------------------------
