@@ -33,6 +33,24 @@ fixed-size and fully owned by one sequence, so freeing a sequence
 returns its pages to the free list with zero compaction — occupancy is
 exactly ``live_pages / usable_pages`` at all times.
 
+**The cache set of a stack whose layers differ in kind**
+(:func:`init_hybrid_cache`, docs/serving.md "Layer kinds and the cache
+set") holds two more kinds of state beside — or instead of — K/V pages,
+all of it the layer loop's carry and donated like the pool:
+
+- a **latent page kind** — one row a token for every latent-attention
+  (MLA) layer, ``(L_mla, P, 1, page, W)``: the normalised latent and the
+  rotated shared key side by side, zero-padded to whole 128-lane tiles.
+  Pages, page tables, the null page and :class:`PagePool` are the ones
+  above; every query head reads the same row.
+- a **per-slot recurrent slab** — for every linear-attention (KDA) layer
+  the f32 state ``(L_kda, B, H, d_v, d_k)`` of each decode slot and the
+  short convolution's last inputs ``(L_kda, B, taps - 1, C)``.  It is not
+  positional: it cannot be paged, shared by a prefix, forked or rolled
+  back by page.  A slot's rows are written whole by the prefill that
+  admits a sequence into it (so a reused slot starts from that sequence's
+  own state, whatever it held) and advanced in place by every decode step.
+
 The device-side write helpers here are pure functions meant to be
 called INSIDE the engine's jitted step programs, on the WHOLE pool with
 a layer index: the programs carry the pool through their layer loop and
@@ -51,6 +69,7 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from apex_tpu.ops.mla import latent_row_width
 from apex_tpu.ops.paged_attention import heads_per_row
 from apex_tpu.parallel import comm
 
@@ -66,6 +85,10 @@ __all__ = [
     "append_rows",
     "write_prompt_kv",
     "append_token_kv",
+    "init_hybrid_cache",
+    "write_prompt_latent",
+    "append_token_latent",
+    "write_slot_state",
 ]
 
 #: page 0 — never allocated; the write-only garbage target for padded
@@ -580,3 +603,61 @@ def append_token_kv(kv, layer, page_ids, slots, k, v):
         name: append_rows(kv[name], layer, page_ids, slots, rows)
         for name, rows in _planes(kv, k, v).items()
     })
+
+
+# ---------------------------------------------------------------------------
+# the cache set of a hybrid stack: latent pages + per-slot recurrent slab
+# ---------------------------------------------------------------------------
+
+
+def init_hybrid_cache(cfg, num_pages: int, page_size: int,
+                      max_batch: int) -> dict:
+    """Fresh zeroed cache set for a :class:`~apex_tpu.models.hybrid.
+    HybridConfig`: ``"latent"`` ``(L_mla, P, 1, page, W)`` in the compute
+    dtype, ``"state"`` ``(L_kda, B, H, d_v, d_k)`` f32 and ``"conv"``
+    ``(L_kda, B, taps - 1, 3 H d)`` in the compute dtype.  A kind with no
+    layer has no entry."""
+    cache = {}
+    n_mla, n_kda = len(cfg.layers_of("mla")), len(cfg.layers_of("kda"))
+    if n_mla:
+        w = latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+        cache["latent"] = jnp.zeros(
+            (n_mla, num_pages, 1, page_size, w), cfg.dtype
+        )
+    if n_kda:
+        h, d = cfg.num_heads, cfg.head_dim
+        cache["state"] = jnp.zeros((n_kda, max_batch, h, d, d), jnp.float32)
+        cache["conv"] = jnp.zeros(
+            (n_kda, max_batch, cfg.conv_kernel - 1, 3 * h * d), cfg.dtype
+        )
+    return cache
+
+
+def write_prompt_latent(cache, layer, page_ids, rows):
+    """One MLA layer's prompt rows ``(S, W)`` into pages ``page_ids``."""
+    page_size = cache["latent"].shape[3]
+    return dict(cache, latent=write_pages(
+        cache["latent"], layer, page_ids,
+        pack_prompt_pages(rows[:, None, :], page_size),
+    ))
+
+
+def append_token_latent(cache, layer, page_ids, slots, rows):
+    """One token's latent row ``(B, W)`` per sequence at ``(page_ids[b],
+    slots[b])`` of MLA layer ``layer``."""
+    return dict(cache, latent=append_rows(
+        cache["latent"], layer, page_ids, slots, rows[:, None, :]
+    ))
+
+
+def write_slot_state(cache, layer, slot, state, conv_tail):
+    """A sequence's whole recurrent state into decode slot ``slot`` of KDA
+    layer ``layer``: ``state`` ``(H, d_v, d_k)``, ``conv_tail`` ``(taps -
+    1, C)``.  What the slot held before is gone."""
+    return dict(
+        cache,
+        state=cache["state"].at[layer, slot].set(state),
+        conv=cache["conv"].at[layer, slot].set(
+            conv_tail.astype(cache["conv"].dtype)
+        ),
+    )

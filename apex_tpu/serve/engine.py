@@ -113,6 +113,12 @@ class ServeConfig:
     #: vocab); per-request temperature rides the call (temp<=0 stays
     #: greedy/argmax, bit-identical to the pre-sampling engine)
     top_k: int = 0
+    #: decode iterations ONE decode program runs (a stack whose layers
+    #: differ in kind only): each slot emits up to this many tokens a call,
+    #: so the host's launch, transfers and bookkeeping are paid once a
+    #: block — an offline batch deployment's knob; 1 = a token a call.
+    #: Admission happens between blocks.
+    decode_block: int = 1
     #: PRNG seed for the fused sampler (one key per engine call,
     #: folded with the call index — deterministic replay)
     sample_seed: int = 0
@@ -131,6 +137,9 @@ class ServeConfig:
             raise ValueError(f"kv_wire must be f32|int8, got {self.kv_wire!r}")
         if self.top_k < 0:
             raise ValueError(f"top_k must be >= 0, got {self.top_k}")
+        if self.decode_block < 1:
+            raise ValueError(
+                f"decode_block must be >= 1, got {self.decode_block}")
         if self.weight_wire not in ("f32", "int8"):
             raise ValueError(
                 f"weight_wire must be f32|int8, got {self.weight_wire!r}"
@@ -228,40 +237,50 @@ def _prompt(s: ServeConfig, bucket: int):
     return (((bucket, 1), np.int32), ((bucket // s.page_size,), np.int32))
 
 
-def _prefill_fields(s, bucket):
-    # tokens, page ids, length, call index, temperature
-    return _prompt(s, bucket) + (_INT, _INT, _FLOAT)
+# ``fields(engine, bucket)``: a kind's host arguments, in order
 
 
-def _chunk_fields(s, bucket):
+def _prefill_fields(eng, bucket):
+    # tokens, page ids, length, call index, temperature — and, for a model
+    # with recurrent layers, the decode slot its state is left in
+    return _prompt(eng.serve, bucket) + (_INT, _INT, _FLOAT) + (
+        (_INT,) if eng.stateful else ()
+    )
+
+
+def _chunk_fields(eng, bucket):
     # tokens, chunk page ids, page table row, length, offset, call
     # index, temperature
+    s = eng.serve
     row = ((s.max_pages_per_seq,), np.int32)
     return _prompt(s, bucket) + (row, _INT, _INT, _INT, _FLOAT)
 
 
-def _decode_fields(s, bucket):
+def _decode_fields(eng, bucket):
     # tokens, lengths, page tables, temperatures, and the two integers
-    # each slot's sampling key is folded from
+    # each slot's sampling key is folded from — and, for a decode block,
+    # the iterations each slot runs
+    s = eng.serve
     return (
         _per_slot(s), _per_slot(s), _tables(s), _per_slot(s, np.float32),
         _per_slot(s, np.uint32), _per_slot(s),
-    )
+    ) + ((_per_slot(s),) if s.decode_block > 1 else ())
 
 
-def _verify_fields(s, bucket):
+def _verify_fields(eng, bucket):
     # decode's, with the host's verdict on the round's draft (0 under a
     # serve.draft fault) before the key integers
-    decode = _decode_fields(s, bucket)
+    decode = _decode_fields(eng, bucket)
     return decode[:4] + (_INT,) + decode[4:]
 
 
-def _rollback_fields(s, bucket):
+def _rollback_fields(eng, bucket):
     # starts, counts, page tables
+    s = eng.serve
     return (_per_slot(s), _per_slot(s), _tables(s))
 
 
-def _fork_fields(s, bucket):
+def _fork_fields(eng, bucket):
     # source page, destination page
     return (_INT, _INT)
 
@@ -275,11 +294,12 @@ def _prefill_step(eng, host, params, kv_pages, base_key, packed, *,
     """The target's and the draft's prefill: one body, two model
     configs.  The call's key is folded HERE from its index: the host
     folds nothing."""
-    tokens, page_ids, length, call, temp = host.unpack(packed)
+    tokens, page_ids, length, call, temp, *slot = host.unpack(packed)
     return model_lib.prefill_body(
         eng._draft_cfg if draft else eng.cfg, params, kv_pages, tokens,
         length, page_ids, temp, model_lib.fold_in(base_key, call),
         page_size=eng.serve.page_size, top_k=eng.serve.top_k,
+        **({"slot": slot[0]} if slot else {}),
     )
 
 
@@ -295,15 +315,21 @@ def _chunk_step(eng, host, params, kv_pages, base_key, packed):
 
 
 def _decode_step(eng, host, params, kv_pages, base_key, packed):
-    tokens, lengths, page_tables, temps, streams, gens = (
+    tokens, lengths, page_tables, temps, streams, gens, *steps = (
         host.unpack(packed)
     )
+    block = {}
+    if steps:
+        # a decode block: iteration j's keys are emission gens + j's
+        k = eng.serve.decode_block
+        gens = gens[None, :] + jnp.arange(k, dtype=gens.dtype)[:, None]
+        block = {"steps": steps[0], "block": k}
     # per-slot keys fold_in(fold_in(base, streams[b]), gens[b]), folded
     # HERE from the two integer vectors the host packs
     return model_lib.decode_body(
         eng.cfg, params, kv_pages, tokens, lengths, page_tables,
         temps, model_lib.slot_keys(base_key, streams, gens),
-        page_size=eng.serve.page_size, top_k=eng.serve.top_k,
+        page_size=eng.serve.page_size, top_k=eng.serve.top_k, **block,
     )
 
 
@@ -385,13 +411,14 @@ class _Program:
     shows (``jit_serve_decode``)."""
 
     kind: str
-    #: ``(serve, bucket) -> _HostArgs fields``: the host arguments
+    #: ``(engine, bucket) -> _HostArgs fields``: the host arguments
     fields: Callable
     #: the traced ``body(engine, host, *device_args, packed)``
     body: Callable
     #: ``engine -> device arguments`` (example = the live ones)
     device_args: Callable
-    #: which device argument is donated: the pool
+    #: which device argument is donated: the cache set (the K/V pool;
+    #: latent pages and the per-slot recurrent slab of a hybrid stack)
     donate: int = 1
     per_bucket: bool = False
     #: exists only on an engine with a SpecConfig
@@ -459,6 +486,33 @@ class InferenceEngine:
     ):
         self.cfg = model_lib.validate_config(cfg)
         self.serve = serve or ServeConfig()
+        #: the model's layer kinds (None: the homogeneous GPT stack), and
+        #: what they ask of the engine: a decode slot per prefill
+        #: (recurrent state), the MoE counts behind each token readback
+        self.kinds = model_lib.layer_kinds(cfg)
+        #: ``{kinds}`` on the engine spans of a model that has them
+        self._span_kinds = {} if self.kinds is None else {
+            "kinds": ",".join(sorted({k for pair in self.kinds for k in pair}))
+        }
+        self.stateful = model_lib.is_stateful(cfg)
+        self.routed = model_lib.is_routed(cfg)
+        model_lib.validate_features(cfg, spec=spec is not None)
+        if self.kinds is not None and (
+            self.serve.kv_wire != "f32" or self.serve.weight_wire != "f32"
+        ):
+            raise ValueError(
+                "the int8 KV and weight wires are the GPT stack's: a "
+                "hybrid stack's cache set and weights stay in their own "
+                "dtypes"
+            )
+        if self.serve.decode_block > 1 and self.kinds is None:
+            raise ValueError(
+                "decode_block > 1 is a hybrid stack's: the GPT stack's "
+                "decode program emits one token a call"
+            )
+        #: a routed model's last step's counts ``(pairs routed to held
+        #: experts, distinct held experts touched)``, summed over layers
+        self.last_moe_counts: Optional[np.ndarray] = None
         if self.serve.max_context > cfg.max_seq_len:
             raise ValueError(
                 f"max context {self.serve.max_context} exceeds the "
@@ -563,6 +617,10 @@ class InferenceEngine:
         (the draft's mirrors the target's, so ONE PagePool's page ids
         index both)."""
         s = self.serve
+        if model_lib.layer_kinds(cfg) is not None:
+            return cache_lib.init_hybrid_cache(
+                cfg, s.num_pages, s.page_size, s.max_batch
+            )
         return cache_lib.init_kv_pages(
             cfg.num_layers, s.num_pages, cfg.num_heads, s.page_size,
             cfg.hidden_size // cfg.num_heads,
@@ -641,7 +699,7 @@ class InferenceEngine:
         key = (kind, bucket)
         if key not in self._layouts:
             self._layouts[key] = _HostArgs(
-                *_PROGRAMS[kind].fields(self.serve, bucket)
+                *_PROGRAMS[kind].fields(self, bucket)
             )
         return self._layouts[key]
 
@@ -676,9 +734,12 @@ class InferenceEngine:
         strict = (
             jax.default_backend() == "tpu" and self.serve.kv_wire != "int8"
         )
+        # a hybrid stack's latent pool and recurrent slab are under the
+        # same rule; the convolution tails are not (a step rewrites a
+        # layer of them whole: 9 MB at the benchmark's shapes)
         return {
             "shapes": [
-                leaf.shape for leaf in jax.tree_util.tree_leaves(cache)
+                leaf.shape for name, leaf in cache.items() if name != "conv"
             ],
             "severity": None if strict else analysis.WARNING,
         }
@@ -878,14 +939,36 @@ class InferenceEngine:
             return np.zeros((self.serve.max_batch,), np.float32)
         return np.asarray(temps, np.float32)
 
-    def prefill(self, prompt_ids, page_ids, *,
-                temperature: float = 0.0) -> Tuple[np.ndarray, int]:
+    def _read_tokens(self, next_tokens) -> np.ndarray:
+        """The step's ONE token readback.  A routed model's carries its MoE
+        counts behind the tokens (:func:`apex_tpu.serve.model.
+        _with_counts`): they land on :attr:`last_moe_counts`."""
+        out = np.asarray(next_tokens)
+        if self.routed:
+            self.last_moe_counts = out[-2:]
+            out = out[:-2]
+        return out
+
+    def prefill(self, prompt_ids, page_ids, *, temperature: float = 0.0,
+                slot: Optional[int] = None, lazy: bool = False):
         """Run the prompt through the bucketed prefill: writes its K/V
         into ``page_ids`` (null-padded to the bucket's page count) and
         returns ``(last_logits (V,), first_token)``.  The first token
         is sampled in-step (``temperature<=0`` = greedy argmax); the
         in-step non-finite screen lands on
-        :attr:`last_prefill_finite`."""
+        :attr:`last_prefill_finite`.  ``slot`` is the decode slot a model
+        with recurrent layers leaves the prompt's state in (required
+        there, ignored elsewhere).
+
+        ``lazy=True`` dispatches the program and returns ``(logits,
+        pending)`` without reading anything back: :meth:`resolve_prefill`
+        reads the token (and waits for the device) later, so that several
+        prefills queue on the device back to back while the host stages
+        the next (the scheduler's admissions under a decode block)."""
+        if self.stateful and slot is None:
+            raise ValueError(
+                "a model with recurrent layers prefills into a decode slot"
+            )
         poison = self._chaos_gate(chaos.SERVE_PREFILL, self.prefill_calls)
         n = len(prompt_ids)
         bucket = self.bucket_for(n)
@@ -900,23 +983,39 @@ class InferenceEngine:
             args = (
                 self.step_params, self.cache, self._rng_base,
                 self._host_args("prefill", bucket).pack(
-                    tokens, ids, n, self.prefill_calls, temperature
+                    tokens, ids, n, self.prefill_calls, temperature,
+                    *((slot,) if self.stateful else ()),
                 ),
             )
             self._sentinels[name].observe(*args)
+            if lazy:
+                logits, next_token, finite, self.cache = compiled(*args)
         self.prefill_calls += 1
+        span = dict(bucket=bucket, tokens=n, call=self.prefill_calls,
+                    **self._span_kinds)
+        if lazy:
+            return logits, (next_token, finite, poison, span)
         # int(next_token) syncs, so the phase covers the real device
         # time, not just the async dispatch
-        with self._phase("engine/prefill", bucket=bucket, tokens=n,
-                         call=self.prefill_calls):
+        with self._phase("engine/prefill", **span):
             logits, next_token, finite, self.cache = compiled(*args)
             # logits stay ON DEVICE (lazy jax.Array): only the sampled
             # token and the scalar finite screen cross to the host —
             # the logits matrix is (V,)/(B, V) and most callers never
             # read it
-            first = int(next_token)
+            first = int(self._read_tokens(next_token).reshape(-1)[0])
             self.last_prefill_finite = bool(finite) and poison is None
         return logits, first
+
+    def resolve_prefill(self, pending) -> int:
+        """Read a ``lazy`` :meth:`prefill`'s first token (the wait for the
+        device is this ``engine/prefill`` phase); the non-finite screen and
+        a routed model's counts land where a plain prefill leaves them."""
+        next_token, finite, poison, span = pending
+        with self._phase("engine/prefill", **span):
+            first = int(self._read_tokens(next_token).reshape(-1)[0])
+            self.last_prefill_finite = bool(finite) and poison is None
+        return first
 
     def chunk_prefill(self, chunk_ids, offset, page_table_row,
                       chunk_page_ids, *,
@@ -980,7 +1079,7 @@ class InferenceEngine:
         self.cache = compiled(*args)
 
     def decode(self, tokens, lengths, page_tables, temps=None, *,
-               streams=None, gens=None):
+               streams=None, gens=None, steps=None):
         """One decode iteration over the full slot array.  ``lengths``
         counts each slot's context INCLUDING the token being fed (0 =
         idle slot).  Returns ``(logits (B, V), next_tokens (B,))`` —
@@ -997,7 +1096,14 @@ class InferenceEngine:
         paths bit-identical.  None keeps the legacy per-iteration key
         chain, ``fold_in(fold_in(base, iteration), slot)``: the same
         two folds of the same program, fed ``(iteration, slot index)``
-        in place of ``(stream seed, emission index)``."""
+        in place of ``(stream seed, emission index)``.
+
+        With ``ServeConfig.decode_block = k > 1`` the program runs ``k``
+        iterations: ``steps`` ``(B,)`` says how many each slot runs (None:
+        ``k`` for every live slot), ``next_tokens`` comes back ``(k, B)``
+        (row ``j`` is iteration ``j``'s token; a slot's rows from its
+        ``steps`` on repeat its last) and ``logits`` is the last
+        iteration's."""
         poison = self._chaos_gate(chaos.SERVE_DECODE, self.decode_iters)
         with self._phase("engine/stage", program="decode"):
             compiled = self._program("decode")
@@ -1005,20 +1111,26 @@ class InferenceEngine:
                 b = self.serve.max_batch
                 streams = np.full((b,), self.decode_iters, np.uint32)
                 gens = np.arange(b, dtype=np.int32)
+            block = self.serve.decode_block
+            if block > 1 and steps is None:
+                steps = np.where(np.asarray(lengths) > 0, block, 0)
             args = (
                 self.step_params, self.cache, self._rng_base,
                 self._host_args("decode").pack(
                     tokens, lengths, page_tables, self._temps(temps),
-                    streams, gens,
+                    streams, gens, *((steps,) if block > 1 else ()),
                 ),
             )
             self._sentinels["decode"].observe(*args)
         self.decode_iters += 1
         # np.asarray(next_tokens) syncs — real device time
         with self._phase("engine/decode", iter=self.decode_iters,
-                         batch=int((np.asarray(lengths) > 0).sum())):
+                         batch=int((np.asarray(lengths) > 0).sum()),
+                         **self._span_kinds):
             logits, next_tokens, finite, self.cache = compiled(*args)
-            out = np.asarray(next_tokens)
+            out = self._read_tokens(next_tokens)
+            if block > 1:
+                out = out.reshape(block, -1)
             finite_np = np.array(finite)
         if poison is not None:
             # an injected poisoned-logits fault: flag the first LIVE
@@ -1090,7 +1202,8 @@ class InferenceEngine:
             # through the full page-table row below
             prompt_pages = page_ids[: -(-n // self.serve.page_size)]
             logits, first = self.prefill(
-                prompt_ids, prompt_pages, temperature=0.0
+                prompt_ids, prompt_pages, temperature=0.0,
+                slot=0 if self.stateful else None,
             )
             logits_bytes = np.asarray(logits, np.float32).tobytes()
             finite = bool(self.last_prefill_finite)
@@ -1106,11 +1219,15 @@ class InferenceEngine:
                 lengths = np.zeros((b,), np.int32)
                 tok[0] = tokens[-1]
                 lengths[0] = n + i + 1  # ctx incl. the fed token
-                _, next_tokens = self.decode(tok, lengths, table)
+                _, next_tokens = self.decode(
+                    tok, lengths, table,
+                    **({"steps": (lengths > 0).astype(np.int32)}
+                       if self.serve.decode_block > 1 else {}),
+                )
                 finite = finite and bool(
                     np.asarray(self.last_decode_finite)[0]
                 )
-                tokens.append(int(next_tokens[0]))
+                tokens.append(int(np.asarray(next_tokens).reshape(-1)[0]))
         finally:
             self.pool.free(page_ids)
         return tokens, logits_bytes, finite

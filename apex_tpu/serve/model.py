@@ -1,4 +1,4 @@
-"""Functional GPT forward for serving — prefill and decode bodies.
+"""Functional forward for serving — prefill and decode bodies.
 
 The training stack's :class:`apex_tpu.models.gpt.GptModel` is a flax
 module built for ``value_and_grad`` over a full sequence; serving needs
@@ -23,8 +23,25 @@ forward:
   — ``tests/test_serve.py`` pins prefill/decode logits against
   ``GptModel.apply`` itself.
 
-Serving scope: dense blocks, single model shard (no SP/CP/MoE — the
-engine validates).  **Weight wires**: :func:`quantize_params` /
+**Layer kinds.**  A block is a *mixer* and an *FFN*, each of a kind
+(:data:`_MIXERS`, :data:`_FFNS`): the GPT stack above is ``("mha",
+"gelu")`` throughout and keeps its ``lax.scan``; a
+:class:`~apex_tpu.models.hybrid.HybridConfig` names a kind per layer —
+``"kda"`` (linear attention with a per-slot recurrent state) or ``"mla"``
+(latent attention over latent pages), ``"dense"`` (SwiGLU) or ``"moe"``
+(a dropless routed layer told which experts it holds, plus a shared
+expert) — over RMSNorm and an untied head, and runs as a Python loop over
+its layers through the same :func:`_block`.  Its two dataflows are the
+prompt (:class:`_Flow` with ``prompt=True``) and the decode step; the
+cache set they carry is :func:`apex_tpu.serve.cache.init_hybrid_cache`'s
+(docs/serving.md "Layer kinds and the cache set").
+
+Serving scope: a single model shard (no SP/CP).  What a kind cannot run is
+refused by name (:func:`validate_config`, :func:`validate_features`): a
+model with recurrent layers has no prefix cache, copy-on-write fork,
+chunked prefill or speculative program (each needs a snapshot of the
+per-slot state); the routed FFN runs one chip's share only; training runs
+none of these kinds.  **Weight wires**: :func:`quantize_params` /
 :func:`dequantize_params` put the large parameter leaves on the
 blockwise int8 code of ``parallel/comm.py`` (small leaves — biases, LN
 affines — stay exact, mirroring ``sync_gradients``'s ``min_size``
@@ -44,8 +61,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.models.gpt import GptConfig, _rope_cos_sin
+from apex_tpu.ops import kda as kda_ops
 from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.layer_norm import fused_layer_norm_affine
+from apex_tpu.ops.mla import latent_row_width, mla_decode_attention
 from apex_tpu.ops.paged_attention import (
     gather_history,
     paged_decode_attention,
@@ -53,9 +72,14 @@ from apex_tpu.ops.paged_attention import (
 from apex_tpu.ops.rope import fused_apply_rotary_pos_emb_cached, rotate_half
 from apex_tpu.parallel import comm
 from apex_tpu.serve import cache as cache_lib
+from apex_tpu.transformer.moe import dropless_moe, route_group_limited
 
 __all__ = [
     "validate_config",
+    "validate_features",
+    "layer_kinds",
+    "is_stateful",
+    "is_routed",
     "rope_tables",
     "PackedWeight",
     "quantize_params",
@@ -74,16 +98,76 @@ __all__ = [
 WEIGHT_WIRE_MIN_SIZE = 1024
 
 
-def validate_config(cfg: GptConfig) -> GptConfig:
-    """Serving supports the dense single-shard GPT stack."""
+def layer_kinds(cfg):
+    """``((mixer, ffn), ...)`` of a stack whose layers differ in kind, None
+    for the homogeneous GPT stack (``("mha", "gelu")`` throughout)."""
+    return getattr(cfg, "kinds", None)
+
+
+def is_stateful(cfg) -> bool:
+    """Some layer keeps a per-slot recurrent state."""
+    return bool(getattr(cfg, "stateful", False))
+
+
+def is_routed(cfg) -> bool:
+    """Some layer is a routed FFN (its programs return the MoE counts)."""
+    return bool(getattr(cfg, "routed", False))
+
+
+#: what serving refuses, for which kind of model, and why
+_REFUSED = {
+    "prefix_cache": (
+        "prefix cache (and its copy-on-write fork): a cached page run "
+        "holds no recurrent state, and a borrower's slot would start from "
+        "zeros — needs per-prefix state snapshots"
+    ),
+    "chunked_prefill": (
+        "chunked prefill: a chunk would have to resume the slot's "
+        "recurrent state and convolution tail mid-prompt"
+    ),
+    "spec": (
+        "speculative programs (draft / verify / rollback): a rejected "
+        "position cannot be rolled back out of a recurrent state"
+    ),
+}
+
+
+def validate_config(cfg):
+    """What serving runs, by kind of model; what it refuses, by name:
+
+    - any model: sequence or context parallelism (the engine owns the
+      whole sequence on one shard);
+    - the GPT stack (:class:`GptConfig`): its flax ``SwitchMoe`` FFN — the
+      capacity-dropping path is a training path; a routed FFN is served
+      through :class:`~apex_tpu.models.hybrid.HybridConfig`'s dropless
+      ``"moe"`` kind;
+    - a model with recurrent layers: :func:`validate_features`."""
     if cfg.sequence_parallel or cfg.context_parallel:
         raise ValueError(
             "serving requires sequence_parallel=False and "
             "context_parallel=None (the engine owns the whole sequence)"
         )
-    if cfg.num_experts:
-        raise ValueError("MoE serving is not supported yet")
+    if layer_kinds(cfg) is None and cfg.num_experts:
+        raise ValueError(
+            "serving does not run GptConfig's capacity-dropping SwitchMoe "
+            "FFN (num_experts > 0); describe the model as a HybridConfig, "
+            "whose routed FFN is the dropless held-experts layer"
+        )
     return cfg
+
+
+def validate_features(cfg, **asked) -> None:
+    """Refuse, by name, each serving mechanism in ``asked``
+    (``prefix_cache``, ``chunked_prefill``, ``spec``: truthy = wanted)
+    that a model with recurrent layers cannot run."""
+    if not is_stateful(cfg):
+        return
+    for name, wanted in asked.items():
+        if wanted:
+            raise ValueError(
+                f"a model with recurrent (KDA) layers does not run the "
+                f"{_REFUSED[name]} (ROADMAP M5)"
+            )
 
 
 def _head_dim(cfg: GptConfig) -> int:
@@ -431,24 +515,46 @@ def _embed_at(cfg: GptConfig, tree, tokens, positions):
     return x + rows.astype(cfg.dtype), None
 
 
-def _block(cfg: GptConfig, lp, x, kv, layer, attend):
-    """One pre-LN decoder block over ``x`` ``(*rows, hidden)`` — THE
-    block: every step body applies a layer through this function and no
-    other way (``tests/test_serve.py`` pins it).  Returns the new
-    hidden and the pool ``attend`` handed back."""
+def _mha_mixer(cfg, lp, x, kv, layer, attend):
+    """The GPT mixer: LayerNorm, fused QKV, the dataflow's ``attend``,
+    the output projection."""
     y = _layer_norm(x, lp["ln_attn"], cfg.layer_norm_eps)
     qkv = _linear(y, lp["qkv"], cfg.dtype).reshape(
         *x.shape[:-1], cfg.num_heads, 3, _head_dim(cfg)
     )
     ctx, kv = attend(kv, layer, *(qkv[..., i, :] for i in range(3)))
-    x = x + _linear(ctx.reshape(x.shape), lp["out"], cfg.dtype)
-    return _mlp(x, lp, cfg), kv
+    return x + _linear(ctx.reshape(x.shape), lp["out"], cfg.dtype), kv
 
 
-def _layers(cfg: GptConfig, tree, x, kv_pages, attend):
+#: a mixer ``(cfg, lp, x, kv, layer, flow) -> (x + mixed, kv)`` and an FFN
+#: ``(cfg, lp, x, flow) -> x + ffn`` per kind; ``flow`` is the dataflow:
+#: the ``attend`` closure for ``"mha"``, a :class:`_Flow` for the rest
+_MIXERS = {"mha": _mha_mixer}
+_FFNS = {"gelu": lambda cfg, lp, x, flow: _mlp(x, lp, cfg)}
+
+
+def _block(cfg, lp, x, kv, layer, attend):
+    """One pre-norm decoder block over ``x`` ``(*rows, hidden)`` — THE
+    block: every step body applies a layer through this function and no
+    other way (``tests/test_serve.py`` pins it), whatever the layer's
+    kinds.  Returns the new hidden and the cache set the mixer handed
+    back."""
+    kinds = layer_kinds(cfg)
+    mixer, ffn = kinds[layer] if kinds else ("mha", "gelu")
+    x, kv = _MIXERS[mixer](cfg, lp, x, kv, layer, attend)
+    return _FFNS[ffn](cfg, lp, x, attend), kv
+
+
+def _layers(cfg, tree, x, kv_pages, attend):
     """The layer loop.  The pool is the loop's CARRY (indexed by
     layer), never its xs/ys: a scanned-over pool is sliced and
-    restacked every layer (docs/serving.md "The KV pool")."""
+    restacked every layer (docs/serving.md "The KV pool").  A stack
+    whose layers differ in kind is a Python loop over its layers."""
+    if layer_kinds(cfg) is not None:
+        kv_pages = dict(kv_pages)
+        for l, lp in enumerate(tree["layers"]):
+            x, kv_pages = _block(cfg, lp, x, kv_pages, l, attend)
+        return x, kv_pages
 
     def layer(carry, xs):
         lp, l = xs
@@ -508,6 +614,296 @@ def _write_prompt(kv, layer, page_ids, k, v):
 
 
 # ---------------------------------------------------------------------------
+# the kinds of a hybrid stack: KDA and MLA mixers, SwiGLU and routed FFNs
+# ---------------------------------------------------------------------------
+#
+# A hybrid step works on rows ``x (T, hidden)``: the ``T`` positions of one
+# prompt (``flow.prompt``) or one token of each of ``T`` decode slots.
+# Weights are read in the compute dtype with f32 accumulation; RMSNorm
+# statistics, the router, every gate and the whole KDA recurrence are f32.
+
+
+class _Flow:
+    """The dataflow of one hybrid step — what a mixer needs beyond its
+    rows.  Prompt: ``length`` live rows from position 0, ``page_ids`` the
+    prompt's pages, ``slot`` the decode slot whose state it will leave.
+    Decode: ``lengths`` per slot (0 = idle), ``page_tables``.  ``live``
+    ``(T,)`` marks the real rows: padding and idle rows leave every state
+    as it was and reach no expert.  ``stats`` collects each routed layer's
+    counts (the layer loop is a Python loop)."""
+
+    def __init__(self, *, prompt, live, positions, page_size, length=None,
+                 page_ids=None, slot=None, lengths=None, page_tables=None):
+        self.prompt, self.live, self.positions = prompt, live, positions
+        self.length, self.page_ids, self.slot = length, page_ids, slot
+        self.lengths, self.page_tables = lengths, page_tables
+        self.page_size = page_size
+        self.stats = []
+
+
+def _rms_norm(x, p, eps):
+    """RMSNorm with f32 statistics; f32 out (callers cast)."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return xf * jax.lax.rsqrt(ms + eps) * p["scale"]
+
+
+def _matmul(x, w, dtype):
+    """``x @ w`` in the compute dtype, f32 out."""
+    return jnp.matmul(
+        x.astype(dtype), w.astype(dtype), preferred_element_type=jnp.float32
+    )
+
+
+def _swiglu(y, p, dtype):
+    h = jax.nn.silu(_matmul(y, p["gate"]["weight"], dtype)) * _matmul(
+        y, p["up"]["weight"], dtype)
+    return _matmul(h, p["down"]["weight"], dtype)
+
+
+def _rope_partial(x, positions, theta):
+    """Rotate-half RoPE at ``positions`` ``(T,)`` over the last axis of
+    ``x`` ``(T, ..., R)``, f32."""
+    r = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
+    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (r,)
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1).reshape(shape)
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1).reshape(shape)
+    xf = x.astype(jnp.float32)
+    return xf * cos + rotate_half(xf) * sin
+
+
+def _kda_mixer(cfg, lp, x, kv, layer, flow):
+    """Kimi Delta Attention: q, k, v through a short causal convolution and
+    SiLU, q and k L2-normalised, one decay per key channel, a delta-rule
+    state per head (:mod:`apex_tpu.ops.kda`), RMSNorm and a sigmoid gate
+    per head on the way out."""
+    p, dtype = lp["kda"], cfg.dtype
+    n, d, taps = cfg.num_heads, cfg.head_dim, cfg.conv_kernel
+    li = cfg.layers_of("kda").index(layer)
+    t = x.shape[0]
+    y = _rms_norm(x, lp["norm_mixer"], cfg.rms_eps).astype(dtype)
+    proj = _matmul(y, p["qkvg"]["weight"], dtype)
+    pre = proj[:, : 3 * n * d].astype(dtype)      # what the conv tail keeps
+    live = flow.live[:, None]
+    g = jnp.where(live, cfg.kda_lower_bound * jax.nn.sigmoid(
+        proj[:, 3 * n * d:] + p["g_bias"]), 0.0).reshape(t, n, d)
+    beta = jnp.where(
+        live, jax.nn.sigmoid(_matmul(y, p["beta"]["weight"], dtype)), 0.0)
+    gate = jax.nn.sigmoid(_matmul(y, p["ogate"]["weight"], dtype))
+    if flow.prompt:
+        # rows before the prompt's start are zeros; the tail a decode step
+        # will need is the last taps-1 inputs AT THE TRUE LENGTH
+        window = jnp.concatenate(
+            [jnp.zeros((taps - 1, pre.shape[1]), dtype), pre], axis=0)
+        mixed = sum(p["conv"][i] * window[i:i + t] for i in range(taps))
+        tail = jax.lax.dynamic_slice_in_dim(window, flow.length, taps - 1, 0)
+    else:
+        window = jnp.concatenate([kv["conv"][li], pre[:, None]], axis=1)
+        mixed = sum(p["conv"][i] * window[:, i] for i in range(taps))
+        # an idle row keeps its tail (a slot past its steps of a decode
+        # block may go on in the next one)
+        kv = dict(kv, conv=kv["conv"].at[li].set(
+            jnp.where(live[:, :, None], window[:, 1:], window[:, :-1])))
+    q, k, v = jnp.split(jax.nn.silu(mixed).reshape(t, 3 * n, d), 3, axis=1)
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) * d**-0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    if flow.prompt:
+        o, state = kda_ops.kda_chunked(q, k, v, g, beta)
+        kv = cache_lib.write_slot_state(kv, li, flow.slot, state, tail)
+    else:
+        o, state = kda_ops.kda_step(kv["state"], li, q, k, v, g, beta)
+        kv = dict(kv, state=state)
+    o = _rms_norm(o, p["o_norm"], cfg.rms_eps) * gate[:, :, None]
+    out = _matmul(o.reshape(t, n * d), p["out"]["weight"], dtype)
+    return x + out.astype(dtype), kv
+
+
+def _mla_mixer(cfg, lp, x, kv, layer, flow):
+    """Multi-head latent attention.  The cache holds one row a token, ``[c
+    | k_r | 0]``: the normalised latent and the rotated key all heads
+    share.  A prompt attends un-absorbed over its own rows; a decode step
+    attends in absorbed form over the latent pages
+    (:mod:`apex_tpu.ops.mla`)."""
+    p, dtype = lp["mla"], cfg.dtype
+    n, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    li = cfg.layers_of("mla").index(layer)
+    t = x.shape[0]
+    scale = (dn + dr) ** -0.5
+    y = _rms_norm(x, lp["norm_mixer"], cfg.rms_eps).astype(dtype)
+    q = _matmul(y, p["q"]["weight"], dtype).reshape(t, n, dn + dr)
+    a = _matmul(y, p["kv_a"]["weight"], dtype)
+    c = _rms_norm(a[:, :r], p["kv_norm"], cfg.rms_eps).astype(dtype)
+    k_r = _rope_partial(a[:, r:], flow.positions, cfg.rope_theta)
+    q_r = _rope_partial(q[..., dn:], flow.positions, cfg.rope_theta)
+    gate = jax.nn.sigmoid(_matmul(y, p["ogate"]["weight"], dtype))
+    w = latent_row_width(r, dr)
+    row = jnp.concatenate(
+        [c, k_r.astype(dtype), jnp.zeros((t, w - r - dr), dtype)], axis=-1)
+    w_b = p["kv_b"]["weight"].reshape(r, n, dn + dv)
+    f32 = dict(preferred_element_type=jnp.float32)
+    if flow.prompt:
+        kv = cache_lib.write_prompt_latent(kv, li, flow.page_ids, row)
+        with jax.named_scope("mla_prefill_attention"):
+            kvb = _matmul(c, p["kv_b"]["weight"], dtype).reshape(
+                t, n, dn + dv).astype(dtype)
+            s = jnp.einsum(
+                "qnd,knd->nqk", q[..., :dn].astype(dtype), kvb[..., :dn],
+                **f32,
+            ) + jnp.einsum(
+                "qnd,kd->nqk", q_r.astype(dtype), k_r.astype(dtype), **f32)
+            idx = jnp.arange(t)
+            s = jnp.where(idx[:, None] >= idx[None, :], s * scale, -jnp.inf)
+            o = jnp.einsum(
+                "nqk,knd->qnd", jax.nn.softmax(s, axis=-1).astype(dtype),
+                kvb[..., dn:], **f32)
+    else:
+        pos = flow.positions
+        # an idle row (a free slot, or one past its steps of a decode
+        # block) writes into the null page, whatever its table holds
+        pages = jnp.where(
+            flow.live, flow.page_tables[jnp.arange(t), pos // flow.page_size],
+            cache_lib.NULL_PAGE,
+        )
+        kv = cache_lib.append_token_latent(
+            kv, li, pages, pos % flow.page_size, row)
+        q_abs = jnp.einsum(
+            "bnd,rnd->bnr", q[..., :dn].astype(dtype),
+            w_b[..., :dn].astype(dtype), **f32)
+        q_row = jnp.concatenate(
+            [q_abs, q_r, jnp.zeros((t, n, w - r - dr), jnp.float32)], -1)
+        ctx = mla_decode_attention(
+            q_row, kv["latent"], flow.page_tables, flow.lengths,
+            layer=li, scale=scale,
+        )
+        o = jnp.einsum(
+            "bnr,rnd->bnd", ctx[..., :r].astype(dtype),
+            w_b[..., dn:].astype(dtype), **f32)
+    out = _matmul(
+        (o * gate[:, :, None]).reshape(t, n * dv), p["out"]["weight"], dtype)
+    return x + out.astype(dtype), kv
+
+
+def _dense_ffn(cfg, lp, x, flow):
+    y = _rms_norm(x, lp["norm_ffn"], cfg.rms_eps).astype(cfg.dtype)
+    return x + _swiglu(y, lp["mlp"], cfg.dtype).astype(cfg.dtype)
+
+
+def _moe_ffn(cfg, lp, x, flow):
+    """This chip's share of the routed layer plus the shared expert: route
+    over all the layer's experts (f32, on the un-rounded norm output), add
+    the held experts' terms only."""
+    p = lp["moe"]
+    y32 = _rms_norm(x, lp["norm_ffn"], cfg.rms_eps)
+    idx, weights = route_group_limited(
+        y32, p["router"]["weight"], p["expert_bias"], top_k=cfg.top_k,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
+        scale=cfg.routed_scaling_factor,
+    )
+    y = y32.astype(cfg.dtype)
+    out, stats = dropless_moe(
+        y, idx, weights, p["experts"], held=cfg.held_experts, live=flow.live)
+    flow.stats.append(stats)
+    out = out + _swiglu(y, p["shared"], cfg.dtype)
+    return x + out.astype(cfg.dtype)
+
+
+_MIXERS.update(kda=_kda_mixer, mla=_mla_mixer)
+_FFNS.update(dense=_dense_ffn, moe=_moe_ffn)
+
+
+def _hybrid_logits(cfg, tree, h):
+    """Final RMSNorm and the untied head over the vocabulary slice held."""
+    h = _rms_norm(h, tree["norm_f"], cfg.rms_eps)
+    return _matmul(h, tree["lm_head"]["weight"], cfg.dtype)
+
+
+def _with_counts(cfg, flow, out):
+    """A routed model's step hands its MoE counts (pairs routed to held
+    experts, distinct held experts touched, summed over layers) back INSIDE
+    the token readback: two more int32 behind the sampled token(s)."""
+    logits, tokens, finite = out
+    if is_routed(cfg):
+        tokens = jnp.concatenate(
+            [jnp.reshape(tokens, (-1,)), sum(flow.stats)])
+    return logits, tokens, finite
+
+
+def _hybrid_prefill(cfg, params, cache, tokens, length, page_ids, slot,
+                    temp, rng, page_size, top_k):
+    tree = _tree(params)
+    s = tokens.shape[0]
+    x = _embed(tree["word_embeddings"], tokens[:, 0], cfg.dtype)
+    flow = _Flow(
+        prompt=True, live=jnp.arange(s) < length, positions=jnp.arange(s),
+        page_size=page_size, length=length, page_ids=page_ids, slot=slot,
+    )
+    x, cache = _layers(cfg, tree, x, cache, flow)
+    h_last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(length - 1, 0), 1, 0)
+    out = _sample_tail(_hybrid_logits(cfg, tree, h_last)[0], temp, rng, top_k)
+    return *_with_counts(cfg, flow, out), cache
+
+
+def _hybrid_decode(cfg, tree, cache, tokens, lengths, page_tables,
+                   page_size):
+    x = _embed(tree["word_embeddings"], tokens, cfg.dtype)
+    flow = _Flow(
+        prompt=False, live=lengths > 0, positions=jnp.maximum(lengths - 1, 0),
+        page_size=page_size, lengths=lengths, page_tables=page_tables,
+    )
+    x, cache = _layers(cfg, tree, x, cache, flow)
+    return _hybrid_logits(cfg, tree, x), cache, flow
+
+
+def _hybrid_decode_block(cfg, tree, cache, tokens, lengths, page_tables,
+                         temps, rng, steps, page_size, top_k, block):
+    """``block`` decode iterations in ONE program: iteration ``j`` feeds
+    slot ``b`` the token iteration ``j - 1`` sampled for it, at context
+    ``lengths[b] + j``, while ``j < steps[b]``; past its ``steps`` a slot is
+    idle (no state change, no page written, no expert reached).  The host
+    then pays its launch, its transfers and its bookkeeping once a block
+    (docs/serving.md "Layer kinds and the cache set").  ``rng`` is ``(block,
+    B, 2)`` keys (or None: argmax).  Returns ``(last logits (B, V), tokens
+    (block * B,) [+ the MoE counts summed over the block], finite (B,),
+    cache)``; ``block = 1`` is the plain single step."""
+    counts0 = jnp.zeros((2,), jnp.int32)
+
+    def one(cache, tokens, j, key):
+        live = j < steps
+        logits, cache, flow = _hybrid_decode(
+            cfg, tree, cache, tokens, jnp.where(live, lengths + j, 0),
+            page_tables, page_size,
+        )
+        logits, nxt, finite = _sample_tail(logits, temps, key, top_k)
+        counts = sum(flow.stats) if flow.stats else counts0
+        return logits, cache, jnp.where(live, nxt, tokens), finite | ~live, \
+            counts
+
+    if block == 1:
+        logits, cache, out, finite, counts = one(cache, tokens, 0, rng)
+    else:
+        def body(carry, xs):
+            cache, tokens, _, finite, counts = carry
+            j, key = xs
+            logits, cache, tokens, fin, c = one(cache, tokens, j, key)
+            return (cache, tokens, logits, finite & fin, counts + c), tokens
+
+        b = tokens.shape[0]
+        (cache, _, logits, finite, counts), out = jax.lax.scan(
+            body,
+            (cache, tokens, jnp.zeros((b, cfg.vocab_size), jnp.float32),
+             jnp.ones((b,), jnp.bool_), counts0),
+            (jnp.arange(block), rng),
+        )
+        out = out.reshape(-1)
+    if is_routed(cfg):
+        out = jnp.concatenate([out, counts])
+    return logits, out, finite, cache
+
+
+# ---------------------------------------------------------------------------
 # prefill: full-sequence forward that also yields per-position K/V
 # ---------------------------------------------------------------------------
 
@@ -525,6 +921,7 @@ def prefill_body(
     page_size: int,
     kv_wire: str = "f32",
     top_k: int = 0,
+    slot=None,       # ()    int32 — the decode slot (recurrent state only)
 ):
     """Full prefill: forward the (padded) prompt, write every layer's
     K/V into the assigned pages, and return the last live position's
@@ -541,7 +938,17 @@ def prefill_body(
     ``page_size`` and ``kv_wire`` restate what the pool's shape and
     planes say (the writes read them there); they stay for callers that
     pass them (``benchmark/rehearse_compile.py``).
+
+    A stack whose layers differ in kind (:func:`layer_kinds`) takes the
+    same arguments plus ``slot``: its prompt leaves the sequence's recurrent
+    state in that decode slot, padding rows excluded, and a routed model's
+    ``next_token`` carries the MoE counts behind it (:func:`_with_counts`).
     """
+    if layer_kinds(cfg) is not None:
+        return _hybrid_prefill(
+            cfg, params, kv_pages, tokens, length, page_ids, slot, temp,
+            rng, page_size, top_k,
+        )
     del page_size, kv_wire
     tree = _tree(dequantize_params(params))
     x, rope = _embed_at(cfg, tree, tokens, range(tokens.shape[0]))
@@ -713,6 +1120,8 @@ def decode_body(
     page_size: int,
     kv_wire: str = "f32",
     top_k: int = 0,
+    steps=None,   # (B,) int32 — iterations each slot runs (hybrid blocks)
+    block: int = 1,
 ):
     """One continuous-batching decode iteration over the full slot
     array (:func:`_decode_step` plus the fused sampling tail).  Per
@@ -731,6 +1140,15 @@ def decode_body(
     callers that pass it (``benchmark/rehearse_compile.py``).
     """
     del kv_wire
+    if layer_kinds(cfg) is not None:
+        # a stack whose layers differ in kind: ``block`` iterations a
+        # program (:func:`_hybrid_decode_block`; 1 = the plain step)
+        if steps is None:
+            steps = jnp.where(lengths > 0, block, 0)
+        return _hybrid_decode_block(
+            cfg, _tree(params), kv_pages, tokens, lengths, page_tables,
+            temps, rng, steps, page_size, top_k, block,
+        )
     logits, kv_pages = _decode_step(
         cfg, _tree(dequantize_params(params)), kv_pages, tokens, lengths,
         page_tables, page_size=page_size,

@@ -35,7 +35,14 @@ guarantees:
   ``retrying`` phase with its pages and generated prefix RETAINED;
   re-admission resumes decode from the last completed iteration (no
   re-prefill once the first token exists), bounded by ``max_retries``
-  and ledgered as ``shed(retries_exhausted)`` past it;
+  and ledgered as ``shed(retries_exhausted)`` past it.  A model with
+  RECURRENT layers keeps its pages and prefix too, but its per-slot
+  state is not retained across the fault (the slot may be another
+  request's by then, and the faulted step may have advanced it): its
+  re-admission PREFILLS AGAIN over prompt + generated prefix into the
+  slot it is given, and is shed as ``oversize`` when that no longer
+  fits a prefill bucket (docs/serving.md "Layer kinds and the cache
+  set");
 - **poisoned-request quarantine** — a non-finite logits row (the
   engine's in-step screen) evicts ONLY the offending slot, ledgered
   ``shed(poisoned)``; the rest of the batch keeps decoding;
@@ -163,6 +170,7 @@ from apex_tpu.observability.ometrics import (
 )
 from apex_tpu.observability.spans import host_recorder
 from apex_tpu.resilience import chaos
+from apex_tpu.serve import model as model_lib
 from apex_tpu.serve.cache import NULL_PAGE, PrefixCache
 
 __all__ = [
@@ -378,8 +386,21 @@ class Request:
         }
 
 
-def declare_serve_metrics(registry) -> None:
-    """Declare the serving metric set on a registry (idempotent)."""
+def declare_serve_metrics(registry, *, stateful: bool = False,
+                          routed: bool = False, latent: bool = False) -> None:
+    """Declare the serving metric set on a registry (idempotent); the
+    metrics of a layer kind only for a model that has it."""
+    if routed:
+        # per step program, summed over the routed layers: (token,
+        # expert) pairs routed to the experts this chip holds, and
+        # distinct held experts touched (= expert weights streamed)
+        registry.counter("serve/moe/routed_local_tokens")
+        registry.counter("serve/moe/experts_touched")
+    if stateful:
+        registry.gauge("serve/state/slots_in_use")
+        registry.gauge("serve/state/bytes", "bytes")
+    if latent:
+        registry.gauge("serve/latent/pages_in_use")
     for g in ("serve/queue_depth", "serve/batch_fill",
               "serve/page_occupancy", "serve/tokens_per_s",
               "serve/ttft_ms", "serve/draining"):
@@ -462,6 +483,21 @@ class ContinuousBatchingScheduler:
         self.pool = engine.pool
         self.serve = engine.serve
         self.clock = clock
+        # what the model's layer kinds ask of this loop: a slot's
+        # recurrent state is written by the prefill that admits into it;
+        # a routed model's steps hand back the MoE counts
+        self._stateful = bool(getattr(engine, "stateful", False))
+        self._routed = bool(getattr(engine, "routed", False))
+        self._latent = "latent" in engine.cache
+        # under a decode block the admissions of a step are dispatched back
+        # to back and read once (:meth:`_resolve_prefills`): the device
+        # never waits for the host between two of them
+        self._lazy_prefill = self.serve.decode_block > 1
+        self._dispatched: List = []
+        model_lib.validate_features(
+            engine.cfg, prefix_cache=prefix_cache,
+            chunked_prefill=prefill_chunk_tokens is not None,
+        )
         # cross-request prefix cache + chunked prefill (docs/serving.md
         # "Prefix caching & chunked prefill"); both default OFF — the
         # monolithic cold path stays byte-for-byte the legacy one
@@ -551,7 +587,10 @@ class ContinuousBatchingScheduler:
         # counter would launch a transfer and a program per event
         self._mstate = None
         if self.registry is not None:
-            declare_serve_metrics(self.registry)
+            declare_serve_metrics(
+                self.registry, stateful=self._stateful, routed=self._routed,
+                latent=self._latent,
+            )
             self._mstate = self.registry.host_init()
 
     # -- bookkeeping ------------------------------------------------------
@@ -562,6 +601,18 @@ class ContinuousBatchingScheduler:
     @property
     def pending(self) -> bool:
         return bool(self.queue) or any(s is not None for s in self.slots)
+
+    def slots_in_use(self) -> int:
+        """Decode slots a request holds — for a model with recurrent
+        layers, the slots whose state is some request's."""
+        return sum(1 for r in self.slots if r is not None)
+
+    def _fold_moe(self) -> None:
+        """A routed model's last step's counts into the registry: Python
+        numbers the engine read inside the step's one token readback."""
+        pairs, touched = self.engine.last_moe_counts
+        self._count("serve/moe/routed_local_tokens", float(pairs))
+        self._count("serve/moe/experts_touched", float(touched))
 
     def batch_fill(self) -> float:
         return len(self.running) / len(self.slots)
@@ -897,14 +948,22 @@ class ContinuousBatchingScheduler:
             else:
                 self._shed_request(req, SHED_DRAINING)
             return True
-        if req.status == RETRYING and req.first_token_at is not None:
+        resumed = req.status == RETRYING and req.first_token_at is not None
+        if resumed and not self._stateful:
             self.queue.popleft()
             return self._readmit(req, slot)
-        if len(req.prompt) > self.serve.max_context:
+        # what the prefill runs over: the prompt — or, re-admitting a
+        # retried request of a model with recurrent layers, the prompt and
+        # the generated prefix already fed (the last token is fed by the
+        # next decode step, as it would have been)
+        text = req.prompt + req.tokens[:-1] if resumed else req.prompt
+        if len(text) > (
+            self.serve.buckets()[-1] if resumed else self.serve.max_context
+        ):
             self.queue.popleft()
             self._shed_request(req, SHED_OVERSIZE)
             return True
-        need = self.pool.pages_for(len(req.prompt))
+        need = self.pool.pages_for(len(text))
         if (
             self.prefix is not None
             and not req.cache_probed
@@ -987,12 +1046,13 @@ class ContinuousBatchingScheduler:
         self.queue.popleft()
         now = self.clock()
         self._close_blocked(req, now)
-        req.admitted_at = now
+        if not resumed:
+            req.admitted_at = now
         if self.spans is not None:
             self.spans.request_event(
                 req.rid, "prefill", now,
-                bucket=self.engine.bucket_for(len(req.prompt)),
-                prompt_tokens=len(req.prompt), pages=len(pages),
+                bucket=self.engine.bucket_for(len(text)),
+                prompt_tokens=len(text), pages=len(pages),
                 **({"cached_tokens": req.cache_hit_tokens}
                    if req.cache_hit_tokens else {}),
                 **({"attempt": req.retries} if req.retries else {}),
@@ -1004,11 +1064,13 @@ class ContinuousBatchingScheduler:
             # iterations — a long cold prompt no longer stalls running
             # streams, and a cache hit re-runs only its final chunk
             return self._start_chunked_prefill(req, slot)
-        ph.set(rid=req.rid, bucket=self.engine.bucket_for(len(req.prompt)),
-               prompt_tokens=len(req.prompt))
+        ph.set(rid=req.rid, bucket=self.engine.bucket_for(len(text)),
+               prompt_tokens=len(text))
         try:
             _, first = self.engine.prefill(
-                req.prompt, pages, temperature=req.temperature
+                text, pages[:need], temperature=req.temperature,
+                **({"slot": slot} if self._stateful else {}),
+                **({"lazy": True} if self._lazy_prefill else {}),
             )
         except Exception as e:
             # a crashed prefill is transient by default: the request
@@ -1018,12 +1080,43 @@ class ContinuousBatchingScheduler:
             self._count("serve/engine_faults")
             self._send_to_retry(req, f"prefill:{type(e).__name__}")
             return True
+        if self._lazy_prefill:
+            # the slot is taken; the token is read after the admit loop
+            self.slots[slot] = req
+            self._dispatched.append((req, slot, first, resumed))
+            return True
+        return self._prefilled(req, slot, first, resumed)
+
+    def _prefilled(self, req: Request, slot: int, first: int,
+                   resumed: bool) -> bool:
+        """A prefill's outcome, once its first token is on the host."""
         if not self.engine.last_prefill_finite:
             # poisoned at the first token: quarantine the request, not
             # the process — its logits are not evidence of anything
             self._shed_request(req, SHED_POISONED)
             return True
+        if self._routed:
+            self._fold_moe()
+        if resumed:
+            # the slot holds the sequence's state again; the token this
+            # prefill sampled is the one already on the stream
+            self._count("serve/prefills")
+            return self._readmit(req, slot)
         return self._finish_prefill(req, slot, first)
+
+    def _resolve_prefills(self) -> None:
+        """Read the first tokens of the prefills this step dispatched
+        lazily, in order (one wait for the device covers them all)."""
+        for req, slot, pending, resumed in self._dispatched:
+            self.slots[slot] = None
+            try:
+                first = self.engine.resolve_prefill(pending)
+            except Exception as e:
+                self._count("serve/engine_faults")
+                self._send_to_retry(req, f"prefill:{type(e).__name__}")
+                continue
+            self._prefilled(req, slot, first, resumed)
+        self._dispatched.clear()
 
     def _start_chunked_prefill(self, req: Request, slot: int) -> bool:
         """Enter the ``prefilling`` phase: position the prefill cursor
@@ -1170,12 +1263,26 @@ class ContinuousBatchingScheduler:
             req.pages.extend(got)
         return True
 
-    def _ensure_growth_page(self, req: Request) -> bool:
-        """The next append lands at position ``ctx_len``; allocate (or
-        COW-fork) its page if needed."""
-        return self._ensure_target_page(
-            req, req.ctx_len // self.serve.page_size
+    def _ensure_growth_page(self, req: Request, ahead: int = 1) -> bool:
+        """The next ``ahead`` appends land at positions ``ctx_len ..
+        ctx_len + ahead - 1`` (one, but for a decode block); allocate (or
+        COW-fork) their pages if needed."""
+        ps = self.serve.page_size
+        return all(
+            self._ensure_target_page(req, idx)
+            for idx in range(
+                req.ctx_len // ps, (req.ctx_len + ahead - 1) // ps + 1
+            )
         )
+
+    def _block_steps(self, req: Request) -> int:
+        """Iterations ``req`` may ride of the next decode program: the
+        block, cut at its token budget and at the context's end."""
+        return max(1, min(
+            self.serve.decode_block,
+            req.max_new_tokens - len(req.tokens),
+            self.serve.max_context - req.ctx_len,
+        ))
 
     def _ensure_spec_span(self, req: Request) -> bool:
         """Provision the whole speculative window BEFORE the round: a
@@ -1222,6 +1329,9 @@ class ContinuousBatchingScheduler:
             temps = np.zeros((b,), np.float32)
             streams = np.zeros((b,), np.uint32)
             gens = np.zeros((b,), np.int32)
+            # iterations each slot rides: one, but for a decode block
+            block = self.serve.decode_block
+            steps = np.zeros((b,), np.int32)
             tables = np.full(
                 (b, self.serve.max_pages_per_seq), NULL_PAGE, np.int32
             )
@@ -1232,7 +1342,8 @@ class ContinuousBatchingScheduler:
                     continue
                 if only is not None and i not in only:
                     continue
-                if not self._ensure_growth_page(req):
+                ahead = self._block_steps(req) if block > 1 else 1
+                if not self._ensure_growth_page(req, ahead):
                     # pool exhausted mid-decode: shed the youngest running
                     # request (least sunk cost) and retry this one
                     victims = sorted(
@@ -1247,8 +1358,11 @@ class ContinuousBatchingScheduler:
                     # (now freed) pages
                     tokens[v_slot] = 0
                     lengths[v_slot] = 0
+                    steps[v_slot] = 0
                     tables[v_slot] = NULL_PAGE
-                    if victim is req or not self._ensure_growth_page(req):
+                    if victim is req or not self._ensure_growth_page(
+                        req, ahead
+                    ):
                         if self.slots[i] is req:
                             self.slots[i] = None
                             self._shed_request(req, SHED_POOL_EXHAUSTED)
@@ -1258,6 +1372,7 @@ class ContinuousBatchingScheduler:
                 temps[i] = req.temperature
                 streams[i] = self._stream(req)
                 gens[i] = len(req.tokens) - 1
+                steps[i] = ahead
                 tables[i] = self._page_table_row(req)
         if not lengths.any():
             return
@@ -1266,7 +1381,10 @@ class ContinuousBatchingScheduler:
             _, next_tokens = self.engine.decode(
                 tokens, lengths, tables, temps,
                 streams=streams, gens=gens,
+                **({"steps": steps} if block > 1 else {}),
             )
+            # (iterations, slots): one row, but for a decode block
+            next_tokens = np.asarray(next_tokens).reshape(block, b)
         except Exception as e:
             # a crashed decode step produced nothing host-side: every
             # rider keeps its prefix and pages and re-enters through
@@ -1278,6 +1396,8 @@ class ContinuousBatchingScheduler:
             finite = self.engine.last_decode_finite
             self._riders += int((lengths > 0).sum())
             self._count("serve/decode_steps")
+            if self._routed:
+                self._fold_moe()
             # engine-numbered iteration id: the correlation key linking a
             # request's decode span to the engine batch iterations it rode
             it = getattr(self.engine, "decode_iters", None)
@@ -1315,14 +1435,18 @@ class ContinuousBatchingScheduler:
                     if req.first_decode_iter is None:
                         req.first_decode_iter = it
                     req.last_decode_iter = it
-                req.ctx_len += 1
-                req.tokens.append(int(next_tokens[i]))
-                self._tokens_out += 1
-                self._count("serve/tokens_out")
-                if self._finished(req):
-                    self.slots[i] = None
-                    self._retire(req, DONE)
-                    self._count("serve/completed")
+                for tok in next_tokens[: steps[i], i]:
+                    req.ctx_len += 1
+                    req.tokens.append(int(tok))
+                    self._tokens_out += 1
+                    self._count("serve/tokens_out")
+                    if self._finished(req):
+                        # (an EOS inside a block: the iterations after it
+                        # advanced a slot that is now free)
+                        self.slots[i] = None
+                        self._retire(req, DONE)
+                        self._count("serve/completed")
+                        break
 
     # -- speculative decoding ---------------------------------------------
     def _stream(self, req: Request) -> int:
@@ -1556,6 +1680,17 @@ class ContinuousBatchingScheduler:
                     "serve/prefix_cached_pages",
                     float(len(self.prefix.cached_pages())),
                 )
+            if self._stateful:
+                held = self.slots_in_use()
+                slab = self.engine.cache["state"]
+                self._gauge("serve/state/slots_in_use", float(held))
+                self._gauge(
+                    "serve/state/bytes",
+                    float(held * (slab.nbytes // slab.shape[1])),
+                )
+            if self._latent:
+                self._gauge(
+                    "serve/latent/pages_in_use", float(self.pool.in_use))
             if self._spec_window:
                 tot_d = sum(w[0] for w in self._spec_window)
                 tot_a = sum(w[1] for w in self._spec_window)
@@ -1585,6 +1720,8 @@ class ContinuousBatchingScheduler:
             # between decode iterations by construction
             while self._admit_one():
                 pass
+            if self._dispatched:
+                self._resolve_prefills()
             if self.queue:
                 # admission gave up with requests still queued: they are
                 # resource-blocked (no slot / pool cannot cover the head)

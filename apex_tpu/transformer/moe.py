@@ -24,6 +24,15 @@ The one-hot dispatch keeps every shape static and lowers to MXU-friendly
 einsums; overflow tokens beyond an expert's capacity are dropped (their
 combine weight is zero — the standard Switch behavior) and pass through
 the residual connection of the surrounding block.
+
+**The dropless path** (:func:`route_group_limited`, :func:`dropless_moe`)
+is what serving runs: any ``top_k``, no capacity and no ``(tokens,
+experts, capacity)`` tensor.  The layer is TOLD which experts it holds —
+one chip's share of an expert-parallel group: it routes over all the
+layer's experts, sorts the tokens routed to the experts it holds, runs one
+grouped matmul over them (:mod:`apex_tpu.ops.moe_grouped`) and adds only
+their terms.  What the other chips' experts would add, and the exchange
+with them, is not stood in for.
 """
 
 from __future__ import annotations
@@ -36,12 +45,15 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu import parallel_state as ps
+from apex_tpu.ops.moe_grouped import grouped_swiglu
 
 __all__ = [
     "MoeConfig",
     "SwitchMoe",
     "moe_dispatch_combine",
     "sync_moe_gradients",
+    "route_group_limited",
+    "dropless_moe",
 ]
 
 
@@ -73,12 +85,113 @@ class MoeConfig:
 
     def __post_init__(self):
         if self.top_k not in (1, 2):
-            raise ValueError(f"top_k must be 1 or 2, got {self.top_k}")
+            raise ValueError(
+                f"the capacity path routes top-1 or top-2, got top_k="
+                f"{self.top_k} (dropless_moe takes any top_k)"
+            )
         if self.sequence_parallel and self.context_parallel:
             raise ValueError(
                 "sequence_parallel and context_parallel are mutually "
                 "exclusive (both shard the token dimension)"
             )
+
+
+def route_group_limited(x, router, bias, *, top_k: int, n_group: int,
+                        topk_group: int, scale: float):
+    """Sigmoid router with group-limited top-k (DeepSeek-V3's rule).
+    ``x`` ``(T, H)``, ``router`` ``(H, E)`` f32, ``bias`` ``(E)``: scores
+    ``sigmoid(x @ router)`` in f32; selection on ``score + bias``; the
+    experts lie in ``n_group`` runs of ``E / n_group``, a group scores the
+    sum of its two best, the best ``topk_group`` groups stay, the ``top_k``
+    best experts within them are chosen; weights are the chosen experts'
+    scores (without the bias) normalised to sum 1, times ``scale``.
+    Returns ``(idx (T, top_k) int32, weights (T, top_k) f32)``."""
+    with jax.named_scope("moe_route"):
+        e = router.shape[-1]
+        s = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        ))
+        sel = s + bias
+        grp = jnp.sum(
+            jax.lax.top_k(sel.reshape(-1, n_group, e // n_group), 2)[0], -1
+        )
+        # the topk_group-th best group's score is the bar (ties keep both:
+        # scores are f32 sums of sigmoids, a tie is a measure-zero event)
+        bar = jax.lax.top_k(grp, topk_group)[0][:, -1:]
+        allowed = jnp.repeat(grp >= bar, e // n_group, axis=1)
+        _, idx = jax.lax.top_k(jnp.where(allowed, sel, -jnp.inf), top_k)
+        w = jnp.take_along_axis(s, idx, axis=1)
+        w = w / jnp.sum(w, axis=-1, keepdims=True) * scale
+        return idx.astype(jnp.int32), w
+
+
+def dropless_moe(x, idx, weights, experts, *, held, live=None,
+                 tile: int = 16):
+    """The terms of the experts this chip holds, with nothing dropped.
+
+    ``x`` ``(T, H)``; ``idx, weights`` ``(T, K)`` from the router, over
+    ALL the layer's experts; ``experts`` ``{"gate", "up": (E_held, H, I),
+    "down": (E_held, I, H)}``; ``held = (first, count)``: this chip holds
+    experts ``first .. first + count - 1``; ``live`` ``(T,)`` bool leaves
+    rows out (bucket padding, idle slots).  The pairs (token, expert) that
+    land here are sorted by expert, each expert's group padded to whole
+    tiles of ``tile`` rows, and run through one grouped matmul; a token's
+    output is the weighted sum of its own pairs' rows.
+
+    Returns ``(out (T, H) f32, stats (2,) int32)``: pairs routed to held
+    experts, and distinct held experts touched."""
+    t, k = idx.shape
+    first, count = held
+    with jax.named_scope("moe_sort"):
+        local = idx - first
+        here = (local >= 0) & (local < count)
+        if live is not None:
+            here = here & live[:, None]
+        local = jnp.where(here, local, count).reshape(-1)     # (T*K,)
+        order = jnp.argsort(local, stable=True)
+        sorted_local = local[order]
+        sizes = jnp.sum(
+            local[:, None] == jnp.arange(count)[None, :], axis=0
+        )                                                      # (count,)
+        padded = -(-sizes // tile) * tile
+        starts = jnp.cumsum(sizes) - sizes                     # unpadded
+        pad_starts = jnp.cumsum(padded) - padded
+        m = (-(-t * k // tile) + count) * tile                 # worst case
+        # sorted pair i -> its row in the padded layout (m: not held)
+        safe = jnp.minimum(sorted_local, count - 1)
+        dest = jnp.where(
+            sorted_local < count,
+            pad_starts[safe] + jnp.arange(t * k) - starts[safe], m,
+        )
+        row_token = jnp.zeros((m,), jnp.int32).at[dest].set(
+            (order // k).astype(jnp.int32), mode="drop"
+        )
+        # pair (token, j) -> its row
+        pair_row = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.minimum(dest, m - 1).astype(jnp.int32)
+        ).reshape(t, k)
+        ends = jnp.cumsum(padded)
+        live_tiles = ends[-1] // tile
+        tile_start = jnp.arange(m // tile) * tile
+        tile_expert = jnp.sum(tile_start[:, None] >= ends[None, :], axis=1)
+        last = tile_expert[jnp.maximum(live_tiles - 1, 0)]
+        tile_expert = jnp.minimum(
+            jnp.where(tile_start < ends[-1], tile_expert, last), count - 1
+        )
+        rows = jnp.take(x, row_token, axis=0)
+    y = grouped_swiglu(
+        rows, tile_expert, live_tiles, experts["gate"], experts["up"],
+        experts["down"], tile=tile,
+    )
+    with jax.named_scope("moe_combine"):
+        picked = jnp.take(y, pair_row, axis=0).astype(jnp.float32)
+        out = jnp.sum(
+            jnp.where(here[..., None], weights[..., None] * picked, 0.0),
+            axis=1,
+        )
+        stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)])
+    return out, stats.astype(jnp.int32)
 
 
 def _axis_size(axis: Optional[str]) -> int:

@@ -17,7 +17,10 @@ from jax.sharding import SingleDeviceSharding
 from apex_tpu import analysis
 from apex_tpu.models.gpt import GptConfig, GptModel
 from apex_tpu.ops import _dispatch
-from apex_tpu.ops.pallas import decode_attention, flash_attention, layer_norm
+from apex_tpu.ops.pallas import (
+    decode_attention, flash_attention, kda, layer_norm, mla_decode,
+    moe_grouped,
+)
 from apex_tpu.serve import cache as cache_lib
 from apex_tpu.serve import model as model_lib
 
@@ -40,7 +43,8 @@ def lower_as_chip(monkeypatch):
     """Kernels on and in Mosaic (not interpret) mode while lowering —
     ``jax.default_backend()`` still says cpu here."""
     monkeypatch.setattr(_dispatch, "use_pallas", lambda: True)
-    for mod in (_dispatch, decode_attention, flash_attention, layer_norm):
+    for mod in (_dispatch, decode_attention, flash_attention, layer_norm,
+                kda, mla_decode, moe_grouped):
         monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
 
 
@@ -137,3 +141,98 @@ def test_serving_step_never_moves_the_kv_pool(
         )
     ]
     assert len(stacked) == 4 and not casts, casts
+
+
+#: Ling-3.0-flash's language stack as benchmark/configs/
+#: ling-3.0-flash-vl-ep8.json serves it: one chip of 8, the first 8 layers
+LING_EP8 = dict(
+    vocab_size=19648, hidden_size=2560, num_layers=8, num_heads=32,
+    head_dim=128, intermediate_size=6144, max_seq_len=131072,
+    layer_group_size=6, first_dense_layers=2, num_experts=512,
+    held_experts=(0, 64), moe_intermediate_size=768,
+    shared_intermediate_size=768, top_k=8, n_group=8, topk_group=4,
+    routed_scaling_factor=2.5, rope_theta=6e6,
+)
+LING_PAGES, LING_SLOTS, LING_PAGES_PER_SEQ = 16385, 128, 128
+
+
+@pytest.mark.parametrize(
+    "program", ["serve_decode", "serve_decode_block16", "serve_prefill_1024"])
+def test_hybrid_step_never_moves_its_cache_set(
+    one_chip, lower_as_chip, program
+):
+    """The hybrid stack's programs at the benchmark's shapes, on the step
+    tree (bf16 weights, as installed): the latent pool and the per-slot
+    recurrent slab stay one buffer each from entry to exit — no
+    instruction materializes either or a layer of either
+    (`memory-pool-copy`), all four kernels are in the program under the
+    names the trace reads, and XLA's temporaries stay under one layer of
+    the slab in decode (a prompt's chunked-KDA Gram factors and MLA scores
+    take 0.43 GB: under a gigabyte)."""
+    from apex_tpu.models.hybrid import HybridConfig, param_shapes
+
+    cfg = HybridConfig(**LING_EP8)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip
+            ),
+            tree,
+        )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = on_chip(param_shapes(cfg))
+    cache = on_chip(jax.eval_shape(lambda: cache_lib.init_hybrid_cache(
+        cfg, LING_PAGES, PAGE, LING_SLOTS
+    )))
+    assert cache["latent"].shape == (1, LING_PAGES, 1, PAGE, 640)
+    assert cache["state"].shape == (7, LING_SLOTS, 32, 128, 128)
+    if program.startswith("serve_decode"):
+        # the cell's decode program runs 16 iterations a call
+        block = 16 if program.endswith("block16") else 1
+
+        def fn(params, kv, tokens, lengths, tables, temps, rng):
+            return model_lib.decode_body(
+                cfg, params, kv, tokens, lengths, tables, temps, rng,
+                page_size=PAGE, block=block)
+        args = (
+            arg((LING_SLOTS,), jnp.int32), arg((LING_SLOTS,), jnp.int32),
+            arg((LING_SLOTS, LING_PAGES_PER_SEQ), jnp.int32),
+            arg((LING_SLOTS,), jnp.float32),
+            arg((LING_SLOTS, 2) if block == 1 else (block, LING_SLOTS, 2),
+                jnp.uint32),
+        )
+        names = ("kda_step_fwd", "moe_grouped_fwd", "mla_decode_fwd")
+    else:
+        def fn(params, kv, tokens, length, page_ids, slot, temp, rng):
+            return model_lib.prefill_body(
+                cfg, params, kv, tokens, length, page_ids, temp, rng,
+                page_size=PAGE, slot=slot)
+        args = (
+            arg((1024, 1), jnp.int32), arg((), jnp.int32),
+            arg((1024 // PAGE,), jnp.int32), arg((), jnp.int32),
+            arg((), jnp.float32), arg((2,), jnp.uint32),
+        )
+        names = ("kda_chunk_fwd", "moe_grouped_fwd")
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args
+    ).compile()
+
+    text = compiled.as_text()
+    report = analysis.lint_hlo(
+        text, donated=3, rules=("memory", "donation"),
+        expect_pool={"shapes": [cache["latent"].shape, cache["state"].shape]},
+    )
+    assert report.findings == [], report.render()
+    for name in names:
+        assert name in text, name
+    slab_layer = cache["state"].size * 4 // 7
+    mem = compiled.memory_analysis()
+    limit = slab_layer if program.startswith("serve_decode") else 1e9
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    # what a chip holds: weights + cache set + temporaries, under 16 GB
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 12e9, held
