@@ -600,6 +600,7 @@ def default_kernel_specs() -> List[Tuple[str, List[KernelSpec]]]:
     norm = ln.kernel_specs(16384, 1024)
     # serve.ServeConfig defaults: page_size=16, num_pages=128,
     # max_batch=4, max_pages_per_seq=8; a 128-wide 8-head attention
+    # (the walk the kernel's own page copies make: 8 pages a step)
     decode = da.kernel_specs(
         4, 8, 128, pool_pages=128, page=16, pages_per_seq=8,
     )

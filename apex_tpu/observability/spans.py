@@ -103,9 +103,11 @@ __all__ = [
 ENV_SPANS = "APEX_TPU_SPANS"
 DEFAULT_SPANS_DIR = "/tmp/apex_tpu_spans"
 DEFAULT_CAPACITY = 4096
-#: the always-on process ring: ~10 phases a decode step, so the last
-#: few thousand scheduler steps
-PROCESS_CAPACITY = 32768
+#: the always-on process ring: ~6-10 phases a decode step, so the last
+#: ten to twenty thousand scheduler steps — a minute of a loop whose
+#: steps take 4 ms (32,768 entries dropped the head of a 50 s run once
+#: a step of GPT-2 Large took 8 ms: PR 36)
+PROCESS_CAPACITY = 131072
 
 # -- track names (one Perfetto track per source) ----------------------------
 TRACK_REQUESTS = "serve/requests"

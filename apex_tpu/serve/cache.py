@@ -9,17 +9,21 @@ motivates the fused read side) splits the cache into fixed-size
 **pages** drawn from one shared pool:
 
 - **device side** — one pool per layer, stacked: ``k``/``v`` arrays of
-  shape ``(L, P, H/G, page, D*G)`` — heads OUTSIDE the page dim (the
+  shape ``(L, P, H/G, page, W)`` — heads OUTSIDE the page dim (the
   layout :func:`apex_tpu.ops.paged_decode_attention` contracts with no
   transposes), ``G`` heads side by side in one 128-lane row
   (:func:`~apex_tpu.ops.paged_attention.heads_per_row`: ``G = 2`` at
-  ``head_dim`` 64, 1 from 128 up).  A lane-dense minor dimension is what
+  ``head_dim`` 64, 1 from 128 up), ``W = lane_width(D*G)``: a row that
+  does not fill its tiles (heads that do not pair up, or of 80 or 96
+  lanes) is zero-padded to them.  A lane-dense minor dimension is what
   lets XLA:TPU keep the pool in plain row-major layout — the one layout
   the kernel, the appends and the prompt writes all agree on, so no
   serving program ever relays the pool (docs/serving.md "The KV pool").
   With ``kv_wire="int8"`` the pools hold blockwise int8 codes plus f32
-  scale planes ``(L, P, H/G, page, G)`` — one scale per (head, token)
-  row at ``block = head_dim``, the exact ``parallel/comm.py`` codec
+  scale planes ``(L, P, 1, page, lane_width(H))`` — one scale per
+  (head, token) at ``block = head_dim``, a token a row and a head a
+  lane, whole 128-lane tiles like the pages so the decode kernel copies
+  them the same way — the exact ``parallel/comm.py`` codec
   (:func:`~apex_tpu.parallel.comm.quantize_blocks`), so the KV wire
   format is the same code the gradient wire uses.
 - **host side** — :class:`PagePool`, a free-list allocator.  Page 0 is
@@ -70,7 +74,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.ops.mla import latent_row_width
-from apex_tpu.ops.paged_attention import heads_per_row
+from apex_tpu.ops.paged_attention import (
+    heads_per_row,
+    lane_width,
+    pad_lanes,
+)
 from apex_tpu.parallel import comm
 
 __all__ = [
@@ -496,23 +504,28 @@ def init_kv_pages(
     kv_wire: str = "f32",
 ) -> dict:
     """Fresh zeroed pool arrays: ``{"k", "v"}`` of ``(L, P, H/G, page,
-    D*G)`` with ``G = heads_per_row(H, D)``, plus ``{"k_scale",
-    "v_scale"}`` ``(L, P, H/G, page, G)`` f32 planes under
-    ``kv_wire="int8"`` (codes then carry dtype int8)."""
+    W)`` with ``G = heads_per_row(H, D)`` and ``W = lane_width(D*G)`` —
+    whole 128-lane tiles, the lanes past ``D*G`` zero and never written
+    (heads that do not pair up, or of 80 or 96 lanes: the decode kernel
+    copies pages out of HBM by whole tiles) — plus ``{"k_scale", "v_scale"}``
+    ``(L, P, 1, page, lane_width(H))`` f32 planes under ``kv_wire="int8"``
+    (a token a row, a head a lane; codes then carry dtype int8)."""
     if kv_wire not in ("f32", "int8"):
         raise ValueError(f"kv_wire must be 'f32' or 'int8', got {kv_wire!r}")
     g = heads_per_row(num_heads, head_dim)
     rows = (num_layers, num_pages, num_heads // g, page_size)
     store = jnp.int8 if kv_wire == "int8" else dtype
+    w = lane_width(head_dim * g)
     cache = {
-        "k": jnp.zeros(rows + (head_dim * g,), store),
-        "v": jnp.zeros(rows + (head_dim * g,), store),
+        "k": jnp.zeros(rows + (w,), store),
+        "v": jnp.zeros(rows + (w,), store),
     }
     if kv_wire == "int8":
         # two DISTINCT buffers: the engine donates the whole cache
         # tree, and donating one shared buffer twice is a runtime error
-        cache["k_scale"] = jnp.ones(rows + (g,), jnp.float32)
-        cache["v_scale"] = jnp.ones(rows + (g,), jnp.float32)
+        scales = (num_layers, num_pages, 1, page_size, lane_width(num_heads))
+        cache["k_scale"] = jnp.ones(scales, jnp.float32)
+        cache["v_scale"] = jnp.ones(scales, jnp.float32)
     return cache
 
 
@@ -564,21 +577,24 @@ def append_rows(pool, layer, page_ids, slots, rows):
 
 
 def _planes(kv, k, v):
-    """K/V rows ``(..., H, D)`` as the pool's planes ``{name: (...,
-    H/G, W*G)}``: lane rows of ``G`` heads (a free reshape), int8 codes
-    and their scales when ``kv`` carries scale planes."""
+    """K/V rows ``(..., H, D)`` as the pool's planes ``{name: (..., R,
+    W)}``: lane rows of ``G`` heads (a free reshape) and, when ``kv``
+    carries scale planes, int8 codes and their scales a token a row —
+    each zero-padded to its plane's whole tiles."""
     g = k.shape[-2] // kv["k"].shape[2]
     planes = {"k": k, "v": v}
     if "k_scale" in kv:
         planes["k"], k_scale = encode_kv(k)
         planes["v"], v_scale = encode_kv(v)
-        planes["k_scale"] = k_scale[..., None]
-        planes["v_scale"] = v_scale[..., None]
-    return {
-        name: x.reshape(
-            x.shape[:-2] + (x.shape[-2] // g, g * x.shape[-1])
-        )
+    planes = {
+        name: x.reshape(x.shape[:-2] + (x.shape[-2] // g, g * x.shape[-1]))
         for name, x in planes.items()
+    }
+    if "k_scale" in kv:
+        planes["k_scale"] = k_scale[..., None, :]
+        planes["v_scale"] = v_scale[..., None, :]
+    return {
+        name: pad_lanes(x, kv[name].shape[-1]) for name, x in planes.items()
     }
 
 

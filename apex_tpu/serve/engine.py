@@ -726,9 +726,10 @@ class InferenceEngine:
         ``cache``: the pool stays one buffer in one layout from entry
         to exit (docs/serving.md "The KV pool").  An ERROR where it
         costs, on the TPU at the bf16/f32 wire; a WARNING for the int8
-        wire's scale planes (minor dimension G, not lane-dense:
-        XLA:TPU still relays them, PERF.md section 7) and for the CPU
-        compiler's own copy insertion."""
+        wire (its scale planes are whole tiles since PR 36 and
+        ``serve_decode`` / ``serve_prefill_1024`` compile clean, but the
+        other programs were not compiled under it: ROADMAP S15) and for
+        the CPU compiler's own copy insertion."""
         from apex_tpu import analysis
 
         strict = (

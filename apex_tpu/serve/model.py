@@ -1033,10 +1033,12 @@ def chunk_prefill_body(
         # through the cache wire (exactly how decode reads it), as
         # (H, T, D) f32 in absolute position order
         k_ctx = gather_history(
-            kv["k"], kv.get("k_scale"), l, page_table[None], heads
+            kv["k"], kv.get("k_scale"), l, page_table[None], heads,
+            _head_dim(cfg),
         )[0]
         v_ctx = gather_history(
-            kv["v"], kv.get("v_scale"), l, page_table[None], heads
+            kv["v"], kv.get("v_scale"), l, page_table[None], heads,
+            _head_dim(cfg),
         )[0]
         # in-chunk keys stay exact (the same in-flight numerics the
         # monolithic prefill uses for every prompt position)

@@ -169,6 +169,8 @@ from apex_tpu.observability.ometrics import (
     Histogram,
 )
 from apex_tpu.observability.spans import host_recorder
+from apex_tpu.ops import _dispatch
+from apex_tpu.ops.paged_attention import walk_live_share
 from apex_tpu.resilience import chaos
 from apex_tpu.serve import model as model_lib
 from apex_tpu.serve.cache import NULL_PAGE, PrefixCache
@@ -387,9 +389,14 @@ class Request:
 
 
 def declare_serve_metrics(registry, *, stateful: bool = False,
-                          routed: bool = False, latent: bool = False) -> None:
+                          routed: bool = False, latent: bool = False,
+                          paged: bool = True) -> None:
     """Declare the serving metric set on a registry (idempotent); the
     metrics of a layer kind only for a model that has it."""
+    if paged:
+        # of the pages the paged decode kernel's walk copied in for the
+        # step's decode call, the share that held live positions
+        registry.gauge("serve/decode_walk_live_share")
     if routed:
         # per step program, summed over the routed layers: (token,
         # expert) pairs routed to the experts this chip holds, and
@@ -489,6 +496,11 @@ class ContinuousBatchingScheduler:
         self._stateful = bool(getattr(engine, "stateful", False))
         self._routed = bool(getattr(engine, "routed", False))
         self._latent = "latent" in engine.cache
+        # a K/V page pool: the paged decode kernel walks it, and
+        # `serve/decode_walk_live_share` is reckoned from the lengths of
+        # the step's plain decode call
+        self._paged = "k" in engine.cache
+        self._decode_lengths = None
         # under a decode block the admissions of a step are dispatched back
         # to back and read once (:meth:`_resolve_prefills`): the device
         # never waits for the host between two of them
@@ -589,7 +601,7 @@ class ContinuousBatchingScheduler:
         if self.registry is not None:
             declare_serve_metrics(
                 self.registry, stateful=self._stateful, routed=self._routed,
-                latent=self._latent,
+                latent=self._latent, paged=self._paged,
             )
             self._mstate = self.registry.host_init()
 
@@ -1376,6 +1388,7 @@ class ContinuousBatchingScheduler:
                 tables[i] = self._page_table_row(req)
         if not lengths.any():
             return
+        self._decode_lengths = lengths
         t0 = self.clock()
         try:
             _, next_tokens = self.engine.decode(
@@ -1675,6 +1688,19 @@ class ContinuousBatchingScheduler:
             self._gauge("serve/batch_fill", self.batch_fill())
             self._gauge("serve/page_occupancy", self.pool.occupancy())
             self._gauge("serve/tokens_per_s", tps)
+            if self._decode_lengths is not None:
+                # only a walk that ran: the decode program's attention
+                # took the kernel (the jnp path gathers the whole table)
+                took = _dispatch.last_paths().get("paged_decode_attention")
+                if self._paged and took == "pallas":
+                    self._gauge(
+                        "serve/decode_walk_live_share",
+                        walk_live_share(
+                            self._decode_lengths, self.engine.cache["k"],
+                            self.serve.max_pages_per_seq,
+                        ),
+                    )
+                self._decode_lengths = None
             if self.prefix is not None:
                 self._gauge(
                     "serve/prefix_cached_pages",

@@ -811,6 +811,12 @@ class TestPagedDecodeAttention:
             pages.reshape(l, p, h // g, g, page, w), (0, 1, 2, 4, 3, 5)
         ).reshape(l, p, h // g, page, g * w)
 
+    @staticmethod
+    def _scale_pool(scales):
+        """Per-head scales ``(L, P, H, page)`` -> the serving layout
+        ``(L, P, 1, page, H)``: a token a row, a head a lane."""
+        return jnp.swapaxes(scales, 2, 3)[:, :, None]
+
     @pytest.mark.parametrize("int8", [False, True], ids=["f32kv", "int8kv"])
     @pytest.mark.parametrize("rope", [False, True], ids=["plain", "rope"])
     @pytest.mark.parametrize("h,d,g", [
@@ -851,8 +857,8 @@ class TestPagedDecodeAttention:
             vp, vs = encode_kv(vp)
             kw.update(k_scale=ks[1], v_scale=vs[1])
             pool_kw.update(
-                k_scale=self._to_pool(ks[..., None], g),
-                v_scale=self._to_pool(vs[..., None], g),
+                k_scale=self._scale_pool(ks),
+                v_scale=self._scale_pool(vs),
             )
         want = paged_decode_attention_reference(
             q, kp[1], vp[1], table, lengths, **kw
@@ -871,6 +877,167 @@ class TestPagedDecodeAttention:
             layer=1, **kw
         )
         np.testing.assert_allclose(ref, want, atol=2e-6, rtol=2e-6)
+
+    # -- the walk: K pages a step, clamped to each sequence's live pages --
+
+    #: page 8 gives the table's K = 16 pages (128 positions) a step; 40
+    #: table entries are then two whole steps and a ragged third
+    WALK_PAGE, WALK_NP = 8, 40
+
+    def _walk_case(self, h, d, dtype, *, rope, int8, seed=0):
+        """One sequence for every boundary of the walk, its live pages
+        scattered through the pool and its dead table entries left at the
+        null page.  Returns the pool-layout operands and keywords."""
+        from apex_tpu.ops.paged_attention import heads_per_row, pages_per_step
+        from apex_tpu.serve.cache import encode_kv
+
+        page, np_ = self.WALK_PAGE, self.WALK_NP
+        g = heads_per_row(h, d)
+        k = pages_per_step(page, h * d * (1 if int8 else 4), np_)
+        assert k == 16 and np_ % k
+        lengths = np.asarray([
+            0, 1, page, page + 1, k * page - 1, k * page, k * page + 1,
+            np_ * page,
+        ], np.int32)
+        live = -(-lengths // page)
+        rs = np.random.RandomState(seed)
+        layers, pool, b = 2, int(live.sum()) + 1, len(lengths)
+        kp = jnp.asarray(rs.randn(layers, pool, h, page, d), jnp.float32)
+        vp = jnp.asarray(rs.randn(layers, pool, h, page, d), jnp.float32)
+        q = jnp.asarray(rs.randn(b, h, d), dtype)
+        ids = list(rs.permutation(pool - 1) + 1)
+        table = np.zeros((b, np_), np.int32)
+        for row, n in enumerate(live):
+            table[row, :n] = [ids.pop() for _ in range(n)]
+        kw = {}
+        if rope:
+            kw["rope_cos"] = jnp.asarray(rs.randn(b, d), dtype)
+            kw["rope_sin"] = jnp.asarray(rs.randn(b, d), dtype)
+        if int8:
+            kp, ks = encode_kv(kp)
+            vp, vs = encode_kv(vp)
+            kw["k_scale"] = self._scale_pool(ks)
+            kw["v_scale"] = self._scale_pool(vs)
+        else:
+            kp, vp = kp.astype(dtype), vp.astype(dtype)
+        args = (q, self._to_pool(kp, g), self._to_pool(vp, g),
+                jnp.asarray(table), jnp.asarray(lengths))
+        return args, dict(layer=jnp.asarray(1, jnp.int32), **kw)
+
+    def _check_walk(self, h, d, dtype, rope, int8):
+        from apex_tpu.ops.paged_attention import (
+            paged_decode_attention,
+            paged_decode_attention_reference,
+        )
+
+        args, kw = self._walk_case(h, d, dtype, rope=rope, int8=int8)
+        got = paged_decode_attention(*args, **kw)
+        assert _dispatch.last_paths()["paged_decode_attention"] == "pallas"
+        want = paged_decode_attention_reference(*args, **kw)
+        tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            atol=tol, rtol=tol,
+        )
+        assert not np.asarray(got[0], np.float32).any()  # the idle slot
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["kv", "int8kv"])
+    @pytest.mark.parametrize("rope", [False, True], ids=["plain", "rope"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("h,d", [(2, 128), (4, 64)], ids=["G1", "G2"])
+    def test_walk_boundaries_match_reference(
+        self, force_pallas, h, d, dtype, rope, int8
+    ):
+        """Lengths 0, 1, page, page + 1, K*page - 1, K*page, K*page + 1
+        and NP*page (NP not a multiple of K) against the gather
+        reference, one or two heads a lane row, with and without fused
+        RoPE and int8 scales, f32 and bf16."""
+        self._check_walk(h, d, dtype, rope, int8)
+
+    @pytest.mark.parametrize("dtype,rope,int8", [
+        (jnp.float32, True, False), (jnp.float32, True, True),
+        (jnp.bfloat16, False, True),
+    ], ids=["f32-rope-kv", "f32-rope-int8kv", "bf16-plain-int8kv"])
+    @pytest.mark.parametrize("h,d", [(3, 64), (2, 80), (2, 192)],
+                             ids=["3x64", "d80", "d192"])
+    def test_walk_over_rows_padded_to_whole_tiles(
+        self, force_pallas, h, d, dtype, rope, int8
+    ):
+        """The same boundaries over pools whose rows do not fill their
+        128-lane tiles — heads that do not pair up, heads of 80 and of
+        192 lanes: one head a row, zero lanes up to the tile, the fused
+        rotation inside the head's own lanes."""
+        self._check_walk(h, d, dtype, rope, int8)
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["kv", "int8kv"])
+    def test_dead_table_entries_are_never_read(self, force_pallas, int8):
+        """Entries past a sequence's live pages may point anywhere in the
+        pool: here at pages full of NaN (and NaN scales).  The output is
+        bit-for-bit what null entries give, so nothing of them was read —
+        codes, values or scales."""
+        from apex_tpu.ops.paged_attention import (
+            paged_decode_attention,
+            paged_decode_attention_reference,
+        )
+
+        (q, kp, vp, table, lengths), kw = self._walk_case(
+            4, 64, jnp.float32, rope=False, int8=int8, seed=1
+        )
+        want = paged_decode_attention(q, kp, vp, table, lengths, **kw)
+        # two more pages at the pool's end, poisoned in every layer
+        def poisoned(x, value):
+            pad = jnp.full((x.shape[0], 2) + x.shape[2:], value, x.dtype)
+            return jnp.concatenate([x, pad], axis=1)
+
+        bad = kp.shape[1] + np.arange(2)
+        live = -(-np.asarray(lengths) // self.WALK_PAGE)
+        dead = np.arange(self.WALK_NP)[None, :] >= live[:, None]
+        dirty = np.where(
+            dead, bad[np.arange(dead.size).reshape(dead.shape) % 2], table
+        )
+        if int8:
+            kw.update(
+                k_scale=poisoned(kw["k_scale"], jnp.nan),
+                v_scale=poisoned(kw["v_scale"], jnp.nan),
+            )
+            kp, vp = poisoned(kp, 127), poisoned(vp, 127)
+        else:
+            kp, vp = poisoned(kp, jnp.nan), poisoned(vp, jnp.nan)
+        got = paged_decode_attention(
+            q, kp, vp, jnp.asarray(dirty, jnp.int32), lengths, **kw
+        )
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        ref = paged_decode_attention_reference(
+            q, kp, vp, table, lengths, **kw
+        )
+        np.testing.assert_allclose(got, ref, atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("page,row_bytes,np_,want", [
+        (16, 10 * 128 * 2, 64, 8),    # GPT-2 Large's bf16 pool: 128 rows
+        (16, 10 * 128 * 1, 64, 8),    # the same pool on the int8 wire
+        (128, 8 * 128 * 4, 2, 1),     # a page of 128 rows is a step
+        (8, 4 * 128 * 4, 3, 3),       # a table narrower than a step
+        (16, 64 * 128 * 4, 64, 2),    # a row so wide the bytes bound it
+    ])
+    def test_pages_per_step_table(self, page, row_bytes, np_, want):
+        from apex_tpu.ops.paged_attention import pages_per_step
+
+        assert pages_per_step(page, row_bytes, np_) == want
+
+    def test_walk_live_share(self):
+        """Live pages over pages copied: a step's tail re-reads its last
+        live page, an idle slot copies nothing."""
+        from apex_tpu.ops.paged_attention import walk_live_share
+
+        # 11 live pages in 2 steps of 8, 47 in 6, an idle slot
+        pool = jax.ShapeDtypeStruct((36, 1201, 10, 16, 128), jnp.bfloat16)
+        lengths = np.asarray([176, 0, 740], np.int32)
+        assert walk_live_share(lengths, pool, 64) == pytest.approx(58 / 64)
+        assert walk_live_share(np.asarray([128, 256]), pool, 64) == 1.0
+        assert walk_live_share(np.zeros(4, np.int32), pool, 64) is None
+        # K is the kernel's own: a table of 4 entries is one step of 4
+        assert walk_live_share(np.asarray([16]), pool, 4) == 0.25
 
     def test_pool_operands_are_checked(self, force_pallas):
         from apex_tpu.ops.paged_attention import paged_decode_attention
@@ -891,6 +1058,32 @@ class TestPagedDecodeAttention:
         q, kp, vp, table, lengths = self._paged_case(4)
         out = paged_decode_attention(q, kp, vp, table, lengths)
         assert float(jnp.abs(out[2]).max()) == 0.0  # lengths[2] == 0
+
+    def test_narrow_rows_are_padded_to_whole_tiles(self, force_pallas,
+                                                   monkeypatch):
+        """Mosaic copies out of HBM by whole 128-lane rows, so the kernel
+        only ever sees lane-dense rows: pages of 32 lanes reach it
+        zero-padded to 128, on the interpreter as on the chip, and a pool
+        whose rows are not whole tiles is refused by the kernel itself."""
+        from apex_tpu.ops import paged_attention as pa
+        from apex_tpu.ops.pallas import decode_attention as da
+
+        seen = []
+        fwd = pa.paged_decode_fwd
+        monkeypatch.setattr(pa, "paged_decode_fwd", lambda q, k, *a, **kw: (
+            seen.append(k.shape) or fwd(q, k, *a, **kw)))
+        q, kp, vp, table, lengths = self._paged_case(7)  # rows of 32 lanes
+        out = pa.paged_decode_attention(q, kp, vp, table, lengths)
+        assert _dispatch.last_paths()["paged_decode_attention"] == "pallas"
+        assert seen == [(1,) + kp.shape[:3] + (128,)]
+        want = pa.paged_decode_attention_reference(q, kp, vp, table, lengths)
+        np.testing.assert_allclose(out, want, atol=2e-6, rtol=2e-6)
+        assert da.lane_width(1) == da.lane_width(128) == 128
+        assert da.lane_width(129) == da.lane_width(192) == 256
+        with pytest.raises(ValueError, match="whole 128-lane tiles"):
+            da.paged_decode_fwd(
+                q, kp[None], vp[None], table, lengths, 0, scale=1.0
+            )
 
     def test_jnp_dispatch_default_off_tpu(self):
         """Auto mode off-TPU routes to the gather-based jnp path (the

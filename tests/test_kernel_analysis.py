@@ -305,6 +305,110 @@ class TestCoveragePass:
         report = ka.analyze(spec, device_kind=V5E)
         assert "kernel-grid-oob" in {f.rule for f in report.errors()}
 
+    def test_decode_walk_plan_at_the_serving_shape(self):
+        """GPT-2 Large as the benchmark serves it: B=32 slots, 64 table
+        entries, 16-row pages.  The export is the walk the kernel's
+        copies make — K = 8 page operands a pool a step through the
+        clamped maps — and its step count is pinned."""
+        (spec,) = da.kernel_specs(
+            32, 20, 64, pool_pages=1201, page=16, pages_per_seq=64,
+        )
+        k = spec.meta["pages_per_step"]
+        assert k == da.pages_per_step(16, 10 * 128 * 2, 64) == 8
+        assert spec.grid == (32, -(-64 // k)) and spec.cells() == 256
+        assert spec.dimension_semantics == ("parallel", "arbitrary")
+        names = [a.name for a in spec.inputs]
+        assert names == (
+            ["q"] + [f"k_pages[{i}]" for i in range(k)]
+            + [f"v_pages[{i}]" for i in range(k)] + ["rope_cos", "rope_sin"]
+        )
+        for a in spec.inputs[1:1 + 2 * k]:
+            assert a.shape == (1, 1201, 10, 16, 128)
+            assert a.block == (1, 1, 10, 16, 128)
+        report = ka.analyze(spec, device_kind=V5E)
+        assert report.findings == [], report.render()
+        # two buffer slots of K pages of K and of V, the rows, the output
+        fp = ka.vmem_footprint(spec)
+        assert fp["block_bytes"] >= 2 * 2 * k * 10 * 16 * 128 * 2
+        assert fp["total_bytes"] < 4 << 20
+
+    def test_decode_walk_maps_clamp_to_the_live_pages(self):
+        """On a concrete table with SHORT lengths every page operand of
+        every step stays on the sequence's live pages: dead entries may
+        hold ids far outside the pool and the coverage pass stays clean;
+        at full lengths the same table is out of bounds."""
+        b, np_, page, pool = 4, 40, 8, 64
+        k = da.pages_per_step(page, 2 * 128 * 2, np_)
+        assert k == 16 and np_ % k
+        lengths = np.asarray([0, 1, k * page + 1, np_ * page], np.int32)
+        live = -(-lengths // page)
+        table = np.full((b, np_), 9999, np.int32)
+        table[0, 0] = 0  # an idle slot's row: the null page
+        for row, n in enumerate(live):
+            table[row, :n] = 1 + np.arange(n) + row * 7
+        (spec,) = da.kernel_specs(
+            b, 4, 64, pool_pages=pool, page=page, pages_per_seq=np_,
+            page_table=table, lengths=lengths, kv_wire="int8",
+        )
+        assert spec.grid == (b, 3)
+        report = ka.analyze(spec, device_kind=V5E)
+        assert report.by_rule("kernel-grid-oob") == [], report.render()
+        assert report.by_rule("kernel-block-race") == []
+        pages = [a for a in spec.inputs if a.name.startswith("k_pages")]
+        assert len(pages) == k
+        for row in range(b):
+            last = max(int(live[row]) - 1, 0)
+            for j in range(3):
+                for i, a in enumerate(pages):
+                    want = table[row, min(j * k + i, last)]
+                    assert int(a.index_map(row, j)[1]) == want
+        # the scale planes are paged like the pools: K pages a step
+        # through the same clamped maps, a token a row and a head a lane
+        scales = [a for a in spec.inputs if a.name.startswith("k_scale")]
+        assert len(scales) == k
+        for i, a in enumerate(scales):
+            assert a.shape == (1, pool, 1, page, 128)
+            assert a.block == (1, 1, 1, page, 128)
+            assert int(a.index_map(2, 1)[1]) == table[
+                2, min(k + i, int(live[2]) - 1)]
+        (full,) = da.kernel_specs(
+            b, 4, 64, pool_pages=pool, page=page, pages_per_seq=np_,
+            page_table=table,
+        )
+        report = ka.analyze(full, device_kind=V5E)
+        assert "kernel-grid-oob" in {f.rule for f in report.errors()}
+
+    @pytest.mark.parametrize("h,d,hg,w", [
+        (25, 64, 25, 128), (20, 80, 20, 128), (12, 96, 12, 128),
+        (8, 192, 8, 256),
+    ])
+    def test_decode_walk_rows_are_whole_tiles(self, h, d, hg, w):
+        """Heads that do not pair up, or of 80, 96 or 192 lanes: one head
+        a row, the row padded to whole 128-lane tiles — every operand the
+        kernel copies or blocks has a lane-dense minor dimension."""
+        (spec,) = da.kernel_specs(
+            32, h, d, pool_pages=301, page=16, pages_per_seq=64,
+            kv_wire="int8",
+        )
+        for a in list(spec.inputs) + list(spec.outputs):
+            assert a.block[-1] % 128 == 0, (a.name, a.block)
+        pages = [a for a in spec.inputs if a.name.startswith("k_pages")]
+        assert {a.block for a in pages} == {(1, 1, hg, 16, w)}
+        report = ka.analyze(spec, device_kind=V5E)
+        assert report.errors() == [], report.render()
+
+    def test_decode_walk_scales_in_vmem_do_not_grow_with_the_table(self):
+        """The int8 wire at a 32k-token table (2,048 entries): a step's
+        scale slab is what VMEM holds, as at 64 entries."""
+        def footprint(np_):
+            (spec,) = da.kernel_specs(
+                8, 32, 128, pool_pages=4097, page=16, pages_per_seq=np_,
+                kv_wire="int8",
+            )
+            return ka.vmem_footprint(spec)["total_bytes"]
+
+        assert footprint(2048) == footprint(64) < 8 << 20
+
     def test_shipped_kernels_cover_cleanly(self):
         specs = (
             fa.kernel_specs(2, 512, 512, 64, block_q=128, block_k=128)

@@ -116,30 +116,36 @@ class TestPagePool:
 # ---------------------------------------------------------------------------
 
 
-def _read_history(kv, layer, table, heads):
+def _read_history(kv, layer, table, heads, head_dim=None):
     """One layer's K history ``(B, H, T, D)`` f32 through ``table``."""
     from apex_tpu.ops.paged_attention import gather_history
 
     return np.asarray(gather_history(
-        kv["k"], kv.get("k_scale"), layer, jnp.asarray(table), heads
+        kv["k"], kv.get("k_scale"), layer, jnp.asarray(table), heads,
+        head_dim,
     ))
 
 
 class TestCacheWrites:
     """The pool helpers: whole pages at ``[layer, page_ids]``, in the
-    lane-dense layout ``(L, P, H/G, page, D*G)``."""
+    lane-dense layout ``(L, P, H/G, page, W)``."""
 
-    @pytest.mark.parametrize("h,d,g", [
-        (2, 128, 1), (4, 64, 2), (4, 32, 4), (3, 64, 1),
+    @pytest.mark.parametrize("h,d,g,w", [
+        (2, 128, 1, 128), (4, 64, 2, 128), (4, 32, 4, 128),
+        # heads that do not pair up, or no divisor of a tile: one head a
+        # row, the row padded to whole 128-lane tiles
+        (3, 64, 1, 128), (25, 64, 1, 128), (4, 80, 1, 128),
+        (4, 96, 1, 128), (2, 192, 1, 256), (2, 256, 1, 256),
     ])
-    def test_pool_shape_follows_heads_and_head_dim(self, h, d, g):
+    def test_pool_shape_follows_heads_and_head_dim(self, h, d, g, w):
         kv = cache_lib.init_kv_pages(2, 5, h, 4, d, dtype=jnp.float32)
-        assert kv["k"].shape == kv["v"].shape == (2, 5, h // g, 4, d * g)
+        assert kv["k"].shape == kv["v"].shape == (2, 5, h // g, 4, w)
         q = cache_lib.init_kv_pages(2, 5, h, 4, d, kv_wire="int8")
         assert q["k"].dtype == jnp.int8
-        assert q["k_scale"].shape == (2, 5, h // g, 4, g)
+        # a token a row, a head a lane, whole tiles
+        assert q["k_scale"].shape == (2, 5, 1, 4, 128)
 
-    @pytest.mark.parametrize("h,d", [(2, 128), (4, 64), (3, 64)])
+    @pytest.mark.parametrize("h,d", [(2, 128), (4, 64), (3, 64), (2, 80)])
     def test_prompt_pages_roundtrip(self, h, d):
         """pack -> write at [layer, page_ids] -> read back in table
         order; the other layer and the other pages stay untouched."""
@@ -149,10 +155,12 @@ class TestCacheWrites:
         kv = cache_lib.init_kv_pages(2, 10, h, page, d, dtype=jnp.float32)
         ids = jnp.asarray([3, 5, 2, 7], jnp.int32)
         out = cache_lib.write_prompt_kv(kv, 1, ids, k, -k)
-        got = _read_history(out, 1, np.asarray(ids)[None], h)[0]
+        got = _read_history(out, 1, np.asarray(ids)[None], h, d)[0]
         np.testing.assert_array_equal(
             got, np.asarray(jnp.transpose(k, (1, 0, 2)))
         )
+        # a row's padding lanes stay zero
+        assert not np.asarray(out["k"][..., d * (h // kv["k"].shape[2]):]).any()
         np.testing.assert_array_equal(
             np.asarray(out["v"]), -np.asarray(out["k"])
         )
@@ -298,6 +306,48 @@ class TestEngineNumerics:
                 assert int(nxt[0]) == int(np.argmax(ref))
             ctx += 1
             tok = int(nxt[0])
+
+    @pytest.mark.parametrize("kv_wire,tol", [
+        ("f32", TOL_F32), ("int8", TOL_INT8_KV),
+    ])
+    def test_decode_kernel_walks_rows_padded_to_whole_tiles(
+        self, gpt, kv_wire, tol
+    ):
+        """Two heads of 16 lanes do not fill a lane row: the pool's rows
+        are one head padded to a whole 128-lane tile, written by the
+        prefill and the appends, and the decode program's attention is
+        the page-walk kernel over them (forced here; interpreted) — the
+        same envelope against the growing full forward."""
+        from apex_tpu.ops import _dispatch
+
+        cfg, model, params = gpt
+        eng = make_engine(gpt, kv_wire=kv_wire)
+        assert eng.cache["k"].shape[2:] == (2, 8, 128)
+        rs = np.random.RandomState(6)
+        prompt = [int(t) for t in rs.randint(0, cfg.vocab_size, size=13)]
+        pages = eng.pool.alloc(eng.pool.pages_for(len(prompt)))
+        _, tok = eng.prefill(prompt, pages)
+        cur, ctx = list(prompt), len(prompt)
+        table = np.zeros((2, 8), np.int32)
+        _dispatch.set_use_pallas(True)
+        try:
+            for _ in range(4):
+                if ctx // 8 >= len(pages):
+                    pages += eng.pool.alloc(1)
+                table[0, : len(pages)] = pages
+                logits, nxt = eng.decode(
+                    np.array([tok, 0]), np.array([ctx + 1, 0]), table
+                )
+                cur.append(tok)
+                ref = ref_logits(model, params, cur)[-1]
+                assert np.abs(logits[0] - ref).max() <= tol, kv_wire
+                ctx += 1
+                tok = int(nxt[0])
+        finally:
+            _dispatch.set_use_pallas(None)
+        assert _dispatch.last_paths()["paged_decode_attention"] == "pallas"
+        # the rows' padding lanes were never written
+        assert not np.asarray(eng.cache["k"][..., 16:]).any()
 
     def test_weight_wire_int8_stays_close(self, gpt):
         cfg, model, params = gpt

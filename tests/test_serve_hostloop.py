@@ -149,6 +149,46 @@ class TestHostCounters:
         assert vals["serve/ttft_ms"] > 0.0
         assert vals["serve/ttft_prefill_ms_p95"] > 0.0
 
+    @pytest.mark.parametrize("path", ["pallas", "jnp"])
+    def test_walk_live_share_is_folded_from_the_steps_lengths(
+        self, gpt, monkeypatch, path
+    ):
+        """`serve/decode_walk_live_share`: live pages over the pages the
+        decode kernel's walk copies in, from the lengths of the step's
+        decode call — a Python float, like every other value, reckoned
+        by the kernel's own helper, and published only when the decode
+        program's attention took the kernel: the jnp path walks nothing."""
+        from apex_tpu.ops import _dispatch
+        from apex_tpu.ops.paged_attention import walk_live_share
+
+        reg = MetricRegistry(fetch_every=1)
+        sched = ContinuousBatchingScheduler(make_engine(gpt), registry=reg)
+        pool = sched.engine.cache["k"]
+        width = sched.serve.max_pages_per_seq
+        assert pool.shape[3] == 8 and width == 8  # one step of 8 pages
+        monkeypatch.setattr(
+            _dispatch, "last_paths", lambda: {"paged_decode_attention": path})
+        calls = []
+        decode = sched.engine.decode
+        sched.engine.decode = lambda tokens, lengths, *a, **kw: (
+            calls.append(np.array(lengths)) or decode(
+                tokens, lengths, *a, **kw))
+        for r in script_requests():
+            sched.submit(r)
+        seen = set()
+        while sched.queue or sched.running:
+            n = len(calls)
+            sched.step()
+            if len(calls) > n:
+                got = sched._mstate["serve/decode_walk_live_share"]
+                if path == "pallas":
+                    want = walk_live_share(calls[-1], pool, width)
+                    assert type(got) is float and got == want
+                    assert 1.0 / 8 <= got <= 1.0
+                seen.add(got)
+        # it moved with the riders' lengths, or was never published
+        assert len(seen) > 1 if path == "pallas" else seen == {0.0}
+
     def test_fleet_ledger_is_host_numbers(self, gpt):
         import time
 

@@ -818,12 +818,12 @@ class TestPhasePrimitive:
         from apex_tpu.observability.spans import PROCESS_CAPACITY
 
         rec = SpanRecorder(PROCESS_CAPACITY)
-        for i in range(100_000):
+        for i in range(200_000):
             with rec.phase("serve/batch"):
                 pass
-        assert len(rec.snapshot()) == PROCESS_CAPACITY == 32768
-        assert rec.dropped == 100_000 - PROCESS_CAPACITY
-        assert rec.snapshot()[-1]["seq"] == 99_999
+        assert len(rec.snapshot()) == PROCESS_CAPACITY == 131072
+        assert rec.dropped == 200_000 - PROCESS_CAPACITY
+        assert rec.snapshot()[-1]["seq"] == 199_999
 
     def test_threads_share_a_ring_and_keep_their_own_stacks(self):
         """Replicas stepped from several threads: no entry is lost, no

@@ -57,9 +57,10 @@ GPT2_LARGE = dict(
 PAGE, PAGES, SLOTS, PAGES_PER_SEQ = 16, 1201, 32, 64
 
 
+@pytest.mark.parametrize("kv_wire", ["f32", "int8"])
 @pytest.mark.parametrize("program", ["serve_decode", "serve_prefill_1024"])
 def test_serving_step_never_moves_the_kv_pool(
-    one_chip, lower_as_chip, program
+    one_chip, lower_as_chip, program, kv_wire
 ):
     """At the benchmark's shapes the pool is one buffer in one layout
     from entry to exit: no instruction of the compiled program
@@ -91,9 +92,11 @@ def test_serving_step_never_moves_the_kv_pool(
     assert stack["ln_attn"]["scale"].dtype == jnp.float32
     cache = on_chip(jax.eval_shape(lambda: cache_lib.init_kv_pages(
         cfg.num_layers, PAGES, cfg.num_heads, PAGE,
-        cfg.hidden_size // cfg.num_heads, dtype=cfg.dtype,
+        cfg.hidden_size // cfg.num_heads, dtype=cfg.dtype, kv_wire=kv_wire,
     )))
     assert cache["k"].shape == (36, PAGES, 10, PAGE, 128)
+    if kv_wire == "int8":
+        assert cache["k_scale"].shape == (36, PAGES, 1, PAGE, 128)
     if program == "serve_decode":
         def fn(params, kv, tokens, lengths, tables, temps, rng):
             return model_lib.decode_body(
@@ -141,6 +144,78 @@ def test_serving_step_never_moves_the_kv_pool(
         )
     ]
     assert len(stacked) == 4 and not casts, casts
+
+
+@pytest.mark.parametrize("h,d,page,np_,dtype,kv_wire,rope", [
+    (20, 64, PAGE, PAGES_PER_SEQ, jnp.bfloat16, "f32", False),
+    (20, 64, PAGE, PAGES_PER_SEQ, jnp.bfloat16, "int8", True),
+    (8, 128, 128, 4, jnp.float32, "int8", True),
+    # rows that do not fill their tiles: heads that do not pair up, heads
+    # of 80, 96 and 192 lanes — one head a row, padded to whole tiles
+    (25, 64, PAGE, PAGES_PER_SEQ, jnp.bfloat16, "f32", True),
+    (20, 80, PAGE, PAGES_PER_SEQ, jnp.bfloat16, "int8", True),
+    (12, 96, PAGE, PAGES_PER_SEQ, jnp.float32, "f32", True),
+    (8, 192, PAGE, PAGES_PER_SEQ, jnp.bfloat16, "int8", False),
+    # a 32k-token table on the int8 wire: a step's scale slab in VMEM
+    (32, 128, PAGE, 2048, jnp.bfloat16, "int8", True),
+], ids=["gpt2-large", "gpt2-large-int8-rope", "f32-d128-int8-rope",
+        "25x64-rope", "d80-int8-rope", "d96-f32-rope", "d192-int8",
+        "np2048-int8-rope"])
+def test_paged_decode_walk_compiles(
+    one_chip, lower_as_chip, h, d, page, np_, dtype, kv_wire, rope
+):
+    """The decode kernel's in-kernel walk in Mosaic, over the pool as
+    ``init_kv_pages`` lays it out: page copies out of a pool left in HBM
+    (a copy may slice only whole 128-lane rows, which is why every row —
+    the int8 wire's scale planes' too — is whole tiles), a loop to the
+    sequence's live steps, ``G`` query rows a contraction."""
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    kv = jax.eval_shape(lambda: cache_lib.init_kv_pages(
+        2, 65, h, page, d, dtype=dtype, kv_wire=kv_wire))
+    assert kv["k"].shape[-1] % 128 == 0
+    kw = {n: arg(x.shape, x.dtype) for n, x in kv.items() if "scale" in n}
+    if rope:
+        kw.update(rope_cos=arg((SLOTS, d), dtype),
+                  rope_sin=arg((SLOTS, d), dtype))
+    text = jax.jit(
+        lambda *a, **k: decode_attention.paged_decode_fwd(
+            *a, scale=d ** -0.5, **k)
+    ).lower(
+        arg((SLOTS, h, d), dtype), arg(kv["k"].shape, kv["k"].dtype),
+        arg(kv["v"].shape, kv["v"].dtype), arg((SLOTS, np_), jnp.int32),
+        arg((SLOTS,), jnp.int32), arg((), jnp.int32), **kw
+    ).compile().as_text()
+    assert text.count("paged_decode_fwd") >= 1
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_plain_narrow_pages_reach_the_compiled_walk(
+    one_chip, lower_as_chip, kv_int8
+):
+    """Plain ``(P, H, page, D)`` pages of 64 lanes through the public op
+    — what ``tests_tpu`` feeds the chip: the op pads the rows to whole
+    tiles, so the kernel compiles in Mosaic and no shape falls back."""
+    from apex_tpu.ops.paged_attention import paged_decode_attention
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    b, h, d, page, pool, np_ = 2, 8, 64, 128, 8, 2
+    kw = dict(rope_cos=arg((b, d), jnp.float32),
+              rope_sin=arg((b, d), jnp.float32))
+    if kv_int8:
+        kw.update(k_scale=arg((pool, h, page), jnp.float32),
+                  v_scale=arg((pool, h, page), jnp.float32))
+    pages = arg((pool, h, page, d), jnp.int8 if kv_int8 else jnp.float32)
+    text = jax.jit(paged_decode_attention).lower(
+        arg((b, h, d), jnp.float32), pages, pages,
+        arg((b, np_), jnp.int32), arg((b,), jnp.int32), **kw
+    ).compile().as_text()
+    assert _dispatch.last_paths()["paged_decode_attention"] == "pallas"
+    assert text.count("paged_decode_fwd") >= 1
 
 
 #: Ling-3.0-flash's language stack as benchmark/configs/

@@ -532,12 +532,12 @@ def test_flash_bwd_independent_dq_tiles_on_chip():
 @pytest.mark.parametrize("kv_int8", [False, True])
 @pytest.mark.parametrize("d", [128, 64])
 def test_paged_decode_attention_on_chip(kv_int8, d):
-    """Compiled page-walk kernel (scalar-prefetched page-table index
-    maps + fused q-RoPE + optional in-kernel int8 dequant) vs the jnp
-    gather reference, on the real chip.  Shapes chosen tile-aligned:
-    page=128 rows, H=8 heads, D=128 lanes (one head a lane row) and
-    D=64 (two heads side by side in the row, the serving pool's layout
-    for GPT-2's heads: ``heads_per_row``)."""
+    """Compiled page-walk kernel (page copies through the scalar-prefetched
+    table + fused q-RoPE + optional int8 scales) vs the jnp gather
+    reference, on the real chip, over plain ``(P, H, page, D)`` pages:
+    page=128 rows, H=8 heads, D=128 lanes (one head a lane row) and D=64
+    (rows narrower than a tile: the op pads them to whole 128-lane tiles
+    and the kernel walks them like any pool — no shape falls back)."""
     from apex_tpu.ops.paged_attention import (
         paged_decode_attention,
         paged_decode_attention_reference,
@@ -570,6 +570,9 @@ def test_paged_decode_attention_on_chip(kv_int8, d):
             got = paged_decode_attention(
                 q, k_pages, v_pages, table, lengths, **kw
             )
+            assert (
+                _dispatch.last_paths()["paged_decode_attention"] == "pallas"
+            )
         finally:
             _dispatch.set_use_pallas(None)
         want = paged_decode_attention_reference(
@@ -581,24 +584,99 @@ def test_paged_decode_attention_on_chip(kv_int8, d):
 
 
 @pytest.mark.parametrize("kv_int8", [False, True])
-def test_paged_decode_pool_layout_on_chip(kv_int8):
-    """The serving form at GPT-2 Large's row shape: the whole bf16 pool
-    ``(L, P, H/2, 16, 128)`` read at a layer index through the engine's
-    own helpers (``init_kv_pages`` / ``write_prompt_kv``), Mosaic
-    kernel against the jnp reference on the same pool."""
+def test_paged_decode_walk_at_the_cells_shape_on_chip(kv_int8):
+    """The walk at the serving cells' own shape — 32 slots, 20 heads of
+    64 lanes, 16-row pages, 64 table entries, so 8 pages a step — over a
+    mix of idle, short and 960-token rows whose dead table entries point
+    at a page full of NaN: the compiled kernel against the jnp reference
+    on the clean table, and nothing of a dead entry read."""
+    from apex_tpu.ops.paged_attention import (
+        paged_decode_attention,
+        paged_decode_attention_reference,
+        pages_per_step,
+    )
+    b, h, d, page, np_, layers, pool = 32, 20, 64, 16, 64, 3, 1201
+    assert pages_per_step(page, 10 * 128 * (1 if kv_int8 else 2), np_) == 8
+    rs = np.random.RandomState(2)
+    lengths = np.zeros(b, np.int32)
+    lengths[[1, 2, 5, 7, 8, 13]] = [1, 16, 17, 127, 128, 129]
+    lengths[[3, 9, 20, 31]] = [176, 37, 251, 1024]
+    lengths[[4, 11, 12, 17, 18, 19, 25, 30]] = 960
+    live = -(-lengths // page)
+    ids = list(rs.permutation(pool - 2) + 1)  # page 0 null, the last poison
+    clean = np.zeros((b, np_), np.int32)
+    for row, n in enumerate(live):
+        clean[row, :n] = [ids.pop() for _ in range(n)]
+    dead = np.arange(np_)[None, :] >= live[:, None]
+    dirty = np.where(dead, pool - 1, clean).astype(np.int32)
+
+    shape = (layers, pool, h // 2, page, 2 * d)
+    k = jnp.asarray(rs.randn(*shape), jnp.float32)
+    v = jnp.asarray(rs.randn(*shape), jnp.float32)
+    kw = dict(layer=jnp.asarray(1, jnp.int32))
+    if kv_int8:
+        # the codec at block = D: one scale per (head, token), stored a
+        # token a row and a head a lane, (L, P, 1, page, 128)
+        def encode(x):
+            heads = x.reshape(shape[:-1] + (2, d))
+            scale = jnp.max(jnp.abs(heads), axis=-1) / 127.0
+            codes = jnp.round(heads / scale[..., None]).astype(jnp.int8)
+            rows = jnp.swapaxes(scale, 2, 3).reshape(layers, pool, page, h)
+            rows = jnp.pad(rows, [(0, 0)] * 3 + [(0, 128 - h)])
+            return codes.reshape(shape), rows[:, :, None]
+
+        (k, ks), (v, vs) = encode(k), encode(v)
+        kw.update(k_scale=ks.at[:, -1].set(jnp.nan),
+                  v_scale=vs.at[:, -1].set(jnp.nan))
+        k, v = k.at[:, -1].set(127), v.at[:, -1].set(127)
+    else:
+        k = k.astype(jnp.bfloat16).at[:, -1].set(jnp.nan)
+        v = v.astype(jnp.bfloat16).at[:, -1].set(jnp.nan)
+    q = jnp.asarray(rs.randn(b, h, d), jnp.bfloat16)
+    lengths = jnp.asarray(lengths)
+    _dispatch.set_use_pallas(True)
+    try:
+        got = paged_decode_attention(q, k, v, jnp.asarray(dirty), lengths, **kw)
+        assert _dispatch.last_paths()["paged_decode_attention"] == "pallas"
+        same = paged_decode_attention(q, k, v, jnp.asarray(clean), lengths, **kw)
+    finally:
+        _dispatch.set_use_pallas(None)
+    want = paged_decode_attention_reference(
+        q, k, v, jnp.asarray(clean), lengths, **kw
+    )
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(same))
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=2e-2, rtol=2e-2,
+    )
+    assert not np.asarray(got, np.float32)[np.asarray(lengths) == 0].any()
+
+
+@pytest.mark.parametrize("kv_int8", [False, True])
+@pytest.mark.parametrize("h,d,rows", [
+    (20, 64, (10, 128)), (25, 64, (25, 128)), (20, 80, (20, 128)),
+    (12, 96, (12, 128)), (8, 192, (8, 256)),
+], ids=["gpt2-large", "25x64", "d80", "d96", "d192"])
+def test_paged_decode_pool_layout_on_chip(kv_int8, h, d, rows):
+    """The serving form: the whole bf16 pool ``(L, P, H/G, 16, W)`` read
+    at a layer index through the engine's own helpers (``init_kv_pages``
+    / ``write_prompt_kv``), Mosaic kernel against the jnp reference on
+    the same pool — at GPT-2 Large's row shape (two heads a 128-lane
+    row) and at rows that do not fill their tiles (25 heads of 64, heads
+    of 80, 96 and 192 lanes: one head a row, padded), with fused RoPE."""
     from apex_tpu.ops.paged_attention import (
         paged_decode_attention,
         paged_decode_attention_reference,
     )
     from apex_tpu.serve import cache as cache_lib
 
-    b, h, d, page, np_, layers = 4, 20, 64, 16, 8, 3
+    b, page, np_, layers = 4, 16, 8, 3
     rs = np.random.RandomState(1)
     kv = cache_lib.init_kv_pages(
         layers, 1 + b * np_, h, page, d, dtype=jnp.bfloat16,
         kv_wire="int8" if kv_int8 else "f32",
     )
-    assert kv["k"].shape == (layers, 1 + b * np_, h // 2, page, 2 * d)
+    assert kv["k"].shape == (layers, 1 + b * np_) + rows[:1] + (page,) + rows[1:]
     table = jnp.arange(1, 1 + b * np_, dtype=jnp.int32).reshape(b, np_)
     for layer in range(layers):
         for seq in range(b):
@@ -609,7 +687,9 @@ def test_paged_decode_pool_layout_on_chip(kv_int8):
     lengths = jnp.asarray([np_ * page, 37, 1, 0], jnp.int32)
     args = (q, kv["k"], kv["v"], table, lengths)
     kw = dict(layer=jnp.asarray(1, jnp.int32),
-              k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
+              k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"),
+              rope_cos=jnp.asarray(rs.randn(b, d), jnp.bfloat16),
+              rope_sin=jnp.asarray(rs.randn(b, d), jnp.bfloat16))
     _dispatch.set_use_pallas(True)
     try:
         got = paged_decode_attention(*args, **kw)
