@@ -3,12 +3,21 @@
 :class:`HybridConfig` says, layer by layer, which **mixer** a block has
 (``"kda"``: Kimi Delta Attention, a linear-attention layer with a
 per-sequence recurrent state; ``"mla"``: multi-head latent attention, whose
-cache is one latent row a token) and which **FFN** (``"dense"``: SwiGLU;
+cache is one latent row a token; ``"ssm_gqa"``: Falcon-H1's parallel block,
+a Mamba-2 state-space branch with a per-sequence state and a grouped-query
+attention branch over K/V pages, both reading one norm and both added to
+the residual) and which **FFN** (``"dense"``: SwiGLU;
 ``"moe"``: a routed SwiGLU layer plus a shared expert), over RMSNorm
 pre-norm blocks, an untied head and partial rotary on MLA's rope
 dimensions.  It stands beside :class:`apex_tpu.models.gpt.GptConfig`:
 ``apex_tpu.serve`` takes either, and runs both through one block
 (``serve/model.py::_block``).
+
+The per-layer pattern is the configuration's DATA: ``pattern`` is a tuple
+of ``(mixer, ffn)``, one a layer, built by whoever constructs the config.
+``layer_group_size`` / ``first_dense_layers`` stay as a way to *state* the
+Ling family's pattern (:func:`ling_pattern`), used when ``pattern`` is not
+given.
 
 The routed FFN is described as ONE CHIP'S SHARE of an expert-parallel
 group: ``num_experts`` is what the router scores, ``held_experts = (first,
@@ -31,11 +40,27 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["HybridConfig", "param_shapes", "init_params", "leaf_rule",
-           "draw_leaf", "path_names"]
+__all__ = ["HybridConfig", "ling_pattern", "param_shapes", "init_params",
+           "leaf_rule", "draw_leaf", "path_names"]
 
-KDA, MLA = "kda", "mla"
+KDA, MLA, SSM_GQA = "kda", "mla", "ssm_gqa"
 DENSE, MOE = "dense", "moe"
+#: the mixer kinds that keep a per-sequence recurrent state
+RECURRENT = (KDA, SSM_GQA)
+
+
+def ling_pattern(num_layers: int, layer_group_size: int,
+                 first_dense_layers: int, routed: bool):
+    """The Ling family's pattern: layer ``i`` is MLA when ``(i + 1) %
+    layer_group_size == 0``, else KDA; the first ``first_dense_layers``
+    layers (every layer of a model with no experts) keep a dense FFN."""
+    return tuple(
+        (
+            MLA if (i + 1) % layer_group_size == 0 else KDA,
+            DENSE if i < first_dense_layers or not routed else MOE,
+        )
+        for i in range(num_layers)
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,13 +69,18 @@ class HybridConfig:
     hidden_size: int
     num_layers: int
     num_heads: int
-    head_dim: int                   # KDA key/value width
+    head_dim: int                   # KDA key/value width; GQA head width
     intermediate_size: int          # dense SwiGLU width
     max_seq_len: int
+    #: ``((mixer, ffn), ...)``, one a layer; None: the Ling pattern stated
+    #: by the next two fields (:func:`ling_pattern`)
+    pattern: Optional[Tuple[Tuple[str, str], ...]] = None
     #: layer i is MLA when (i + 1) % layer_group_size == 0, else KDA
     layer_group_size: int = 6
     #: the first layers keep a dense FFN
     first_dense_layers: int = 0
+    #: K/V heads of a grouped-query attention branch (0: ``num_heads``)
+    num_kv_heads: int = 0
     # -- routed FFN ------------------------------------------------------
     num_experts: int = 0            # experts the router scores (0: none)
     held_experts: Tuple[int, int] = (0, 0)   # (first, count) held here
@@ -70,6 +100,24 @@ class HybridConfig:
     conv_kernel: int = 4
     kda_lower_bound: float = -5.0
     rms_eps: float = 1e-6
+    # -- Mamba-2 branch of "ssm_gqa" ---------------------------------------
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_chunk: int = 128
+    # -- muP multipliers (Falcon-H1; 1 elsewhere) --------------------------
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    #: over in_proj's z | x | B | C | dt channels
+    ssm_multipliers: Tuple[float, ...] = (1.0,) * 5
+    #: on the SwiGLU gate's pre-activation, on the FFN's output
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
     dtype: Any = jnp.bfloat16       # compute dtype
     param_dtype: Any = jnp.bfloat16
     # what the engine asks of every model description
@@ -87,18 +135,49 @@ class HybridConfig:
             )
         if self.num_experts and self.num_experts % self.n_group:
             raise ValueError("n_group must divide num_experts")
+        pattern = self.pattern
+        if pattern is None:
+            pattern = ling_pattern(
+                self.num_layers, self.layer_group_size,
+                self.first_dense_layers, bool(self.num_experts))
+        pattern = tuple((str(m), str(f)) for m, f in pattern)
+        if len(pattern) != self.num_layers:
+            raise ValueError(
+                f"pattern names {len(pattern)} layers, num_layers is "
+                f"{self.num_layers}")
+        for mixer, ffn in pattern:
+            if mixer not in (KDA, MLA, SSM_GQA) or ffn not in (DENSE, MOE):
+                raise ValueError(f"unknown layer kind {(mixer, ffn)}")
+        object.__setattr__(self, "pattern", pattern)
+        if any(m == SSM_GQA for m, _ in pattern):
+            kv = self.kv_heads
+            if self.num_heads % kv:
+                raise ValueError("num_kv_heads must divide num_heads")
+            if not (self.ssm_heads and self.ssm_head_dim and self.ssm_state):
+                raise ValueError(
+                    "an ssm_gqa layer needs ssm_heads, ssm_head_dim and "
+                    "ssm_state")
+            if self.ssm_heads % self.ssm_groups:
+                raise ValueError("ssm_groups must divide ssm_heads")
 
     @property
     def kinds(self) -> Tuple[Tuple[str, str], ...]:
         """``((mixer, ffn), ...)``, one a layer."""
-        return tuple(
-            (
-                MLA if (i + 1) % self.layer_group_size == 0 else KDA,
-                DENSE if i < self.first_dense_layers or not self.num_experts
-                else MOE,
-            )
-            for i in range(self.num_layers)
-        )
+        return self.pattern
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def ssm_width(self) -> int:
+        """``d_ssm``: the state-space branch's inner width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels the short convolution runs over: ``x | B | C``."""
+        return self.ssm_width + 2 * self.ssm_groups * self.ssm_state
 
     def layers_of(self, mixer: str) -> Tuple[int, ...]:
         return tuple(i for i, k in enumerate(self.kinds) if k[0] == mixer)
@@ -106,7 +185,7 @@ class HybridConfig:
     @property
     def stateful(self) -> bool:
         """Some layer keeps a per-sequence recurrent state."""
-        return bool(self.layers_of(KDA))
+        return any(k[0] in RECURRENT for k in self.kinds)
 
     @property
     def routed(self) -> bool:
@@ -134,6 +213,24 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, ffn: str) -> dict:
             "ogate": {"weight": _mat(cfg, h, n)},
             "o_norm": {"scale": _f32(d)},
             "out": {"weight": _mat(cfg, n * d, h)},
+        }
+    elif mixer == SSM_GQA:
+        ds, c, nh = cfg.ssm_width, cfg.ssm_conv_width, cfg.ssm_heads
+        lp["ssm"] = {
+            # z | x B C | dt, one matmul
+            "in_proj": {"weight": _mat(cfg, h, ds + c + nh)},
+            "conv": _f32(cfg.conv_kernel, c),
+            "conv_bias": _f32(c),
+            "dt_bias": _f32(nh),
+            "a_log": _f32(nh),
+            "d": _f32(nh),
+            "norm": {"scale": _f32(ds)},
+            "out_proj": {"weight": _mat(cfg, ds, h)},
+        }
+        lp["attn"] = {
+            # q | k | v, one matmul
+            "wqkv": {"weight": _mat(cfg, h, (n + 2 * cfg.kv_heads) * d)},
+            "wo": {"weight": _mat(cfg, n * d, h)},
         }
     else:
         dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -183,14 +280,21 @@ def leaf_rule(names: Tuple[str, ...], hidden: int):
     are ones; the expert bias zeros; the router N(0, 1/sqrt(hidden))
     (unit-spread logits over a unit-RMS input, so the top-k is no tie); the
     decay-gate bias U(-6, -2) (decays that remember tens to hundreds of
-    tokens); the conv taps N(0, 0.5); every other matrix N(0, 0.02)."""
+    tokens); the conv taps N(0, 0.5); every other matrix N(0, 0.02).  The
+    state-space leaves follow Mamba-2's published init: ``a_log = log A``, A
+    ~ U(1, 16); ``dt_bias`` the inverse softplus of a step log-uniform in
+    [1e-3, 1e-1]; ``d`` ones; the conv bias zeros."""
     name = names[-2] if names[-1] == "weight" else names[-1]
-    if names[-1] == "scale":
+    if names[-1] == "scale" or name == "d":
         return "ones", 0.0, 0.0
-    if name == "expert_bias":
+    if name in ("expert_bias", "conv_bias"):
         return "zeros", 0.0, 0.0
     if name == "g_bias":
         return "uniform", -6.0, -2.0
+    if name == "a_log":
+        return "log_of_uniform", 1.0, 16.0
+    if name == "dt_bias":
+        return "inv_softplus_log_uniform", 1e-3, 1e-1
     return "normal", 0.0, {"router": hidden ** -0.5, "conv": 0.5}.get(
         name, 0.02)
 
@@ -204,6 +308,13 @@ def draw_leaf(law: str, shape, dtype, key, a, b):
         return jnp.zeros(shape, dtype)
     if law == "uniform":
         return jax.random.uniform(key, shape, jnp.float32, a, b).astype(dtype)
+    if law == "log_of_uniform":
+        return jnp.log(
+            jax.random.uniform(key, shape, jnp.float32, a, b)).astype(dtype)
+    if law == "inv_softplus_log_uniform":
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, jnp.log(a), jnp.log(b)))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
     return (a + b * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 

@@ -115,6 +115,7 @@ def paged_decode_attention_reference(
     q, k_pages, v_pages, page_table, lengths, *,
     layer=None, scale: Optional[float] = None,
     k_scale=None, v_scale=None, rope_cos=None, rope_sin=None,
+    kv_heads: Optional[int] = None,
 ):
     """Gather-then-attend jnp composition — the correctness reference.
 
@@ -133,8 +134,12 @@ def paged_decode_attention_reference(
         cos = rope_cos.astype(jnp.float32)[:, None, :]  # (B, 1, D)
         sin = rope_sin.astype(jnp.float32)[:, None, :]
         qf = qf * cos + rotate_half(qf) * sin
-    k = gather_history(k_pages, k_scale, layer, page_table, h, d)
-    v = gather_history(v_pages, v_scale, layer, page_table, h, d)
+    hkv = h if kv_heads is None else kv_heads
+    k = gather_history(k_pages, k_scale, layer, page_table, hkv, d)
+    v = gather_history(v_pages, v_scale, layer, page_table, hkv, d)
+    if hkv != h:
+        # grouped-query heads: query head i reads KV head i // (H / kv)
+        k, v = (jnp.repeat(x, h // hkv, axis=1) for x in (k, v))
     s = jnp.einsum("bhd,bhtd->bht", qf, k) * scale
     pos = jnp.arange(np_ * page, dtype=jnp.int32)
     valid = pos[None, :] < lengths[:, None]  # (B, T)
@@ -153,6 +158,7 @@ def paged_decode_attention(
     q, k_pages, v_pages, page_table, lengths, *,
     layer=None, scale: Optional[float] = None,
     k_scale=None, v_scale=None, rope_cos=None, rope_sin=None,
+    kv_heads: Optional[int] = None,
 ):
     """Single-query attention over the paged KV cache.
 
@@ -168,7 +174,10 @@ def paged_decode_attention(
       lane_width(H))`` (a token a row, a head a lane) / ``(P, H,
       page)`` — the ``parallel/comm.py`` codec at ``block = D``;
     - ``page_table`` (B, NP) int32; ``lengths`` (B,) int32: live KV
-      positions per sequence including the current token.
+      positions per sequence including the current token;
+    - ``kv_heads``: the heads the pool holds when fewer than ``H``
+      (grouped-query attention: query head ``i`` reads KV head ``i // (H /
+      kv_heads)``; the kernel copies a page in once for all of them).
 
     Returns (B, H, D) in ``q.dtype``.  Inference-only (no VJP;
     gradients are stopped).  Dispatch: the Pallas in-place page-walk
@@ -185,6 +194,8 @@ def paged_decode_attention(
         scale=scale, k_scale=k_scale, v_scale=v_scale,
         rope_cos=rope_cos, rope_sin=rope_sin,
     )
+    if kv_heads is not None and kv_heads != q.shape[1]:
+        kw["kv_heads"] = kv_heads
     if _dispatch.use_pallas():
         _dispatch.record_path("paged_decode_attention", "pallas")
         out = paged_decode_fwd(*args, layer, **kw)

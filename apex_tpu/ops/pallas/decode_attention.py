@@ -170,12 +170,13 @@ def _live_page(pt, ln, b, n, page):
 
 def _decode_plan(
     b, h, d, layers, p_, page, np_, dtype, kv_dtype, *,
-    groups, has_scales, has_rope,
+    groups, has_scales, has_rope, rep=1,
 ):
     """The ``pallas_call``'s arguments: grid ``(B,)``, the pool (and the
     int8 wire's scale planes) left in HBM, the step's buffers as
-    scratch."""
-    hg, w = h // groups, lane_width(d * groups)
+    scratch.  ``rep`` query heads read each of the pool's ``h / rep`` KV
+    heads (grouped-query attention; 1: every head its own K/V)."""
+    hg, w = h // (groups * rep), lane_width(d * groups)
     pool = (layers, p_, hg, page, w)
     _, pp = _pool_step(jax.ShapeDtypeStruct(pool, kv_dtype), np_)
     # a token's scales a row, a head a lane, whole tiles like a page's
@@ -185,9 +186,9 @@ def _decode_plan(
         return (b, 0, 0, 0)
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [pl.BlockSpec((1, 1, hg, w), row), hbm, hbm]
+    in_specs = [pl.BlockSpec((1, rep, hg, w), row), hbm, hbm]
     in_names = ["q", "k_pages", "v_pages"]
-    in_shapes = [(b, 1, hg, w), pool, pool]
+    in_shapes = [(b, rep, hg, w), pool, pool]
     in_dtypes = [dtype, kv_dtype, kv_dtype]
     if has_scales:
         in_specs += [hbm, hbm]
@@ -214,9 +215,9 @@ def _decode_plan(
         in_names=in_names,
         in_shapes=in_shapes,
         in_dtypes=in_dtypes,
-        out_specs=[pl.BlockSpec((1, 1, hg, w), row)],
+        out_specs=[pl.BlockSpec((1, rep, hg, w), row)],
         out_names=["o"],
-        out_shape=[jax.ShapeDtypeStruct((b, 1, hg, w), dtype)],
+        out_shape=[jax.ShapeDtypeStruct((b, rep, hg, w), dtype)],
         scratch_shapes=[
             pltpu.VMEM((2, pp) + shape[2:], dt) for shape, dt in paged
         ] + [pltpu.SemaphoreType.DMA((2, len(paged), pp))],
@@ -238,7 +239,7 @@ def heads_per_row(num_heads: int, head_dim: int) -> int:
 
 def kernel_specs(
     b, h, d, *, pool_pages, page, pages_per_seq, dtype=jnp.bfloat16,
-    kv_wire="f32", rope=True, page_table=None, lengths=None,
+    kv_wire="f32", rope=True, page_table=None, lengths=None, kv_heads=None,
 ):
     """Export the paged-decode kernel's :class:`introspect.KernelSpec`
     without compiling (a one-layer pool in the serving layout).  The
@@ -263,10 +264,12 @@ def kernel_specs(
     if lengths is None:
         lengths = np.full((b,), pages_per_seq * page, np.int32)
     lengths = np.asarray(lengths, np.int32)
-    groups = heads_per_row(h, d)
+    hkv = h if kv_heads is None else kv_heads
+    groups = heads_per_row(hkv, d)
     plan = _decode_plan(
         b, h, d, 1, pool_pages, page, pages_per_seq, dtype, kv_dtype,
         groups=groups, has_scales=kv_wire == "int8", has_rope=rope,
+        rep=h // hkv,
     )
     pp = plan.pop("pages_per_step")
     steps = -(-pages_per_seq // pp)
@@ -319,8 +322,8 @@ def kernel_specs(
         flops_per_cell=4.0 * h * rows * w,
         # the joined f32 K and V blocks, scores and probabilities
         intermediates=(
-            ((h // groups, rows, w), jnp.float32),
-            ((h // groups, rows, w), jnp.float32),
+            ((hkv // groups, rows, w), jnp.float32),
+            ((hkv // groups, rows, w), jnp.float32),
             ((h, rows), jnp.float32), ((h, rows), jnp.float32),
         ),
     )
@@ -376,7 +379,7 @@ def _decode_kernel(
     *, scale, page, pp, groups, d, prec,
 ):
     b = pl.program_id(0)
-    hg, w = q_ref.shape[2:]
+    rep, hg, w = q_ref.shape[1:]
     rows = pp * page
     length = len_ref[b]
     planes = [(k_hbm, k_buf), (v_hbm, v_buf)]
@@ -404,12 +407,19 @@ def _decode_kernel(
         steps = (length + (rows - 1)) // rows
         for c in copies(0, 0):
             c.start()
-        q = q_ref[0, 0].astype(jnp.float32)  # (H/G, W)
-        if cos_ref is not None:
-            cos = cos_ref[0].astype(jnp.float32)  # (1, W)
-            sin = sin_ref[0].astype(jnp.float32)
-            q = q * cos + _rotate_half_rows(q, d, groups) * sin
-        qs = jnp.where(own, q[:, None, :], 0.0)  # (H/G, G, W)
+        # a KV head's ``rep`` query heads (grouped-query attention) are
+        # ``rep`` more query rows over the same block: row ``i * G + g``
+        # is query ``i`` of the lane row's head ``g``, so a page is copied
+        # in once for all of them
+        qs = []
+        for i in range(rep):
+            q = q_ref[0, i].astype(jnp.float32)  # (H/G, W)
+            if cos_ref is not None:
+                cos = cos_ref[0].astype(jnp.float32)  # (1, W)
+                sin = sin_ref[0].astype(jnp.float32)
+                q = q * cos + _rotate_half_rows(q, d, groups) * sin
+            qs.append(jnp.where(own, q[:, None, :], 0.0))  # (H/G, G, W)
+        qs = qs[0] if rep == 1 else jnp.concatenate(qs, axis=1)
 
         def step(j, carry):
             m, l, acc = carry
@@ -454,13 +464,20 @@ def _decode_kernel(
             return m_new, l, acc
 
         m, l, acc = jax.lax.fori_loop(0, steps, step, (
-            jnp.full((hg, groups, 1), -jnp.inf, jnp.float32),
-            jnp.zeros((hg, groups, 1), jnp.float32),
-            jnp.zeros((hg, groups, w), jnp.float32),
+            jnp.full((hg, rep * groups, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((hg, rep * groups, 1), jnp.float32),
+            jnp.zeros((hg, rep * groups, w), jnp.float32),
         ))
         # length > 0: position 0 is live, so l >= 1
-        o = jnp.sum(jnp.where(own, acc / l, 0.0), axis=1)
-        o_ref[...] = o.astype(o_ref.dtype)[None, None]
+        if rep == 1:
+            o = jnp.sum(jnp.where(own, acc / l, 0.0), axis=1)
+            o_ref[...] = o.astype(o_ref.dtype)[None, None]
+        else:
+            o = acc / l
+            for i in range(rep):
+                rows_i = o[:, i * groups:(i + 1) * groups]
+                o_ref[0, i] = jnp.sum(
+                    jnp.where(own, rows_i, 0.0), axis=1).astype(o_ref.dtype)
 
 
 def _decode_entry(*refs, has_scales, has_rope, **kw):
@@ -490,10 +507,11 @@ def pad_lanes(x, w):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, w - x.shape[-1])])
 
 
-@functools.partial(jax.jit, static_argnames=("scale",))
+@functools.partial(jax.jit, static_argnames=("scale", "kv_heads"))
 def paged_decode_fwd(
     q, k_pages, v_pages, page_table, lengths, layer, *,
     scale, k_scale=None, v_scale=None, rope_cos=None, rope_sin=None,
+    kv_heads=None,
 ):
     """Single-query attention over one layer of the paged KV pool.
 
@@ -510,24 +528,32 @@ def paged_decode_fwd(
       the current token (whose k/v the caller appended before calling);
     - ``layer`` () int32: which layer of the pool to read;
     - ``rope_cos`` / ``rope_sin`` (B, D): the rotation rows of each
-      sequence's current position — fused onto ``q`` in-kernel.
+      sequence's current position — fused onto ``q`` in-kernel;
+    - ``kv_heads`` (static): the heads the pool holds when fewer than
+      ``H`` (grouped-query attention: query head ``i`` reads KV head ``i //
+      (H / kv_heads)``; a page is copied in once for all the query heads
+      of its KV heads).  None: ``H``.
 
     Returns (B, H, D) in ``q.dtype``; rows with ``lengths == 0`` are
     exactly zero.
     """
     b, h, d = q.shape
     layers, p_, hg, page, w = k_pages.shape
-    groups = h // hg
-    if hg * groups != h or w != lane_width(d * groups):
+    hkv = h if kv_heads is None else kv_heads
+    rep, groups = h // hkv, hkv // hg
+    if hkv * rep != h or hg * groups != hkv or w != lane_width(d * groups):
         raise ValueError(
-            f"pool rows {(hg, w)} do not hold {h} heads of {d} lanes in "
-            f"whole 128-lane tiles"
+            f"pool rows {(hg, w)} do not hold {hkv} heads of {d} lanes in "
+            f"whole 128-lane tiles (for {h} query heads)"
         )
     np_ = page_table.shape[1]
     has_scales = k_scale is not None
     has_rope = rope_cos is not None
     if has_scales != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be given together")
+    if has_scales and rep > 1:
+        raise ValueError(
+            "the int8 KV wire is not written for grouped-query heads")
     planes = (layers, p_, 1, page, lane_width(h))
     if has_scales and not (k_scale.shape == v_scale.shape == planes):
         raise ValueError(
@@ -546,13 +572,20 @@ def paged_decode_fwd(
     # q as (B, 1, H/G, W): a token's (H, D) row IS its lane rows
     plan = _decode_plan(
         b, h, d, layers, p_, page, np_, q.dtype, k_pages.dtype,
-        groups=groups, has_scales=has_scales, has_rope=has_rope,
+        groups=groups, has_scales=has_scales, has_rope=has_rope, rep=rep,
     )
     pp = plan["pages_per_step"]
     page_table = jnp.asarray(page_table, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)
-    args = [pad_lanes(q.reshape(b, 1, hg, d * groups), w), k_pages, v_pages]
+    if rep == 1:
+        rows = q.reshape(b, 1, hg, d * groups)
+    else:
+        # query i of KV head (r, g) to row i of lane row r, head g's lanes
+        rows = jnp.transpose(
+            q.reshape(b, hg, groups, rep, d), (0, 3, 1, 2, 4)
+        ).reshape(b, rep, hg, d * groups)
+    args = [pad_lanes(rows, w), k_pages, v_pages]
     if has_scales:
         args += [k_scale, v_scale]
     if has_rope:
@@ -589,4 +622,7 @@ def paged_decode_fwd(
     )(page_table, lengths, layer.reshape(1), *args)
     if w != d * groups:
         out = out[..., :d * groups]
+    if rep > 1:
+        out = jnp.transpose(
+            out.reshape(b, rep, hg, groups, d), (0, 2, 3, 1, 4))
     return out.reshape(b, h, d)

@@ -39,8 +39,13 @@ exactly ``live_pages / usable_pages`` at all times.
 
 **The cache set of a stack whose layers differ in kind**
 (:func:`init_hybrid_cache`, docs/serving.md "Layer kinds and the cache
-set") holds two more kinds of state beside — or instead of — K/V pages,
-all of it the layer loop's carry and donated like the pool:
+set") is DECLARED: each mixer kind says once what it keeps
+(:class:`CacheKind`: per token or per slot, over how many layers, a row's
+shape, its dtype — :func:`hybrid_cache_kinds`), and the allocation, the
+scheduler's gauges and the engine's ``memory-pool-copy`` intent read that
+declaration.  Beside — or instead of — the K/V page kind above (which a
+grouped-query branch declares at its ``num_kv_heads``) it holds, all of it
+the layer loop's carry and donated like the pool:
 
 - a **latent page kind** — one row a token for every latent-attention
   (MLA) layer, ``(L_mla, P, 1, page, W)``: the normalised latent and the
@@ -54,6 +59,9 @@ all of it the layer loop's carry and donated like the pool:
   back by page.  A slot's rows are written whole by the prefill that
   admits a sequence into it (so a reused slot starts from that sequence's
   own state, whatever it held) and advanced in place by every decode step.
+  A state-space (Mamba-2) branch keeps the same two things under its own
+  names: ``"ssm"`` ``(L_ssm, B, H, P, N)`` f32 and ``"ssm_conv"``
+  ``(L_ssm, B, taps - 1, C)``.
 
 The device-side write helpers here are pure functions meant to be
 called INSIDE the engine's jitted step programs, on the WHOLE pool with
@@ -66,9 +74,10 @@ layer-sized temporary).
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 from collections import Counter
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -93,6 +102,8 @@ __all__ = [
     "append_rows",
     "write_prompt_kv",
     "append_token_kv",
+    "CacheKind",
+    "hybrid_cache_kinds",
     "init_hybrid_cache",
     "write_prompt_latent",
     "append_token_latent",
@@ -626,27 +637,98 @@ def append_token_kv(kv, layer, page_ids, slots, k, v):
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass(frozen=True)
+class CacheKind:
+    """One entry of a hybrid stack's cache set, declared by the mixer kind
+    that keeps it.  ``per`` says what a row belongs to: ``"token"`` — a
+    page kind, ``(layers, num_pages, *shape)``, ``shape`` one page's
+    ``(rows, page, lanes)``, addressed through the page tables and
+    :class:`PagePool` — or ``"slot"`` — ``(layers, max_batch, *shape)``,
+    one row a decode slot, written whole by the prefill that admits a
+    sequence and advanced in place by every decode step.  ``in_place``: the
+    entry is under the ``memory-pool-copy`` rule (no program may hold a
+    layer-sized temporary of it) and counts as the model's state in
+    ``serve/state/bytes``; a convolution tail is not (a step rewrites a
+    layer of it whole, and it is small)."""
+
+    name: str
+    per: str
+    layers: int
+    shape: Tuple[int, ...]
+    dtype: Any
+    in_place: bool = True
+
+    def full_shape(self, num_pages: int, max_batch: int) -> Tuple[int, ...]:
+        rows = num_pages if self.per == "token" else max_batch
+        return (self.layers, rows) + tuple(self.shape)
+
+    @property
+    def row_bytes(self) -> int:
+        """Bytes of one page, or of one slot's share, over all the layers."""
+        return self.layers * int(np.prod(self.shape)) * jnp.dtype(
+            self.dtype).itemsize
+
+
+def _kda_kinds(cfg, n, page_size):
+    h, d = cfg.num_heads, cfg.head_dim
+    return (
+        CacheKind("state", "slot", n, (h, d, d), jnp.float32),
+        CacheKind("conv", "slot", n, (cfg.conv_kernel - 1, 3 * h * d),
+                  cfg.dtype, in_place=False),
+    )
+
+
+def _mla_kinds(cfg, n, page_size):
+    w = latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+    return (CacheKind("latent", "token", n, (1, page_size, w), cfg.dtype),)
+
+
+def _ssm_gqa_kinds(cfg, n, page_size):
+    # K and V as the GPT pool lays them (init_kv_pages), at the KV heads
+    kv, d = cfg.kv_heads, cfg.head_dim
+    g = heads_per_row(kv, d)
+    page = (kv // g, page_size, lane_width(d * g))
+    return (
+        CacheKind("k", "token", n, page, cfg.dtype),
+        CacheKind("v", "token", n, page, cfg.dtype),
+        CacheKind("ssm", "slot", n,
+                  (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                  jnp.float32),
+        CacheKind("ssm_conv", "slot", n,
+                  (cfg.conv_kernel - 1, cfg.ssm_conv_width), cfg.dtype,
+                  in_place=False),
+    )
+
+
+#: what each mixer kind keeps: ``(cfg, layers of the kind, page size) ->
+#: CacheKinds``.  A new mixer kind declares its state HERE, once.
+_KINDS_OF = {"kda": _kda_kinds, "mla": _mla_kinds, "ssm_gqa": _ssm_gqa_kinds}
+
+
+def hybrid_cache_kinds(cfg, page_size: int) -> Tuple[CacheKind, ...]:
+    """The cache set a :class:`~apex_tpu.models.hybrid.HybridConfig` asks
+    for, as declarations: each mixer kind present says what it keeps, over
+    its own layers (a layer's index inside an entry is its rank among the
+    layers of its kind).  A kind with no layer declares nothing."""
+    out = []
+    for mixer, declare in _KINDS_OF.items():
+        n = len(cfg.layers_of(mixer))
+        if n:
+            out.extend(declare(cfg, n, page_size))
+    return tuple(out)
+
+
 def init_hybrid_cache(cfg, num_pages: int, page_size: int,
                       max_batch: int) -> dict:
     """Fresh zeroed cache set for a :class:`~apex_tpu.models.hybrid.
-    HybridConfig`: ``"latent"`` ``(L_mla, P, 1, page, W)`` in the compute
-    dtype, ``"state"`` ``(L_kda, B, H, d_v, d_k)`` f32 and ``"conv"``
-    ``(L_kda, B, taps - 1, 3 H d)`` in the compute dtype.  A kind with no
-    layer has no entry."""
-    cache = {}
-    n_mla, n_kda = len(cfg.layers_of("mla")), len(cfg.layers_of("kda"))
-    if n_mla:
-        w = latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
-        cache["latent"] = jnp.zeros(
-            (n_mla, num_pages, 1, page_size, w), cfg.dtype
-        )
-    if n_kda:
-        h, d = cfg.num_heads, cfg.head_dim
-        cache["state"] = jnp.zeros((n_kda, max_batch, h, d, d), jnp.float32)
-        cache["conv"] = jnp.zeros(
-            (n_kda, max_batch, cfg.conv_kernel - 1, 3 * h * d), cfg.dtype
-        )
-    return cache
+    HybridConfig`, one array a declared :class:`CacheKind`
+    (:func:`hybrid_cache_kinds`): e.g. ``"latent"`` ``(L_mla, P, 1, page,
+    W)`` in the compute dtype, ``"state"`` ``(L_kda, B, H, d_v, d_k)`` f32
+    and ``"conv"`` ``(L_kda, B, taps - 1, 3 H d)`` in the compute dtype."""
+    return {
+        kind.name: jnp.zeros(kind.full_shape(num_pages, max_batch), kind.dtype)
+        for kind in hybrid_cache_kinds(cfg, page_size)
+    }
 
 
 def write_prompt_latent(cache, layer, page_ids, rows):
@@ -666,14 +748,17 @@ def append_token_latent(cache, layer, page_ids, slots, rows):
     ))
 
 
-def write_slot_state(cache, layer, slot, state, conv_tail):
-    """A sequence's whole recurrent state into decode slot ``slot`` of KDA
-    layer ``layer``: ``state`` ``(H, d_v, d_k)``, ``conv_tail`` ``(taps -
-    1, C)``.  What the slot held before is gone."""
-    return dict(
-        cache,
-        state=cache["state"].at[layer, slot].set(state),
-        conv=cache["conv"].at[layer, slot].set(
-            conv_tail.astype(cache["conv"].dtype)
+def write_slot_state(cache, layer, slot, state, conv_tail, *,
+                     names=("state", "conv")):
+    """A sequence's whole recurrent state into decode slot ``slot`` of
+    layer ``layer`` of its kind: ``state`` ``(H, d_v, d_k)``, ``conv_tail``
+    ``(taps - 1, C)``, under the entries ``names`` (KDA's by default; the
+    state-space branch's are ``("ssm", "ssm_conv")``).  What the slot held
+    before is gone."""
+    slab, tail = names
+    return dict(cache, **{
+        slab: cache[slab].at[layer, slot].set(state),
+        tail: cache[tail].at[layer, slot].set(
+            conv_tail.astype(cache[tail].dtype)
         ),
-    )
+    })

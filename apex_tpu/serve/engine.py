@@ -496,6 +496,10 @@ class InferenceEngine:
         }
         self.stateful = model_lib.is_stateful(cfg)
         self.routed = model_lib.is_routed(cfg)
+        #: what a hybrid stack's cache set holds, as its mixer kinds
+        #: declared it (``cache_lib.CacheKind``; ``()``: the GPT K/V pool)
+        self.cache_kinds = () if self.kinds is None else (
+            cache_lib.hybrid_cache_kinds(cfg, self.serve.page_size))
         model_lib.validate_features(cfg, spec=spec is not None)
         if self.kinds is not None and (
             self.serve.kv_wire != "f32" or self.serve.weight_wire != "f32"
@@ -518,7 +522,9 @@ class InferenceEngine:
                 f"max context {self.serve.max_context} exceeds the "
                 f"model's max_seq_len {cfg.max_seq_len}"
             )
-        if cfg.hidden_size % cfg.num_heads:
+        if self.kinds is None and cfg.hidden_size % cfg.num_heads:
+            # the GPT stack's head width is hidden_size / num_heads (a
+            # hybrid stack states its own head_dim)
             raise ValueError("num_heads must divide hidden_size")
         self.registry = registry
         #: how many step trees this engine has derived
@@ -735,12 +741,15 @@ class InferenceEngine:
         strict = (
             jax.default_backend() == "tpu" and self.serve.kv_wire != "int8"
         )
-        # a hybrid stack's latent pool and recurrent slab are under the
-        # same rule; the convolution tails are not (a step rewrites a
-        # layer of them whole: 9 MB at the benchmark's shapes)
+        # a hybrid stack's page kinds and recurrent slabs are under the
+        # same rule; what a kind declares ``in_place=False`` is not (the
+        # convolution tails: a step rewrites a layer of them whole, 9 MB
+        # at the benchmark's shapes)
+        exempt = {k.name for k in self.cache_kinds if not k.in_place}
         return {
             "shapes": [
-                leaf.shape for name, leaf in cache.items() if name != "conv"
+                leaf.shape for name, leaf in cache.items()
+                if name not in exempt
             ],
             "severity": None if strict else analysis.WARNING,
         }

@@ -27,8 +27,10 @@ forward:
 (:data:`_MIXERS`, :data:`_FFNS`): the GPT stack above is ``("mha",
 "gelu")`` throughout and keeps its ``lax.scan``; a
 :class:`~apex_tpu.models.hybrid.HybridConfig` names a kind per layer —
-``"kda"`` (linear attention with a per-slot recurrent state) or ``"mla"``
-(latent attention over latent pages), ``"dense"`` (SwiGLU) or ``"moe"``
+``"kda"`` (linear attention with a per-slot recurrent state), ``"mla"``
+(latent attention over latent pages) or ``"ssm_gqa"`` (a Mamba-2
+state-space branch with a per-slot state and a grouped-query attention
+branch over K/V pages, side by side), ``"dense"`` (SwiGLU) or ``"moe"``
 (a dropless routed layer told which experts it holds, plus a shared
 expert) — over RMSNorm and an untied head, and runs as a Python loop over
 its layers through the same :func:`_block`.  Its two dataflows are the
@@ -62,6 +64,7 @@ import numpy as np
 
 from apex_tpu.models.gpt import GptConfig, _rope_cos_sin
 from apex_tpu.ops import kda as kda_ops
+from apex_tpu.ops import ssm as ssm_ops
 from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.layer_norm import fused_layer_norm_affine
 from apex_tpu.ops.mla import latent_row_width, mla_decode_attention
@@ -165,7 +168,8 @@ def validate_features(cfg, **asked) -> None:
     for name, wanted in asked.items():
         if wanted:
             raise ValueError(
-                f"a model with recurrent (KDA) layers does not run the "
+                f"a model with recurrent (KDA or state-space) layers does "
+                f"not run the "
                 f"{_REFUSED[name]} (ROADMAP M5)"
             )
 
@@ -655,10 +659,18 @@ def _matmul(x, w, dtype):
     )
 
 
-def _swiglu(y, p, dtype):
-    h = jax.nn.silu(_matmul(y, p["gate"]["weight"], dtype)) * _matmul(
-        y, p["up"]["weight"], dtype)
-    return _matmul(h, p["down"]["weight"], dtype)
+def _scaled(x, m: float):
+    """``x * m`` for a configuration's multiplier; ``x`` itself at 1 (a
+    model without muP multipliers traces no multiply)."""
+    return x if m == 1.0 else x * m
+
+
+def _swiglu(y, p, dtype, mults=(1.0, 1.0)):
+    """``mults``: on the gate's pre-activation, on the output."""
+    h = jax.nn.silu(
+        _scaled(_matmul(y, p["gate"]["weight"], dtype), mults[0])
+    ) * _matmul(y, p["up"]["weight"], dtype)
+    return _scaled(_matmul(h, p["down"]["weight"], dtype), mults[1])
 
 
 def _rope_partial(x, positions, theta):
@@ -674,13 +686,35 @@ def _rope_partial(x, positions, theta):
     return xf * cos + rotate_half(xf) * sin
 
 
+def _short_conv(flow, taps_w, pre, kv, name, li, live):
+    """The recurrent mixers' short causal depthwise convolution over ``pre``
+    ``(T, C)`` with taps ``taps_w (taps, C)``.  A prompt's rows before its
+    start are zeros, and the tail a decode step will need is the last ``taps
+    - 1`` inputs AT THE TRUE LENGTH; a decode step reads the slot's tail
+    ``kv[name][li]`` and shifts it by one, but for an idle row (``live (T,
+    1)`` false: a slot past its steps of a decode block may go on in the
+    next one).  Returns ``(mixed f32, the prompt's tail or None, kv)``."""
+    taps, t = taps_w.shape[0], pre.shape[0]
+    if flow.prompt:
+        window = jnp.concatenate(
+            [jnp.zeros((taps - 1, pre.shape[1]), pre.dtype), pre], axis=0)
+        mixed = sum(taps_w[i] * window[i:i + t] for i in range(taps))
+        tail = jax.lax.dynamic_slice_in_dim(window, flow.length, taps - 1, 0)
+        return mixed, tail, kv
+    window = jnp.concatenate([kv[name][li], pre[:, None]], axis=1)
+    mixed = sum(taps_w[i] * window[:, i] for i in range(taps))
+    tails = kv[name].at[li].set(
+        jnp.where(live[:, :, None], window[:, 1:], window[:, :-1]))
+    return mixed, None, dict(kv, **{name: tails})
+
+
 def _kda_mixer(cfg, lp, x, kv, layer, flow):
     """Kimi Delta Attention: q, k, v through a short causal convolution and
     SiLU, q and k L2-normalised, one decay per key channel, a delta-rule
     state per head (:mod:`apex_tpu.ops.kda`), RMSNorm and a sigmoid gate
     per head on the way out."""
     p, dtype = lp["kda"], cfg.dtype
-    n, d, taps = cfg.num_heads, cfg.head_dim, cfg.conv_kernel
+    n, d = cfg.num_heads, cfg.head_dim
     li = cfg.layers_of("kda").index(layer)
     t = x.shape[0]
     y = _rms_norm(x, lp["norm_mixer"], cfg.rms_eps).astype(dtype)
@@ -692,20 +726,7 @@ def _kda_mixer(cfg, lp, x, kv, layer, flow):
     beta = jnp.where(
         live, jax.nn.sigmoid(_matmul(y, p["beta"]["weight"], dtype)), 0.0)
     gate = jax.nn.sigmoid(_matmul(y, p["ogate"]["weight"], dtype))
-    if flow.prompt:
-        # rows before the prompt's start are zeros; the tail a decode step
-        # will need is the last taps-1 inputs AT THE TRUE LENGTH
-        window = jnp.concatenate(
-            [jnp.zeros((taps - 1, pre.shape[1]), dtype), pre], axis=0)
-        mixed = sum(p["conv"][i] * window[i:i + t] for i in range(taps))
-        tail = jax.lax.dynamic_slice_in_dim(window, flow.length, taps - 1, 0)
-    else:
-        window = jnp.concatenate([kv["conv"][li], pre[:, None]], axis=1)
-        mixed = sum(p["conv"][i] * window[:, i] for i in range(taps))
-        # an idle row keeps its tail (a slot past its steps of a decode
-        # block may go on in the next one)
-        kv = dict(kv, conv=kv["conv"].at[li].set(
-            jnp.where(live[:, :, None], window[:, 1:], window[:, :-1])))
+    mixed, tail, kv = _short_conv(flow, p["conv"], pre, kv, "conv", li, live)
     q, k, v = jnp.split(jax.nn.silu(mixed).reshape(t, 3 * n, d), 3, axis=1)
     q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) * d**-0.5
     k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
@@ -786,9 +807,117 @@ def _mla_mixer(cfg, lp, x, kv, layer, flow):
     return x + out.astype(dtype), kv
 
 
+def _ssm_branch(cfg, p, y, kv, li, flow):
+    """The Mamba-2 branch over the block's normed rows ``y`` ``(T, hidden)``
+    f32: ``in_proj`` to ``z | x B C | dt``, a short causal convolution with
+    bias and SiLU over ``x B C``, the selective state-space recurrence
+    (:mod:`apex_tpu.ops.ssm`; state per slot, f32), the skip ``D x``, the
+    gate ``SiLU(z)`` and an RMSNorm inside each group's channels."""
+    dtype = cfg.dtype
+    nh, hd, g, n = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                    cfg.ssm_state)
+    ds, c = cfg.ssm_width, cfg.ssm_conv_width
+    t = y.shape[0]
+    proj = _matmul(
+        _scaled(y, cfg.ssm_in_multiplier), p["in_proj"]["weight"], dtype)
+    if any(m != 1.0 for m in cfg.ssm_multipliers):
+        mz, mx, mb, mc, mdt = cfg.ssm_multipliers
+        proj = proj * np.repeat(
+            np.asarray([mz, mx, mb, mc, mdt], np.float32),
+            [ds, ds, g * n, g * n, nh])
+    z = proj[:, :ds].reshape(t, nh, hd)
+    pre = proj[:, ds:ds + c].astype(dtype)        # what the conv tail keeps
+    live = flow.live[:, None]
+    # a padding or idle row takes no step: its state stays as it was
+    dt = jnp.where(
+        live, jax.nn.softplus(proj[:, ds + c:] + p["dt_bias"]), 0.0)
+    mixed, tail, kv = _short_conv(
+        flow, p["conv"], pre, kv, "ssm_conv", li, live)
+    xbc = jax.nn.silu(mixed + p["conv_bias"])
+    x = xbc[:, :ds].reshape(t, nh, hd)
+    b = xbc[:, ds:ds + g * n].reshape(t, g, n)
+    c_ = xbc[:, ds + g * n:].reshape(t, g, n)
+    a = -jnp.exp(p["a_log"])
+    if flow.prompt:
+        o, state = ssm_ops.ssd_chunked(x, dt, a, b, c_, chunk=cfg.ssm_chunk)
+        kv = cache_lib.write_slot_state(
+            kv, li, flow.slot, state, tail, names=("ssm", "ssm_conv"))
+    else:
+        o, state = ssm_ops.ssm_step(kv["ssm"], li, x, dt, a, b, c_)
+        kv = dict(kv, ssm=state)
+    o = (o + p["d"][:, None] * x) * jax.nn.silu(z)
+    # mamba_rms_norm, norm_before_gate false: RMSNorm after the gate,
+    # inside each group's channels, then one scale a channel
+    og = o.reshape(t, g, ds // g)
+    og = og * jax.lax.rsqrt(
+        jnp.mean(og * og, axis=-1, keepdims=True) + cfg.rms_eps)
+    out = _matmul(
+        og.reshape(t, ds) * p["norm"]["scale"], p["out_proj"]["weight"],
+        dtype)
+    return _scaled(out, cfg.ssm_out_multiplier), kv
+
+
+def _gqa_branch(cfg, p, y, kv, li, flow):
+    """The grouped-query attention branch over the same rows: full-width
+    rotate-half RoPE on q and k, ``num_kv_heads`` K/V heads in the paged
+    pool (query head ``i`` on KV head ``i // (H / kv)``).  A prompt attends
+    over its own rows through the flash kernel, K/V repeated per query head
+    for the prompt only; a decode step walks the pages, each copied in once
+    for all the query heads of its KV heads
+    (:func:`~apex_tpu.ops.paged_attention.paged_decode_attention`)."""
+    dtype = cfg.dtype
+    n, nkv, d = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    t = y.shape[0]
+    qkv = _matmul(
+        _scaled(y, cfg.attention_in_multiplier), p["wqkv"]["weight"], dtype)
+    q = qkv[:, :n * d].reshape(t, n, d)
+    k = _scaled(qkv[:, n * d:(n + nkv) * d], cfg.key_multiplier).reshape(
+        t, nkv, d)
+    v = qkv[:, (n + nkv) * d:].reshape(t, nkv, d).astype(dtype)
+    q = _rope_partial(q, flow.positions, cfg.rope_theta).astype(dtype)
+    k = _rope_partial(k, flow.positions, cfg.rope_theta).astype(dtype)
+    scale = d ** -0.5
+    if flow.prompt:
+        kv = cache_lib.write_prompt_kv(kv, li, flow.page_ids, k, v)
+        heads = lambda x: jnp.transpose(x, (1, 0, 2))[None]  # noqa: E731
+        rep = lambda x: jnp.repeat(heads(x), n // nkv, axis=1)  # noqa: E731
+        ctx = flash_attention(
+            heads(q), rep(k), rep(v), causal=True, scale=scale)
+        o = jnp.transpose(ctx[0], (1, 0, 2))
+    else:
+        pos = flow.positions
+        # an idle row writes into the null page, whatever its table holds
+        pages = jnp.where(
+            flow.live, flow.page_tables[jnp.arange(t), pos // flow.page_size],
+            cache_lib.NULL_PAGE,
+        )
+        kv = cache_lib.append_token_kv(
+            kv, li, pages, pos % flow.page_size, k, v)
+        o = paged_decode_attention(
+            q, kv["k"], kv["v"], flow.page_tables, flow.lengths,
+            layer=li, scale=scale, kv_heads=nkv,
+        )
+    out = _matmul(o.reshape(t, n * d), p["wo"]["weight"], dtype)
+    return _scaled(out, cfg.attention_out_multiplier), kv
+
+
+def _ssm_gqa_mixer(cfg, lp, x, kv, layer, flow):
+    """Falcon-H1's parallel block: a Mamba-2 branch and a grouped-query
+    attention branch read ONE RMSNorm of the rows and both are added to the
+    residual."""
+    li = cfg.layers_of("ssm_gqa").index(layer)
+    y = _rms_norm(x, lp["norm_mixer"], cfg.rms_eps)
+    with jax.named_scope("ssm_branch"):
+        m, kv = _ssm_branch(cfg, lp["ssm"], y, kv, li, flow)
+    with jax.named_scope("gqa_branch"):
+        a, kv = _gqa_branch(cfg, lp["attn"], y, kv, li, flow)
+    return x + (m + a).astype(cfg.dtype), kv
+
+
 def _dense_ffn(cfg, lp, x, flow):
     y = _rms_norm(x, lp["norm_ffn"], cfg.rms_eps).astype(cfg.dtype)
-    return x + _swiglu(y, lp["mlp"], cfg.dtype).astype(cfg.dtype)
+    return x + _swiglu(
+        y, lp["mlp"], cfg.dtype, cfg.mlp_multipliers).astype(cfg.dtype)
 
 
 def _moe_ffn(cfg, lp, x, flow):
@@ -810,13 +939,22 @@ def _moe_ffn(cfg, lp, x, flow):
     return x + out.astype(cfg.dtype)
 
 
-_MIXERS.update(kda=_kda_mixer, mla=_mla_mixer)
+_MIXERS.update(kda=_kda_mixer, mla=_mla_mixer, ssm_gqa=_ssm_gqa_mixer)
 _FFNS.update(dense=_dense_ffn, moe=_moe_ffn)
+
+
+def _hybrid_embed(cfg, tree, tokens):
+    x = _embed(tree["word_embeddings"], tokens, cfg.dtype)
+    if cfg.embedding_multiplier == 1.0:
+        return x
+    return (x.astype(jnp.float32) * cfg.embedding_multiplier).astype(
+        cfg.dtype)
 
 
 def _hybrid_logits(cfg, tree, h):
     """Final RMSNorm and the untied head over the vocabulary slice held."""
-    h = _rms_norm(h, tree["norm_f"], cfg.rms_eps)
+    h = _scaled(
+        _rms_norm(h, tree["norm_f"], cfg.rms_eps), cfg.lm_head_multiplier)
     return _matmul(h, tree["lm_head"]["weight"], cfg.dtype)
 
 
@@ -835,7 +973,7 @@ def _hybrid_prefill(cfg, params, cache, tokens, length, page_ids, slot,
                     temp, rng, page_size, top_k):
     tree = _tree(params)
     s = tokens.shape[0]
-    x = _embed(tree["word_embeddings"], tokens[:, 0], cfg.dtype)
+    x = _hybrid_embed(cfg, tree, tokens[:, 0])
     flow = _Flow(
         prompt=True, live=jnp.arange(s) < length, positions=jnp.arange(s),
         page_size=page_size, length=length, page_ids=page_ids, slot=slot,
@@ -848,7 +986,7 @@ def _hybrid_prefill(cfg, params, cache, tokens, length, page_ids, slot,
 
 def _hybrid_decode(cfg, tree, cache, tokens, lengths, page_tables,
                    page_size):
-    x = _embed(tree["word_embeddings"], tokens, cfg.dtype)
+    x = _hybrid_embed(cfg, tree, tokens)
     flow = _Flow(
         prompt=False, live=lengths > 0, positions=jnp.maximum(lengths - 1, 0),
         page_size=page_size, lengths=lengths, page_tables=page_tables,

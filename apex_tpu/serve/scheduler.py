@@ -390,7 +390,7 @@ class Request:
 
 def declare_serve_metrics(registry, *, stateful: bool = False,
                           routed: bool = False, latent: bool = False,
-                          paged: bool = True) -> None:
+                          paged: bool = True, ssm: bool = False) -> None:
     """Declare the serving metric set on a registry (idempotent); the
     metrics of a layer kind only for a model that has it."""
     if paged:
@@ -406,6 +406,11 @@ def declare_serve_metrics(registry, *, stateful: bool = False,
     if stateful:
         registry.gauge("serve/state/slots_in_use")
         registry.gauge("serve/state/bytes", "bytes")
+    if ssm:
+        # prefills that replaced a slot's state-space state, and the bytes
+        # of it the step's decode call had to read and write an iteration
+        registry.counter("serve/ssm/slots_written")
+        registry.gauge("serve/ssm/state_bytes_per_iter", "bytes")
     if latent:
         registry.gauge("serve/latent/pages_in_use")
     for g in ("serve/queue_depth", "serve/batch_fill",
@@ -495,7 +500,15 @@ class ContinuousBatchingScheduler:
         # a routed model's steps hand back the MoE counts
         self._stateful = bool(getattr(engine, "stateful", False))
         self._routed = bool(getattr(engine, "routed", False))
-        self._latent = "latent" in engine.cache
+        # what the cache set holds is read from the declaration its mixer
+        # kinds made (``engine.cache_kinds``): a slot's share of the
+        # recurrent slabs, and of the state-space one alone
+        kinds = getattr(engine, "cache_kinds", ())
+        self._latent = any(k.name == "latent" for k in kinds)
+        self._slot_state_bytes = sum(
+            k.row_bytes for k in kinds if k.per == "slot" and k.in_place)
+        self._ssm_slot_bytes = sum(
+            k.row_bytes for k in kinds if k.name == "ssm")
         # a K/V page pool: the paged decode kernel walks it, and
         # `serve/decode_walk_live_share` is reckoned from the lengths of
         # the step's plain decode call
@@ -601,6 +614,7 @@ class ContinuousBatchingScheduler:
         if self.registry is not None:
             declare_serve_metrics(
                 self.registry, stateful=self._stateful, routed=self._routed,
+                ssm=bool(self._ssm_slot_bytes),
                 latent=self._latent, paged=self._paged,
             )
             self._mstate = self.registry.host_init()
@@ -1109,6 +1123,9 @@ class ContinuousBatchingScheduler:
             return True
         if self._routed:
             self._fold_moe()
+        if self._ssm_slot_bytes:
+            # the prompt's state-space state replaced the slot's
+            self._count("serve/ssm/slots_written")
         if resumed:
             # the slot holds the sequence's state again; the token this
             # prefill sampled is the one already on the stream
@@ -1689,6 +1706,13 @@ class ContinuousBatchingScheduler:
             self._gauge("serve/page_occupancy", self.pool.occupancy())
             self._gauge("serve/tokens_per_s", tps)
             if self._decode_lengths is not None:
+                if self._ssm_slot_bytes:
+                    # every rider's state-space state, read and written
+                    self._gauge(
+                        "serve/ssm/state_bytes_per_iter",
+                        2.0 * self._ssm_slot_bytes
+                        * int(np.count_nonzero(self._decode_lengths)),
+                    )
                 # only a walk that ran: the decode program's attention
                 # took the kernel (the jnp path gathers the whole table)
                 took = _dispatch.last_paths().get("paged_decode_attention")
@@ -1708,12 +1732,9 @@ class ContinuousBatchingScheduler:
                 )
             if self._stateful:
                 held = self.slots_in_use()
-                slab = self.engine.cache["state"]
                 self._gauge("serve/state/slots_in_use", float(held))
                 self._gauge(
-                    "serve/state/bytes",
-                    float(held * (slab.nbytes // slab.shape[1])),
-                )
+                    "serve/state/bytes", float(held * self._slot_state_bytes))
             if self._latent:
                 self._gauge(
                     "serve/latent/pages_in_use", float(self.pool.in_use))

@@ -19,7 +19,7 @@ from apex_tpu.models.gpt import GptConfig, GptModel
 from apex_tpu.ops import _dispatch
 from apex_tpu.ops.pallas import (
     decode_attention, flash_attention, kda, layer_norm, mla_decode,
-    moe_grouped,
+    moe_grouped, ssm,
 )
 from apex_tpu.serve import cache as cache_lib
 from apex_tpu.serve import model as model_lib
@@ -44,7 +44,7 @@ def lower_as_chip(monkeypatch):
     ``jax.default_backend()`` still says cpu here."""
     monkeypatch.setattr(_dispatch, "use_pallas", lambda: True)
     for mod in (_dispatch, decode_attention, flash_attention, layer_norm,
-                kda, mla_decode, moe_grouped):
+                kda, mla_decode, moe_grouped, ssm):
         monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
 
 
@@ -311,3 +311,155 @@ def test_hybrid_step_never_moves_its_cache_set(
     # what a chip holds: weights + cache set + temporaries, under 16 GB
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert held < 12e9, held
+
+
+#: Falcon-H1-34B-Instruct's first four layers and whole vocabulary as
+#: benchmark/configs/falcon-h1-34b-instruct-4l.json serves them
+FALCON_H1 = dict(
+    vocab_size=261120, hidden_size=5120, num_layers=4, num_heads=20,
+    num_kv_heads=4, head_dim=128, intermediate_size=21504,
+    max_seq_len=262144, pattern=(("ssm_gqa", "dense"),) * 4,
+    rope_theta=1e11, rms_eps=1e-5, ssm_heads=32, ssm_head_dim=128,
+    ssm_groups=2, ssm_state=256, ssm_chunk=128,
+    embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125,
+    attention_out_multiplier=0.0375, key_multiplier=0.011048543456039804,
+    ssm_in_multiplier=0.25, ssm_out_multiplier=0.08838834764831845,
+    ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+    mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+)
+H1_PAGES, H1_SLOTS, H1_PAGES_PER_SEQ = 10241, 128, 80
+
+
+@pytest.mark.parametrize(
+    "program", ["serve_decode_block16", "serve_prefill_128",
+                "serve_prefill_1024"])
+def test_falcon_h1_step_never_moves_its_cache_set(
+    one_chip, lower_as_chip, program
+):
+    """The parallel hybrid block's programs at the benchmark's shapes: the
+    K/V pages, and the state-space slab (2.15 GB) stay one buffer each from
+    entry to exit (`memory-pool-copy` over what the cache kinds declare
+    in place), the kernels are in the program under the names the trace
+    reads, XLA's temporaries stay far under a layer of the slab in decode,
+    and weights + cache set + temporaries fit the chip."""
+    from apex_tpu.models.hybrid import HybridConfig, param_shapes
+
+    cfg = HybridConfig(**FALCON_H1)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip
+            ),
+            tree,
+        )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = on_chip(param_shapes(cfg))
+    cache = on_chip(jax.eval_shape(lambda: cache_lib.init_hybrid_cache(
+        cfg, H1_PAGES, PAGE, H1_SLOTS
+    )))
+    assert cache["k"].shape == (4, H1_PAGES, 4, PAGE, 128)
+    assert cache["ssm"].shape == (4, H1_SLOTS, 32, 128, 256)
+    assert cache["ssm_conv"].shape == (4, H1_SLOTS, 3, 5120)
+    if program.startswith("serve_decode"):
+        block = 16
+
+        def fn(params, kv, tokens, lengths, tables, temps, rng):
+            return model_lib.decode_body(
+                cfg, params, kv, tokens, lengths, tables, temps, rng,
+                page_size=PAGE, block=block)
+        args = (
+            arg((H1_SLOTS,), jnp.int32), arg((H1_SLOTS,), jnp.int32),
+            arg((H1_SLOTS, H1_PAGES_PER_SEQ), jnp.int32),
+            arg((H1_SLOTS,), jnp.float32),
+            arg((block, H1_SLOTS, 2), jnp.uint32),
+        )
+        names = ("ssm_step_fwd", "paged_decode_fwd")
+    else:
+        bucket = int(program.rsplit("_", 1)[1])
+
+        def fn(params, kv, tokens, length, page_ids, slot, temp, rng):
+            return model_lib.prefill_body(
+                cfg, params, kv, tokens, length, page_ids, temp, rng,
+                page_size=PAGE, slot=slot)
+        args = (
+            arg((bucket, 1), jnp.int32), arg((), jnp.int32),
+            arg((bucket // PAGE,), jnp.int32), arg((), jnp.int32),
+            arg((), jnp.float32), arg((2,), jnp.uint32),
+        )
+        # the flash kernel takes a prompt from 1,024 rows up
+        names = ("ssd_chunk_fwd",) + (("flash_fwd",) if bucket >= 1024 else ())
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args
+    ).compile()
+
+    text = compiled.as_text()
+    kinds = cache_lib.hybrid_cache_kinds(cfg, PAGE)
+    report = analysis.lint_hlo(
+        text, donated=len(cache), rules=("memory", "donation"),
+        expect_pool={"shapes": [
+            cache[k.name].shape for k in kinds if k.in_place]},
+    )
+    assert report.findings == [], report.render()
+    for name in names:
+        assert name in text, name
+    slab_layer = cache["ssm"].size * 4 // 4
+    mem = compiled.memory_analysis()
+    limit = slab_layer / 4 if program.startswith("serve_decode") else 1e9
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    # what a chip holds: weights + cache set + temporaries, under 16 GB
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 12e9 < held < 13.5e9, held
+
+
+def test_ssm_kernels_compile_at_falcon_h1s_widths(one_chip, lower_as_chip):
+    """`ssm_step_fwd` over the slab in place (aliased) and `ssd_chunk_fwd`
+    over a 1,024-row prompt, in Mosaic: 8 heads of 128 x 256 f32 a grid
+    step, in and out, inside VMEM."""
+    def arg(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    b, h, p, n = H1_SLOTS, 32, 128, 256
+    text = jax.jit(
+        lambda *a: ssm.ssm_step_fwd(*a, layer=2), donate_argnums=(0,)
+    ).lower(
+        arg((4, b, h, p, n)), arg((b, h, p)), arg((b, h, n)),
+        arg((b, h, n)), arg((b, h, n)),
+    ).compile().as_text()
+    assert "ssm_step_fwd" in text and "tpu_custom_call" in text
+    assert "input_output_alias" in text
+    nc, c = 8, 128
+    text = jax.jit(ssm.ssd_chunk_fwd).lower(
+        arg((h, nc, c, n)), arg((h, nc, p, n)), arg((h, nc, 1, n)),
+    ).compile().as_text()
+    assert "ssd_chunk_fwd" in text and "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("h,kv,d", [(20, 4, 128), (8, 4, 64), (10, 2, 96)],
+                         ids=["falcon-h1", "pairs-in-a-row", "padded-row"])
+def test_grouped_query_paged_decode_walk_compiles(
+    one_chip, lower_as_chip, h, kv, d
+):
+    """The decode kernel with ``h / kv`` query rows a KV head, in Mosaic,
+    over the pool as ``init_kv_pages`` lays it at the KV heads."""
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = jax.eval_shape(lambda: cache_lib.init_kv_pages(
+        2, 65, kv, PAGE, d, dtype=jnp.bfloat16))
+    text = jax.jit(
+        lambda *a: decode_attention.paged_decode_fwd(
+            *a, scale=d ** -0.5, kv_heads=kv)
+    ).lower(
+        arg((H1_SLOTS, h, d), jnp.bfloat16),
+        arg(pool["k"].shape, pool["k"].dtype),
+        arg(pool["v"].shape, pool["v"].dtype),
+        arg((H1_SLOTS, H1_PAGES_PER_SEQ), jnp.int32),
+        arg((H1_SLOTS,), jnp.int32), arg((), jnp.int32),
+    ).compile().as_text()
+    assert text.count("paged_decode_fwd") >= 1
+    assert "tpu_custom_call" in text
