@@ -93,23 +93,26 @@ class BertConfig:
     remat: bool = False  # jax.checkpoint each layer (activation ckpt analog)
     # "full" recomputes the whole layer in backward (min memory, ~1.33x
     # compute); "dots" saves every dense (no-batch-dim) matmul output and
-    # recomputes only attention internals + elementwise (softmax/GELU) —
-    # ~0.6% extra FLOPs on BERT-Large, the MFU-preserving default.
+    # recomputes only attention internals + elementwise (softmax/GELU).
     # "sums" saves the same BYTES as "dots" but picks the tensors backward
     # actually consumes: qkv, fc1 (wgrad/recompute inputs) and the two
     # post-residual sums (LayerNorm-backward inputs) instead of the raw
-    # out-proj/fc2 matmul outputs.  Under "dots" those raw outputs have
-    # two consumers (the remat save + the bias/residual add), which
-    # forces XLA to materialize them and run the adds as separate
-    # bandwidth-bound kLoop fusions (measured ~6% of the v5e BERT-Large
-    # step, docs/mfu.md); single-consumer raw outputs let the epilogue
-    # fuse into the matmul.  Extra recompute vs "dots": gelu + 2 LN
-    # forwards per layer (elementwise).
+    # out-proj/fc2 matmul outputs, which leaves every raw matmul output
+    # with one consumer, so its bias + residual epilogue fuses into the
+    # matmul.  Extra recompute vs "dots": gelu + 2 LN forwards per layer
+    # (elementwise).  Measured on a v5e through examples/bert/
+    # pretrain_bert.py's step (BERT-Large, 128 x 128 tokens, K = 20; PERF.md
+    # section 6, PR 38): "full" 383.6 ms a step; with scan_layers=False
+    # "sums" 326.5, "dots" 339.0; under the scan "dots" 348.6 ("sums" there
+    # was read with remat_attention only: 362.7 against "dots" 357.1).
     remat_policy: str = "full"
     # Always recompute the attention core (scores/softmax/PV) in backward,
     # regardless of remat_policy: an inner nothing_saveable checkpoint.
-    # Under "dots" this drops the f32 (B,H,S,S) score saves — the largest
-    # per-layer buffer at short seq — for ~2% extra FLOPs (flash-style).
+    # Under "dots" and "sums" it changes nothing that is saved (the batched
+    # QK^T / PV products are not "dots without a batch dimension", and the
+    # named policy saves its names only) and costs time: "dots" 357.1 ms
+    # with it against 348.6 under the scan, 350.0 against 339.0 unrolled
+    # (same runs).  It matters under policies that would save the scores.
     remat_attention: bool = False
     # jax.checkpoint's prevent_cse for the per-layer remat.  None = auto:
     # False under scan_layers (documented safe there) and True unrolled
@@ -117,16 +120,20 @@ class BertConfig:
     # saves alive).  Setting False explicitly on the unrolled path is a
     # *performance* choice, not a correctness one — values are identical;
     # XLA may then keep forward activations instead of recomputing when
-    # HBM allows (measured v5e BERT-Large b128: 316 ms vs 371 ms honest
-    # recompute) at the cost of the checkpoint's memory guarantee.
+    # HBM allows, at the cost of the checkpoint's memory guarantee.
     remat_prevent_cse: Optional[bool] = None
-    # True: nn.scan over layers (one trace, compile time flat in depth,
-    # params stacked (L, ...)) — required for the pipeline-stage use.
-    # False: unrolled Python loop — XLA schedules each layer separately, so
-    # remat-saved activations stay ordinary op outputs instead of being
-    # copied into (L, ...) stacked buffers through dynamic-update-slice
-    # (measured v5e, BERT-Large b128: the stacking pass costs ~1/3 of the
-    # step); the MFU choice for single-host training.
+    # Who runs the layer loop; the parameters are stacked (L, ...) under
+    # layers/layer either way.  True: nn.scan over layers (XLA sees a rolled
+    # loop, compile time flat in depth) — required for the pipeline-stage
+    # use.  False: a Python loop over slices of the stacked leaves — XLA
+    # schedules each layer separately, so what a checkpoint saves stays an
+    # ordinary buffer, freed as the backward pass passes it, and gradients
+    # are allocated as they are made: at BERT-Large b128 "dots"/"sums" fit a
+    # v5e with 2 GB to spare by XLA's count (13.8 GB) where the scan's (L,
+    # ...) buffers need 17.1 GB, and the step is 10-36 ms shorter.  The
+    # price is the program: L copies of a layer's code (293 MB against 26
+    # MB), 47-53 s to compile cold against 6-14 s, ~3 s to load from the
+    # compile cache.
     scan_layers: bool = True
 
     def __post_init__(self):
@@ -305,11 +312,36 @@ class _BlockStep(nn.Module):
         return y, None
 
 
+@jax.custom_vjp
+def _unstack(stacked):
+    """The ``(L, ...)`` leaf as L per-layer arrays."""
+    return tuple(stacked[i] for i in range(stacked.shape[0]))
+
+
+def _unstack_fwd(stacked):
+    return _unstack(stacked), None
+
+
+def _unstack_bwd(_, grads):
+    # each layer's gradient is finished before the stack is built: left to
+    # itself XLA fuses every weight-gradient matmul into an in-place update
+    # of the (L, ...) buffer, chains the L updates behind the whole backward
+    # pass, and keeps each matmul's inputs alive until its turn (2.2 GB more
+    # at BERT-Large, compiled for v5e)
+    return (jnp.stack(jax.lax.optimization_barrier(grads)),)
+
+
+_unstack.defvjp(_unstack_fwd, _unstack_bwd)
+
+
 class BertEncoderCore(nn.Module):
     """A homogeneous stack of ``num_layers`` BertLayers.
 
-    Scanned over the layer dim (params stacked ``(L, ...)``) so 24 layers
-    trace once — XLA sees a rolled loop, keeping compile time flat in depth.
+    The parameters are stacked ``(L, ...)`` under ``layers/layer`` whatever
+    runs the loop.  ``scan_layers=True``: ``nn.scan`` over the layer dim, so
+    24 layers trace once and XLA sees a rolled loop, compile time flat in
+    depth.  ``scan_layers=False``: a Python loop over slices of the same
+    stacked leaves, one checkpointed function called L times (traced once).
     Also the pipeline-stage module: a pp stage is a BertEncoderCore with
     ``num_layers = L/pp`` (homogeneous stages, the Megatron layout).
     """
@@ -319,8 +351,9 @@ class BertEncoderCore(nn.Module):
 
     @nn.compact
     def __call__(self, x, attention_bias=None, *, deterministic=True):
-        step = _BlockStep
-        if self.cfg.remat:
+        cfg = self.cfg
+        policy = prevent_cse = None
+        if cfg.remat:
             # activation checkpointing per layer ≙ tensor_parallel.random
             # .checkpoint (recompute-in-backward; PRNG replay is automatic
             # in JAX — keys are values, not stateful generators).  "sums":
@@ -330,35 +363,73 @@ class BertEncoderCore(nn.Module):
                 resolve_remat_policy,
             )
 
-            policy = resolve_remat_policy(self.cfg.remat_policy)
+            policy = resolve_remat_policy(cfg.remat_policy)
             # prevent_cse=False is documented safe only under scan/pmap
             # differentiation; on the unrolled path the layer is
             # differentiated directly under jit, where CSE could merge the
             # backward recompute with the forward and silently defeat the
             # checkpoint, so auto mode keeps it True there (see
             # BertConfig.remat_prevent_cse for the explicit override).
-            prevent_cse = self.cfg.remat_prevent_cse
+            prevent_cse = cfg.remat_prevent_cse
             if prevent_cse is None:
-                prevent_cse = not self.cfg.scan_layers
-            step = nn.remat(step, prevent_cse=prevent_cse, policy=policy)
-        if not self.cfg.scan_layers:
-            for i in range(self.num_layers):
-                x, _ = step(self.cfg, deterministic, name=f"layer_{i}")(
-                    x, attention_bias
-                )
-            return x
-        scanned = nn.scan(
-            step,
-            variable_axes={"params": 0},
-            split_rngs={"params": True, "dropout": True},
-            length=self.num_layers,
-            in_axes=nn.broadcast,
-            metadata_params={nn.PARTITION_NAME: "layers"},
+                prevent_cse = not cfg.scan_layers
+        if cfg.scan_layers or self.is_initializing():
+            # (init always goes this way: it is what lays the tree out)
+            step = _BlockStep
+            if cfg.remat:
+                step = nn.remat(step, prevent_cse=prevent_cse, policy=policy)
+            scanned = nn.scan(
+                step,
+                variable_axes={"params": 0},
+                split_rngs={"params": True, "dropout": True},
+                length=self.num_layers,
+                in_axes=nn.broadcast,
+                metadata_params={nn.PARTITION_NAME: "layers"},
+            )
+            y, _ = scanned(cfg, deterministic, name="layers")(
+                x, attention_bias
+            )
+            return y
+        return self._unrolled(x, attention_bias, deterministic, policy,
+                              prevent_cse)
+
+    def _unrolled(self, x, attention_bias, deterministic, policy, prevent_cse):
+        """The layers one after another over the stacked leaves.  XLA
+        schedules each layer on its own: what a checkpoint saves stays an
+        ordinary buffer, freed as the backward pass passes it, and the
+        gradients are allocated as they are made — at BERT-Large on a v5e
+        3 GB less than the scanned loop holds for the same policy."""
+        block = _BlockStep(self.cfg, deterministic)
+        here = self.path + ("layers",)
+
+        def layer(params, x, bias, key):
+            rngs = None if key is None else {"dropout": key}
+            # applied on its own, ``block`` counts paths from itself
+            with ps.sequence_parallel_param_prefix(here):
+                y, _ = block.apply({"params": params}, x, bias, rngs=rngs)
+            return y
+
+        # one function object for every layer: traced once, and under
+        # jit its derivative and its lowering are shared by the L calls too
+        # (tracing and lowering BERT-Large's LAMB step on a v5e's host:
+        # 3.3-3.9 s against the scan's 2.2-3.0; 4.8-5.4 s without the jit;
+        # 11.4 s for L separately named layers)
+        layer = jax.jit(layer)
+        if self.cfg.remat:
+            layer = jax.checkpoint(
+                layer, policy=policy, prevent_cse=prevent_cse
+            )
+        keys = (
+            [None] * self.num_layers if deterministic
+            else jax.random.split(self.make_rng("dropout"), self.num_layers)
         )
-        y, _ = scanned(self.cfg, deterministic, name="layers")(
-            x, attention_bias
+        leaves, treedef = jax.tree_util.tree_flatten(
+            self.variables["params"]["layers"]
         )
-        return y
+        per_layer = zip(*(_unstack(leaf) for leaf in leaves))
+        for params, key in zip(per_layer, keys):
+            x = layer(treedef.unflatten(params), x, attention_bias, key)
+        return x
 
 
 class BertEmbeddings(nn.Module):

@@ -35,6 +35,7 @@ helpers are static Python ints valid anywhere.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Optional, Sequence
 
@@ -72,6 +73,7 @@ __all__ = [
     "set_virtual_pipeline_model_parallel_world_size",
     "destroy_model_parallel",
     "register_sequence_parallel_param",
+    "sequence_parallel_param_prefix",
     "sequence_parallel_param_paths",
     "clear_sequence_parallel_params",
     "divide",
@@ -479,11 +481,30 @@ def _sp_registry() -> set:
     return _SEQUENCE_PARALLEL_PARAM_PATHS
 
 
+_SEQUENCE_PARALLEL_PATH_PREFIX: tuple = ()
+
+
+@contextlib.contextmanager
+def sequence_parallel_param_prefix(prefix):
+    """Paths registered inside start with ``prefix``: for a module applied
+    on its own (``Module.apply``) inside another, whose ``self.path`` starts
+    at itself and not where its parameters sit in the caller's tree."""
+    global _SEQUENCE_PARALLEL_PATH_PREFIX
+    outer = _SEQUENCE_PARALLEL_PATH_PREFIX
+    _SEQUENCE_PARALLEL_PATH_PREFIX = outer + tuple(str(p) for p in prefix)
+    try:
+        yield
+    finally:
+        _SEQUENCE_PARALLEL_PATH_PREFIX = outer
+
+
 def register_sequence_parallel_param(path) -> None:
     """Mark the param at ``path`` (module path + param name, a tuple of
     strings, excluding the "params" collection key) as having tp-partial
     gradients under sequence parallelism."""
-    _sp_registry().add(tuple(str(p) for p in path))
+    _sp_registry().add(
+        _SEQUENCE_PARALLEL_PATH_PREFIX + tuple(str(p) for p in path)
+    )
 
 
 def sequence_parallel_param_paths() -> frozenset:

@@ -116,69 +116,28 @@ def batch_stream(args, cfg, start_step=0):
         yield jax.tree_util.tree_map(lambda *xs: np.stack(xs), *chunk)
 
 
-def main(argv=None):
-    """Run the recipe; returns the per-step losses, the params, the mesh
-    and the global parameter norm before and after (the initial params
-    are donated to the first step) for callers that check the run —
-    ``chip_smoke.py`` — rather than read its prints."""
-    args = parse_args(argv)
-    enable_compile_cache()
-    cfg = (
-        BertConfig(
+def model_config(args) -> BertConfig:
+    """The model ``main`` trains: BERT-Large, or the ``--tiny`` toy."""
+    if args.tiny:
+        return BertConfig(
             vocab_size=2048, hidden_size=64, num_layers=2, num_heads=4,
             intermediate_size=128, max_position_embeddings=args.seq_len,
             dtype=jnp.float32,
         )
-        if args.tiny
-        else BertConfig(remat=True)
-    )
-    mesh = ps.initialize_model_parallel()
-    dp = ps.get_data_parallel_world_size()
-    if args.batch % dp:
-        raise SystemExit(f"--batch must divide dp={dp}")
-    if args.max_predictions_per_seq < 0:
-        raise SystemExit("--max-predictions-per-seq must be >= 0")
+    # each layer's checkpoint keeps what its four dense matmuls made (qkv,
+    # fc1 and the two post-residual sums: 302 MB a layer at 16,384 tokens a
+    # chip) and the backward recomputes only attention's core, GELU and
+    # LayerNorm; "full" ran every layer's matmuls a second time.  The layers
+    # run as a loop over the stacked leaves, which holds 3 GB less than the
+    # scan for the same saves and is the fastest form measured (a v5e, 128 x
+    # 128 tokens: 324 ms a step against 384; PERF.md section 6, PR 38).
+    return BertConfig(remat=True, remat_policy="sums", scan_layers=False)
 
-    model = BertForPreTraining(cfg)
-    tx = fused_lamb(learning_rate=args.lr, weight_decay=0.01)
-    ids0 = jnp.zeros((args.seq_len, args.batch), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), ids0)
-    opt_state = tx.init(params)
-    start_step = 0
-    if (
-        args.resume
-        and args.ckpt_dir
-        and ckpt.latest_step(args.ckpt_dir) is not None
-    ):
-        # restore replicated over the mesh (a concrete-array template
-        # would re-commit every leaf to device 0 and clash with shard_map)
-        rep = jax.sharding.NamedSharding(mesh, P())
-        tmpl = jax.tree_util.tree_map(
-            # .dtype/np.shape read metadata only — no device->host copy
-            # of the (large) params/optimizer leaves (jnp.result_type
-            # would also downcast the int64 step under disabled x64)
-            lambda x: jax.ShapeDtypeStruct(
-                np.shape(x), x.dtype, sharding=rep
-            ),
-            ckpt.snapshot_training_state(params, opt_state, step=0),
-        )
-        with ckpt.CheckpointManager(args.ckpt_dir) as mgr:
-            restored = mgr.restore(template=tmpl)
-        params, opt_state, start_step, _, _ = ckpt.restore_training_state(
-            restored
-        )
-        print(f"resumed from step {start_step} ({args.ckpt_dir})")
-    n_params = sum(p.size for p in jax.tree_util.tree_leaves(params))
-    print(
-        f"BERT {n_params/1e6:.0f}M params | dp={dp} | "
-        f"native input pipeline: {_native.available()}"
-    )
-    param_norm = jax.jit(
-        lambda tree: jnp.sqrt(
-            sum(jnp.vdot(x, x) for x in jax.tree_util.tree_leaves(tree))
-        )
-    )
-    norm_start = float(param_norm(params))
+
+def build_step(model, tx, mesh, max_predictions_per_seq):
+    """The jitted chunk ``main`` runs: ``(params, opt_state, batches) ->
+    (params, opt_state, losses)`` over ``mesh``, each leaf of ``batches``
+    stacked ``(chunk, ...)``, params and optimizer state donated."""
 
     def one_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(
@@ -207,7 +166,7 @@ def main(argv=None):
         "mlm_labels": P(None, None, "dp"),
         "nsp_labels": P(None, "dp"),
     }
-    if args.max_predictions_per_seq:
+    if max_predictions_per_seq:
         # the packed triple is (chunk, K, B) — dp shards B like the labels
         # (which the stream drops in this mode; see batch_stream)
         del batch_specs["mlm_labels"]
@@ -216,7 +175,7 @@ def main(argv=None):
             mlm_label_ids=P(None, None, "dp"),
             mlm_weights=P(None, None, "dp"),
         )
-    step = jax.jit(
+    return jax.jit(
         jax.shard_map(
             chunk_fn,
             mesh=mesh,
@@ -226,6 +185,68 @@ def main(argv=None):
         ),
         donate_argnums=(0, 1),
     )
+
+
+def main(argv=None):
+    """Run the recipe; returns the per-step losses, the params, the mesh
+    and the global parameter norm before and after (the initial params
+    are donated to the first step) for callers that check the run —
+    ``chip_smoke.py`` — rather than read its prints."""
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg = model_config(args)
+    mesh = ps.initialize_model_parallel()
+    dp = ps.get_data_parallel_world_size()
+    if args.batch % dp:
+        raise SystemExit(f"--batch must divide dp={dp}")
+    if args.max_predictions_per_seq < 0:
+        raise SystemExit("--max-predictions-per-seq must be >= 0")
+
+    model = BertForPreTraining(cfg)
+    tx = fused_lamb(learning_rate=args.lr, weight_decay=0.01)
+    ids0 = jnp.zeros((args.seq_len, args.batch), jnp.int32)
+    # the state enters the first step placed as every step returns it:
+    # fresh from init it made the second step a second program (traced,
+    # lowered and compiled again)
+    rep = jax.sharding.NamedSharding(mesh, P())
+    params = jax.device_put(model.init(jax.random.PRNGKey(0), ids0), rep)
+    opt_state = jax.device_put(tx.init(params), rep)
+    start_step = 0
+    if (
+        args.resume
+        and args.ckpt_dir
+        and ckpt.latest_step(args.ckpt_dir) is not None
+    ):
+        # restore replicated over the mesh (a concrete-array template
+        # would re-commit every leaf to device 0 and clash with shard_map)
+        tmpl = jax.tree_util.tree_map(
+            # .dtype/np.shape read metadata only — no device->host copy
+            # of the (large) params/optimizer leaves (jnp.result_type
+            # would also downcast the int64 step under disabled x64)
+            lambda x: jax.ShapeDtypeStruct(
+                np.shape(x), x.dtype, sharding=rep
+            ),
+            ckpt.snapshot_training_state(params, opt_state, step=0),
+        )
+        with ckpt.CheckpointManager(args.ckpt_dir) as mgr:
+            restored = mgr.restore(template=tmpl)
+        params, opt_state, start_step, _, _ = ckpt.restore_training_state(
+            restored
+        )
+        print(f"resumed from step {start_step} ({args.ckpt_dir})")
+    n_params = sum(p.size for p in jax.tree_util.tree_leaves(params))
+    print(
+        f"BERT {n_params/1e6:.0f}M params | dp={dp} | "
+        f"native input pipeline: {_native.available()}"
+    )
+    param_norm = jax.jit(
+        lambda tree: jnp.sqrt(
+            sum(jnp.vdot(x, x) for x in jax.tree_util.tree_leaves(tree))
+        )
+    )
+    norm_start = float(param_norm(params))
+
+    step = build_step(model, tx, mesh, args.max_predictions_per_seq)
 
     n_chunks = max(0, (args.steps - start_step) // args.chunk)
     if n_chunks == 0:

@@ -42,6 +42,23 @@ def eight_devices():
     return devs
 
 
+@pytest.fixture(scope="session")
+def bert_recipe():
+    """``examples/bert/pretrain_bert.py`` as a module (imported, not run)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "pretrain_bert",
+        os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "examples", "bert", "pretrain_bert.py",
+        ),
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: multi-process / long-running tests"

@@ -51,6 +51,34 @@ def _pack_batch(batch, k):
     return packed, (pos, ids, w)
 
 
+def _recipe_schedule_fields(recipe):
+    """The checkpoint / layer-loop fields of the BertConfig the recipe
+    trains (its full-size branch), to put on a small model."""
+    cfg = recipe.model_config(recipe.parse_args([]))
+    return {
+        k: getattr(cfg, k)
+        for k in ("remat", "remat_policy", "remat_attention",
+                  "remat_prevent_cse", "scan_layers")
+    }
+
+
+def _dense_dots(jaxpr):
+    """dot_generals without a batch dimension in ``jaxpr``, a scan's body
+    counted once per iteration."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (_, _), (lhs_batch, _) = eqn.params["dimension_numbers"]
+            n += not lhs_batch
+        times = eqn.params["length"] if eqn.primitive.name == "scan" else 1
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += times * _dense_dots(sub)
+    return n
+
+
 def _sharded_bert_loss(sp, tp=8, packed=False):
     mesh = ps.initialize_model_parallel(tensor_model_parallel_size=tp)
     m = BertForPreTraining(BertConfig(sequence_parallel=sp, **BERT_KW))
@@ -171,15 +199,20 @@ class TestBert:
         )(params)
         return params, l_r, g_r
 
-    @pytest.mark.parametrize("policy", ["full", "dots", "sums"])
-    def test_remat_policy_preserves_values(self, policy, no_remat_reference):
+    @pytest.mark.parametrize("policy", ["full", "dots", "sums", "recipe"])
+    def test_remat_policy_preserves_values(
+        self, policy, no_remat_reference, bert_recipe
+    ):
         """Remat policies (incl. the named-saves 'sums' policy that frees
         raw matmul outputs for epilogue fusion) are pure schedule knobs:
-        loss and grads must match the no-remat model exactly."""
+        loss and grads must match the no-remat model exactly.  "recipe"
+        is the combination examples/bert/pretrain_bert.py trains with."""
         params, l_r, g_r = no_remat_reference
-        m_pol = BertForPreTraining(
-            BertConfig(remat=True, remat_policy=policy, **BERT_KW)
+        fields = (
+            _recipe_schedule_fields(bert_recipe) if policy == "recipe"
+            else dict(remat=True, remat_policy=policy)
         )
+        m_pol = BertForPreTraining(BertConfig(**fields, **BERT_KW))
         batch = _bert_batch()
         l_p, g_p = jax.value_and_grad(
             lambda p: bert_pretrain_loss(p, m_pol, batch)
@@ -193,10 +226,65 @@ class TestBert:
             g_r, g_p,
         )
 
+    def test_recipe_backward_reruns_no_dense_matmul(self, bert_recipe):
+        """The recipe's per-layer checkpoint keeps what the four dense
+        matmuls (qkv, out, fc1, fc2) made: a layer costs 3 dense
+        dot_generals a matmul in value_and_grad (forward, dgrad, wgrad),
+        where "full" costs 4 (the backward runs the forward's again).
+        Counted as the difference two more layers make, so the heads drop
+        out."""
+        batch = _bert_batch()
+
+        def dense_dots(num_layers, **fields):
+            m = BertForPreTraining(
+                BertConfig(**fields, **dict(BERT_KW, num_layers=num_layers))
+            )
+            params = m.init(jax.random.PRNGKey(0), batch["input_ids"])
+            return _dense_dots(jax.make_jaxpr(jax.value_and_grad(
+                lambda p: bert_pretrain_loss(p, m, batch)
+            ))(params).jaxpr)
+
+        def per_layer(**fields):
+            return (dense_dots(4, **fields) - dense_dots(2, **fields)) // 2
+
+        assert per_layer(remat=True) == 4 * 4
+        assert per_layer(**_recipe_schedule_fields(bert_recipe)) == 4 * 3
+
+    def test_recipe_tree_is_the_stacked_one(self, bert_recipe):
+        """The recipe's full-size model keeps every encoder leaf stacked
+        ``(24, ...)`` under ``bert/encoder/layers/layer`` — the paths
+        ``benchmark/drivers/bert_recipe.py::to_reference`` reads and the
+        recipe's checkpoints hold."""
+        cfg = bert_recipe.model_config(bert_recipe.parse_args([]))
+        shapes = jax.eval_shape(
+            BertForPreTraining(cfg).init, jax.random.PRNGKey(0),
+            jnp.zeros((128, 128), jnp.int32),
+        )
+        lay = shapes["params"]["bert"]["encoder"]["layers"]["layer"]
+        h, f = cfg.hidden_size, cfg.intermediate_size
+        want = {
+            ("attention", "qkv", "weight"): (24, h, 3 * h),
+            ("attention", "qkv", "bias"): (24, 3 * h),
+            ("attention", "out", "weight"): (24, h, h),
+            ("attention", "out", "bias"): (24, h),
+            ("mlp", "fc1", "weight"): (24, h, f),
+            ("mlp", "fc1", "bias"): (24, f),
+            ("mlp", "fc2", "weight"): (24, f, h),
+            ("mlp", "fc2", "bias"): (24, h),
+            ("ln_attn", "scale"): (24, h), ("ln_attn", "bias"): (24, h),
+            ("ln_mlp", "scale"): (24, h), ("ln_mlp", "bias"): (24, h),
+        }
+        got = {
+            tuple(k.key for k in path): leaf.shape
+            for path, leaf in jax.tree_util.tree_leaves_with_path(lay)
+        }
+        assert got == want
+        assert set(shapes["params"]["bert"]["encoder"]) == {"layers"}
+
     def test_unrolled_matches_scanned(self):
-        """scan_layers / remat_attention are pure layout+schedule knobs:
-        same params (modulo the (L, ...) stacking axis), same loss, same
-        grads as the scanned encoder."""
+        """scan_layers / remat_attention are pure schedule knobs: the same
+        stacked ``(L, ...)`` tree, same loss, same grads as the scanned
+        encoder."""
         m_scan = BertForPreTraining(BertConfig(**BERT_KW))
         m_unroll = BertForPreTraining(
             BertConfig(
@@ -205,48 +293,60 @@ class TestBert:
             )
         )
         batch = _bert_batch()
-        params_s = m_scan.init(jax.random.PRNGKey(0), batch["input_ids"])
-
-        # restack the scanned (L, ...) params into per-layer trees
-        def to_unrolled(ps_tree):
-            enc = ps_tree["params"]["bert"]["encoder"]["layers"]["layer"]
-            L = BERT_KW["num_layers"]
-            out = dict(ps_tree["params"]["bert"]["encoder"])
-            del out["layers"]
-            for i in range(L):
-                out[f"layer_{i}"] = {
-                    "layer": jax.tree_util.tree_map(lambda x: x[i], enc)
-                }
-            new = jax.tree_util.tree_map(lambda x: x, ps_tree)  # copy
-            new["params"]["bert"]["encoder"] = out
-            return new
-
-        params_u = to_unrolled(params_s)
-        # sanity: the unrolled model accepts the restacked tree
+        params = m_scan.init(jax.random.PRNGKey(0), batch["input_ids"])
+        # the unrolled model lays out (and draws) the very same tree
+        params_u = m_unroll.init(jax.random.PRNGKey(0), batch["input_ids"])
+        jax.tree_util.tree_map(
+            np.testing.assert_array_equal, params, params_u
+        )
         l_s, g_s = jax.value_and_grad(
             lambda p: bert_pretrain_loss(p, m_scan, batch)
-        )(params_s)
+        )(params)
         l_u, g_u = jax.value_and_grad(
             lambda p: bert_pretrain_loss(p, m_unroll, batch)
-        )(params_u)
+        )(params)
         np.testing.assert_allclose(float(l_s), float(l_u), rtol=1e-5)
-        # compare grads on the shared (non-encoder) subtrees and on the
-        # restacked encoder layers
-        np.testing.assert_allclose(
-            np.asarray(g_s["params"]["mlm_bias"]),
-            np.asarray(g_u["params"]["mlm_bias"]),
-            rtol=1e-4, atol=1e-6,
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=5e-4, atol=1e-5
+            ),
+            g_s, g_u,
         )
-        enc_s = g_s["params"]["bert"]["encoder"]["layers"]["layer"]
-        for i in range(BERT_KW["num_layers"]):
-            want = jax.tree_util.tree_map(lambda x: x[i], enc_s)
-            got = g_u["params"]["bert"]["encoder"][f"layer_{i}"]["layer"]
-            jax.tree_util.tree_map(
-                lambda a, b: np.testing.assert_allclose(
-                    np.asarray(a), np.asarray(b), rtol=5e-4, atol=1e-5
-                ),
-                want, got,
-            )
+
+    def test_unrolled_dropout_and_sequence_parallel_marks(self, eight_devices):
+        """The unrolled loop applies the layer on its own: dropout still
+        draws a key a layer, and the sequence-parallel leaves are marked at
+        the paths the stacked tree has them (what the tp gradient sync
+        matches, strictly)."""
+        batch = _bert_batch()
+        marks = {}
+        for scan in (True, False):
+            mesh = ps.initialize_model_parallel(tensor_model_parallel_size=8)
+            m = BertForPreTraining(BertConfig(
+                sequence_parallel=True, scan_layers=scan, **BERT_KW))
+
+            def f(key, batch):
+                params = m.init(key, batch["input_ids"])
+                return bert_pretrain_loss(params, m, batch)
+
+            jax.jit(jax.shard_map(
+                f, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+                check_vma=False,
+            )).lower(jax.random.PRNGKey(0), batch)
+            marks[scan] = ps.sequence_parallel_param_paths()
+            ps.destroy_model_parallel()
+        assert marks[False] == marks[True] and marks[True]
+        m = BertForPreTraining(BertConfig(scan_layers=False, **BERT_KW))
+        params = m.init(jax.random.PRNGKey(0), batch["input_ids"])
+
+        def noisy(seed):
+            return float(bert_pretrain_loss(
+                params, m, batch, deterministic=False,
+                rngs={"dropout": jax.random.PRNGKey(seed)},
+            ))
+
+        assert noisy(1) == noisy(1) != noisy(2)
+        assert noisy(1) != float(bert_pretrain_loss(params, m, batch))
 
     def test_tp_matches_unsharded(self, eight_devices):
         """sharded_init + per-head QKV layout ⇒ tp changes nothing."""

@@ -463,3 +463,65 @@ def test_grouped_query_paged_decode_walk_compiles(
     ).compile().as_text()
     assert text.count("paged_decode_fwd") >= 1
     assert "tpu_custom_call" in text
+
+
+def test_bert_recipe_step_leaves_room_for_a_second_copy_of_the_weights(
+    one_chip, lower_as_chip, bert_recipe
+):
+    """``examples/bert/pretrain_bert.py``'s step at the benchmark cell's
+    size (BERT-Large, 128 rows of 128 tokens, K = 20, one step a call),
+    under the checkpoint form the recipe builds: it compiles, the Pallas
+    LayerNorm kernels are in it, and XLA's own count — arguments + outputs
+    + temporaries, less what is aliased — leaves 1.5 GB of the chip's 16 GB:
+    the harness holds a second copy of the weights (1.34 GB) for a moment.
+    (XLA's count over-reads what the chip needs: a form it counts at 17.1 GB
+    ran on the chip's 16.9 GB, PERF.md section 6, PR 38.)"""
+    from apex_tpu import parallel_state as ps
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    recipe = bert_recipe
+    rows, seq, k = 128, 128, 20
+    args = recipe.parse_args([
+        "--batch", str(rows), "--seq-len", str(seq), "--chunk", "1",
+        "--max-predictions-per-seq", str(k),
+    ])
+    model = recipe.BertForPreTraining(recipe.model_config(args))
+    tx = recipe.fused_lamb(learning_rate=args.lr, weight_decay=0.01)
+    (device,) = one_chip.device_set
+    mesh = ps.initialize_model_parallel(devices=[device])
+
+    def on_mesh(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    params = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((seq, rows), jnp.int32)
+    )
+    state = jax.tree_util.tree_map(
+        lambda x: on_mesh(x.shape, x.dtype),
+        (params, jax.eval_shape(tx.init, params)),
+    )
+    by_row = P(None, None, "dp")
+    batches = {
+        "input_ids": on_mesh((1, seq, rows), jnp.int32, by_row),
+        "token_type_ids": on_mesh((1, seq, rows), jnp.int32, by_row),
+        "attention_mask": on_mesh((1, rows, seq), jnp.int32, P(None, "dp")),
+        "nsp_labels": on_mesh((1, rows), jnp.int32, P(None, "dp")),
+        "mlm_positions": on_mesh((1, k, rows), jnp.int32, by_row),
+        "mlm_label_ids": on_mesh((1, k, rows), jnp.int32, by_row),
+        "mlm_weights": on_mesh((1, k, rows), jnp.float32, by_row),
+    }
+    compiled = recipe.build_step(model, tx, mesh, k).lower(
+        *state, batches
+    ).compile()
+    text = compiled.as_text()
+    assert "layer_norm_fwd" in text and "layer_norm_bwd" in text
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    weights = sum(
+        x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params)
+    )
+    assert 1.3e9 < weights < 1.4e9, weights
+    assert held < 16e9 - 1.5e9, held
