@@ -7,9 +7,11 @@ proofs about them:
   decode executable for the full slot array, compiled ahead of time
   (``jit(...).lower(...).compile()``) at :meth:`InferenceEngine.build`.
   Steady-state serving calls compiled executables only: a retrace is
-  impossible by construction, and :attr:`compile_counts` +
-  a :class:`~apex_tpu.analysis.RetraceSentinel` per program pin it
-  observably (``tests/test_serve.py``).
+  impossible by construction — an executable refuses a call whose
+  pytree or avals differ (``TypeError``), and :attr:`retraces` counts
+  those refusals where they happen, in the ``engine/dispatch`` phase —
+  and :attr:`compile_counts` pins it observably
+  (``tests/test_serve.py``).
 - **verification** — with ``verify=True`` (the default), the
   :mod:`apex_tpu.analysis` passes run over every step program at
   build (``lint_hlo`` on the one AOT-compiled module + ``lint_jaxpr``
@@ -405,8 +407,8 @@ def _verify_args(eng):
 @dataclasses.dataclass(frozen=True)
 class _Program:
     """One KIND of step program.  ``kind`` is its name on the board, in
-    :attr:`InferenceEngine.compile_counts` and on its retrace sentinel
-    (a per-bucket kind's programs are ``<kind>_<bucket>``); the jitted
+    :attr:`InferenceEngine.compile_counts` and on its host phases (a
+    per-bucket kind's programs are ``<kind>_<bucket>``); the jitted
     function is ``serve_<that name>`` — the module name a device trace
     shows (``jit_serve_decode``)."""
 
@@ -588,12 +590,13 @@ class InferenceEngine:
         #: optional :class:`~apex_tpu.observability.spans.SpanRecorder`
         #: (the scheduler attaches its recorder here automatically).
         #: Every prefill/decode call records an ``engine/stage`` phase
-        #: (the call's host arguments packed into one numpy vector,
-        #: retrace sentinel) and
+        #: (the call's host arguments packed into one numpy vector) and
         #: an ``engine/prefill`` / ``engine/decode`` phase (compiled
-        #: call → first host read) — here, or with none attached in
-        #: the process ring (:func:`~apex_tpu.observability.spans.
-        #: process_recorder`)
+        #: call → host reads), under which ``engine/dispatch`` is the
+        #: compiled call and each ``engine/read`` one device→host read
+        #: (a lazy prefill dispatches under its ``engine/stage``) —
+        #: here, or with none attached in the process ring
+        #: (:func:`~apex_tpu.observability.spans.process_recorder`)
         self.spans = None
         #: monotonically increasing call counters — the correlation
         #: ids linking a request's span chain to the engine batch
@@ -604,6 +607,10 @@ class InferenceEngine:
         #: retrace-freedom pin (steady state never increments it; a
         #: supervised :meth:`rebuild` does, honestly)
         self.compile_counts: Dict[str, int] = {}
+        #: calls whose executable refused their arguments' pytree or
+        #: avals (the calls a ``jit`` would have retraced): 0 in steady
+        #: state; counted where the compiled call raises, and re-raised
+        self.retraces = 0
         #: supervised recoveries (:meth:`rebuild` calls) — 0 in steady
         #: state; every increment is a fault the scheduler survived
         self.rebuilds = 0
@@ -615,7 +622,6 @@ class InferenceEngine:
         self.last_prefill_finite: bool = True
         self.last_decode_finite: Optional[np.ndarray] = None
         self.reports: Dict[str, object] = {}
-        self._sentinels: Dict[str, object] = {}
         self._publish_build_gauges()
 
     def _new_cache(self, cfg: GptConfig) -> dict:
@@ -800,7 +806,6 @@ class InferenceEngine:
                     f"{len(errors)} ERROR finding(s):\n{report.render()}"
                 )
         self.compile_counts[name] = self.compile_counts.get(name, 0) + 1
-        self._sentinels[name] = analysis.RetraceSentinel(name=name)
         return compiled
 
     def build(self, buckets: Optional[Tuple[int, ...]] = None, *,
@@ -848,7 +853,6 @@ class InferenceEngine:
                 k for k in self._programs if _PROGRAMS[k[0]].dropped
             ]:
                 del self._programs[key]
-                del self._sentinels[_program_name(*key)]
         for prog in self._kinds():
             if prog.rebuilt:
                 self._programs[prog.kind, None] = self._compile(
@@ -869,10 +873,6 @@ class InferenceEngine:
                 donate=_PROGRAMS[kind].donate,
             )
         return self._programs[key]
-
-    @property
-    def retraces(self) -> int:
-        return sum(s.retraces for s in self._sentinels.values())
 
     def lint(self, bucket: Optional[int] = None):
         """One merged :class:`apex_tpu.analysis.Report` over the
@@ -944,6 +944,27 @@ class InferenceEngine:
             name, track=TRACK_ENGINE, **args
         )
 
+    def _call(self, compiled, *args):
+        """A compiled call.  An AOT executable checks its arguments'
+        pytree and avals against the ones it was compiled for and raises
+        ``TypeError`` before it runs (nothing donated): that call is
+        counted on :attr:`retraces` and the error goes on."""
+        try:
+            return compiled(*args)
+        except TypeError:
+            self.retraces += 1
+            raise
+
+    def _dispatch(self, program: str, compiled, *args):
+        """:meth:`_call` as the ``engine/dispatch`` phase: the launch and
+        the packed vector's one transfer, not the device's time."""
+        with self._phase("engine/dispatch", program=program):
+            return self._call(compiled, *args)
+
+    def _read(self, program: str, what: str):
+        """One device→host read of a call: an ``engine/read`` phase."""
+        return self._phase("engine/read", program=program, what=what)
+
     def _temps(self, temps):
         if temps is None:
             return np.zeros((self.serve.max_batch,), np.float32)
@@ -997,35 +1018,41 @@ class InferenceEngine:
                     *((slot,) if self.stateful else ()),
                 ),
             )
-            self._sentinels[name].observe(*args)
             if lazy:
-                logits, next_token, finite, self.cache = compiled(*args)
+                logits, next_token, finite, self.cache = self._dispatch(
+                    name, compiled, *args)
         self.prefill_calls += 1
         span = dict(bucket=bucket, tokens=n, call=self.prefill_calls,
                     **self._span_kinds)
         if lazy:
-            return logits, (next_token, finite, poison, span)
+            return logits, (name, next_token, finite, poison, span)
         # int(next_token) syncs, so the phase covers the real device
         # time, not just the async dispatch
         with self._phase("engine/prefill", **span):
-            logits, next_token, finite, self.cache = compiled(*args)
+            logits, next_token, finite, self.cache = self._dispatch(
+                name, compiled, *args)
             # logits stay ON DEVICE (lazy jax.Array): only the sampled
             # token and the scalar finite screen cross to the host —
             # the logits matrix is (V,)/(B, V) and most callers never
             # read it
-            first = int(self._read_tokens(next_token).reshape(-1)[0])
-            self.last_prefill_finite = bool(finite) and poison is None
+            first = self._read_first(name, next_token, finite, poison)
         return logits, first
+
+    def _read_first(self, name, next_token, finite, poison) -> int:
+        """A prefill's two reads: its first token, then its screen."""
+        with self._read(name, "tokens"):
+            first = int(self._read_tokens(next_token).reshape(-1)[0])
+        with self._read(name, "finite"):
+            self.last_prefill_finite = bool(finite) and poison is None
+        return first
 
     def resolve_prefill(self, pending) -> int:
         """Read a ``lazy`` :meth:`prefill`'s first token (the wait for the
         device is this ``engine/prefill`` phase); the non-finite screen and
         a routed model's counts land where a plain prefill leaves them."""
-        next_token, finite, poison, span = pending
+        name, next_token, finite, poison, span = pending
         with self._phase("engine/prefill", **span):
-            first = int(self._read_tokens(next_token).reshape(-1)[0])
-            self.last_prefill_finite = bool(finite) and poison is None
-        return first
+            return self._read_first(name, next_token, finite, poison)
 
     def chunk_prefill(self, chunk_ids, offset, page_table_row,
                       chunk_page_ids, *,
@@ -1068,14 +1095,13 @@ class InferenceEngine:
                     temperature,
                 ),
             )
-            self._sentinels[name].observe(*args)
         self.prefill_calls += 1
         with self._phase("engine/prefill", bucket=bucket, tokens=n,
                          offset=int(offset), call=self.prefill_calls,
                          chunked=True):
-            logits, next_token, finite, self.cache = compiled(*args)
-            first = int(next_token)
-            self.last_prefill_finite = bool(finite) and poison is None
+            logits, next_token, finite, self.cache = self._dispatch(
+                name, compiled, *args)
+            first = self._read_first(name, next_token, finite, poison)
         return logits, first
 
     def fork_page(self, src: int, dst: int) -> None:
@@ -1084,9 +1110,8 @@ class InferenceEngine:
         int8 KV wire) through one tiny compiled donated program — the
         device half of the scheduler's shared-tail-page fork."""
         compiled = self._program("fork_page")
-        args = (self.cache, self._host_args("fork_page").pack(src, dst))
-        self._sentinels["fork_page"].observe(*args)
-        self.cache = compiled(*args)
+        self.cache = self._call(
+            compiled, self.cache, self._host_args("fork_page").pack(src, dst))
 
     def decode(self, tokens, lengths, page_tables, temps=None, *,
                streams=None, gens=None, steps=None):
@@ -1131,17 +1156,19 @@ class InferenceEngine:
                     streams, gens, *((steps,) if block > 1 else ()),
                 ),
             )
-            self._sentinels["decode"].observe(*args)
         self.decode_iters += 1
         # np.asarray(next_tokens) syncs — real device time
         with self._phase("engine/decode", iter=self.decode_iters,
                          batch=int((np.asarray(lengths) > 0).sum()),
                          **self._span_kinds):
-            logits, next_tokens, finite, self.cache = compiled(*args)
-            out = self._read_tokens(next_tokens)
+            logits, next_tokens, finite, self.cache = self._dispatch(
+                "decode", compiled, *args)
+            with self._read("decode", "tokens"):
+                out = self._read_tokens(next_tokens)
             if block > 1:
                 out = out.reshape(block, -1)
-            finite_np = np.array(finite)
+            with self._read("decode", "finite"):
+                finite_np = np.array(finite)
         if poison is not None:
             # an injected poisoned-logits fault: flag the first LIVE
             # slot exactly as the in-step screen would flag a real
@@ -1265,9 +1292,8 @@ class InferenceEngine:
                 tokens, ids, n, self.draft_prefill_calls, 0.0
             ),
         )
-        self._sentinels[name].observe(*args)
         self.draft_prefill_calls += 1
-        _logits, _tok, _finite, self.draft_cache = compiled(*args)
+        _logits, _tok, _finite, self.draft_cache = self._call(compiled, *args)
 
     def spec_step(self, tokens, lengths, page_tables, draft_tables,
                   temps, streams, gens):
@@ -1306,11 +1332,8 @@ class InferenceEngine:
                     tokens, lengths, draft_tables, temps, streams, gens
                 ),
             )
-            compiled = self._program("draft_decode")
-            self._sentinels["draft_decode"].observe(*d_args)
-            d_tokens, d_probs, d_finite, self.draft_cache = compiled(
-                *d_args
-            )
+            d_tokens, d_probs, d_finite, self.draft_cache = self._dispatch(
+                "draft_decode", self._program("draft_decode"), *d_args)
             v_args = (
                 self.step_params, self.cache, self._rng_base, d_tokens,
                 d_probs, d_finite,
@@ -1320,17 +1343,20 @@ class InferenceEngine:
                 ),
             )
             compiled = self._program("verify")
-            self._sentinels["verify"].observe(*v_args)
         self.decode_iters += 1
         live_n = int((np.asarray(lengths) > 0).sum())
         # np.asarray(out_tokens) syncs — real device time
         with self._phase("engine/decode", iter=self.decode_iters,
                          batch=live_n, spec=True,
                          drafted=spec.k * live_n) as ph:
-            out_tokens, n_accept, finite, self.cache = compiled(*v_args)
-            out = np.asarray(out_tokens)
-            acc = np.asarray(n_accept)
-            finite_np = np.array(finite)
+            out_tokens, n_accept, finite, self.cache = self._dispatch(
+                "verify", compiled, *v_args)
+            with self._read("verify", "tokens"):
+                out = np.asarray(out_tokens)
+            with self._read("verify", "accepted"):
+                acc = np.asarray(n_accept)
+            with self._read("verify", "finite"):
+                finite_np = np.array(finite)
             ph.set(accepted=int(acc.sum()))
         if poison is not None:
             live = np.flatnonzero(np.asarray(lengths) > 0)
@@ -1350,8 +1376,7 @@ class InferenceEngine:
             self.cache,
             self._host_args("rollback").pack(starts, counts, page_tables),
         )
-        self._sentinels["rollback"].observe(*args)
-        self.cache = compiled(*args)
+        self.cache = self._call(compiled, *args)
 
     def draft_rollback(self, starts, counts, page_tables) -> None:
         """:meth:`rollback` for the draft KV pool (draft page ids)."""
@@ -1362,8 +1387,7 @@ class InferenceEngine:
                 starts, counts, page_tables
             ),
         )
-        self._sentinels["draft_rollback"].observe(*args)
-        self.draft_cache = compiled(*args)
+        self.draft_cache = self._call(compiled, *args)
 
     def update_draft_params(self, draft_params) -> None:
         """Swap the draft weights in place (a fleet redeploy shipping a

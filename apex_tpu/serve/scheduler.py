@@ -146,7 +146,8 @@ Host phases: what the host does inside ``step()`` is named by
 ``serve/step`` and under it ``serve/admit``, ``serve/chunks``,
 ``serve/batch``, ``serve/retire``, ``serve/publish``, with the engine's
 ``engine/stage`` / ``engine/prefill`` / ``engine/decode`` between them
-(docs/serving.md "Host phases").  They go to the attached recorder and,
+and, inside a call, ``engine/dispatch`` and one ``engine/read`` a host
+read (docs/serving.md "Host phases").  They go to the attached recorder and,
 with none attached, to the ring every process keeps
 (:func:`~apex_tpu.observability.spans.process_recorder`); the request
 lifecycle above is recorded on an attached recorder only.

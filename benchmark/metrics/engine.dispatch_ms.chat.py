@@ -1,0 +1,4 @@
+"""Milliseconds of the decode call's launch (median): its `engine/dispatch`,
+the compiled call that takes the packed vector as its one transfer."""
+
+from benchmark.call_readers import dispatch_ms_decode as read  # noqa: F401

@@ -2030,7 +2030,11 @@ class TestProgramTable:
         eng.build(chunked=True)
         assert list(eng.compile_counts) == PROGRAMS_AT_PR32
         assert set(eng.compile_counts.values()) == {1}
-        assert sorted(eng._sentinels) == sorted(PROGRAMS_AT_PR32)
+        assert sorted(
+            kind if bucket is None else f"{kind}_{bucket}"
+            for kind, bucket in eng._programs
+        ) == sorted(PROGRAMS_AT_PR32)
+        assert eng.retraces == 0
         assert {
             re.search(r"HloModule (\S+?),", exe.as_text()).group(1)
             for exe in eng._programs.values()
@@ -2048,9 +2052,8 @@ class TestProgramTable:
             assert eng.compile_counts[name] == 1 + (name in rebuilt)
             if name in dropped:
                 assert (kind, bucket) not in eng._programs
-                assert name not in eng._sentinels
             else:
-                assert name in eng._sentinels
+                assert (kind, bucket) in eng._programs
                 # a recompiled program is a NEW executable, the rest
                 # are the ones build() made
                 same = eng._programs[kind, bucket] is exe

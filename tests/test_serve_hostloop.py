@@ -316,9 +316,9 @@ class TestHostPathLaunchesNothing:
             guard.one_transfer(lambda: eng.fork_page(pages[0], pages[2]))
             tok = 0
         assert 0 <= int(tok) < eng.cfg.vocab_size
-        # the sentinels saw one abstract signature per program
+        # no executable refused a call, and none was compiled again
         assert eng.retraces == 0
-        assert all(s.signatures <= 1 for s in eng._sentinels.values())
+        assert set(eng.compile_counts.values()) == {1}
 
     def test_speculative_round_folds_nothing_and_stages_once(
         self, gpt, monkeypatch
@@ -368,6 +368,86 @@ class TestHostPathLaunchesNothing:
                 programs=lambda: eng.prefill_calls + eng.decode_iters - calls,
             )
         assert [r.tokens for r in sched.completed] and not sched.shed
+
+
+# ---------------------------------------------------------------------------
+# retraces are counted where the executable refuses a call (ISSUE 39)
+# ---------------------------------------------------------------------------
+
+
+class TestRetraceCount:
+    def test_steady_run_counts_none(self, gpt):
+        eng = make_engine(gpt).build()
+        sched = ContinuousBatchingScheduler(eng, registry=None)
+        for r in script_requests():
+            sched.submit(r)
+        while sched.queue or sched.running:
+            sched.step()
+        assert len(sched.completed) == len(SCRIPT) and not sched.shed
+        assert eng.retraces == 0
+        assert set(eng.compile_counts.values()) == {1}
+
+    @pytest.mark.parametrize("call", ["decode", "prefill"])
+    def test_refused_vector_counts_one_and_raises(
+        self, gpt, monkeypatch, call
+    ):
+        """A host vector one word short: the executable raises
+        ``TypeError`` before it runs, the engine counts it once and lets
+        it go on; nothing was donated, so the next call is served."""
+        eng = make_engine(gpt).build()
+        pages = eng.pool.alloc(2)
+        prompt = list(range(1, 10))
+        args = _decode_args(eng, pages, len(prompt), 7)
+        layout = eng._host_args(call, None if call == "decode" else 16)
+        real_pack = layout.pack
+        monkeypatch.setattr(layout, "pack", lambda *v: real_pack(*v)[:-1])
+        with pytest.raises(TypeError):
+            if call == "decode":
+                eng.decode(*args)
+            else:
+                eng.prefill(prompt, pages)
+        assert eng.retraces == 1
+        monkeypatch.setattr(layout, "pack", real_pack)
+        eng.prefill(prompt, pages)
+        eng.decode(*args)
+        assert eng.retraces == 1
+        assert set(eng.compile_counts.values()) == {1}
+
+    def test_speculative_round_phases(self, gpt):
+        """The round's draft program dispatches while the verify call is
+        staged; the verify call dispatches and reads its tokens, its
+        acceptance counts and its screen under ``engine/decode``."""
+        from apex_tpu.observability import SpanRecorder
+
+        sp = spec_lib.SpecConfig(draft_params=None, k=2, mode="temperature")
+        eng = make_engine(gpt, spec=sp).build()
+        eng.spans = rec = SpanRecorder(capacity=256)
+        pages, dpages = eng.pool.alloc(3), eng.pool.alloc(3)
+        prompt = list(range(1, 10))
+        eng.prefill(prompt, pages[:2])
+        eng.draft_prefill(prompt, dpages[:2])
+        tokens, lengths, table = _decode_args(eng, pages, len(prompt), 7)
+        dtable = np.zeros_like(table)
+        dtable[0, :3] = dpages
+        eng.spec_step(tokens, lengths, table, dtable, None,
+                      np.array([3, 0], np.uint32), np.array([1, 0], np.int32))
+        spans = [e for e in rec.snapshot() if "id" in e]
+        kids = {}
+        for e in spans:
+            kids.setdefault(e["parent"], []).append(
+                (e["name"], e.get("args")))
+        by_name = {e["name"]: e for e in spans}
+        stage = [e for e in spans if e["name"] == "engine/stage"][-1]
+        assert stage["args"] == {"program": "spec"}
+        assert kids[stage["id"]] == [
+            ("engine/dispatch", {"program": "draft_decode"})]
+        assert kids[by_name["engine/decode"]["id"]] == [
+            ("engine/dispatch", {"program": "verify"}),
+            ("engine/read", {"program": "verify", "what": "tokens"}),
+            ("engine/read", {"program": "verify", "what": "accepted"}),
+            ("engine/read", {"program": "verify", "what": "finite"}),
+        ]
+        assert eng.retraces == 0
 
 
 # ---------------------------------------------------------------------------

@@ -1063,6 +1063,133 @@ class TestSchedulerPhases:
             "serve/chunks"}
         assert names["serve/chunks"] <= names["serve/step"]
 
+    def test_each_call_dispatches_once_then_reads_tokens_then_finite(
+            self, engine):
+        """Under every ``engine/prefill`` / ``engine/decode``: exactly one
+        ``engine/dispatch``, then ``engine/read {tokens}``, then
+        ``engine/read {finite}``, all named after the call's program; the
+        new phases are leaves and ``engine/stage`` has no children."""
+        from apex_tpu.observability import process_recorder
+
+        ring = process_recorder()
+        seq0 = ring._seq
+        _sched, did = self._stepwise(engine)
+        steps, kids = _tree(_since(ring, seq0))
+        phases = [e for e in _since(ring, seq0) if "id" in e]
+        calls = [e for e in phases
+                 if e["name"] in ("engine/prefill", "engine/decode")]
+        assert len(calls) == sum(p + d for p, d, _, _ in did) >= 5
+        for e in calls:
+            program = "decode" if e["name"] == "engine/decode" else (
+                f"prefill_{e['args']['bucket']}")
+            mine = sorted(kids[e["id"]], key=lambda c: c["t0"])
+            assert [(c["name"], c["args"], c["track"]) for c in mine] == [
+                ("engine/dispatch", {"program": program}, TRACK_ENGINE),
+                ("engine/read", {"program": program, "what": "tokens"},
+                 TRACK_ENGINE),
+                ("engine/read", {"program": program, "what": "finite"},
+                 TRACK_ENGINE),
+            ]
+            assert all(c["id"] not in kids for c in mine)
+        assert [e for e in phases if e["name"] == "engine/dispatch"
+                and e["parent"] not in {c["id"] for c in calls}] == []
+        assert all(e["id"] not in kids for e in phases
+                   if e["name"] == "engine/stage")
+
+    def test_lazy_prefill_dispatches_under_stage_and_reads_under_resolve(
+            self):
+        """A hybrid stack's scheduler (``decode_block > 1``) dispatches each
+        admission's prefill inside its ``engine/stage`` and reads it in the
+        ``engine/prefill`` that ``resolve_prefill`` opens after the admit
+        loop."""
+        from apex_tpu.models.hybrid import init_params
+        from apex_tpu.serve import InferenceEngine, ServeConfig
+        from benchmark.drivers import ling_serve
+
+        pcfg = ling_serve.program_config(dict(
+            num_hidden_layers=2, hidden_size=64, intermediate_size=96,
+            moe_intermediate_size=32, moe_shared_expert_intermediate_size=32,
+            num_attention_heads=4, head_dim=16, num_experts=4,
+            num_experts_published=16, held_experts_first=0,
+            num_experts_per_tok=4, n_group=4, topk_group=2,
+            routed_scaling_factor=2.5, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, vocab_size=96,
+            rope_theta=6000000, rms_norm_eps=1e-6, layer_group_size=2,
+            first_k_dense_replace=1, max_position_embeddings=512,
+            short_conv_kernel_size=4, kda_lower_bound=-5,
+            compute_dtype="float32", param_dtype="float32",
+            serve=dict(page_size=8, max_pages_per_seq=16),
+        ))
+        eng = InferenceEngine(pcfg, init_params(pcfg, seed=11), ServeConfig(
+            page_size=8, num_pages=33, max_batch=2, max_pages_per_seq=8,
+            prefill_buckets=(32,), decode_block=2, verify=False,
+        )).build()
+        rec = SpanRecorder(capacity=4096)
+        sched, did = self._stepwise(eng, spans=rec, n=2)
+        eng.spans = None
+        assert len(sched.completed) == 2 and eng.retraces == 0
+        steps, kids = _tree(rec.snapshot())
+        by_id = {e["id"]: e for e in rec.snapshot() if "id" in e}
+        stages = [e for e in by_id.values() if e["name"] == "engine/stage"
+                  and e["args"]["program"] == "prefill_32"]
+        prefills = [e for e in by_id.values()
+                    if e["name"] == "engine/prefill"]
+        assert len(stages) == len(prefills) == 2
+        for st in stages:
+            assert by_id[st["parent"]]["name"] == "serve/admit"
+            assert [(c["name"], c["args"]) for c in kids[st["id"]]] == [
+                ("engine/dispatch", {"program": "prefill_32"})]
+        for pf in prefills:
+            assert by_id[pf["parent"]]["name"] == "serve/step"
+            assert [(c["name"], c["args"]) for c in sorted(
+                kids[pf["id"]], key=lambda c: c["t0"])] == [
+                ("engine/read", {"program": "prefill_32", "what": "tokens"}),
+                ("engine/read", {"program": "prefill_32", "what": "finite"}),
+            ]
+        # the decode block's call: dispatched and read under engine/decode
+        for dec in (e for e in by_id.values()
+                    if e["name"] == "engine/decode"):
+            assert [c["name"] for c in sorted(
+                kids[dec["id"]], key=lambda c: c["t0"])] == [
+                "engine/dispatch", "engine/read", "engine/read"]
+
+    def test_dispatch_and_reads_land_in_the_profiler_trace(
+            self, engine, tmp_path):
+        """The new phases reach the trace's host plane as annotations,
+        inside the call that holds them (the pattern of
+        ``test_phase_lands_in_the_profiler_trace_too``)."""
+        from jax.profiler import ProfileData
+
+        pages = engine.pool.alloc(1)
+        try:
+            engine.prefill([1, 2, 3], pages)        # warm, off the trace
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                engine.prefill([4, 5, 6], pages)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            engine.pool.free(pages)
+        found = []
+        for root, _dirs, files in os.walk(tmp_path):
+            found += [os.path.join(root, f) for f in files
+                      if f.endswith(".xplane.pb")]
+        assert found
+        spans = {}
+        for plane in ProfileData.from_file(found[0]).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for e in line.events:
+                        if e.name.startswith("engine/"):
+                            spans.setdefault(e.name, []).append(
+                                (e.start_ns, e.start_ns + e.duration_ns))
+        assert {k: len(v) for k, v in spans.items()} == {
+            "engine/stage": 1, "engine/prefill": 1, "engine/dispatch": 1,
+            "engine/read": 2}
+        (p0, p1), = spans["engine/prefill"]
+        for c0, c1 in spans["engine/dispatch"] + spans["engine/read"]:
+            assert p0 <= c0 and c1 <= p1
+
 
 # ---------------------------------------------------------------------------
 # watchdog: queue-wait fraction rule
