@@ -258,22 +258,30 @@ def _chunk_fields(eng, bucket):
     return _prompt(s, bucket) + (row, _INT, _INT, _INT, _FLOAT)
 
 
-def _decode_fields(eng, bucket):
+def _slot_fields(eng, bucket):
     # tokens, lengths, page tables, temperatures, and the two integers
-    # each slot's sampling key is folded from — and, for a decode block,
-    # the iterations each slot runs
+    # each slot's sampling key is folded from
     s = eng.serve
     return (
         _per_slot(s), _per_slot(s), _tables(s), _per_slot(s, np.float32),
         _per_slot(s, np.uint32), _per_slot(s),
-    ) + ((_per_slot(s),) if s.decode_block > 1 else ())
+    )
+
+
+def _decode_fields(eng, bucket):
+    # the slot fields, which slots take their fed token from the previous
+    # decode call's output (nonzero: carried) — and, for a decode block,
+    # the iterations each slot runs
+    s = eng.serve
+    return _slot_fields(eng, bucket) + (_per_slot(s),) + (
+        (_per_slot(s),) if s.decode_block > 1 else ())
 
 
 def _verify_fields(eng, bucket):
-    # decode's, with the host's verdict on the round's draft (0 under a
-    # serve.draft fault) before the key integers
-    decode = _decode_fields(eng, bucket)
-    return decode[:4] + (_INT,) + decode[4:]
+    # the slot fields, with the host's verdict on the round's draft (0
+    # under a serve.draft fault) before the key integers
+    slot = _slot_fields(eng, bucket)
+    return slot[:4] + (_INT,) + slot[4:]
 
 
 def _rollback_fields(eng, bucket):
@@ -316,10 +324,17 @@ def _chunk_step(eng, host, params, kv_pages, base_key, packed):
     )
 
 
-def _decode_step(eng, host, params, kv_pages, base_key, packed):
-    tokens, lengths, page_tables, temps, streams, gens, *steps = (
+def _decode_step(eng, host, params, kv_pages, base_key, prev, packed):
+    tokens, lengths, page_tables, temps, streams, gens, carry, *steps = (
         host.unpack(packed)
     )
+    # a carried slot is fed the token the previous decode call sampled for
+    # it, still on the device: ``prev`` is that call's output as it
+    # returned it — a block's rows end to end (the last is the last
+    # iteration's), a routed model's two counts behind them
+    b = tokens.shape[0]
+    fed = prev[-2 - b:-2] if eng.routed else prev[-b:]
+    tokens = jnp.where(carry != 0, fed, tokens)
     block = {}
     if steps:
         # a decode block: iteration j's keys are emission gens + j's
@@ -391,6 +406,10 @@ def _target_args(eng):
     return (eng.step_params, eng.cache, eng._rng_base)
 
 
+def _decode_args(eng):
+    return _target_args(eng) + (eng._decode_out,)
+
+
 def _draft_args(eng):
     return (eng.draft_step_params, eng.draft_cache, eng._rng_base)
 
@@ -451,9 +470,9 @@ _PROGRAMS: Dict[str, _Program] = {p.kind: p for p in (
              per_bucket=True, spec=True, dropped=True),
     _Program("fork_page", _fork_fields, _fork_step,
              lambda eng: (eng.cache,), donate=0, chunked=True),
-    _Program("decode", _decode_fields, _decode_step, _target_args,
+    _Program("decode", _decode_fields, _decode_step, _decode_args,
              rebuilt=True),
-    _Program("draft_decode", _decode_fields, _draft_step, _draft_args,
+    _Program("draft_decode", _slot_fields, _draft_step, _draft_args,
              spec=True, rebuilt=True),
     _Program("verify", _verify_fields, _verify_step, _verify_args,
              spec=True, rebuilt=True),
@@ -462,6 +481,22 @@ _PROGRAMS: Dict[str, _Program] = {p.kind: p for p in (
     _Program("draft_rollback", _rollback_fields, _rollback_step,
              lambda eng: (eng.draft_cache,), donate=0, spec=True),
 )}
+
+
+@dataclasses.dataclass
+class _UnreadDecode:
+    """A ``lazy`` decode call until :meth:`InferenceEngine.read_decode`
+    hands it over: what its read needs, then what the read got."""
+
+    #: the in-step non-finite screen, on the device
+    finite: object
+    #: an injected ``serve.decode`` fault (stall / poison), or None
+    fault: object
+    lengths: np.ndarray
+    #: ``(tokens, finite, counts)`` on the host, once read
+    got: Optional[tuple] = None
+    #: what the read raised
+    error: Optional[BaseException] = None
 
 
 class InferenceEngine:
@@ -516,9 +551,8 @@ class InferenceEngine:
                 "decode_block > 1 is a hybrid stack's: the GPT stack's "
                 "decode program emits one token a call"
             )
-        #: a routed model's last step's counts ``(pairs routed to held
-        #: experts, distinct held experts touched)``, summed over layers
-        self.last_moe_counts: Optional[np.ndarray] = None
+        # a routed model's last call's counts (:attr:`last_moe_counts`)
+        self._moe_counts: Optional[np.ndarray] = None
         if self.serve.max_context > cfg.max_seq_len:
             raise ValueError(
                 f"max context {self.serve.max_context} exceeds the "
@@ -587,6 +621,23 @@ class InferenceEngine:
         self._rng_base = jax.device_put(
             jax.random.PRNGKey(self.serve.sample_seed)
         )
+        # the decode program's token output as the last call returned it,
+        # on the device: the next call's carried slots are fed from it.  A
+        # zeroed one of the same shape stands in before the first call and
+        # after a call that raised (its buffers may hold the error)
+        s = self.serve
+        self._decode_zeros = jax.device_put(np.zeros(
+            (s.max_batch * s.decode_block + (2 if self.routed else 0),),
+            np.int32))
+        self._decode_out = self._decode_zeros
+        #: ``lazy`` decode calls not handed over yet: call index -> what
+        #: their read needs, then what it got (:class:`_UnreadDecode`).  A
+        #: lazy call returns its token array as the program returned it, so
+        #: the rest of its read waits here
+        self.unread_decodes: Dict[int, _UnreadDecode] = {}
+        # a routed model's last lazy decode call whose counts nothing has
+        # read yet, ``(index, token array)`` (:attr:`last_moe_counts`)
+        self._counts_due: Optional[tuple] = None
         #: optional :class:`~apex_tpu.observability.spans.SpanRecorder`
         #: (the scheduler attaches its recorder here automatically).
         #: Every prefill/decode call records an ``engine/stage`` phase
@@ -848,6 +899,7 @@ class InferenceEngine:
         next use.  The swap is one atomic attribute write.
         """
         self.rebuilds += 1
+        self._decode_out = self._decode_zeros
         if full:
             for key in [
                 k for k in self._programs if _PROGRAMS[k[0]].dropped
@@ -918,19 +970,22 @@ class InferenceEngine:
         )
 
     @staticmethod
-    def _chaos_gate(site: str, call_idx: int):
+    def _chaos_gate(site: str, call_idx: int, *, sleep: bool = True):
         """Serving chaos hook (one ``APEX_TPU_CHAOS`` spec drives train
         AND serve drills): ``raise`` mode raises :class:`~apex_tpu.
         resilience.chaos.InjectedFault` standing in for a wedged or
         crashed step, ``stall`` sleeps (a hung device call — the
-        scheduler's per-request decode timeouts see it), ``nan``/
-        ``inf`` return the fault so the caller poisons its non-finite
-        verdict (the quarantine drill).  ``call_idx`` is the 0-based
-        prefill-call / decode-iteration index."""
+        scheduler's per-request decode timeouts see it; ``sleep=False``
+        returns the fault for the caller to sleep where the call would
+        hang), ``nan``/``inf`` return the fault so the caller poisons its
+        non-finite verdict (the quarantine drill).  ``call_idx`` is the
+        0-based prefill-call / decode-iteration index."""
         fault = chaos.active(site, call_idx)
         if fault is None:
             return None
         if fault.mode == "stall":
+            if not sleep:
+                return fault
             time.sleep(fault.stall_seconds)
             return None
         if fault.mode in ("nan", "inf"):
@@ -970,15 +1025,29 @@ class InferenceEngine:
             return np.zeros((self.serve.max_batch,), np.float32)
         return np.asarray(temps, np.float32)
 
-    def _read_tokens(self, next_tokens) -> np.ndarray:
-        """The step's ONE token readback.  A routed model's carries its MoE
-        counts behind the tokens (:func:`apex_tpu.serve.model.
-        _with_counts`): they land on :attr:`last_moe_counts`."""
+    def _read_tokens(self, next_tokens):
+        """The step's ONE token readback: ``(tokens, counts)``.  A routed
+        model's carries its MoE counts behind the tokens (:func:`apex_tpu.
+        serve.model._with_counts`); elsewhere ``counts`` is None."""
         out = np.asarray(next_tokens)
         if self.routed:
-            self.last_moe_counts = out[-2:]
-            out = out[:-2]
-        return out
+            return out[:-2], out[-2:]
+        return out, None
+
+    @property
+    def last_moe_counts(self) -> Optional[np.ndarray]:
+        """A routed model's last call's counts ``(pairs routed to held
+        experts, distinct held experts touched)``, summed over layers (None
+        elsewhere).  Of a decode call dispatched ``lazy`` and not read yet,
+        asking reads its token array for them: the host waits for that
+        call, in an ``engine/decode`` phase of its own."""
+        due, self._counts_due = self._counts_due, None
+        if due is not None:
+            call, next_tokens = due
+            with self._phase("engine/decode", iter=call, **self._span_kinds):
+                with self._read("decode", "tokens"):
+                    self._moe_counts = self._read_tokens(next_tokens)[1]
+        return self._moe_counts
 
     def prefill(self, prompt_ids, page_ids, *, temperature: float = 0.0,
                 slot: Optional[int] = None, lazy: bool = False):
@@ -1041,7 +1110,9 @@ class InferenceEngine:
     def _read_first(self, name, next_token, finite, poison) -> int:
         """A prefill's two reads: its first token, then its screen."""
         with self._read(name, "tokens"):
-            first = int(self._read_tokens(next_token).reshape(-1)[0])
+            first, counts = self._read_tokens(next_token)
+            first = int(first.reshape(-1)[0])
+        self._moe_counts, self._counts_due = counts, None
         with self._read(name, "finite"):
             self.last_prefill_finite = bool(finite) and poison is None
         return first
@@ -1114,7 +1185,8 @@ class InferenceEngine:
             compiled, self.cache, self._host_args("fork_page").pack(src, dst))
 
     def decode(self, tokens, lengths, page_tables, temps=None, *,
-               streams=None, gens=None, steps=None):
+               streams=None, gens=None, steps=None, carry=None,
+               lazy=False, read=None):
         """One decode iteration over the full slot array.  ``lengths``
         counts each slot's context INCLUDING the token being fed (0 =
         idle slot).  Returns ``(logits (B, V), next_tokens (B,))`` —
@@ -1138,47 +1210,122 @@ class InferenceEngine:
         ``k`` for every live slot), ``next_tokens`` comes back ``(k, B)``
         (row ``j`` is iteration ``j``'s token; a slot's rows from its
         ``steps`` on repeat its last) and ``logits`` is the last
-        iteration's."""
-        poison = self._chaos_gate(chaos.SERVE_DECODE, self.decode_iters)
+        iteration's.
+
+        One call in flight (docs/serving.md "Host phases"): ``carry``
+        ``(B,)`` marks the slots fed the token the previous decode call
+        sampled for them, which never left the device (their ``tokens``
+        entry is ignored; the call's phase carries ``overlapped``);
+        ``lazy=True`` reads nothing and returns the program's token array
+        as it is, still on the device — the call's index is
+        :attr:`decode_iters` on return, and :meth:`read_decode` reads it;
+        ``read=(next_tokens, call)`` reads such an earlier call right
+        behind this one's launch, inside this call's ``engine/decode``
+        phase (the step's one wait for the device), and its
+        :meth:`read_decode` then hands the result over.  An injected stall
+        (``serve.decode`` chaos) hangs a call where it is read."""
+        fault = self._chaos_gate(
+            chaos.SERVE_DECODE, self.decode_iters, sleep=False)
+        b, block = self.serve.max_batch, self.serve.decode_block
         with self._phase("engine/stage", program="decode"):
             compiled = self._program("decode")
             if streams is None:
-                b = self.serve.max_batch
                 streams = np.full((b,), self.decode_iters, np.uint32)
                 gens = np.arange(b, dtype=np.int32)
-            block = self.serve.decode_block
             if block > 1 and steps is None:
                 steps = np.where(np.asarray(lengths) > 0, block, 0)
             args = (
                 self.step_params, self.cache, self._rng_base,
+                self._decode_out,
                 self._host_args("decode").pack(
                     tokens, lengths, page_tables, self._temps(temps),
-                    streams, gens, *((steps,) if block > 1 else ()),
+                    streams, gens,
+                    np.zeros((b,), np.int32) if carry is None else carry,
+                    *((steps,) if block > 1 else ()),
                 ),
             )
         self.decode_iters += 1
+        call = self.decode_iters
         # np.asarray(next_tokens) syncs — real device time
-        with self._phase("engine/decode", iter=self.decode_iters,
+        with self._phase("engine/decode", iter=call,
                          batch=int((np.asarray(lengths) > 0).sum()),
+                         overlapped=carry is not None,
                          **self._span_kinds):
-            logits, next_tokens, finite, self.cache = self._dispatch(
-                "decode", compiled, *args)
+            try:
+                logits, next_tokens, finite, self.cache = self._dispatch(
+                    "decode", compiled, *args)
+            except BaseException:
+                self._decode_out = self._decode_zeros
+                raise
+            self._decode_out = next_tokens
+            self.unread_decodes[call] = _UnreadDecode(finite, fault, lengths)
+            if read is not None:
+                self._fetch(*read)
+            if lazy:
+                if self.routed:
+                    self._counts_due = (call, next_tokens)
+                return logits, next_tokens
+            self._fetch(next_tokens, call)
+            out, _, self._moe_counts = self._hand_over(call)
+            self._counts_due = None
+        return logits, out
+
+    def read_decode(self, next_tokens, call: int):
+        """Read a ``lazy`` :meth:`decode` call, given ``next_tokens`` as it
+        returned them and its index: ``(tokens, finite, counts)`` on the
+        host — the tokens as :meth:`decode` would have returned them, its
+        non-finite screen (also on :attr:`last_decode_finite`), a routed
+        model's MoE counts (else None).  A call a later one's ``read=``
+        read is handed over as it was read (what that read raised is raised
+        here); else it is read now, in an ``engine/decode`` phase of its
+        own with no launch under it."""
+        rec = self.unread_decodes[call]
+        if rec.got is None and rec.error is None:
+            with self._phase("engine/decode", iter=call, **self._span_kinds):
+                self._fetch(next_tokens, call)
+        got = self._hand_over(call)
+        if self._counts_due is not None and self._counts_due[0] == call:
+            self._moe_counts, self._counts_due = got[2], None
+        return got
+
+    def _fetch(self, next_tokens, call: int) -> None:
+        """A decode call's two reads, once — its tokens, then its screen —
+        onto its record; a read that raises leaves its error there."""
+        rec = self.unread_decodes[call]
+        if rec.got is not None or rec.error is not None:
+            return
+        if rec.fault is not None and rec.fault.mode == "stall":
+            # an injected hung call: the host sees the hang where it waits
+            time.sleep(rec.fault.stall_seconds)
+        try:
             with self._read("decode", "tokens"):
-                out = self._read_tokens(next_tokens)
-            if block > 1:
-                out = out.reshape(block, -1)
+                out, counts = self._read_tokens(next_tokens)
             with self._read("decode", "finite"):
-                finite_np = np.array(finite)
-        if poison is not None:
+                finite_np = np.array(rec.finite)
+        except Exception as e:
+            self._decode_out = self._decode_zeros
+            rec.error = e
+            return
+        if self.serve.decode_block > 1:
+            out = out.reshape(self.serve.decode_block, -1)
+        if rec.fault is not None and rec.fault.mode != "stall":
             # an injected poisoned-logits fault: flag the first LIVE
             # slot exactly as the in-step screen would flag a real
             # non-finite row — the quarantine path downstream is the
             # production path, only the evidence is simulated
-            live = np.flatnonzero(np.asarray(lengths) > 0)
+            live = np.flatnonzero(np.asarray(rec.lengths) > 0)
             if live.size:
                 finite_np[live[0]] = False
-        self.last_decode_finite = finite_np
-        return logits, out
+        rec.got = (out, finite_np, counts)
+
+    def _hand_over(self, call: int):
+        """A read decode call's host results, off its record (what its
+        read raised is raised here)."""
+        rec = self.unread_decodes.pop(call)
+        if rec.error is not None:
+            raise rec.error
+        self.last_decode_finite = rec.got[1]
+        return rec.got
 
     def reset_cache(self) -> None:
         """Re-zero the paged KV arrays (target AND draft).  Only legal

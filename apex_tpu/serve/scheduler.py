@@ -151,6 +151,18 @@ read (docs/serving.md "Host phases").  They go to the attached recorder and,
 with none attached, to the ring every process keeps
 (:func:`~apex_tpu.observability.spans.process_recorder`); the request
 lifecycle above is recorded on an attached recorder only.
+
+**One decode call in flight** (docs/serving.md "Host phases"): while no
+request waits, no chunked prefill runs, the pass is no speculative round
+and no rider ends with the call by its budget (:meth:`~ContinuousBatchingScheduler.
+_can_overlap`), a plain decode call is left unread past its step; the
+next step dispatches call N + 1, fed each rider's token from call N's
+output on the device, and reads call N after that launch — the host's
+reads, bookkeeping and launch run inside the next program.  A step reads
+one call: a call with a rider that ends with it, or one the rule no longer
+lets stay in flight, is read by the next step, which does nothing else.
+An EOS or a quarantine call N reveals is acted on when N is read, and the
+rider's N + 1 token is discarded.
 """
 
 from __future__ import annotations
@@ -389,6 +401,25 @@ class Request:
         }
 
 
+@dataclasses.dataclass
+class _DecodeCall:
+    """A plain decode call the scheduler dispatched, until it is read."""
+
+    #: its token array as ``engine.decode`` returned it
+    out: object
+    #: ``engine.decode_iters`` once it was dispatched: the call's index
+    index: int
+    #: ``(slot, request)`` of every slot that rode it
+    riders: list
+    #: ``(B,)`` iterations each slot runs
+    steps: np.ndarray
+    #: its dispatch on the scheduler's clock (a decode timeout runs from it)
+    t0: float
+    #: left in flight only until the next step starts, which reads it and
+    #: does nothing else: a rider ends with it
+    land: bool = False
+
+
 def declare_serve_metrics(registry, *, stateful: bool = False,
                           routed: bool = False, latent: bool = False,
                           paged: bool = True, ssm: bool = False) -> None:
@@ -420,6 +451,9 @@ def declare_serve_metrics(registry, *, stateful: bool = False,
         registry.gauge(g)
     for c in ("serve/admitted", "serve/completed", "serve/shed",
               "serve/tokens_out", "serve/prefills", "serve/decode_steps",
+              # decode calls dispatched with the call before them unread
+              # (docs/serving.md "Host phases": one call in flight)
+              "serve/decode_overlapped",
               # the failure/degradation ledger (docs/serving.md
               # "Failure semantics"): retries + re-admissions, clamped
               # admissions, per-request decode timeouts, engine faults
@@ -520,6 +554,9 @@ class ContinuousBatchingScheduler:
         # never waits for the host between two of them
         self._lazy_prefill = self.serve.decode_block > 1
         self._dispatched: List = []
+        # the plain decode call left in flight past a step, unread
+        # (:meth:`_can_overlap`); its riders' tokens are still on the device
+        self._flight: Optional[_DecodeCall] = None
         model_lib.validate_features(
             engine.cfg, prefix_cache=prefix_cache,
             chunked_prefill=prefill_chunk_tokens is not None,
@@ -627,17 +664,18 @@ class ContinuousBatchingScheduler:
 
     @property
     def pending(self) -> bool:
-        return bool(self.queue) or any(s is not None for s in self.slots)
+        return (bool(self.queue) or any(s is not None for s in self.slots)
+                or self._flight is not None)
 
     def slots_in_use(self) -> int:
         """Decode slots a request holds — for a model with recurrent
         layers, the slots whose state is some request's."""
         return sum(1 for r in self.slots if r is not None)
 
-    def _fold_moe(self) -> None:
-        """A routed model's last step's counts into the registry: Python
-        numbers the engine read inside the step's one token readback."""
-        pairs, touched = self.engine.last_moe_counts
+    def _fold_moe(self, counts) -> None:
+        """A routed model's counts of one call into the registry: Python
+        numbers the engine read inside the call's one token readback."""
+        pairs, touched = counts
         self._count("serve/moe/routed_local_tokens", float(pairs))
         self._count("serve/moe/experts_touched", float(touched))
 
@@ -878,6 +916,7 @@ class ContinuousBatchingScheduler:
             self._rebuild_pending = True
 
     def _run_rebuild(self) -> None:
+        self._land_flight()
         self._rebuild_pending = False
         self._rebuilds_started += 1
         self._count("serve/engine_rebuilds")
@@ -1123,7 +1162,7 @@ class ContinuousBatchingScheduler:
             self._shed_request(req, SHED_POISONED)
             return True
         if self._routed:
-            self._fold_moe()
+            self._fold_moe(self.engine.last_moe_counts)
         if self._ssm_slot_bytes:
             # the prompt's state-space state replaced the slot's
             self._count("serve/ssm/slots_written")
@@ -1255,14 +1294,11 @@ class ContinuousBatchingScheduler:
         return True
 
     def _finished(self, req: Request) -> bool:
-        if len(req.tokens) >= req.max_new_tokens:
-            return True
         if req.eos_token is not None and req.tokens and (
             req.tokens[-1] == req.eos_token
         ):
             return True
-        # context capacity: the NEXT fed token would not fit
-        return req.ctx_len + 1 > self.serve.max_context
+        return self._ends(req, 0)
 
     # -- decode -----------------------------------------------------------
     def _ensure_target_page(self, req: Request, idx: int) -> bool:
@@ -1293,26 +1329,36 @@ class ContinuousBatchingScheduler:
             req.pages.extend(got)
         return True
 
-    def _ensure_growth_page(self, req: Request, ahead: int = 1) -> bool:
-        """The next ``ahead`` appends land at positions ``ctx_len ..
-        ctx_len + ahead - 1`` (one, but for a decode block); allocate (or
-        COW-fork) their pages if needed."""
+    def _ensure_growth_page(self, req: Request, ahead: int = 1,
+                            held: int = 0) -> bool:
+        """The next ``ahead`` appends land at positions ``ctx_len + held
+        .. ctx_len + held + ahead - 1`` (one, but for a decode block;
+        ``held``: the tokens a call in flight holds for ``req``, their
+        positions already provisioned); allocate (or COW-fork) their
+        pages if needed."""
         ps = self.serve.page_size
+        at = req.ctx_len + held
         return all(
             self._ensure_target_page(req, idx)
-            for idx in range(
-                req.ctx_len // ps, (req.ctx_len + ahead - 1) // ps + 1
-            )
+            for idx in range(at // ps, (at + ahead - 1) // ps + 1)
         )
 
-    def _block_steps(self, req: Request) -> int:
+    def _block_steps(self, req: Request, held: int = 0) -> int:
         """Iterations ``req`` may ride of the next decode program: the
-        block, cut at its token budget and at the context's end."""
+        block, cut at its token budget and at the context's end (past the
+        ``held`` tokens a call in flight holds for it)."""
         return max(1, min(
             self.serve.decode_block,
-            req.max_new_tokens - len(req.tokens),
-            self.serve.max_context - req.ctx_len,
+            req.max_new_tokens - len(req.tokens) - held,
+            self.serve.max_context - req.ctx_len - held,
         ))
+
+    def _ends(self, req: Request, n: int) -> bool:
+        """Whether ``n`` more tokens end ``req`` by its token budget or the
+        context's end — the NEXT fed token would not fit (an EOS only the
+        tokens can tell: :meth:`_finished`)."""
+        return (len(req.tokens) + n >= req.max_new_tokens
+                or req.ctx_len + n + 1 > self.serve.max_context)
 
     def _ensure_spec_span(self, req: Request) -> bool:
         """Provision the whole speculative window BEFORE the round: a
@@ -1348,10 +1394,39 @@ class ContinuousBatchingScheduler:
         else:
             self._plain_decode_once(None)
 
+    def _can_overlap(self, ending: bool = False) -> bool:
+        """The rule for one decode call in flight (docs/serving.md "Host
+        phases"): the next plain decode call may be dispatched before this
+        one is read only when no request waits in the queue, no chunked
+        prefill is in progress, the pass is no speculative round, and no
+        rider ends with this call by its budget or the context's end
+        (``ending``) — a slot about to be refilled wants its read now."""
+        return not (
+            ending
+            or self.queue
+            or (self.engine.spec is not None and not self._spec_fallback)
+            or any(r is not None and r.status == PREFILLING
+                   for r in self.slots)
+        )
+
     def _plain_decode_once(self, only: Optional[set]) -> None:
         """One plain (single-token) decode iteration.  ``only`` limits
         the pass to the given slot indices (the non-speculative side of
-        a mixed batch); ``None`` rides every running slot."""
+        a mixed batch); ``None`` rides every running slot.
+
+        With a call in flight (:meth:`_can_overlap` held when it was
+        dispatched) the riders' host view is one call behind: this call
+        feeds each of them the token that call holds for it, still on the
+        device, at the position past it, and reads that call only after
+        its own launch.  A step reads one call: this one is left in
+        flight, if only until the next step starts (``land``)."""
+        # (a mixed speculative pass, ``only``, never finds one: the rule
+        # holds off speculation, and the step read it first)
+        flight = self._flight
+        held = {} if flight is None else {
+            i: int(flight.steps[i]) for i, r in flight.riders
+            if self.slots[i] is r
+        }
         with self._phase("serve/batch"):
             b = len(self.slots)
             tokens = np.zeros((b,), np.int32)
@@ -1359,12 +1434,14 @@ class ContinuousBatchingScheduler:
             temps = np.zeros((b,), np.float32)
             streams = np.zeros((b,), np.uint32)
             gens = np.zeros((b,), np.int32)
+            carry = np.zeros((b,), np.int32)
             # iterations each slot rides: one, but for a decode block
             block = self.serve.decode_block
             steps = np.zeros((b,), np.int32)
             tables = np.full(
                 (b, self.serve.max_pages_per_seq), NULL_PAGE, np.int32
             )
+            ending = aborted = False
             for i, req in enumerate(self.slots):
                 if req is None or req.status == PREFILLING:
                     # a prefilling slot rides no decode iteration — its
@@ -1372,8 +1449,12 @@ class ContinuousBatchingScheduler:
                     continue
                 if only is not None and i not in only:
                     continue
-                ahead = self._block_steps(req) if block > 1 else 1
-                if not self._ensure_growth_page(req, ahead):
+                h = held.get(i, 0)
+                ahead = self._block_steps(req, h) if block > 1 else 1
+                if not self._ensure_growth_page(req, ahead, h):
+                    if flight is not None:
+                        aborted = True
+                        break
                     # pool exhausted mid-decode: shed the youngest running
                     # request (least sunk cost) and retry this one
                     victims = sorted(
@@ -1397,45 +1478,98 @@ class ContinuousBatchingScheduler:
                             self.slots[i] = None
                             self._shed_request(req, SHED_POOL_EXHAUSTED)
                         continue
-                tokens[i] = req.tokens[-1]
-                lengths[i] = req.ctx_len + 1  # context incl. the fed token
+                # a held token is fed from the device (carry); the host's
+                # view of the slot moves past it
+                tokens[i] = 0 if h else req.tokens[-1]
+                carry[i] = 1 if h else 0
+                lengths[i] = req.ctx_len + h + 1  # context incl. the fed token
                 temps[i] = req.temperature
                 streams[i] = self._stream(req)
-                gens[i] = len(req.tokens) - 1
+                gens[i] = len(req.tokens) + h - 1
                 steps[i] = ahead
                 tables[i] = self._page_table_row(req)
+                ending |= self._ends(req, h + ahead)
+        if flight is not None and (aborted or not lengths.any()):
+            # no page to grow into past the call in flight, or no rider
+            # left to feed: read it, and the next step stages afresh (a
+            # growth victim is chosen with that call's tokens on the host)
+            self._land_flight()
+            return
         if not lengths.any():
             return
+        riders = [(int(i), self.slots[i]) for i in np.flatnonzero(lengths)]
+        # keep this call in flight past the step, unread, while the rule
+        # holds for it; else it is read before the step returns — or, with
+        # a call in flight, when the next step starts
+        keep = only is None and self._can_overlap(ending)
+        lazy = keep or flight is not None
         self._decode_lengths = lengths
+        self._riders += len(riders)
         t0 = self.clock()
         try:
-            _, next_tokens = self.engine.decode(
+            _, out = self.engine.decode(
                 tokens, lengths, tables, temps,
                 streams=streams, gens=gens,
                 **({"steps": steps} if block > 1 else {}),
+                **({} if flight is None else {
+                    "carry": carry, "read": (flight.out, flight.index)}),
+                **({"lazy": True} if lazy else {}),
             )
-            # (iterations, slots): one row, but for a decode block
-            next_tokens = np.asarray(next_tokens).reshape(block, b)
         except Exception as e:
-            # a crashed decode step produced nothing host-side: every
-            # rider keeps its prefix and pages and re-enters through
-            # bounded retry while the engine rebuilds under supervision
-            self._on_engine_fault(e)
+            # a crashed decode step produced nothing host-side: the call in
+            # flight is read first, then every rider keeps its prefix and
+            # pages and re-enters through bounded retry while the engine
+            # rebuilds under supervision (a read that raised was the fault)
+            if self._land_flight():
+                self._on_engine_fault(e)
             return
+        call = _DecodeCall(out, self.engine.decode_iters, riders, steps, t0,
+                           land=not keep)
+        if flight is not None:
+            self._count("serve/decode_overlapped")
+            if not self._land_flight():
+                # its riders went to retry with the fault: read at the next
+                # step's start, its tokens are discarded
+                call.land = True
+        if lazy:
+            self._flight = call
+        else:
+            # read inside the call, as it always was
+            self._retire_call(call, out, self.engine.last_decode_finite,
+                              self.engine.last_moe_counts)
+
+    def _land_flight(self) -> bool:
+        """Read the decode call in flight, if any, and retire its tokens;
+        a read that raises is an engine fault (False)."""
+        call, self._flight = self._flight, None
+        if call is None:
+            return True
+        try:
+            out, finite, counts = self.engine.read_decode(
+                call.out, call.index)
+        except Exception as e:
+            self._on_engine_fault(e)
+            return False
+        self._retire_call(call, out, finite, counts)
+        return True
+
+    def _retire_call(self, call: "_DecodeCall", next_tokens, finite,
+                     counts) -> None:
+        """A plain decode call's tokens, on the host, onto the riders that
+        still hold their slots: an EOS or a quarantine the call before it
+        revealed, or a growth shed, took a rider out while the call was in
+        flight, and its token is discarded."""
+        block, b = self.serve.decode_block, len(self.slots)
+        # (iterations, slots): one row, but for a decode block
+        next_tokens = np.asarray(next_tokens).reshape(block, b)
         with self._phase("serve/retire"):
-            elapsed_ms = 1e3 * (self.clock() - t0)
-            finite = self.engine.last_decode_finite
-            self._riders += int((lengths > 0).sum())
+            # a decode timeout runs from the call's dispatch to its read
+            elapsed_ms = 1e3 * (self.clock() - call.t0)
             self._count("serve/decode_steps")
             if self._routed:
-                self._fold_moe()
-            # engine-numbered iteration id: the correlation key linking a
-            # request's decode span to the engine batch iterations it rode
-            it = getattr(self.engine, "decode_iters", None)
-            for i, req in enumerate(self.slots):
-                if req is None or req.status == PREFILLING:
-                    continue
-                if only is not None and i not in only:
+                self._fold_moe(counts)
+            for i, req in call.riders:
+                if self.slots[i] is not req:
                     continue
                 if finite is not None and not bool(finite[i]):
                     # poisoned-request quarantine: a non-finite logits row
@@ -1462,11 +1596,13 @@ class ContinuousBatchingScheduler:
                         req, f"decode_timeout:{elapsed_ms:.0f}ms"
                     )
                     continue
-                if it is not None:
-                    if req.first_decode_iter is None:
-                        req.first_decode_iter = it
-                    req.last_decode_iter = it
-                for tok in next_tokens[: steps[i], i]:
+                # engine-numbered iteration id: the correlation key linking
+                # a request's decode span to the engine batch iterations it
+                # rode
+                if req.first_decode_iter is None:
+                    req.first_decode_iter = call.index
+                req.last_decode_iter = call.index
+                for tok in next_tokens[: call.steps[i], i]:
                     req.ctx_len += 1
                     req.tokens.append(int(tok))
                     self._tokens_out += 1
@@ -1758,46 +1894,21 @@ class ContinuousBatchingScheduler:
     # -- the iteration ----------------------------------------------------
     def step(self) -> None:
         """One continuous-batching iteration: admit (prefill) into free
-        slots, then one decode pass over the running batch."""
+        slots, then one decode pass over the running batch — or, where a
+        decode call in flight may not stay so, only its read."""
         with self._phase("serve/step", step=self._step) as ph:
             prefills = self.engine.prefill_calls
             tokens = self._tokens_out
             retired = len(self.completed) + len(self.shed)
             self._riders = 0
-            # admit until slots or pages run out — each prefill slots in
-            # between decode iterations by construction
-            while self._admit_one():
-                pass
-            if self._dispatched:
-                self._resolve_prefills()
-            if self.queue:
-                # admission gave up with requests still queued: they are
-                # resource-blocked (no slot / pool cannot cover the head)
-                # from here until the next admission attempt — the
-                # queue_wait TTFT component.  Only pre-first-token requests
-                # accrue it: a retrying request past its first token is in
-                # RECOVERY wait, which must not pollute TTFT attribution
-                # (the components would stop summing to the measured TTFT).
-                now = self.clock()
-                for r in self.queue:
-                    if r.first_token_at is None and r.blocked_since is None:
-                        r.blocked_since = now
-            if self.prefix is not None and chaos.active(
-                chaos.SERVE_PREFIX_EVICT, self._step
-            ) is not None:
-                # forced full eviction sweep (the ``serve.prefix_evict``
-                # chaos drill): every idle cached run is reclaimed at once
-                # — borrowed pages MUST survive (refcount > 1 is never
-                # evictable) and the ledger must stay exact, proven by the
-                # leak check right here
-                self._count("serve/prefix_evict_faults")
-                freed = self.prefix.evict()
-                if freed:
-                    self._count("serve/prefix_evictions", freed)
-                if self.leak_checks:
-                    self.leak_check()
-            self._advance_prefills()
-            self._decode_once()
+            if self._flight is not None and (
+                    self._flight.land or not self._can_overlap()):
+                # a rider ends with the call in flight, or the rule no
+                # longer holds (a request arrived): the step reads it and
+                # returns, so its tokens and its ends go out at once
+                self._land_flight()
+            else:
+                self._admit_and_decode()
             self._step += 1
             self._publish()
             # counts measured where the work happens: the engine's
@@ -1814,6 +1925,44 @@ class ContinuousBatchingScheduler:
             # the owed rebuild now, off the traffic path (run()/drain()
             # reach the same flush through their own exits)
             self.flush_rebuild()
+
+    def _admit_and_decode(self) -> None:
+        """A step's work with no call in flight to read first: admit
+        (prefill) into free slots, then one decode pass."""
+        # admit until slots or pages run out — each prefill slots in
+        # between decode iterations by construction
+        while self._admit_one():
+            pass
+        if self._dispatched:
+            self._resolve_prefills()
+        if self.queue:
+            # admission gave up with requests still queued: they are
+            # resource-blocked (no slot / pool cannot cover the head)
+            # from here until the next admission attempt — the
+            # queue_wait TTFT component.  Only pre-first-token requests
+            # accrue it: a retrying request past its first token is in
+            # RECOVERY wait, which must not pollute TTFT attribution
+            # (the components would stop summing to the measured TTFT).
+            now = self.clock()
+            for r in self.queue:
+                if r.first_token_at is None and r.blocked_since is None:
+                    r.blocked_since = now
+        if self.prefix is not None and chaos.active(
+            chaos.SERVE_PREFIX_EVICT, self._step
+        ) is not None:
+            # forced full eviction sweep (the ``serve.prefix_evict``
+            # chaos drill): every idle cached run is reclaimed at once
+            # — borrowed pages MUST survive (refcount > 1 is never
+            # evictable) and the ledger must stay exact, proven by the
+            # leak check right here
+            self._count("serve/prefix_evict_faults")
+            freed = self.prefix.evict()
+            if freed:
+                self._count("serve/prefix_evictions", freed)
+            if self.leak_checks:
+                self.leak_check()
+        self._advance_prefills()
+        self._decode_once()
 
     def run(self, max_steps: int = 10_000) -> None:
         """Drain: step until every submitted request completed or shed.

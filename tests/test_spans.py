@@ -904,11 +904,14 @@ class TestSchedulerPhases:
 
     def _stepwise(self, engine, spans=None, n=3, max_new=3, **kw):
         """Run a load one step at a time; per step, what it did as seen
-        from outside."""
+        from outside.  The synchronous path: no decode call is left in
+        flight past its step (tests/test_serve_overlap.py pins the phases
+        of the other)."""
         from apex_tpu.serve import ContinuousBatchingScheduler, Request
 
         sched = ContinuousBatchingScheduler(
             engine, registry=None, spans=spans, **kw)
+        sched._can_overlap = lambda ending=False: False
         rs = np.random.RandomState(1)
         reqs = [sched.submit(Request(
             prompt=[int(t) for t in rs.randint(0, 64, size=6)],
