@@ -135,6 +135,14 @@ class BertConfig:
     # MB), 47-53 s to compile cold against 6-14 s, ~3 s to load from the
     # compile cache.
     scan_layers: bool = True
+    # The mesh axis whose ranks hold the rows of one global batch, for a
+    # data-parallel step that averages its gradients over that axis:
+    # :func:`bert_pretrain_loss` then sums the MLM loss's numerator and its
+    # count of masked positions over the axis before the divide (the global
+    # mean, whatever each rank's share of masked positions) and scales the
+    # rank's term by the axis size, so that the averaged gradient is the
+    # global loss's.  None: the mean over this rank's rows.
+    mlm_mean_axis: Optional[str] = None
 
     def __post_init__(self):
         if self.remat_policy not in ("full", "dots", "sums"):
@@ -712,13 +720,19 @@ def bert_pretrain_loss(
                 s, c = chunk_fn(hc[i], lc[i], wc[i])
                 total = total + s
                 count = count + c
-            mlm_loss = total / jnp.maximum(count, 1.0)
         else:
             total, count = rows_loss(
                 h.reshape(-1, h.shape[-1]), labels.reshape(-1),
                 weights.reshape(-1),
             )
-            mlm_loss = total / jnp.maximum(count, 1.0)
+        axis = model.cfg.mlm_mean_axis
+        if axis is not None:
+            # the global mean: every rank's masked positions counted; the
+            # rank's term times the axis size, which the caller's gradient
+            # average divides out again
+            count = jax.lax.psum(count, axis)
+            total = total * jax.lax.axis_size(axis)
+        mlm_loss = total / jnp.maximum(count, 1.0)
 
     nsp_labels = batch.get("nsp_labels")
     nsp_loss = 0.0

@@ -91,6 +91,11 @@ class HybridConfig:
     topk_group: int = 1
     routed_scaling_factor: float = 1.0
     # -- MLA ---------------------------------------------------------------
+    #: 0: one query matrix; > 0: the low-rank query path ``q_a`` (hidden ->
+    #: q_lora_rank), an RMSNorm on its output, ``q_b`` (-> heads)
+    q_lora_rank: int = 0
+    #: Ling's head-wise sigmoid gate on MLA's output, by head
+    mla_output_gate: bool = True
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -235,14 +240,20 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, ffn: str) -> dict:
     else:
         dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                          cfg.v_head_dim, cfg.kv_lora_rank)
-        lp["mla"] = {
-            "q": {"weight": _mat(cfg, h, n * (dn + dr))},
+        qr = cfg.q_lora_rank
+        lp["mla"] = ({
+            "q_a": {"weight": _mat(cfg, h, qr)},
+            "q_a_norm": {"scale": _f32(qr)},
+            "q_b": {"weight": _mat(cfg, qr, n * (dn + dr))},
+        } if qr else {"q": {"weight": _mat(cfg, h, n * (dn + dr))}})
+        lp["mla"].update({
             "kv_a": {"weight": _mat(cfg, h, r + dr)},
             "kv_norm": {"scale": _f32(r)},
             "kv_b": {"weight": _mat(cfg, r, n * (dn + dv))},
-            "ogate": {"weight": _mat(cfg, h, n)},
             "out": {"weight": _mat(cfg, n * dv, h)},
-        }
+        })
+        if cfg.mla_output_gate:
+            lp["mla"]["ogate"] = {"weight": _mat(cfg, h, n)}
     if ffn == DENSE:
         i = cfg.intermediate_size
         lp["mlp"] = {"gate": {"weight": _mat(cfg, h, i)},

@@ -1,6 +1,7 @@
-"""Latent-attention (MLA) decode over the latent page pool: the Pallas
-kernel on TPU (:mod:`apex_tpu.ops.pallas.mla_decode`), a gather-the-pages
-jnp form of the same arithmetic elsewhere."""
+"""Latent-attention (MLA) over the latent page pool — a decode step's one
+query a sequence, and a prefill chunk's many: the Pallas kernels on TPU
+(:mod:`apex_tpu.ops.pallas.mla_decode`, :mod:`apex_tpu.ops.pallas.mla_chunk`),
+a gather-the-pages jnp form of the same arithmetic elsewhere."""
 
 from __future__ import annotations
 
@@ -9,7 +10,7 @@ import jax.numpy as jnp
 
 from apex_tpu.ops import _dispatch
 
-__all__ = ["latent_row_width", "mla_decode_attention"]
+__all__ = ["latent_row_width", "mla_decode_attention", "mla_chunk_attention"]
 
 _LANES = 128
 
@@ -50,3 +51,34 @@ def mla_decode_attention(q, pool, page_table, lengths, *, layer: int, scale):
             preferred_element_type=jnp.float32,
         )
         return jnp.where(l > 0, ctx / jnp.maximum(l, 1e-30), 0.0)
+
+
+def mla_chunk_attention(q, pool, page_table, offset, *, layer: int, scale):
+    """Absorbed-form attention of a prefill chunk.  ``q`` ``(C, heads, W)``
+    ``[q_n W_b^K | q_r | 0]``, query ``t`` at position ``offset + t``;
+    ``pool`` ``(L, P, 1, page, W)`` with the chunk's own rows written;
+    ``page_table`` ``(NP,)`` the sequence's pages.  Query ``t`` attends rows
+    ``0 .. offset + t``.  Returns the latent-space context ``(C, heads, W)``
+    f32."""
+    if _dispatch.use_pallas():
+        from apex_tpu.ops.pallas.mla_chunk import mla_chunk_fwd
+
+        _dispatch.record_path("mla_chunk", "pallas")
+        return mla_chunk_fwd(
+            q, pool, page_table, offset, layer=layer, scale=float(scale)
+        )
+    _dispatch.record_path("mla_chunk", "jnp")
+    with jax.named_scope("mla_chunk_fwd"):
+        c, _, w = q.shape
+        kv = pool[layer][page_table][:, 0].reshape(-1, w)
+        s = jnp.einsum(
+            "chw,tw->cht", q.astype(pool.dtype), kv,
+            preferred_element_type=jnp.float32,
+        ) * scale
+        live = jnp.arange(kv.shape[0])[None, :] <= (
+            offset + jnp.arange(c))[:, None]
+        p = jax.nn.softmax(jnp.where(live[:, None, :], s, -jnp.inf), -1)
+        return jnp.einsum(
+            "cht,tw->chw", p.astype(pool.dtype), kv,
+            preferred_element_type=jnp.float32,
+        )

@@ -316,7 +316,11 @@ def _prefill_step(eng, host, params, kv_pages, base_key, packed, *,
 def _chunk_step(eng, host, params, kv_pages, base_key, packed):
     (tokens, chunk_page_ids, page_table, length, offset, call,
      temp) = host.unpack(packed)
-    return model_lib.chunk_prefill_body(
+    # the model's kinds say which body: the GPT block's, or the layer loop
+    # of a stack whose layers differ in kind
+    body = (model_lib.chunk_prefill_body if eng.kinds is None
+            else model_lib.hybrid_chunk_prefill_body)
+    return body(
         eng.cfg, params, kv_pages, tokens, length, offset,
         chunk_page_ids, page_table, temp,
         model_lib.fold_in(base_key, call),
@@ -446,6 +450,9 @@ class _Program:
     spec: bool = False
     #: warmed by ``build(chunked=True)`` only
     chunked: bool = False
+    #: the prefix cache's own: not on a stack whose layers differ in kind
+    #: (which refuses the prefix cache)
+    prefix: bool = False
     #: ``rebuild()`` compiles a replacement (the incumbent serves until
     #: it is ready) ...
     rebuilt: bool = False
@@ -469,7 +476,7 @@ _PROGRAMS: Dict[str, _Program] = {p.kind: p for p in (
              functools.partial(_prefill_step, draft=True), _draft_args,
              per_bucket=True, spec=True, dropped=True),
     _Program("fork_page", _fork_fields, _fork_step,
-             lambda eng: (eng.cache,), donate=0, chunked=True),
+             lambda eng: (eng.cache,), donate=0, chunked=True, prefix=True),
     _Program("decode", _decode_fields, _decode_step, _decode_args,
              rebuilt=True),
     _Program("draft_decode", _slot_fields, _draft_step, _draft_args,
@@ -771,7 +778,8 @@ class InferenceEngine:
         order."""
         return [
             prog for prog in _PROGRAMS.values()
-            if self.spec is not None or not prog.spec
+            if (self.spec is not None or not prog.spec)
+            and (self.kinds is None or not prog.prefix)
         ]
 
     def _trace_args(self, kind: str, bucket: Optional[int] = None):
@@ -1167,9 +1175,10 @@ class InferenceEngine:
                 ),
             )
         self.prefill_calls += 1
-        with self._phase("engine/prefill", bucket=bucket, tokens=n,
-                         offset=int(offset), call=self.prefill_calls,
-                         chunked=True):
+        with self._phase("engine/prefill", program=name, bucket=bucket,
+                         tokens=n, offset=int(offset),
+                         call=self.prefill_calls, chunked=True,
+                         **self._span_kinds):
             logits, next_token, finite, self.cache = self._dispatch(
                 name, compiled, *args)
             first = self._read_first(name, next_token, finite, poison)

@@ -33,8 +33,10 @@ state-space branch with a per-slot state and a grouped-query attention
 branch over K/V pages, side by side), ``"dense"`` (SwiGLU) or ``"moe"``
 (a dropless routed layer told which experts it holds, plus a shared
 expert) — over RMSNorm and an untied head, and runs as a Python loop over
-its layers through the same :func:`_block`.  Its two dataflows are the
-prompt (:class:`_Flow` with ``prompt=True``) and the decode step; the
+its layers through the same :func:`_block`.  Its dataflows are the
+prompt (:class:`_Flow` with ``prompt=True``), the prefill chunk over the
+latent pages of a stack with no recurrent layer (``prompt=True`` with an
+``offset``: :func:`hybrid_chunk_prefill_body`) and the decode step; the
 cache set they carry is :func:`apex_tpu.serve.cache.init_hybrid_cache`'s
 (docs/serving.md "Layer kinds and the cache set").
 
@@ -42,8 +44,9 @@ Serving scope: a single model shard (no SP/CP).  What a kind cannot run is
 refused by name (:func:`validate_config`, :func:`validate_features`): a
 model with recurrent layers has no prefix cache, copy-on-write fork,
 chunked prefill or speculative program (each needs a snapshot of the
-per-slot state); the routed FFN runs one chip's share only; training runs
-none of these kinds.  **Weight wires**: :func:`quantize_params` /
+per-slot state), and no hybrid stack has the prefix cache or the
+speculative programs; the routed FFN runs one chip's share only; training
+runs none of these kinds.  **Weight wires**: :func:`quantize_params` /
 :func:`dequantize_params` put the large parameter leaves on the
 blockwise int8 code of ``parallel/comm.py`` (small leaves — biases, LN
 affines — stay exact, mirroring ``sync_gradients``'s ``min_size``
@@ -67,7 +70,11 @@ from apex_tpu.ops import kda as kda_ops
 from apex_tpu.ops import ssm as ssm_ops
 from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.layer_norm import fused_layer_norm_affine
-from apex_tpu.ops.mla import latent_row_width, mla_decode_attention
+from apex_tpu.ops.mla import (
+    latent_row_width,
+    mla_chunk_attention,
+    mla_decode_attention,
+)
 from apex_tpu.ops.paged_attention import (
     gather_history,
     paged_decode_attention,
@@ -93,6 +100,7 @@ __all__ = [
     "sample_tokens",
     "prefill_body",
     "chunk_prefill_body",
+    "hybrid_chunk_prefill_body",
     "decode_body",
 ]
 
@@ -117,7 +125,8 @@ def is_routed(cfg) -> bool:
     return bool(getattr(cfg, "routed", False))
 
 
-#: what serving refuses, for which kind of model, and why
+#: what serving refuses, for which kind of model, and why: a model with
+#: recurrent layers ...
 _REFUSED = {
     "prefix_cache": (
         "prefix cache (and its copy-on-write fork): a cached page run "
@@ -134,6 +143,23 @@ _REFUSED = {
     ),
 }
 
+#: ... and a stack whose layers differ in kind but keep no recurrent state
+#: (latent attention throughout), which chunks its prompts
+_REFUSED_HYBRID = {
+    "prefix_cache": (
+        "prefix cache (and its copy-on-write fork): the cache commits and "
+        "borrows the GPT stack's K/V page runs, and a hit re-runs the "
+        "prompt's final chunk through the GPT chunk body; the latent pages "
+        "are not committed or borrowed, nor forked on a shared tail page"
+    ),
+    "spec": (
+        "speculative programs (draft / verify / rollback): the draft model, "
+        "the verify scan and the rollback program are written over the GPT "
+        "block and its K/V pool; none reads the latent pages or the routed "
+        "FFN"
+    ),
+}
+
 
 def validate_config(cfg):
     """What serving runs, by kind of model; what it refuses, by name:
@@ -144,7 +170,7 @@ def validate_config(cfg):
       capacity-dropping path is a training path; a routed FFN is served
       through :class:`~apex_tpu.models.hybrid.HybridConfig`'s dropless
       ``"moe"`` kind;
-    - a model with recurrent layers: :func:`validate_features`."""
+    - a stack whose layers differ in kind: :func:`validate_features`."""
     if cfg.sequence_parallel or cfg.context_parallel:
         raise ValueError(
             "serving requires sequence_parallel=False and "
@@ -162,15 +188,23 @@ def validate_config(cfg):
 def validate_features(cfg, **asked) -> None:
     """Refuse, by name, each serving mechanism in ``asked``
     (``prefix_cache``, ``chunked_prefill``, ``spec``: truthy = wanted)
-    that a model with recurrent layers cannot run."""
-    if not is_stateful(cfg):
+    that a stack whose layers differ in kind cannot run: with recurrent
+    layers, none of the three; with none, the prefix cache and the
+    speculative programs (it chunks its prompts)."""
+    if layer_kinds(cfg) is None:
         return
+    stateful = is_stateful(cfg)
+    refused = _REFUSED if stateful else _REFUSED_HYBRID
     for name, wanted in asked.items():
-        if wanted:
+        if wanted and name in refused:
+            if stateful:
+                raise ValueError(
+                    f"a model with recurrent (KDA or state-space) layers "
+                    f"does not run the {refused[name]} (ROADMAP M5)"
+                )
             raise ValueError(
-                f"a model with recurrent (KDA or state-space) layers does "
-                f"not run the "
-                f"{_REFUSED[name]} (ROADMAP M5)"
+                f"a stack whose layers differ in kind does not run the "
+                f"{refused[name]} (ROADMAP M4)"
             )
 
 
@@ -630,17 +664,21 @@ def _write_prompt(kv, layer, page_ids, k, v):
 class _Flow:
     """The dataflow of one hybrid step — what a mixer needs beyond its
     rows.  Prompt: ``length`` live rows from position 0, ``page_ids`` the
-    prompt's pages, ``slot`` the decode slot whose state it will leave.
-    Decode: ``lengths`` per slot (0 = idle), ``page_tables``.  ``live``
-    ``(T,)`` marks the real rows: padding and idle rows leave every state
-    as it was and reach no expert.  ``stats`` collects each routed layer's
-    counts (the layer loop is a Python loop)."""
+    prompt's pages, ``slot`` the decode slot whose state it will leave; a
+    prefill chunk besides: ``offset`` the position of its first row and
+    ``page_tables`` the sequence's whole table ``(NP,)``.  Decode:
+    ``lengths`` per slot (0 = idle), ``page_tables``.  ``live`` ``(T,)``
+    marks the real rows: padding and idle rows leave every state as it was
+    and reach no expert.  ``stats`` collects each routed layer's counts
+    (the layer loop is a Python loop)."""
 
     def __init__(self, *, prompt, live, positions, page_size, length=None,
-                 page_ids=None, slot=None, lengths=None, page_tables=None):
+                 page_ids=None, slot=None, lengths=None, page_tables=None,
+                 offset=None):
         self.prompt, self.live, self.positions = prompt, live, positions
         self.length, self.page_ids, self.slot = length, page_ids, slot
         self.lengths, self.page_tables = lengths, page_tables
+        self.offset = offset
         self.page_size = page_size
         self.stats = []
 
@@ -741,11 +779,23 @@ def _kda_mixer(cfg, lp, x, kv, layer, flow):
     return x + out.astype(dtype), kv
 
 
+def _mla_query(cfg, p, y, dtype):
+    """MLA's queries ``(T, heads * (dn + dr))`` f32 from the normed rows:
+    one matrix, or the low-rank path ``q_b(RMSNorm(q_a y))``."""
+    if not cfg.q_lora_rank:
+        return _matmul(y, p["q"]["weight"], dtype)
+    c_q = _rms_norm(_matmul(y, p["q_a"]["weight"], dtype), p["q_a_norm"],
+                    cfg.rms_eps)
+    return _matmul(c_q, p["q_b"]["weight"], dtype)
+
+
 def _mla_mixer(cfg, lp, x, kv, layer, flow):
     """Multi-head latent attention.  The cache holds one row a token, ``[c
     | k_r | 0]``: the normalised latent and the rotated key all heads
-    share.  A prompt attends un-absorbed over its own rows; a decode step
-    attends in absorbed form over the latent pages
+    share.  A single-bucket prompt attends un-absorbed over its own rows; a
+    prefill chunk writes its rows and then attends in absorbed form over the
+    latent pages, the rows before its offset and its own; a decode step
+    appends its row and attends in absorbed form over the pages
     (:mod:`apex_tpu.ops.mla`)."""
     p, dtype = lp["mla"], cfg.dtype
     n, r = cfg.num_heads, cfg.kv_lora_rank
@@ -754,18 +804,28 @@ def _mla_mixer(cfg, lp, x, kv, layer, flow):
     t = x.shape[0]
     scale = (dn + dr) ** -0.5
     y = _rms_norm(x, lp["norm_mixer"], cfg.rms_eps).astype(dtype)
-    q = _matmul(y, p["q"]["weight"], dtype).reshape(t, n, dn + dr)
+    q = _mla_query(cfg, p, y, dtype).reshape(t, n, dn + dr)
     a = _matmul(y, p["kv_a"]["weight"], dtype)
     c = _rms_norm(a[:, :r], p["kv_norm"], cfg.rms_eps).astype(dtype)
     k_r = _rope_partial(a[:, r:], flow.positions, cfg.rope_theta)
     q_r = _rope_partial(q[..., dn:], flow.positions, cfg.rope_theta)
-    gate = jax.nn.sigmoid(_matmul(y, p["ogate"]["weight"], dtype))
+    gate = jax.nn.sigmoid(_matmul(y, p["ogate"]["weight"], dtype)) \
+        if cfg.mla_output_gate else None
     w = latent_row_width(r, dr)
     row = jnp.concatenate(
         [c, k_r.astype(dtype), jnp.zeros((t, w - r - dr), dtype)], axis=-1)
     w_b = p["kv_b"]["weight"].reshape(r, n, dn + dv)
     f32 = dict(preferred_element_type=jnp.float32)
-    if flow.prompt:
+
+    def absorbed():
+        # [q_n W_b^K | q_r | 0]: each head's query in latent space
+        q_abs = jnp.einsum(
+            "bnd,rnd->bnr", q[..., :dn].astype(dtype),
+            w_b[..., :dn].astype(dtype), **f32)
+        return jnp.concatenate(
+            [q_abs, q_r, jnp.zeros((t, n, w - r - dr), jnp.float32)], -1)
+
+    if flow.prompt and flow.offset is None:
         kv = cache_lib.write_prompt_latent(kv, li, flow.page_ids, row)
         with jax.named_scope("mla_prefill_attention"):
             kvb = _matmul(c, p["kv_b"]["weight"], dtype).reshape(
@@ -781,29 +841,35 @@ def _mla_mixer(cfg, lp, x, kv, layer, flow):
                 "nqk,knd->qnd", jax.nn.softmax(s, axis=-1).astype(dtype),
                 kvb[..., dn:], **f32)
     else:
-        pos = flow.positions
-        # an idle row (a free slot, or one past its steps of a decode
-        # block) writes into the null page, whatever its table holds
-        pages = jnp.where(
-            flow.live, flow.page_tables[jnp.arange(t), pos // flow.page_size],
-            cache_lib.NULL_PAGE,
-        )
-        kv = cache_lib.append_token_latent(
-            kv, li, pages, pos % flow.page_size, row)
-        q_abs = jnp.einsum(
-            "bnd,rnd->bnr", q[..., :dn].astype(dtype),
-            w_b[..., :dn].astype(dtype), **f32)
-        q_row = jnp.concatenate(
-            [q_abs, q_r, jnp.zeros((t, n, w - r - dr), jnp.float32)], -1)
-        ctx = mla_decode_attention(
-            q_row, kv["latent"], flow.page_tables, flow.lengths,
-            layer=li, scale=scale,
-        )
+        if flow.prompt:
+            # a chunk: its rows first, so that it reads them back with the
+            # rows before its offset
+            kv = cache_lib.write_prompt_latent(kv, li, flow.page_ids, row)
+            ctx = mla_chunk_attention(
+                absorbed(), kv["latent"], flow.page_tables, flow.offset,
+                layer=li, scale=scale,
+            )
+        else:
+            pos = flow.positions
+            # an idle row (a free slot, or one past its steps of a decode
+            # block) writes into the null page, whatever its table holds
+            pages = jnp.where(
+                flow.live,
+                flow.page_tables[jnp.arange(t), pos // flow.page_size],
+                cache_lib.NULL_PAGE,
+            )
+            kv = cache_lib.append_token_latent(
+                kv, li, pages, pos % flow.page_size, row)
+            ctx = mla_decode_attention(
+                absorbed(), kv["latent"], flow.page_tables, flow.lengths,
+                layer=li, scale=scale,
+            )
         o = jnp.einsum(
             "bnr,rnd->bnd", ctx[..., :r].astype(dtype),
             w_b[..., dn:].astype(dtype), **f32)
-    out = _matmul(
-        (o * gate[:, :, None]).reshape(t, n * dv), p["out"]["weight"], dtype)
+    if gate is not None:
+        o = o * gate[:, :, None]
+    out = _matmul(o.reshape(t, n * dv), p["out"]["weight"], dtype)
     return x + out.astype(dtype), kv
 
 
@@ -982,6 +1048,43 @@ def _hybrid_prefill(cfg, params, cache, tokens, length, page_ids, slot,
     h_last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(length - 1, 0), 1, 0)
     out = _sample_tail(_hybrid_logits(cfg, tree, h_last)[0], temp, rng, top_k)
     return *_with_counts(cfg, flow, out), cache
+
+
+def hybrid_chunk_prefill_body(
+    cfg,
+    params,
+    kv_pages: dict,
+    tokens,          # (C, 1) int32 — one chunk, bucket-padded
+    length,          # ()     int32 — live tokens in THIS chunk
+    offset,          # ()     int32 — absolute position of tokens[0]
+    chunk_page_ids,  # (C/page,) int32 — the chunk's pages, null-padded
+    page_table,      # (NP,)  int32 — the sequence's full page table
+    temp=None,
+    rng=None,
+    *,
+    page_size: int,
+    top_k: int = 0,
+):
+    """:func:`chunk_prefill_body` for a stack whose layers differ in kind
+    and none of which keeps a recurrent state: the chunk's rows at
+    ``offset ...`` through the same layer loop, each latent-attention layer
+    writing its rows and attending over the latent pages
+    (:func:`_mla_mixer`), the FFNs as a prompt's.  Same arguments and
+    returns; a routed model's ``next_token`` carries the MoE counts
+    (:func:`_with_counts`)."""
+    tree = _tree(params)
+    c = tokens.shape[0]
+    x = _hybrid_embed(cfg, tree, tokens[:, 0])
+    flow = _Flow(
+        prompt=True, live=jnp.arange(c) < length,
+        positions=offset + jnp.arange(c, dtype=jnp.int32),
+        page_size=page_size, length=length, page_ids=chunk_page_ids,
+        page_tables=page_table, offset=offset,
+    )
+    x, kv_pages = _layers(cfg, tree, x, kv_pages, flow)
+    h_last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(length - 1, 0), 1, 0)
+    out = _sample_tail(_hybrid_logits(cfg, tree, h_last)[0], temp, rng, top_k)
+    return *_with_counts(cfg, flow, out), kv_pages
 
 
 def _hybrid_decode(cfg, tree, cache, tokens, lengths, page_tables,

@@ -445,12 +445,18 @@ def declare_serve_metrics(registry, *, stateful: bool = False,
         registry.gauge("serve/ssm/state_bytes_per_iter", "bytes")
     if latent:
         registry.gauge("serve/latent/pages_in_use")
+        # latent rows the step's decode and chunk calls read, summed over
+        # the latent-attention layers (from the lengths the host holds)
+        registry.counter("serve/latent_rows_read")
     for g in ("serve/queue_depth", "serve/batch_fill",
               "serve/page_occupancy", "serve/tokens_per_s",
               "serve/ttft_ms", "serve/draining"):
         registry.gauge(g)
     for c in ("serve/admitted", "serve/completed", "serve/shed",
               "serve/tokens_out", "serve/prefills", "serve/decode_steps",
+              # page-multiple prefill chunks run (docs/serving.md
+              # "Prefix caching & chunked prefill")
+              "serve/prefill_chunks",
               # decode calls dispatched with the call before them unread
               # (docs/serving.md "Host phases": one call in flight)
               "serve/decode_overlapped",
@@ -540,6 +546,9 @@ class ContinuousBatchingScheduler:
         # recurrent slabs, and of the state-space one alone
         kinds = getattr(engine, "cache_kinds", ())
         self._latent = any(k.name == "latent" for k in kinds)
+        # each latent-attention layer reads its own rows
+        self._latent_layers = sum(
+            k.layers for k in kinds if k.name == "latent")
         self._slot_state_bytes = sum(
             k.row_bytes for k in kinds if k.per == "slot" and k.in_place)
         self._ssm_slot_bytes = sum(
@@ -1115,9 +1124,12 @@ class ContinuousBatchingScheduler:
         if not resumed:
             req.admitted_at = now
         if self.spans is not None:
+            # a chunked prompt's first chunk's bucket (it may be longer
+            # than the largest)
             self.spans.request_event(
                 req.rid, "prefill", now,
-                bucket=self.engine.bucket_for(len(text)),
+                bucket=self.engine.bucket_for(
+                    min(len(text), self.prefill_chunk_tokens or len(text))),
                 prompt_tokens=len(text), pages=len(pages),
                 **({"cached_tokens": req.cache_hit_tokens}
                    if req.cache_hit_tokens else {}),
@@ -1234,6 +1246,13 @@ class ContinuousBatchingScheduler:
             self.slots[slot] = None
             self._shed_request(req, SHED_POISONED)
             return
+        self._count("serve/prefill_chunks")
+        if self._latent:
+            # the chunk's queries read every row up to its end
+            self._count("serve/latent_rows_read",
+                        float(self._latent_layers * end))
+        if self._routed:
+            self._fold_moe(self.engine.last_moe_counts)
         req.prefill_pos = end
         if end == n:
             self._finish_prefill(req, slot, first)
@@ -1505,6 +1524,13 @@ class ContinuousBatchingScheduler:
         lazy = keep or flight is not None
         self._decode_lengths = lengths
         self._riders += len(riders)
+        if self._latent:
+            # iteration j of a slot reads its context at lengths + j
+            its = steps.astype(np.int64) if block > 1 else (
+                lengths > 0).astype(np.int64)
+            self._count("serve/latent_rows_read", float(
+                self._latent_layers * int(
+                    (its * lengths + its * (its - 1) // 2).sum())))
         t0 = self.clock()
         try:
             _, out = self.engine.decode(

@@ -22,6 +22,7 @@ sys.path.insert(
 )
 
 import argparse
+import dataclasses
 import tempfile
 import time
 
@@ -199,6 +200,11 @@ def main(argv=None):
     dp = ps.get_data_parallel_world_size()
     if args.batch % dp:
         raise SystemExit(f"--batch must divide dp={dp}")
+    if dp > 1:
+        # the MLM loss is the mean over the global batch's masked positions,
+        # not the mean of the ranks' means (each rank's rows hold their own
+        # number of them); the step averages the gradients over dp
+        cfg = dataclasses.replace(cfg, mlm_mean_axis=ps.DATA_PARALLEL_AXIS)
     if args.max_predictions_per_seq < 0:
         raise SystemExit("--max-predictions-per-seq must be >= 0")
 

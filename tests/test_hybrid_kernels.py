@@ -181,3 +181,46 @@ def test_mla_absorbed_decode_matches_unabsorbed_attention(path):
         p /= p.sum(-1, keepdims=True)
         want = np.einsum("nt,tnd->nd", p, kv[..., dn:])
         np.testing.assert_allclose(got[i], want, atol=2e-2)
+
+
+@pytest.mark.parametrize("offset,c", [(0, 32), (40, 32), (96, 16), (120, 64)])
+def test_mla_absorbed_chunk_matches_unabsorbed_attention(path, offset, c):
+    """A prefill chunk of ``c`` queries at ``offset``: query t over rows 0
+    .. offset + t, the chunk's own rows already in the pages, against
+    per-head keys and values rebuilt from the latent with a causal mask;
+    bf16 pages and probabilities: 2e-2 on contexts of O(1).  Offsets at
+    zero, mid-page, on a page and past several query blocks."""
+    rs = np.random.RandomState(offset + c)
+    n, r, dr, dn, dv, page, np_ = 4, 32, 8, 16, 16, 8, 24
+    w = mla.latent_row_width(r, dr)
+    pool = np.zeros((2, 40, 1, page, w), np.float32)
+    pool[..., : r + dr] = rs.randn(2, 40, 1, page, r + dr)
+    pool = jnp.asarray(pool, jnp.bfloat16)
+    w_b = jnp.asarray(rs.randn(r, n, dn + dv) / 6, jnp.float32)
+    q = jnp.asarray(rs.randn(c, n, dn + dr), jnp.float32)
+    live = -(-(offset + c) // page)
+    table = np.zeros((np_,), np.int32)
+    table[:live] = rs.choice(np.arange(1, 40), live, replace=False)
+    scale = (dn + dr) ** -0.5
+    q_abs = jnp.einsum("cnd,rnd->cnr", q[..., :dn], w_b[..., :dn])
+    q_row = jnp.concatenate(
+        [q_abs, q[..., dn:], jnp.zeros((c, n, w - r - dr))], -1)
+    ctx = mla.mla_chunk_attention(
+        q_row, pool, jnp.asarray(table), jnp.int32(offset), layer=1,
+        scale=scale)
+    assert _dispatch.last_paths()["mla_chunk"] == (
+        "pallas" if path else "jnp")
+    got = np.asarray(jnp.einsum("cnr,rnd->cnd", ctx[..., :r], w_b[..., dn:]))
+    lat = np.asarray(pool[1].astype(jnp.float32))[table][:, 0].reshape(-1, w)
+    lat = lat[: offset + c]
+    c_, k_r = lat[:, :r], lat[:, r:r + dr]
+    kv = np.einsum("tr,rnd->tnd", c_, np.asarray(w_b))
+    s = (np.einsum("cnd,tnd->cnt", np.asarray(q[..., :dn]), kv[..., :dn])
+         + np.einsum("cnd,td->cnt", np.asarray(q[..., dn:]), k_r)) * scale
+    pos = np.arange(offset + c)
+    s = np.where(pos[None, None, :] <= offset + np.arange(c)[:, None, None],
+                 s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    want = np.einsum("cnt,tnd->cnd", p, kv[..., dn:])
+    np.testing.assert_allclose(got, want, atol=2e-2)

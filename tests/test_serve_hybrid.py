@@ -344,10 +344,14 @@ def test_refusals_name_the_mechanism(small):
         InferenceEngine(pcfg, params, ServeConfig(), spec=SpecConfig(None, k=2))
     with pytest.raises(ValueError, match="int8"):
         InferenceEngine(pcfg, params, ServeConfig(kv_wire="int8"))
-    # a stack without recurrent layers is not refused these
-    serve_model.validate_features(
-        ling_serve.program_config(tiny(layer_group_size=1)),
-        prefix_cache=True, chunked_prefill=True, spec=True)
+    # a stack without recurrent layers chunks its prompts, and is refused
+    # the prefix cache and the speculative programs for reasons of its own
+    mla_only = ling_serve.program_config(tiny(layer_group_size=1))
+    serve_model.validate_features(mla_only, chunked_prefill=True)
+    with pytest.raises(ValueError, match="prefix cache.*latent pages"):
+        serve_model.validate_features(mla_only, prefix_cache=True)
+    with pytest.raises(ValueError, match="speculative programs"):
+        serve_model.validate_features(mla_only, spec=True)
 
 
 # -- counters, gauges, the program table --------------------------------------

@@ -18,8 +18,8 @@ from apex_tpu import analysis
 from apex_tpu.models.gpt import GptConfig, GptModel
 from apex_tpu.ops import _dispatch
 from apex_tpu.ops.pallas import (
-    decode_attention, flash_attention, kda, layer_norm, mla_decode,
-    moe_grouped, ssm,
+    decode_attention, flash_attention, kda, layer_norm, mla_chunk,
+    mla_decode, moe_grouped, ssm,
 )
 from apex_tpu.serve import cache as cache_lib
 from apex_tpu.serve import model as model_lib
@@ -44,7 +44,7 @@ def lower_as_chip(monkeypatch):
     ``jax.default_backend()`` still says cpu here."""
     monkeypatch.setattr(_dispatch, "use_pallas", lambda: True)
     for mod in (_dispatch, decode_attention, flash_attention, layer_norm,
-                kda, mla_decode, moe_grouped, ssm):
+                kda, mla_chunk, mla_decode, moe_grouped, ssm):
         monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
 
 
@@ -414,6 +414,93 @@ def test_falcon_h1_step_never_moves_its_cache_set(
     # what a chip holds: weights + cache set + temporaries, under 16 GB
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 12e9 < held < 13.5e9, held
+
+
+#: GLM-4.7-Flash's first five layers, every expert and the whole vocabulary
+#: as benchmark/configs/glm-4.7-flash-5l.json serves them
+GLM_5L = dict(
+    vocab_size=154880, hidden_size=2048, num_layers=5, num_heads=20,
+    head_dim=256, intermediate_size=10240, max_seq_len=202752,
+    pattern=(("mla", "dense"),) + (("mla", "moe"),) * 4,
+    num_experts=64, held_experts=(0, 64), moe_intermediate_size=1536,
+    shared_intermediate_size=1536, top_k=4, routed_scaling_factor=1.8,
+    q_lora_rank=768, mla_output_gate=False, kv_lora_rank=512,
+    qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+    rope_theta=1e6, rms_eps=1e-5,
+)
+GLM_PAGES, GLM_SLOTS, GLM_PAGES_PER_SEQ, GLM_CHUNK = 50177, 32, 1568, 2048
+
+
+@pytest.mark.parametrize("program", ["serve_chunk_prefill_2048",
+                                     "serve_decode"])
+def test_glm_chunk_and_decode_programs_at_the_cells_shapes(
+    one_chip, lower_as_chip, program
+):
+    """The latent-attention stack's chunk and decode programs at the
+    benchmark's shapes, on the bf16 weights as installed: the latent pool
+    stays one buffer from entry to exit (`memory-pool-copy`), the chunk
+    attention is the Pallas kernel and holds no (heads, chunk, context)
+    f32 scores — 20 x 2,048 x 25,088 x 4 B would be 4.1 GB; XLA's
+    temporaries stay under 0.5 GB — and weights, pool and temporaries fit
+    a 16 GB chip."""
+    from apex_tpu.models.hybrid import HybridConfig, param_shapes
+
+    cfg = HybridConfig(**GLM_5L)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip
+            ),
+            tree,
+        )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = on_chip(param_shapes(cfg))
+    cache = on_chip(jax.eval_shape(lambda: cache_lib.init_hybrid_cache(
+        cfg, GLM_PAGES, PAGE, GLM_SLOTS
+    )))
+    assert cache["latent"].shape == (5, GLM_PAGES, 1, PAGE, 640)
+    if program == "serve_decode":
+        def fn(params, kv, tokens, lengths, tables, temps, rng):
+            return model_lib.decode_body(
+                cfg, params, kv, tokens, lengths, tables, temps, rng,
+                page_size=PAGE)
+        args = (
+            arg((GLM_SLOTS,), jnp.int32), arg((GLM_SLOTS,), jnp.int32),
+            arg((GLM_SLOTS, GLM_PAGES_PER_SEQ), jnp.int32),
+            arg((GLM_SLOTS,), jnp.float32), arg((GLM_SLOTS, 2), jnp.uint32),
+        )
+        names = ("mla_decode_fwd", "moe_grouped_fwd")
+    else:
+        def fn(params, kv, tokens, length, offset, ids, table, temp, rng):
+            return model_lib.hybrid_chunk_prefill_body(
+                cfg, params, kv, tokens, length, offset, ids, table, temp,
+                rng, page_size=PAGE)
+        args = (
+            arg((GLM_CHUNK, 1), jnp.int32), arg((), jnp.int32),
+            arg((), jnp.int32), arg((GLM_CHUNK // PAGE,), jnp.int32),
+            arg((GLM_PAGES_PER_SEQ,), jnp.int32), arg((), jnp.float32),
+            arg((2,), jnp.uint32),
+        )
+        names = ("mla_chunk_fwd", "moe_grouped_fwd")
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args
+    ).compile()
+    text = compiled.as_text()
+    report = analysis.lint_hlo(
+        text, donated=1, rules=("memory", "donation"),
+        expect_pool={"shapes": [cache["latent"].shape]},
+    )
+    assert report.findings == [], report.render()
+    for name in names:
+        assert name in text, name
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 11.5e9 < held < 12.5e9, held
 
 
 def test_ssm_kernels_compile_at_falcon_h1s_widths(one_chip, lower_as_chip):
